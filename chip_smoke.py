@@ -1,0 +1,464 @@
+"""chip_smoke.py: the quickest proof that the system still starts on the chip.
+
+One process on a machine with a TPU drives both hot paths once through
+the entry points a user calls, at the full width of a model the repo
+supports, with random weights made from a seed:
+
+- **train**   ResNet-50 v1 (NHWC, space-to-depth stem, bf16, batch 128,
+              224x224) stepped through ``gluon.Trainer.compile_step``;
+- **serve**   ``LLMServer`` over ``TinyDecoder`` at head_dim 128 answering
+              eight requests whose steps mix prefill chunks with decodes;
+- **kernels** every Pallas kernel the package dispatches to by default on
+              a TPU, compiled by Mosaic and compared with its ``jnp``
+              reference on the chip.
+
+Every phase checks what came out and prints one JSON line; a failed check
+raises, so the exit code is non-zero. The last line of standard output is
+``{"ok": true, "device": {...}}``. Times printed here are information for
+the builder, not metrics.
+
+    python chip_smoke.py             # one chip
+    python chip_smoke.py --chips 4   # the same two hot paths over a mesh
+
+It refuses to run where JAX finds no TPU. The phases are plain functions
+that take their sizes as arguments, so ``tests/test_chip_smoke.py``
+rehearses the control flow on the CPU at toy sizes; only :func:`main`
+fixes the full sizes.
+"""
+import argparse
+import json
+import sys
+import time
+
+#: kernel-vs-reference bounds, fixed from the dtype before any chip run.
+#: bf16 flash attention: the repo's own bf16 bound (tests/test_attention.py).
+#: f32 ragged attention: VPU arithmetic, so only exp/divide differ from XLA.
+#: int8-weight matmul: bounded by a bf16 pass over f32 activations, which is
+#: below the 1/127 step of the weights themselves.
+FLASH_BF16_TOL = 0.05
+RAGGED_F32_TOL = 1e-4
+WQ_MATMUL_TOL = 1e-2
+
+
+def check(ok, what):
+    """A phase check. Raises (never ``assert``: -O must not skip it)."""
+    if not ok:
+        raise RuntimeError(f"chip_smoke check failed: {what}")
+
+
+def _counters():
+    """(XLA compiles, persistent-cache hits, persistent-cache misses) from
+    the jax.monitoring bridge. A compile request counts as a compile
+    whether or not the persistent cache answered it."""
+    from mxnet_tpu.observability import (get_registry,
+                                         install_jax_monitoring_bridge)
+    install_jax_monitoring_bridge()
+    reg = get_registry()
+    events = reg.counter("mxtpu_xla_events_total", labelnames=("event",))
+    return (int(reg.counter("mxtpu_xla_compile_total").value),
+            int(reg.counter("mxtpu_xla_cache_hits_total").value),
+            int(events.labels(
+                event="/jax/compilation_cache/cache_misses").value))
+
+
+def _report(phase, started, counters0, setup_s, run_s, **facts):
+    """Print the phase's one JSON line."""
+    import jax
+    dev = jax.devices()[0]
+    compiles, hits, misses = (b - a for a, b in
+                              zip(counters0, _counters()))
+    line = {"phase": phase, "platform": dev.platform,
+            "device_kind": dev.device_kind,
+            "device_count": len(jax.devices()), "jax": jax.__version__,
+            "setup_s": round(setup_s, 2), "run_s": round(run_s, 3),
+            "phase_s": round(time.monotonic() - started, 2),
+            "xla_compiles": compiles, "cache_hits": hits,
+            "cache_misses": misses}
+    line.update(facts)
+    print(json.dumps(line), flush=True)
+    return line
+
+
+def _on_platform(arr, platform):
+    return all(d.platform == platform for d in arr.devices())
+
+
+def _check_spread(arrays, n_devices, what):
+    """Several chips: every array must really span the mesh, and every
+    device must hold bytes — a mesh that put everything on the first
+    device would pass a sharding check on paper only."""
+    import jax
+    for a in arrays:
+        check(len(a.sharding.device_set) == n_devices,
+              f"{what}: array spans {len(a.sharding.device_set)} of "
+              f"{n_devices} devices")
+    for d in jax.devices()[:n_devices]:
+        stats = d.memory_stats()
+        if stats is not None:       # the CPU backend reports none
+            check(stats["bytes_in_use"] > 0,
+                  f"{what}: nothing resident on {d}")
+
+
+# ------------------------------------------------------------------ train --
+
+def train_phase(make_net, image, batch, dtype="bfloat16", warmup=2,
+                steps=10, mesh=None):
+    """Step ``make_net()`` through ``Trainer.compile_step`` on batches
+    already on the device, built as ``bench.py:bench_train_compiled``
+    builds it. ``mesh`` (e.g. ``"dp=4"``) shards the step; ``batch`` is
+    then the global batch."""
+    import jax
+    import numpy as np
+    import mxnet_tpu as mx
+    import mxnet_tpu.autograd as ag
+    from mxnet_tpu import nd, parallel
+    from mxnet_tpu.gluon import Trainer
+    from mxnet_tpu.observability import get_registry
+
+    started, c0 = time.monotonic(), _counters()
+    dev = jax.devices()[0]
+    if mesh is not None:
+        mesh = parallel.parse_mesh(mesh)
+    mx.random.seed(0)
+    net = make_net()
+    net.initialize(init=mx.initializer.Xavier())
+    in_shape = (1, image, image, 3)
+    with ag.pause(train_mode=False):
+        net(nd.NDArray(np.ones(in_shape, np.float32)))
+    net.cast(dtype)
+
+    def stage(a):
+        a = nd.NDArray(jax.device_put(a, dev))
+        return a if mesh is None else parallel.shard_batch(a, mesh)
+
+    n_batches = min(4, warmup + steps)
+    xs = [stage(np.random.RandomState(100 + i).randn(
+        batch, *in_shape[1:]).astype(dtype)) for i in range(n_batches)]
+    ys = [stage(np.random.RandomState(200 + i).randint(
+        0, 1000, (batch,)).astype(np.int32)) for i in range(n_batches)]
+
+    def loss_fn(x, y):
+        logp = mx.nd.log_softmax(net(x).astype("float32"), axis=-1)
+        return -mx.nd.pick(logp, y, axis=1)
+
+    params = net.collect_params()
+    trainer = Trainer(params, "sgd",
+                      {"learning_rate": 1e-3, "momentum": 0.9})
+    step = trainer.compile_step(loss_fn, mesh=mesh)
+    fallback = get_registry().counter("mxtpu_train_step_fallback_total",
+                                      labelnames=("reason",))
+
+    def fallbacks():
+        return sum(c.value for c in fallback.children())
+
+    fallbacks0 = fallbacks()
+    probe = [p for p in params.values() if p.grad_req != "null"][0]
+    before = probe.data().asnumpy().astype(np.float32)
+
+    losses = []
+
+    def run(first, n):
+        for i in range(first, first + n):
+            losses.append(step(xs[i % n_batches], ys[i % n_batches]))
+            check(step.last_reason is None,
+                  f"step {i} fell back to eager: {step.last_reason}")
+        losses[-1]._data.block_until_ready()
+
+    run(0, warmup)
+    setup_s = time.monotonic() - started
+    compiles_warm = _counters()[0]
+    t0 = time.monotonic()
+    run(warmup, steps)
+    run_s = time.monotonic() - t0
+    # whether block_until_ready waited: if it had returned early, this
+    # fetch of the same value would have to wait for the device instead
+    t0 = time.monotonic()
+    last = losses[-1].asnumpy()
+    fetch_s = time.monotonic() - t0
+
+    check(_counters()[0] == compiles_warm,
+          "XLA compiled again after the warm-up steps")
+    check(step.cache_size() == 1,
+          f"{step.cache_size()} step programs compiled, expected 1")
+    check(fallbacks() == fallbacks0,
+          "mxtpu_train_step_fallback_total moved: a step ran eagerly")
+    check(all(np.isfinite(l.asnumpy()).all() for l in losses),
+          "loss is not finite")
+    check(last.shape == (batch,), f"loss shape {last.shape}")
+    check(not np.array_equal(
+        before, probe.data().asnumpy().astype(np.float32)),
+        "weights did not change")
+    live = [p.data()._data for p in params.values()] \
+        + [a._data for a in xs + ys]
+    check(all(_on_platform(a, dev.platform) for a in live),
+          f"parameters or batches are not on a {dev.platform} device")
+    if mesh is not None:
+        _check_spread(live, mesh.devices.size, "train")
+    return _report(
+        "train", started, c0, setup_s, run_s, batch=batch, image=image,
+        dtype=dtype, mesh=None if mesh is None else dict(mesh.shape),
+        steps=steps, step_programs=step.cache_size(),
+        final_loss=round(float(last.mean()), 4),
+        fetch_after_block_s=round(fetch_s, 4),
+        block_until_ready_waits=bool(fetch_s < 0.05 * run_s))
+
+
+# ------------------------------------------------------------------ serve --
+
+def serve_phase(config, prompt_lens, new_tokens, n_reference=2,
+                max_seqs=8, block_size=16, mesh=None, precision=None):
+    """Answer ``len(prompt_lens)`` requests submitted together through
+    ``LLMServer`` and compare ``n_reference`` of the generations with
+    ``greedy_decode_reference`` on the same device. ``precision`` pins
+    ``jax_default_matmul_precision`` for the server's programs and the
+    reference alike (None leaves the backend's default)."""
+    import jax
+    import numpy as np
+    from mxnet_tpu.serving.llm import LLMServer
+    from mxnet_tpu.serving.llm.model import (TinyDecoder,
+                                             greedy_decode_reference)
+
+    started, c0 = time.monotonic(), _counters()
+    platform = jax.devices()[0].platform
+    # the engine's worker thread must see it, so the global, not the
+    # thread-local context manager
+    prev_precision = jax.config.jax_default_matmul_precision
+    jax.config.update("jax_default_matmul_precision", precision)
+    try:
+        model = TinyDecoder(config)
+        params = jax.device_put(model.init_params(seed=0))
+        rs = np.random.RandomState(7)
+        prompts = [rs.randint(0, config.vocab_size, n).tolist()
+                   for n in prompt_lens]
+        server = LLMServer(model, params, name="chip_smoke", mesh=mesh,
+                           max_seqs=max_seqs, block_size=block_size)
+        engine = server.engine
+        # the dispatch decision, read off the program the engine built:
+        # only a Mosaic-compiled kernel lowers to a tpu_custom_call
+        kernels = engine.step_program_text().count("tpu_custom_call")
+        check((kernels > 0) == (platform == "tpu"),
+              f"{kernels} compiled Pallas kernels in the step program "
+              f"on {platform}")
+        warm = server.warmup()
+        setup_s = time.monotonic() - started
+        compiles_warm = _counters()[0]
+        before = server.stats()
+        server.start()
+        t0 = time.monotonic()
+        futures = [server.submit(p, new_tokens) for p in prompts]
+        results = [f.result(timeout=600) for f in futures]
+        run_s = time.monotonic() - t0
+        compiles_load = _counters()[0] - compiles_warm
+        server.shutdown()
+        stats = {k: v - before[k] for k, v in server.stats().items()
+                 if k in ("requests_failed", "requests_completed",
+                          "prefill_chunks", "decode_steps")}
+
+        check(compiles_load == 0,
+              f"{compiles_load} XLA compiles between warmup() and the "
+              "last token")
+        check(stats["requests_failed"] == 0
+              and stats["requests_completed"] == len(prompts),
+              f"requests: {stats['requests_completed']} completed, "
+              f"{stats['requests_failed']} failed")
+        for r in results:
+            check(len(r.tokens) == new_tokens
+                  and all(0 <= t < config.vocab_size for t in r.tokens),
+                  f"request {r.seq_id}: bad generation {r.tokens[:8]}")
+        check(stats["prefill_chunks"] > len(prompts)
+              and stats["decode_steps"] > 0,
+              "steps did not mix prefill chunks with decodes")
+        pools = [engine.cache.k_pages, engine.cache.v_pages]
+        check(all(_on_platform(a, platform) for a in pools),
+              f"KV pools are not on a {platform} device")
+        if mesh is not None:
+            sharded = pools + [engine._params["layers"][0]["wq"]]
+            _check_spread(sharded, engine.mesh.devices.size, "serve")
+        for i in range(n_reference):
+            want = greedy_decode_reference(model, params, prompts[i],
+                                           new_tokens)
+            check(results[i].tokens == want,
+                  f"request {i} differs from greedy_decode_reference: "
+                  f"{results[i].tokens} != {want}")
+    finally:
+        jax.config.update("jax_default_matmul_precision", prev_precision)
+    return _report(
+        "serve", started, c0, setup_s, run_s, model=config.to_dict(),
+        head_dim=config.head_dim, max_seqs=max_seqs,
+        block_size=block_size, mesh=mesh, prompt_lens=list(prompt_lens),
+        new_tokens=new_tokens, warmed_programs=len(warm),
+        attention="pallas_compiled" if kernels else "jnp_reference",
+        compiles_during_load=compiles_load,
+        decode_steps=stats["decode_steps"],
+        prefill_chunks=stats["prefill_chunks"],
+        parity={"compared": f"greedy tokens of {n_reference} requests "
+                            "== greedy_decode_reference, token for token",
+                "matmul_precision": precision or "backend default"})
+
+
+# ---------------------------------------------------------------- kernels --
+
+def _rel_err(got, want):
+    import numpy as np
+    got, want = (np.asarray(a, np.float32) for a in (got, want))
+    return float(np.abs(got - want).max() / max(np.abs(want).max(), 1e-30))
+
+
+def kernel_cases(attn_shape, ragged_shape, matmul_shape, interpret=False):
+    """``(name, kernel, reference, args, bound)`` for each Pallas kernel
+    the package dispatches to by default on a TPU."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from mxnet_tpu.ops.flash_attention import (attention_reference,
+                                               flash_attention)
+    from mxnet_tpu.ops.quantization import (quantized_matmul,
+                                            quantized_matmul_reference)
+    from mxnet_tpu.ops.ragged_attention import (
+        ragged_flat_attention, ragged_flat_attention_reference)
+
+    rs = np.random.RandomState(3)
+
+    # flash attention forward and backward, with and without bias
+    B, H, T, D = attn_shape
+    q, k, v, g = (jnp.asarray(rs.randn(B, H, T, D), jnp.bfloat16)
+                  for _ in range(4))
+    bias = jnp.asarray(np.where(rs.rand(B, T) < 0.9, 0.0, -1e9),
+                       jnp.float32)
+
+    def flash(q, k, v, *b):
+        return flash_attention(q, k, v, *b, interpret=interpret)
+
+    def grads(fn):
+        return jax.grad(lambda q, k, v, *b: jnp.sum(
+            fn(q, k, v, *b).astype(jnp.float32) * g), argnums=(0, 1, 2))
+
+    def in_f32(fn):
+        return lambda *a: fn(*(x.astype(jnp.float32) for x in a))
+
+    for tag, args in (("", (q, k, v)), ("_bias", (q, k, v, bias))):
+        yield ("flash_fwd" + tag, flash, in_f32(attention_reference), args,
+               FLASH_BF16_TOL)
+        yield ("flash_bwd" + tag, grads(flash),
+               in_f32(grads(attention_reference)), args, FLASH_BF16_TOL)
+
+    # ragged flat attention over float and int8 pages
+    Tq, Hr, Dr, bs, N, MB = ragged_shape
+    S = 4
+    qr = jnp.asarray(rs.randn(Tq, Hr, Dr), jnp.float32)
+    pages = rs.randn(2, N, bs, Hr, Dr).astype(np.float32)
+    tables = jnp.asarray(rs.permutation(N - 1)[:S * MB].reshape(S, MB) + 1,
+                         jnp.int32)
+    sids = jnp.asarray(rs.randint(0, S, Tq), jnp.int32)
+    poss = jnp.asarray(rs.randint(0, MB * bs, Tq), jnp.int32)
+    scales = np.abs(pages).max(axis=-1) / 127.0          # [2, N, bs, H]
+    qpages = np.clip(np.round(pages / scales[..., None]), -127,
+                     127).astype(np.int8)
+    for name, (kp, vp), kw in (
+            ("ragged_flat_f32", pages, {}),
+            ("ragged_flat_int8", qpages,
+             {"k_scales": jnp.asarray(scales[0]),
+              "v_scales": jnp.asarray(scales[1])})):
+        yield (name,
+               lambda q, kp, vp, kw=kw: ragged_flat_attention(
+                   q, kp, vp, tables, sids, poss, use_pallas=True,
+                   interpret=interpret, **kw),
+               lambda q, kp, vp, kw=kw: ragged_flat_attention_reference(
+                   q, kp, vp, tables, sids, poss, **kw),
+               (qr, jnp.asarray(kp), jnp.asarray(vp)), RAGGED_F32_TOL)
+
+    # weight-only int8 matmul
+    Tm, K, Nm = matmul_shape
+    x = jnp.asarray(rs.randn(Tm, K), jnp.float32)
+    w = rs.randn(K, Nm).astype(np.float32) / np.sqrt(K)
+    ws = np.abs(w).max(axis=0) / 127.0
+    wq = jnp.asarray(np.clip(np.round(w / ws), -127, 127), jnp.int8)
+    yield ("quantized_matmul_int8",
+           lambda x, wq, ws: quantized_matmul(
+               x, wq, ws, use_pallas=True, interpret=interpret),
+           quantized_matmul_reference, (x, wq, jnp.asarray(ws)),
+           WQ_MATMUL_TOL)
+
+
+def kernels_phase(attn_shape, ragged_shape, matmul_shape, interpret=False):
+    """Run every :func:`kernel_cases` kernel against its ``jnp``
+    reference (evaluated at ``highest`` matmul precision) on the default
+    device. ``interpret=True`` is the CPU rehearsal."""
+    import jax
+    import numpy as np
+
+    started, c0 = time.monotonic(), _counters()
+    errs = {}
+    run_s = 0.0
+    for name, kernel, reference, args, tol in kernel_cases(
+            attn_shape, ragged_shape, matmul_shape, interpret):
+        with jax.default_matmul_precision("highest"):
+            want = jax.block_until_ready(jax.jit(reference)(*args))
+        fn = jax.jit(kernel)
+        jax.block_until_ready(fn(*args))        # compile
+        t0 = time.monotonic()
+        got = jax.block_until_ready(fn(*args))
+        run_s += time.monotonic() - t0
+        pairs = list(zip(jax.tree.leaves(got), jax.tree.leaves(want)))
+        check(all(np.isfinite(np.asarray(g, np.float32)).all()
+                  and g.shape == w.shape for g, w in pairs),
+              f"{name}: non-finite or mis-shaped output")
+        errs[name] = max(_rel_err(g, w) for g, w in pairs)
+        check(errs[name] <= tol,
+              f"{name}: error {errs[name]:.3g} over bound {tol}")
+    return _report(
+        "kernels", started, c0, time.monotonic() - started - run_s, run_s,
+        interpret=interpret, attn_shape=list(attn_shape),
+        ragged_shape=list(ragged_shape), matmul_shape=list(matmul_shape),
+        rel_err={k: float(f"{e:.3g}") for k, e in errs.items()})
+
+
+# ------------------------------------------------------------------- main --
+
+def main(argv=None):
+    ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
+    ap.add_argument("--chips", type=int, default=1, choices=(1, 4),
+                    help="4 runs train (dp=4) and serve (tp=4) over a "
+                         "four-chip mesh in this one process")
+    args = ap.parse_args(argv)
+
+    import jax
+    devices = jax.devices()
+    if devices[0].platform != "tpu":
+        sys.exit(f"chip_smoke.py needs a TPU; jax found "
+                 f"{devices[0].platform!r}")
+    if len(devices) < args.chips:
+        sys.exit(f"--chips {args.chips} but jax found {len(devices)}")
+
+    from mxnet_tpu.gluon.model_zoo import vision
+    from mxnet_tpu.runtime import enable_compile_cache
+    from mxnet_tpu.serving.llm.model import DecoderConfig
+    print(json.dumps({"compile_cache": enable_compile_cache()}),
+          flush=True)
+
+    many = args.chips > 1
+    train_phase(
+        lambda: vision.resnet50_v1(layout="NHWC", stem_s2d=True),
+        image=224, batch=128 * args.chips,
+        mesh=f"dp={args.chips}" if many else None)
+    serve_phase(
+        DecoderConfig(vocab_size=32000, d_model=1024, num_layers=8,
+                      num_heads=8, d_ff=4096, max_context=2048),
+        prompt_lens=(16, 48, 96, 160, 240, 320, 416, 512), new_tokens=32,
+        mesh=f"tp={args.chips}" if many else None,
+        # the TPU's default float32 matmul rounds operands to bf16, and
+        # the paged step and the dense reference round different
+        # intermediates; pinned so that equal means equal
+        precision="highest")
+    if not many:
+        kernels_phase(attn_shape=(8, 12, 512, 64),
+                      ragged_shape=(64, 8, 128, 16, 257, 32),
+                      matmul_shape=(256, 1024, 4096))
+    dev = devices[0]
+    print(json.dumps({"ok": True, "device": {
+        "platform": dev.platform, "kind": dev.device_kind,
+        "count": len(devices)}}), flush=True)
+
+
+if __name__ == "__main__":
+    main()

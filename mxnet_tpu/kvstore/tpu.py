@@ -44,10 +44,7 @@ def _enable_cpu_collectives():
                  or getattr(jax.config, "jax_platforms", None) or "")
     if platforms and "cpu" not in str(platforms).split(","):
         return  # explicitly pinned to an accelerator: nothing to do
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:
-        pass  # older/newer jax without the flag: keep the default
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
 
 def init_process_group(coordinator_address=None, num_processes=None,

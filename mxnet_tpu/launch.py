@@ -9,14 +9,19 @@ address / world size / rank via MXNET_TPU_* env vars, and
 ``mx.kv.create("dist_sync")``) joins them into one ``jax.distributed``
 job whose collectives run compiled.
 
-Single-host, N processes (the reference's ``--launcher local``):
-    python -m mxnet_tpu.launch -n 4 train.py --epochs 1
+Single-host, N CPU processes (the reference's ``--launcher local``):
+    python -m mxnet_tpu.launch -n 4 --cpu train.py --epochs 1
 
 Multi-host: run the same command once per host with ``--coordinator
 HOST0:PORT --num-hosts H --host-rank k`` — ranks are assigned
-host-major. (On TPU pods, prefer one process per host with jax's own
-cluster bootstrap; this launcher is for CPU/GPU-style process groups
-and tests.)
+host-major.
+
+A chip belongs to one process at a time, and workers inherit one
+environment, so N workers on a TPU host would each open every chip.
+Without ``--cpu`` the launcher therefore starts ONE worker per host
+(``-n 1``) and refuses more: that one process drives all of the host's
+chips over a mesh (``Trainer.compile_step(mesh="dp=4")``,
+``LLMServer(mesh="tp=4")``).
 """
 from __future__ import annotations
 
@@ -55,7 +60,16 @@ def launch(n, command, coordinator=None, num_hosts=1, host_rank=0,
     _NUM_WORKERS / _RANK plus the reference-compatible DMLC_* names.
     ``timeout`` (seconds) bounds the whole group — a rank that hangs in
     the distributed join (e.g. a peer died before connecting) is torn
-    down rather than blocking forever."""
+    down rather than blocking forever. Raises ``ValueError`` for
+    ``n > 1`` without ``cpu``: see the module docstring."""
+    if n > 1 and not cpu:
+        raise ValueError(
+            f"-n {n} without --cpu: {n} workers on one host would each "
+            "open every chip of it, and a chip belongs to one process. "
+            "Drive the host's chips from ONE process over a mesh "
+            "(Trainer.compile_step(mesh='dp=4'), LLMServer(mesh='tp=4')), "
+            "start one worker per host (-n 1 --num-hosts H), or pass "
+            "--cpu for a CPU process group")
     if coordinator is None:
         coordinator = f"127.0.0.1:{_free_port()}"
     world = n * num_hosts
@@ -159,10 +173,13 @@ def main(argv=None):
     command = args.command
     if command[0].endswith(".py"):
         command = [sys.executable] + command
-    return launch(args.num_workers, command,
-                  coordinator=args.coordinator,
-                  num_hosts=args.num_hosts, host_rank=args.host_rank,
-                  cpu=args.cpu)
+    try:
+        return launch(args.num_workers, command,
+                      coordinator=args.coordinator,
+                      num_hosts=args.num_hosts, host_rank=args.host_rank,
+                      cpu=args.cpu)
+    except ValueError as e:
+        parser.error(str(e))
 
 
 if __name__ == "__main__":

@@ -10,9 +10,9 @@ tool is a thin CLI. Three entry points:
   a capture, grouped by fusion-family prefix;
 - :func:`diff` — the before/after report between two captures (the A/B
   evidence a kernel PR must show);
-- :func:`summary` — a compact JSON-able digest ``perf_capture`` embeds
-  into ``BENCH_rNN.json``, so a bench artifact carries its own
-  attribution instead of a bare MFU scalar.
+- :func:`summary` — a compact JSON-able digest for a benchmark record
+  to embed, so that it carries its own attribution instead of a bare
+  MFU scalar.
 
 The scan wrapper (``while.*``) is excluded everywhere: XLA counts a
 scan body once, so the inner ops already represent one step times the

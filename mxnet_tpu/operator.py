@@ -155,14 +155,6 @@ def custom(*inputs, op_type, **kwargs):
 
     str_kwargs = {k: v for k, v in kwargs.items() if k != "_training"}
     is_train = bool(kwargs.get("_training", False))
-    from .ops.invoke import _host_callback_device
-    if _host_callback_device() is not None and any(
-            isinstance(x, jax.core.Tracer) for x in inputs):
-        raise RuntimeError(
-            "custom ops inside jit/hybridize need host-callback support, "
-            "which this accelerator platform lacks; run the block "
-            "un-hybridized (the eager path reroutes the callback to the "
-            "CPU backend)")
     in_shapes = [tuple(x.shape) for x in inputs]
     in_dtypes = [x.dtype for x in inputs]
     prop, op, out_shapes, out_dtypes = _resolve(

@@ -316,7 +316,11 @@ def _wq_matmul_pallas(x, qw, w_scale, block_t, block_n, interpret):
             pl.BlockSpec((1, block_n), lambda i, j: (0, j)),
         ],
         out_specs=pl.BlockSpec((block_t, block_n), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((T, N), jnp.float32),
+        # inside a shard_map body the output varies over the mesh axes
+        # either operand does (none outside one)
+        out_shape=jax.ShapeDtypeStruct(
+            (T, N), jnp.float32,
+            vma=jax.typeof(x).vma | jax.typeof(qw).vma),
         interpret=interpret,
     )(x, qw, w_scale.reshape(1, N))
 

@@ -155,12 +155,25 @@ def ragged_flat_attention_reference(q, k_pages, v_pages, block_tables,
 # ----------------------------------------------------------- pallas --
 
 
-def _flat_kernel(sid_ref, pos_ref, bt_ref, q_ref, k_ref, v_ref,
-                 o_ref, acc_ref, m_ref, l_ref, *, scale, block_size,
-                 num_blocks):
+def _flat_kernel(sid_ref, pos_ref, bt_ref, q_ref, k_ref, v_ref, *refs,
+                 scale, block_size, num_blocks):
     """Grid (T, MB): the decode kernel generalized to per-TOKEN
     sequence indirection — the page DMA for grid step (t, j) is
-    index-mapped through ``block_tables[seq_ids[t], j]``."""
+    index-mapped through ``block_tables[seq_ids[t], j]``.
+
+    ``refs`` is ``(o, acc, m, l)`` for float pages, or ``(k_scales,
+    v_scales, o, acc, m, l)`` for QUANTIZED pages: the K/V tiles then
+    arrive int8/fp8 with per-slot per-head f32 scale tiles (same index
+    map), and ``page * scale`` is fused right where the tile lands in
+    VMEM — the f32 pages never materialize in HBM.
+
+    One query token against one page is a matrix-vector product per
+    head, so both contractions run on the VPU in the page's own
+    ``(bs, H, D)`` layout (multiply, then reduce lanes for q.k and
+    the leading axis for p.v): Mosaic has no batched matmul with the
+    batch axis in the middle of one operand and no free axis on the
+    other, and this form needs no in-kernel transpose."""
+    *scale_refs, o_ref, acc_ref, m_ref, l_ref = refs
     t, j = pl.program_id(0), pl.program_id(1)
 
     @pl.when(j == 0)
@@ -177,111 +190,20 @@ def _flat_kernel(sid_ref, pos_ref, bt_ref, q_ref, k_ref, v_ref,
         q = q_ref[...].astype(jnp.float32)            # (H, D)
         k = k_ref[...].astype(jnp.float32)            # (bs, H, D)
         v = v_ref[...].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (2,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32) * scale
-        pos = base + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_size), 1)
+        if scale_refs:
+            ks_ref, vs_ref = scale_refs               # (bs, H)
+            k = k * ks_ref[...][..., None]
+            v = v * vs_ref[...][..., None]
+        s = jnp.sum(q[None] * k, axis=-1,
+                    keepdims=True) * scale            # (bs, H, 1)
+        pos = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
         s = jnp.where(pos <= qpos, s, _NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
+        m_prev = m_ref[...]                           # (H, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
+        p = jnp.exp(s - m_new[None])
         alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1,
-                                                  keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
-
-    @pl.when(j == num_blocks - 1)
-    def _finalize():
-        l_safe = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[...] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnums=(6, 7))
-def _ragged_flat_pallas(q, k_pages, v_pages, block_tables, seq_ids,
-                        positions, scale, interpret):
-    T, H, D = q.shape
-    bs = k_pages.shape[1]
-    MB = block_tables.shape[1]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(T, MB),
-        in_specs=[
-            pl.BlockSpec((None, H, D),
-                         lambda t, j, sid, pos, bt: (t, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((None, bs, H, D),
-                         lambda t, j, sid, pos, bt:
-                         (bt[sid[t], j], 0, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((None, bs, H, D),
-                         lambda t, j, sid, pos, bt:
-                         (bt[sid[t], j], 0, 0, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((None, H, D),
-                               lambda t, j, sid, pos, bt: (t, 0, 0),
-                               memory_space=pltpu.VMEM),
-        scratch_shapes=[
-            pltpu.VMEM((H, D), jnp.float32),
-            pltpu.VMEM((H, 1), jnp.float32),
-            pltpu.VMEM((H, 1), jnp.float32),
-        ],
-    )
-    kernel = functools.partial(_flat_kernel, scale=scale,
-                               block_size=bs, num_blocks=MB)
-    return pl.pallas_call(
-        kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((T, H, D), q.dtype),
-        interpret=interpret,
-    )(seq_ids.astype(jnp.int32), positions.astype(jnp.int32),
-      block_tables.astype(jnp.int32), q, k_pages, v_pages)
-
-
-def _flat_quant_kernel(sid_ref, pos_ref, bt_ref, q_ref, k_ref, v_ref,
-                       ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref, *,
-                       scale, block_size, num_blocks):
-    """The flat kernel's QUANTIZED-page variant: identical grid and
-    online softmax, but the K/V page tiles arrive int8 with per-slot
-    per-head f32 scale tiles (same ``bt[sid[t], j]`` index map), and
-    dequantization ``int8 * scale`` is fused right where the tile
-    lands in VMEM — the f32 pages never materialize in HBM."""
-    t, j = pl.program_id(0), pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    qpos = pos_ref[t]
-    base = j * block_size
-
-    @pl.when(base <= qpos)
-    def _body():
-        q = q_ref[...].astype(jnp.float32)            # (H, D)
-        k = k_ref[...].astype(jnp.float32) \
-            * ks_ref[...][..., None]                  # (bs, H, D)
-        v = v_ref[...].astype(jnp.float32) \
-            * vs_ref[...][..., None]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (2,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32) * scale
-        pos = base + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_size), 1)
-        s = jnp.where(pos <= qpos, s, _NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1,
-                                                  keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32)
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=0)
+        acc_ref[...] = acc_ref[...] * alpha + jnp.sum(p * v, axis=0)
         m_ref[...] = m_new
 
     @pl.when(j == num_blocks - 1)
@@ -291,40 +213,38 @@ def _flat_quant_kernel(sid_ref, pos_ref, bt_ref, q_ref, k_ref, v_ref,
 
 
 @functools.partial(jax.jit, static_argnums=(8, 9))
-def _ragged_flat_quant_pallas(q, k_pages, v_pages, k_scales, v_scales,
-                              block_tables, seq_ids, positions, scale,
-                              interpret):
+def _ragged_flat_pallas(q, k_pages, v_pages, k_scales, v_scales,
+                        block_tables, seq_ids, positions, scale,
+                        interpret):
     T, H, D = q.shape
     bs = k_pages.shape[1]
     MB = block_tables.shape[1]
+
+    def tok(t, j, sid, pos, bt):
+        return (t, 0, 0)
+
+    def page(t, j, sid, pos, bt):
+        return (bt[sid[t], j], 0, 0, 0)
+
+    in_specs = [
+        pl.BlockSpec((None, H, D), tok, memory_space=pltpu.VMEM),
+        pl.BlockSpec((None, bs, H, D), page, memory_space=pltpu.VMEM),
+        pl.BlockSpec((None, bs, H, D), page, memory_space=pltpu.VMEM),
+    ]
+    args = [q, k_pages, v_pages]
+    if k_scales is not None:
+        # the scale tiles ride the SAME scalar-prefetched block-table
+        # index map as their pages
+        in_specs += [pl.BlockSpec(
+            (None, bs, H),
+            lambda t, j, sid, pos, bt: (bt[sid[t], j], 0, 0),
+            memory_space=pltpu.VMEM)] * 2
+        args += [k_scales, v_scales]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(T, MB),
-        in_specs=[
-            pl.BlockSpec((None, H, D),
-                         lambda t, j, sid, pos, bt: (t, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((None, bs, H, D),
-                         lambda t, j, sid, pos, bt:
-                         (bt[sid[t], j], 0, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((None, bs, H, D),
-                         lambda t, j, sid, pos, bt:
-                         (bt[sid[t], j], 0, 0, 0),
-                         memory_space=pltpu.VMEM),
-            # the scale tiles ride the SAME scalar-prefetched
-            # block-table index map as their pages
-            pl.BlockSpec((None, bs, H),
-                         lambda t, j, sid, pos, bt:
-                         (bt[sid[t], j], 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((None, bs, H),
-                         lambda t, j, sid, pos, bt:
-                         (bt[sid[t], j], 0, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((None, H, D),
-                               lambda t, j, sid, pos, bt: (t, 0, 0),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((None, H, D), tok,
                                memory_space=pltpu.VMEM),
         scratch_shapes=[
             pltpu.VMEM((H, D), jnp.float32),
@@ -332,15 +252,17 @@ def _ragged_flat_quant_pallas(q, k_pages, v_pages, k_scales, v_scales,
             pltpu.VMEM((H, 1), jnp.float32),
         ],
     )
-    kernel = functools.partial(_flat_quant_kernel, scale=scale,
+    kernel = functools.partial(_flat_kernel, scale=scale,
                                block_size=bs, num_blocks=MB)
+    # inside a shard_map body the output varies over the mesh axes
+    # its heads do (none outside one)
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((T, H, D), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((T, H, D), q.dtype,
+                                       vma=jax.typeof(q).vma),
         interpret=interpret,
     )(seq_ids.astype(jnp.int32), positions.astype(jnp.int32),
-      block_tables.astype(jnp.int32), q, k_pages, v_pages,
-      k_scales, v_scales)
+      block_tables.astype(jnp.int32), *args)
 
 
 def ragged_flat_attention(q, k_pages, v_pages, block_tables, seq_ids,
@@ -368,12 +290,8 @@ def ragged_flat_attention(q, k_pages, v_pages, block_tables, seq_ids,
     if interpret is None:
         interpret = not _on_tpu()
     if k_scales is not None:
-        return _ragged_flat_quant_pallas(
-            q, k_pages, v_pages, jnp.asarray(k_scales),
-            jnp.asarray(v_scales), jnp.asarray(block_tables),
-            jnp.asarray(seq_ids), jnp.asarray(positions),
-            float(scale), bool(interpret)).astype(q.dtype)
-    return _ragged_flat_pallas(q, k_pages, v_pages,
+        k_scales, v_scales = jnp.asarray(k_scales), jnp.asarray(v_scales)
+    return _ragged_flat_pallas(q, k_pages, v_pages, k_scales, v_scales,
                                jnp.asarray(block_tables),
                                jnp.asarray(seq_ids),
                                jnp.asarray(positions),

@@ -51,9 +51,7 @@ class Operator:
         self.mutates = tuple(mutates) if mutates else ()
         self.needs_rng = needs_rng
         self.needs_train = needs_train
-        # host_op: runs python host callbacks (jax.pure_callback); on
-        # accelerator platforms without host-callback support the eager
-        # invocation is rerouted to the CPU backend (ops/invoke.py)
+        # host_op: runs python host callbacks (jax.pure_callback)
         self.host_op = host_op
         self.doc = impl.__doc__
 

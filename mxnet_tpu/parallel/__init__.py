@@ -12,7 +12,6 @@ Axes convention (How-to-Scale-Your-Model recipe):
   pp — pipeline stages               sp — sequence/context parallel
   ep — expert parallel
 """
-from .compat import shard_map  # noqa: F401  (version-proof import path)
 from .mesh import (make_mesh, local_mesh, data_parallel_spec,  # noqa: F401
                    mesh_shard_info, parse_mesh, llm_mesh)  # noqa: F401
 from .functional import functional_call, extract_params, load_params  # noqa: F401

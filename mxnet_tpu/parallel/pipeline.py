@@ -33,11 +33,8 @@ import math as _math
 import jax
 import jax.flatten_util
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from .compat import shard_map, pvary as _pvary, \
-    SHARD_MAP_KWARGS as _SM_KW
 
 __all__ = ["pipeline_stage_loop", "pipeline_value_and_grad",
            "hetero_pipeline", "HeteroPipeline"]
@@ -62,8 +59,9 @@ def pipeline_stage_loop(stage_fn, n_microbatches: int, mesh: Mesh,
         params = jax.tree_util.tree_map(lambda a: a[0], params)
         rank = lax.axis_index(axis_name)
         perm = [(i, (i + 1) % n_stages) for i in range(n_stages)]
-        reg0 = _pvary(jnp.zeros_like(mbs[0]), (axis_name,))
-        out0 = _pvary(jnp.zeros_like(mbs), (axis_name,))
+        reg0 = lax.pcast(jnp.zeros_like(mbs[0]), axis_name,
+                         to="varying")
+        out0 = lax.pcast(jnp.zeros_like(mbs), axis_name, to="varying")
 
         def tick(carry, t):
             reg, out = carry
@@ -86,7 +84,7 @@ def pipeline_stage_loop(stage_fn, n_microbatches: int, mesh: Mesh,
 
     return shard_map(local, mesh=mesh,
                      in_specs=(P(axis_name), P()),
-                     out_specs=P(), **_SM_KW)
+                     out_specs=P())
 
 
 def pipeline_value_and_grad(stage_fn, loss_fn, n_microbatches: int,
@@ -226,8 +224,10 @@ class HeteroPipeline:
             rank = lax.axis_index(axis)
             perm = [(i, (i + 1) % n_stages) for i in range(n_stages)]
             mb_regs = jax.vmap(self._pack_act)(mbs)
-            reg0 = _pvary(jnp.zeros_like(mb_regs[0]), (axis,))
-            out0 = _pvary(jnp.zeros_like(mb_regs), (axis,))
+            reg0 = lax.pcast(jnp.zeros_like(mb_regs[0]), axis,
+                             to="varying")
+            out0 = lax.pcast(jnp.zeros_like(mb_regs), axis,
+                             to="varying")
 
             def tick(carry, t):
                 reg, out = carry
@@ -253,7 +253,7 @@ class HeteroPipeline:
 
         out = shard_map(local, mesh=self.mesh,
                         in_specs=(P(self.axis_name), P()),
-                        out_specs=P(), **_SM_KW)(packed, mbs)
+                        out_specs=P())(packed, mbs)
         return jax.vmap(lambda r: self._unpack_act(r, self.n_stages))(out)
 
     def value_and_grad(self, loss_fn):

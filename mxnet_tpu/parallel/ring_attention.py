@@ -16,11 +16,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from .compat import shard_map, pvary as _pvary, \
-    SHARD_MAP_KWARGS as _SM_KW
 
 __all__ = ["ring_attention", "sequence_shard"]
 
@@ -74,7 +71,8 @@ def ring_attention(q, k, v, mesh: Mesh, axis_name: str = "sp",
         l = jnp.zeros(qb.shape[:3], jnp.float32)
         # accumulators are device-varying (each sp-rank's differ): annotate
         # so the fori_loop carry type is stable under vma checking
-        o, m, l = (_pvary(a, (axis_name,)) for a in (o, m, l))
+        o, m, l = (lax.pcast(a, axis_name, to="varying")
+                   for a in (o, m, l))
         perm = [(i, (i + 1) % n) for i in range(n)]
 
         def body(step, carry):
@@ -100,5 +98,5 @@ def ring_attention(q, k, v, mesh: Mesh, axis_name: str = "sp",
 
     spec = P(None, None, axis_name, None)
     fn = shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
-                   out_specs=spec, **_SM_KW)
+                   out_specs=spec)
     return fn(q, k, v)

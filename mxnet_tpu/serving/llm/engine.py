@@ -415,8 +415,8 @@ def _spmd_wrap(fn, mesh, cache, param_specs, extra):
     yields the ONE donated whole-step program per (mesh, bucket,
     variant). ``extra`` = (replicated leading pool count, replicated
     batch arg count)."""
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
-    from ...parallel.compat import shard_map, SHARD_MAP_KWARGS
     pool, scale = cache.pool_spec(), cache.scale_spec()
     pools = [pool, pool] + ([scale, scale] if cache.quantized else [])
     n_lora, n_batch = extra
@@ -424,7 +424,7 @@ def _spmd_wrap(fn, mesh, cache, param_specs, extra):
                      + [P()] * (n_lora + n_batch))
     out_specs = tuple([P(), P()] + pools)
     return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, **SHARD_MAP_KWARGS)
+                     out_specs=out_specs)
 
 
 class LLMEngine:
@@ -755,9 +755,8 @@ class LLMEngine:
                     # silently unsharding the fleet's KV and
                     # recompiling every step program. shard_map pins
                     # in/out layouts to the head-sharded specs.
+                    from jax import shard_map
                     from jax.sharding import PartitionSpec as P
-                    from ...parallel.compat import (shard_map,
-                                                    SHARD_MAP_KWARGS)
                     pool = self.cache.pool_spec()
                     scale = self.cache.scale_spec()
                     per_cache = [pool, pool] + (
@@ -766,8 +765,7 @@ class LLMEngine:
                     fn = shard_map(
                         fn, mesh=self.mesh,
                         in_specs=tuple(arr_specs) + (P(), P()),
-                        out_specs=tuple(arr_specs),
-                        **SHARD_MAP_KWARGS)
+                        out_specs=tuple(arr_specs))
                 return jax.jit(fn, donate_argnums=cow_donate)
 
             self._cow_jit = _cached_program(
@@ -1035,6 +1033,42 @@ class LLMEngine:
                      attrs={"old": old, "new": new})
 
     # ------------------------------------------------------- warmup --
+    def _null_batch(self, T, MB):
+        """An all-padding step batch of packed length ``T`` and table
+        width ``MB`` (every row inactive on the null block): the real
+        shapes with nothing to compute — what warmup dispatches."""
+        S, K = self.max_seqs, self.spec_k
+        V = self.model.vocab_size
+        batch = (np.zeros(T, np.int32), np.zeros(T, np.int32),
+                 np.zeros(T, np.int32), np.zeros(T, np.int32),
+                 np.full((S, MB), NULL_BLOCK, np.int32),
+                 np.zeros((S, K + 1), np.int32),
+                 np.zeros((S, K), np.int32),
+                 np.zeros((S, K, V), np.float32),
+                 np.zeros(S, np.int32), np.zeros(S, np.float32),
+                 np.zeros(S, np.int32), np.ones(S, np.float32),
+                 np.zeros(S, np.int32), np.zeros(S, np.int32))
+        if self.bank is not None:
+            batch += (np.full((S, self.bank.max_pages_per_adapter),
+                              NULL_ADAPTER_PAGE, np.int32),
+                      np.zeros(S, np.float32))
+        return batch
+
+    def step_program_text(self, sampled=False):
+        """StableHLO text of the step program at its narrowest bucket,
+        lowered (not compiled, not run) against the live params and
+        pools. A Mosaic-compiled Pallas kernel shows up in it as a
+        ``tpu_custom_call``; the interpreted kernel and the ``jnp``
+        reference do not — so this is where a caller reads which
+        attention the engine's programs were built with."""
+        pools = [self.cache.k_pages, self.cache.v_pages]
+        if self.quantized:
+            pools += [self.cache.k_scales, self.cache.v_scales]
+        lora = () if self.bank is None else self.bank.pools()
+        batch = self._null_batch(self._t_buckets[0], self._mb_widths[0])
+        return self._step_jits[sampled].lower(
+            self._params, *pools, *lora, *batch).as_text()
+
     def warmup(self):
         """Compile every program steady state can reach: the chunked
         step at each of its two widths (+ the draft program's widths
@@ -1042,24 +1076,18 @@ class LLMEngine:
         seconds}. After this, a mixed chunked-prefill / sampled-decode
         / speculative workload cannot recompile."""
         timings = {}
-        S, K = self.max_seqs, self.spec_k
-        V = self.model.vocab_size
+        S = self.max_seqs
         temp = np.zeros(S, np.float32)
         top_k = np.zeros(S, np.int32)
         top_p = np.ones(S, np.float32)
         seeds = np.zeros(S, np.int32)
         counters = np.zeros(S, np.int32)
-        lora_tail = ()
         if self.bank is not None:
             # install the all-zero null adapter page (warms the
             # fixed-shape install program every later publish reuses)
             t0 = time.monotonic()
             self.bank.warmup()
             timings["adapter_install"] = time.monotonic() - t0
-            lora_tail = (
-                np.full((S, self.bank.max_pages_per_adapter),
-                        NULL_ADAPTER_PAGE, np.int32),
-                np.zeros(S, np.float32))
         if self.draft_model is not None:
             for T in self._draft_t_buckets:
                 for MB in self._mb_widths:
@@ -1079,19 +1107,10 @@ class LLMEngine:
                             time.monotonic() - t0
         for T in self._t_buckets:
             for MB in self._mb_widths:
-                tables = np.full((S, MB), NULL_BLOCK, np.int32)
+                batch = self._null_batch(T, MB)
                 for sampled in (False, True):
                     t0 = time.monotonic()
-                    toks, n_acc = self._call_step(sampled, (
-                        np.zeros(T, np.int32),
-                        np.zeros(T, np.int32),
-                        np.zeros(T, np.int32),
-                        np.zeros(T, np.int32), tables,
-                        np.zeros((S, K + 1), np.int32),
-                        np.zeros((S, K), np.int32),
-                        np.zeros((S, K, V), np.float32),
-                        np.zeros(S, np.int32), temp, top_k, top_p,
-                        seeds, counters, *lora_tail))
+                    toks, n_acc = self._call_step(sampled, batch)
                     np.asarray(toks)
                     tag = "sampled" if sampled else "greedy"
                     timings[f"step_t{T}mb{MB}_{tag}"] = \

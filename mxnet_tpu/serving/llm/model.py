@@ -378,8 +378,9 @@ class TinyDecoder:
         program. Batch inputs, layer norms, embeddings and the LM
         head ride replicated, as do the LoRA factor pools: q/k/v
         deltas are computed full-width and column-sliced to this
-        shard, the o-delta sees the ``all_gather``-reassembled
-        attention output and lands after the psum, so adapter maths
+        shard, the o-delta sees the full-width attention output
+        (reassembled by a psum of zero-padded shard slices, so it is
+        typed replicated) and lands after the psum, so adapter maths
         is bitwise the single-device result. At axis extent 1 every
         collective is the identity — bit-exact vs the unsharded
         program by construction.
@@ -496,12 +497,17 @@ class TinyDecoder:
                 if axis_name is None:
                     o = o + _delta(att2d, li, PROJ_O)
                 else:
-                    # heads are sharded contiguously, so the tiled
-                    # gather reassembles the full-width att output
-                    # in column order; the delta lands post-psum,
-                    # replicated
-                    att_full = jax.lax.all_gather(
-                        att2d, axis_name, axis=1, tiled=True)
+                    # reassemble the full-width att output in column
+                    # order (heads are sharded contiguously) as a
+                    # psum of each shard's slice placed in zeros —
+                    # the same values a tiled all_gather returns,
+                    # but typed replicated, so shard_map can prove
+                    # the step's token outputs equal on every shard.
+                    # The delta lands post-psum, replicated.
+                    att_full = jax.lax.psum(
+                        jax.lax.dynamic_update_slice_in_dim(
+                            jnp.zeros((T, c.d_model), att2d.dtype),
+                            att2d, col0, axis=1), axis_name)
                     o = o + _delta(att_full, li, PROJ_O)
             h = h + o
             x2 = _layer_norm(h, lp["ln2_g"], lp["ln2_b"])

@@ -86,11 +86,7 @@ def test_int64_index_values_beyond_2_31():
     """>2^31 index VALUES round-trip exactly under the x64 context
     (reference large-array support is the int64 build; TPU-native code
     keeps int32 on-device and goes x64 only where values demand it)."""
-    try:
-        enable_x64 = jax.enable_x64
-    except AttributeError:  # pre-0.6 jax: experimental namespace
-        from jax.experimental import enable_x64
-    with enable_x64(True):
+    with jax.enable_x64(True):
         big = np.int64(2**31 + 123)
         a = nd.array(np.asarray([big, big + 1], np.int64))
         assert a.asnumpy().dtype == np.int64

@@ -1,0 +1,112 @@
+"""chip_smoke.py, rehearsed where there is no chip.
+
+The script itself only runs on a TPU (``chiprun -- python chip_smoke.py``).
+What the CPU can hold to account:
+
+- the control flow of the train and serve phases, by calling the phase
+  functions at toy sizes (their checks run as they do on the chip; only
+  the kernel dispatch differs: the ``jnp`` reference instead of Mosaic);
+- that ``python chip_smoke.py`` refuses to run without a TPU, before
+  anything is built;
+- that every kernel of the kernels phase still LOWERS for a TPU at
+  head_dim 128 (``jax.export`` cross-lowering: the Pallas front end, not
+  Mosaic's own compile, which needs the chip);
+- the compile-cache placement rule of ``runtime.enable_compile_cache``.
+"""
+import json
+import os
+import subprocess
+import sys
+
+import jax
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+import chip_smoke  # noqa: E402
+from mxnet_tpu import runtime  # noqa: E402
+
+
+def test_train_phase_toy():
+    from mxnet_tpu.gluon.model_zoo import vision
+    line = chip_smoke.train_phase(
+        lambda: vision.resnet18_v1(layout="NHWC", stem_s2d=True),
+        image=32, batch=8, dtype="float32", warmup=1, steps=2)
+    assert line["platform"] == "cpu" and line["step_programs"] == 1
+    assert line["block_until_ready_waits"] is True
+
+
+def test_serve_phase_toy():
+    from mxnet_tpu.serving.llm.model import DecoderConfig
+    # two slots for three requests, so one waits its turn; a context of
+    # one block keeps the ladder of warmed programs short (tier-1 budget)
+    line = chip_smoke.serve_phase(
+        DecoderConfig(max_context=64), prompt_lens=(4, 20, 40),
+        new_tokens=6, max_seqs=2, block_size=64)
+    assert line["compiles_during_load"] == 0
+    # off the chip the engine must have been built on the reference,
+    # and the phase must have seen that from the program itself
+    assert line["attention"] == "jnp_reference"
+
+
+def test_refuses_to_run_without_a_tpu():
+    p = subprocess.run(
+        [sys.executable, os.path.join(REPO, "chip_smoke.py")],
+        env=dict(os.environ, JAX_PLATFORMS="cpu"), cwd=REPO,
+        capture_output=True, text=True, timeout=120)
+    assert p.returncode != 0
+    assert "needs a TPU" in p.stderr
+    assert p.stdout == "", "refused, yet printed a result"
+
+
+def test_every_kernel_lowers_for_tpu_at_head_dim_128():
+    cases = chip_smoke.kernel_cases(
+        attn_shape=(2, 12, 512, 64), ragged_shape=(16, 8, 128, 16, 65, 8),
+        matmul_shape=(256, 1024, 4096), interpret=False)
+    names = []
+    for name, kernel, _reference, args, _bound in cases:
+        exported = jax.export.export(jax.jit(kernel),
+                                     platforms=["tpu"])(*args)
+        assert "tpu_custom_call" in exported.mlir_module(), name
+        names.append(name)
+    assert {"flash_fwd", "flash_bwd_bias", "ragged_flat_f32",
+            "ragged_flat_int8", "quantized_matmul_int8"} <= set(names)
+
+
+@pytest.fixture
+def _restore_cache_config():
+    flags = ("jax_compilation_cache_dir",
+             "jax_persistent_cache_min_compile_time_secs",
+             "jax_persistent_cache_min_entry_size_bytes")
+    prev = {f: getattr(jax.config, f) for f in flags}
+    yield
+    for f, v in prev.items():
+        jax.config.update(f, v)
+    from jax.experimental.compilation_cache import compilation_cache
+    compilation_cache.reset_cache()
+
+
+def test_cache_dir_is_fixed_inside_the_checkout(monkeypatch,
+                                                _restore_cache_config):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    first = runtime.enable_compile_cache()
+    assert runtime.enable_compile_cache() == first
+    assert first == os.path.join(REPO, ".jax_cache")
+    assert jax.config.jax_compilation_cache_dir == first
+
+
+def test_cache_dir_from_the_environment_is_left_alone(tmp_path):
+    """JAX reads JAX_COMPILATION_CACHE_DIR when it is imported, so the
+    rule is observed in a fresh interpreter."""
+    code = ("import json, jax\n"
+            "from mxnet_tpu import runtime\n"
+            "read = jax.config.jax_compilation_cache_dir\n"
+            "print(json.dumps([read, runtime.enable_compile_cache(),\n"
+            "                  jax.config.jax_compilation_cache_dir]))\n")
+    want = str(tmp_path / "placed")
+    p = subprocess.run(
+        [sys.executable, "-c", code], cwd=REPO, capture_output=True,
+        text=True, timeout=120, check=True,
+        env=dict(os.environ, JAX_PLATFORMS="cpu",
+                 JAX_COMPILATION_CACHE_DIR=want))
+    assert json.loads(p.stdout.splitlines()[-1]) == [want, want, want]

@@ -23,7 +23,6 @@ _WORKER = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=1"
     os.environ["JAX_PLATFORMS"] = "cpu"
     import jax
-    jax.config.update("jax_platforms", "cpu")
 
     sys.path.insert(0, "__REPO__")
     import mxnet_tpu as mx
@@ -111,6 +110,17 @@ _WORKER = textwrap.dedent("""
 """)
 
 
+def test_launcher_refuses_several_workers_on_the_chips(monkeypatch):
+    """Workers share one environment, so several of them on a TPU host
+    would each open every chip: without cpu=True the launcher starts
+    nothing and names the single-process mesh instead."""
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    monkeypatch.syspath_prepend(repo)
+    from mxnet_tpu.launch import launch
+    with pytest.raises(ValueError, match="ONE process over a mesh"):
+        launch(2, [sys.executable, "-c", "raise SystemExit(7)"])
+
+
 @pytest.mark.parametrize(
     "nproc", [pytest.param(2, marks=pytest.mark.slow)])
 # ~9s on 1 CPU (tier-1 budget): two fresh jax processes; launcher
@@ -169,7 +179,6 @@ _MH_WORKER = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=1"
     os.environ["JAX_PLATFORMS"] = "cpu"
     import jax
-    jax.config.update("jax_platforms", "cpu")
 
     sys.path.insert(0, "__REPO__")
     import mxnet_tpu as mx

@@ -179,10 +179,8 @@ def test_sync_batch_norm_pmean():
     per-device batches (reference sync_batch_norm.cc contract)."""
     import jax
     import jax.numpy as jnp
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
-    # jax 0.4.x has no top-level jax.shard_map; the parallel.compat shim
-    # is the one import path that works on every supported jax
-    from mxnet_tpu.parallel import shard_map
 
     if len(jax.devices()) < 4:
         pytest.skip("needs 4 virtual devices")

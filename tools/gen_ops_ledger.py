@@ -39,8 +39,6 @@ def reference_ops():
 def main():
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     sys.path.insert(0, REPO)
-    import jax
-    jax.config.update("jax_platforms", "cpu")
     import mxnet_tpu as mx
     from mxnet_tpu.ops.registry import _REGISTRY
 

@@ -1,10 +1,8 @@
 #!/usr/bin/env python
 """Roll up a captured TPU device trace into per-op-family time shares.
 
-Thin CLI over :mod:`mxnet_tpu.observability.rollup` (the library form
-every other tool shares — ``perf_capture.py`` embeds the same summary
-into ``BENCH_rNN.json``). Reads the ``*.trace.json.gz`` that
-``BENCH_PROFILE=<dir>`` / tools/perf_capture.py writes (jax.profiler /
+Thin CLI over :mod:`mxnet_tpu.observability.rollup`. Reads the
+``*.trace.json.gz`` that ``BENCH_PROFILE=<dir>`` writes (jax.profiler /
 XPlane -> trace-viewer JSON), sums XLA-op durations on the device's
 "XLA Ops" thread, groups by fusion-family prefix, and prints ms/step +
 share. Use it to quantify a lever's effect between two captures:
