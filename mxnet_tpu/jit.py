@@ -342,82 +342,84 @@ class CompiledTrainStep:
         from .gluon.block import _flatten_arrays, _flat_flags
         from .optimizer import fused as _fused
 
-        tr = self._trainer
-        opt, upd = tr._optimizer, tr._updaters[0]
-        scaler = getattr(tr, "_amp_loss_scaler", None)
-        engaged = scaler is not None and scaler.loss_scale != 1.0
+        # from entry to the cache lookup: key, weights, states, rng draw
+        with _tracer().span("mxtpu.train_step.prepare", "step"):
+            tr = self._trainer
+            opt, upd = tr._optimizer, tr._updaters[0]
+            scaler = getattr(tr, "_amp_loss_scaler", None)
+            engaged = scaler is not None and scaler.loss_scale != 1.0
 
-        flat_in, in_fmt = _flatten_arrays(args)
-        flags = _flat_flags(in_fmt)
-        arrays = [v for v, f in zip(flat_in, flags) if f]
-        opaque = tuple(v for v, f in zip(flat_in, flags) if not f)
-        if not arrays or getattr(arrays[0], "ndim", 0) == 0:
-            raise _Fallback("no_batch_axis")
-        n = int(arrays[0].shape[0])
+            flat_in, in_fmt = _flatten_arrays(args)
+            flags = _flat_flags(in_fmt)
+            arrays = [v for v, f in zip(flat_in, flags) if f]
+            opaque = tuple(v for v, f in zip(flat_in, flags) if not f)
+            if not arrays or getattr(arrays[0], "ndim", 0) == 0:
+                raise _Fallback("no_batch_axis")
+            n = int(arrays[0].shape[0])
 
-        # deferred parameter shapes resolve through one eager predict
-        # pass (no aux writes — CachedOp's warm-up discipline)
-        if any(p._data is None for p in tr._params):
-            from . import autograd
-            with autograd.pause(train_mode=False):
-                self._loss_fn(*args)
+            # deferred parameter shapes resolve through one eager predict
+            # pass (no aux writes — CachedOp's warm-up discipline)
+            if any(p._data is None for p in tr._params):
+                from . import autograd
+                with autograd.pause(train_mode=False):
+                    self._loss_fn(*args)
 
-        work = [(i, p) for i, p in enumerate(tr._params)
-                if p.grad_req != "null" and p._data is not None]
-        if not work:
-            raise _Fallback("no_trainable")
-        bucket = self._pick_bucket(n)
+            work = [(i, p) for i, p in enumerate(tr._params)
+                    if p.grad_req != "null" and p._data is not None]
+            if not work:
+                raise _Fallback("no_trainable")
+            bucket = self._pick_bucket(n)
 
-        # ---- phase A: record the optimizer apply on host ----------------
-        # All host bookkeeping (update counts, schedulers, Adam bias
-        # correction, AMP rescale) advances exactly as in the eager loop;
-        # a fallback from here on must roll the counts back.
-        scale = tr._scale / (scaler.loss_scale if engaged else 1.0)
-        opt.rescale_grad = scale / n
-        _fused.prepare_states(opt, upd, work)
-        try:
-            roles, weight_nds, grad_nds, state_nds, state_defs = \
-                _fused.build_roles(upd, work)
-        except ValueError:
-            raise _Fallback("state_leaf") from None
-        rec = _fused.record_program(upd, work, grad_nds, weight_nds, roles)
-        if not rec.ok:
-            _fused.rollback_counts(opt, work)
-            raise _Fallback("unrecordable")
+            # ---- phase A: record the optimizer apply on host ----------------
+            # All host bookkeeping (update counts, schedulers, Adam bias
+            # correction, AMP rescale) advances exactly as in the eager loop;
+            # a fallback from here on must roll the counts back.
+            scale = tr._scale / (scaler.loss_scale if engaged else 1.0)
+            opt.rescale_grad = scale / n
+            _fused.prepare_states(opt, upd, work)
+            try:
+                roles, weight_nds, grad_nds, state_nds, state_defs = \
+                    _fused.build_roles(upd, work)
+            except ValueError:
+                raise _Fallback("state_leaf") from None
+            rec = _fused.record_program(upd, work, grad_nds, weight_nds, roles)
+            if not rec.ok:
+                _fused.rollback_counts(opt, work)
+                raise _Fallback("unrecordable")
 
-        mesh_pin = None
-        if self._mesh is not None:
-            # place weights + optimizer slots onto the mesh as global
-            # arrays (no-op once placed: donation hands back outputs
-            # with the same, constraint-pinned shardings)
-            mesh_pin = self._place_mesh(work, weight_nds, state_nds,
-                                        state_defs)
+            mesh_pin = None
+            if self._mesh is not None:
+                # place weights + optimizer slots onto the mesh as global
+                # arrays (no-op once placed: donation hands back outputs
+                # with the same, constraint-pinned shardings)
+                mesh_pin = self._place_mesh(work, weight_nds, state_nds,
+                                            state_defs)
 
-        nts = [p for p in tr._params
-               if p.grad_req == "null" and p._data is not None]
-        key = (in_fmt, opaque, bucket, engaged, self._mesh,
-               self._buckets is not None, type(opt), tuple(rec.program),
-               tuple(state_defs),
-               tuple((tuple(a.shape[1:]) if a.shape[:1] == (n,)
-                      else ("F",) + tuple(a.shape),
-                      str(_np.dtype(_dtype_of(a)))) for a in arrays),
-               tuple((tuple(w.shape), str(w.dtype)) for w in weight_nds),
-               tuple((tuple(s.shape), str(s.dtype)) for s in state_nds))
-        try:
-            hash(key)
-        except TypeError:
-            _fused.rollback_counts(opt, work)
-            raise _Fallback("unhashable_signature") from None
+            nts = [p for p in tr._params
+                   if p.grad_req == "null" and p._data is not None]
+            key = (in_fmt, opaque, bucket, engaged, self._mesh,
+                   self._buckets is not None, type(opt), tuple(rec.program),
+                   tuple(state_defs),
+                   tuple((tuple(a.shape[1:]) if a.shape[:1] == (n,)
+                          else ("F",) + tuple(a.shape),
+                          str(_np.dtype(_dtype_of(a)))) for a in arrays),
+                   tuple((tuple(w.shape), str(w.dtype)) for w in weight_nds),
+                   tuple((tuple(s.shape), str(s.dtype)) for s in state_nds))
+            try:
+                hash(key)
+            except TypeError:
+                _fused.rollback_counts(opt, work)
+                raise _Fallback("unhashable_signature") from None
 
-        batch_vals = self._stage_batch(arrays, n, bucket)
-        if self._mesh is not None:
-            batch_vals = self._place_batch(batch_vals, bucket)
-        weights = [w._data for w in weight_nds]
-        states = [s._data for s in state_nds]
-        scalars = tuple(rec.slot_values)
-        ls = float(scaler.loss_scale) if engaged else 1.0
-        rng_base = _rng.base_key()
-        rng_draw = _rng.reserve_draw()
+            batch_vals = self._stage_batch(arrays, n, bucket)
+            if self._mesh is not None:
+                batch_vals = self._place_batch(batch_vals, bucket)
+            weights = [w._data for w in weight_nds]
+            states = [s._data for s in state_nds]
+            scalars = tuple(rec.slot_values)
+            ls = float(scaler.loss_scale) if engaged else 1.0
+            rng_base = _rng.base_key()
+            rng_draw = _rng.reserve_draw()
 
         entry = self._cache.get(key)
         if entry is None:
@@ -472,64 +474,66 @@ class CompiledTrainStep:
                 "exec_retry"
             _fused.rollback_counts(opt, work)
             raise _Fallback(reason) from None
-        self._exec_failures = 0
-        new_w, new_s, aux_out, loss_out, extras, flag = outs
+        # from the program's return to the end: overflow, write-back, loss
+        with _tracer().span("mxtpu.train_step.rebind", "step"):
+            self._exec_failures = 0
+            new_w, new_s, aux_out, loss_out, extras, flag = outs
 
-        overflow = False
-        if engaged:
-            overflow = not bool(_np.asarray(flag))  # the ONE host sync
-        if overflow:
-            # the program kept the pre-step weights/slots (in-program
-            # where()); mirror the eager amp_step skip exactly: no count
-            # advance, no step tick, scale halves
-            _fused.rollback_counts(opt, work)
-            scaler.update_scale(overflow=True)
-            warnings.warn(
-                f"AMP: gradient overflow, skipping update and reducing "
-                f"loss scale to {scaler.loss_scale}", stacklevel=3)
-        else:
+            overflow = False
             if engaged:
-                scaler.update_scale(overflow=False)
-            tr._step_count += 1
+                overflow = not bool(_np.asarray(flag))  # the ONE host sync
+            if overflow:
+                # the program kept the pre-step weights/slots (in-program
+                # where()); mirror the eager amp_step skip exactly: no count
+                # advance, no step tick, scale halves
+                _fused.rollback_counts(opt, work)
+                scaler.update_scale(overflow=True)
+                warnings.warn(
+                    f"AMP: gradient overflow, skipping update and reducing "
+                    f"loss scale to {scaler.loss_scale}", stacklevel=3)
+            else:
+                if engaged:
+                    scaler.update_scale(overflow=False)
+                tr._step_count += 1
 
-        for k, (i, param) in enumerate(work):
-            replicas = param.list_data()
-            replicas[0]._data = new_w[k]
-            for other in replicas[1:]:
-                other._data = jax.device_put(new_w[k],
-                                             other.context.jax_device)
-        for leaf, data in zip(state_nds, new_s):
-            leaf._data = data
-        for p, v in zip(meta["aux_params"], aux_out):
-            ctxs = list(p._data)
-            p._data[ctxs[0]]._data = v
-            for c in ctxs[1:]:
-                p._data[c]._data = jax.device_put(v, c.jax_device)
+            for k, (i, param) in enumerate(work):
+                replicas = param.list_data()
+                replicas[0]._data = new_w[k]
+                for other in replicas[1:]:
+                    other._data = jax.device_put(new_w[k],
+                                                 other.context.jax_device)
+            for leaf, data in zip(state_nds, new_s):
+                leaf._data = data
+            for p, v in zip(meta["aux_params"], aux_out):
+                ctxs = list(p._data)
+                p._data[ctxs[0]]._data = v
+                for c in ctxs[1:]:
+                    p._data[c]._data = jax.device_put(v, c.jax_device)
 
-        obs["dispatch"].inc()
-        obs["compiled"].inc()
-        if self._mesh is not None:
-            from .parallel.mesh import spmd_metrics
-            sobs = spmd_metrics()
-            sobs["dispatch"].inc()
-            if meta.get("spmd_grad_bytes"):
-                sobs["bytes"].labels(collective="grad_reduce").inc(
-                    meta["spmd_grad_bytes"])
-        if bucket != n:
-            obs["padded_rows"].inc(bucket - n)
-        tobs = tr._obs_metrics()
-        if not overflow:
-            # an overflow-skip records nothing, mirroring the eager
-            # amp_step early return — secs samples stay 1:1 with steps
-            tobs["secs"].observe(_time.monotonic() - t0)
-            tobs["steps"].inc()
-            tobs["examples"].inc(n)
-            from .resilience import faults
-            from .resilience import async_writer as _aw
-            _aw.note_step_overlap()
-            faults.on_step(tr._step_count)
-        self.last_reason = None
-        return self._package(meta, loss_out, extras, n, bucket)
+            obs["dispatch"].inc()
+            obs["compiled"].inc()
+            if self._mesh is not None:
+                from .parallel.mesh import spmd_metrics
+                sobs = spmd_metrics()
+                sobs["dispatch"].inc()
+                if meta.get("spmd_grad_bytes"):
+                    sobs["bytes"].labels(collective="grad_reduce").inc(
+                        meta["spmd_grad_bytes"])
+            if bucket != n:
+                obs["padded_rows"].inc(bucket - n)
+            tobs = tr._obs_metrics()
+            if not overflow:
+                # an overflow-skip records nothing, mirroring the eager
+                # amp_step early return — secs samples stay 1:1 with steps
+                tobs["secs"].observe(_time.monotonic() - t0)
+                tobs["steps"].inc()
+                tobs["examples"].inc(n)
+                from .resilience import faults
+                from .resilience import async_writer as _aw
+                _aw.note_step_overlap()
+                faults.on_step(tr._step_count)
+            self.last_reason = None
+            return self._package(meta, loss_out, extras, n, bucket)
 
     # --------------------------------------------------- mesh placement --
     def _leaf_spec_of(self, name, shape):

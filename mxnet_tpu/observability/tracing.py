@@ -13,21 +13,27 @@ end to end.
 
 Three integration rules keep the tracer honest:
 
-- **off = free.** With tracing disabled, every ``tracer.span(...)``
-  call on a hot path returns the same ``_NULL`` singleton — no object,
-  dict or closure is allocated per step (asserted in tier-1 via the
-  ``mxtpu_trace_*`` counters). Call sites therefore never need their
-  own ``if enabled`` guards.
+- **off = free.** With tracing disabled and no profiler capture
+  running, every ``tracer.span(...)`` call on a hot path returns the
+  same ``_NULL`` singleton — no object, dict or closure is allocated
+  per step (asserted in tier-1 via the ``mxtpu_trace_*`` counters).
+  Call sites therefore never need their own ``if enabled`` guards;
+  one that builds an attribute value asks ``span.span_id is not None``
+  first.
 - **bounded memory.** Completed spans land in a ring of
   ``MXNET_TPU_TRACE_RING`` entries (drops counted on
   ``mxtpu_trace_spans_dropped_total``), so a week-long serving process
   with tracing on cannot leak.
-- **one timeline with XLA.** While a ``mx.profiler`` capture is
-  running, every span also enters a ``jax.profiler.TraceAnnotation``
+- **one timeline with XLA.** While a jax profiler capture is running
+  — whoever started it: ``mx.profiler``, ``jax.profiler.start_trace``,
+  TensorBoard — spans record even with the tracer off, and every
+  same-thread span also enters a ``jax.profiler.TraceAnnotation``
   (outermost step-category spans a ``StepTraceAnnotation``; XLA step
   markers do not nest, so an enclosing epoch or wrapped fallback span
-  never claims one), so host spans line up with XLA device ops in the
-  jax trace — the host/device join the rollup (:mod:`.rollup`)
+  never claims one). The ring then holds the spans of the captured
+  window with their host durations and parents, and the same spans
+  stand in the xplane beside the device's events on the capture's
+  clock — the host/device join the rollup (:mod:`.rollup`)
   quantifies.
 
 Cross-thread propagation is explicit: contextvars do not follow work
@@ -48,6 +54,7 @@ import contextvars
 import itertools
 import json
 import os
+import sys
 import threading
 import time
 
@@ -80,12 +87,27 @@ def trace_ring_capacity():
     return max(16, n)
 
 
-def _profiler_running():
-    """True while a ``mx.profiler`` (jax) capture is active. Read
-    lazily so importing the tracer never drags profiler/jax in."""
-    import sys
-    prof = sys.modules.get("mxnet_tpu.profiler")
-    return prof is not None and prof.state() == "run"
+# jax.profiler.TraceAnnotation.is_enabled, held after the first lookup
+_capture_signal = None
+
+
+def _capture_running():
+    """True while a jax profiler capture is active, whoever started it
+    (``TraceAnnotation.is_enabled()`` reads the runtime's own switch).
+    Importing the tracer never drags jax in: where ``jax`` is not in
+    ``sys.modules`` no capture can run."""
+    global _capture_signal
+    signal = _capture_signal
+    if signal is None:
+        jax = sys.modules.get("jax")
+        if jax is None:
+            return False
+        try:
+            signal = jax.profiler.TraceAnnotation.is_enabled
+        except AttributeError:      # jax still importing, or too old
+            return False
+        _capture_signal = signal
+    return signal()
 
 
 def _jax_annotation(name, cat, step):
@@ -123,54 +145,6 @@ class _NullSpan:
 
 
 _NULL = _NullSpan()
-
-
-class _AnnSpan:
-    """Span-shaped wrapper over a bare jax annotation, returned when a
-    profiler capture is running but the tracer itself is off — call
-    sites keep one API (``set``/``finish`` are no-ops; only the device-
-    timeline annotation is real)."""
-
-    __slots__ = ("_ann", "_is_step", "_entered", "_step_token")
-    span_id = None
-    name = None
-
-    def __init__(self, ann, is_step=False):
-        self._ann = ann
-        self._is_step = is_step
-        self._entered = False
-        self._step_token = None
-
-    def __enter__(self):
-        self._ann.__enter__()
-        if self._is_step:
-            self._step_token = _STEP_DEPTH.set(_STEP_DEPTH.get() + 1)
-        self._entered = True
-        return self
-
-    def __exit__(self, *exc):
-        if not self._entered:       # finish() already closed it
-            return False
-        self._entered = False
-        self._reset_step()
-        return self._ann.__exit__(*exc)
-
-    def set(self, key, value):
-        return self
-
-    def finish(self):
-        if self._entered:
-            self._entered = False
-            self._reset_step()
-            self._ann.__exit__(None, None, None)
-
-    def _reset_step(self):
-        if self._step_token is not None:
-            try:
-                _STEP_DEPTH.reset(self._step_token)
-            except ValueError:
-                _STEP_DEPTH.set(0)
-            self._step_token = None
 
 
 class Span:
@@ -215,7 +189,7 @@ class Span:
         # on another; jax TraceMe begin/end pairs are thread-scoped, so
         # only activated (same-thread) spans bridge to the device
         # timeline
-        if activate and _profiler_running():
+        if activate and _capture_running():
             try:
                 ann, is_step = _jax_annotation(name, cat, step)
                 ann.__enter__()
@@ -336,22 +310,18 @@ class Tracer:
     # ------------------------------------------------------------ spans --
     def span(self, name, cat="host", parent=None, attrs=None, step=None):
         """Open a nested, context-activated span. Returns the ``_NULL``
-        singleton when tracing is off (and no profiler capture is
-        running), so hot paths call this unconditionally."""
-        if not self._enabled:
-            if _profiler_running():
-                try:
-                    return _AnnSpan(*_jax_annotation(name, cat, step))
-                except Exception:
-                    return _NULL
+        singleton when tracing is off and no profiler capture is
+        running, so hot paths call this unconditionally."""
+        if not self._enabled and not _capture_running():
             return _NULL
         return Span(self, name, cat, parent, attrs, step, True)
 
     def begin(self, name, cat="host", parent=None, attrs=None):
         """Open a hand-off span: NOT installed as the current context
         (it will be finished on another thread — serving requests,
-        background writers). Pair with ``span.finish()``."""
-        if not self._enabled:
+        background writers). Pair with ``span.finish()``. Recorded
+        under a capture like any span, but never annotated."""
+        if not self._enabled and not _capture_running():
             return _NULL
         return Span(self, name, cat, parent, attrs, None, False)
 

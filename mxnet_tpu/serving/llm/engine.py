@@ -1232,6 +1232,13 @@ class LLMEngine:
             for bid in hit:
                 self.cache.allocator.ref(bid)
             self.scheduler.place(seq, slot)
+            if seq.t_admit is None:
+                # the queue wait ends here, once a request: a preempted
+                # sequence's way back in counts under preemptions
+                seq.t_admit = time.monotonic()
+                if self._stats:
+                    self._stats.record_admitted(
+                        seq.t_admit - seq.t_submit)
             seq.block_ids = list(hit)
             seq.seq_len = hit_tokens
             seq.draft_len = 0
@@ -1686,15 +1693,23 @@ class LLMEngine:
         if any(plans[s]["kind"] == "decode" for s in rows):
             # chaos-harness site: scripted raises / injected latency
             faults.check("llm.decode")
-        t_need = sum(len(plans[s]["tokens"]) for s in rows)
-        t = next(w for w in self._t_buckets if w >= t_need)
-        mb_need = max(self.cache.blocks_for(
-            s.seq_len + plans[s]["ntok"]) for s in rows)
-        mb = next(w for w in self._mb_widths if w >= mb_need)
-        sampled = any(s.sampling.temperature > 0 for s in rows)
-        batch = self._build_batch(rows, plans, t, mb)
-        toks, n_acc = self._call_step(sampled, batch)
-        return self._device_get((toks, n_acc))
+        tracer = get_tracer()
+        with tracer.span("mxtpu.llm.build", "llm") as sp:
+            t_need = sum(len(plans[s]["tokens"]) for s in rows)
+            t = next(w for w in self._t_buckets if w >= t_need)
+            mb_need = max(self.cache.blocks_for(
+                s.seq_len + plans[s]["ntok"]) for s in rows)
+            mb = next(w for w in self._mb_widths if w >= mb_need)
+            sampled = any(s.sampling.temperature > 0 for s in rows)
+            batch = self._build_batch(rows, plans, t, mb)
+            sp.set("tokens", t_need)
+            sp.set("t_bucket", t)
+            sp.set("mb", mb)
+        with tracer.span("mxtpu.llm.launch", "llm") as sp:
+            sp.set("sampled", sampled)
+            out = self._call_step(sampled, batch)
+        with tracer.span("mxtpu.llm.fetch", "llm"):
+            return self._device_get(out)
 
     def _sites(self, rows, plans):
         sites = set()
@@ -1862,9 +1877,32 @@ class LLMEngine:
     def step(self):
         """One engine iteration. Returns events:
         ``[("admitted"|"token"|"finished"|"preempted"|"expired"|
-        "poisoned", Sequence)]``."""
+        "poisoned", Sequence)]``.
+
+        The span ``mxtpu.llm.step`` covers the whole iteration; its
+        children ``schedule``, ``build``, ``launch``, ``fetch`` (the
+        last three in :meth:`_dispatch`) and ``commit`` cover it
+        between them."""
         tracer = get_tracer()
         events = []
+        with tracer.span("mxtpu.llm.step", "llm") as sp:
+            with tracer.span("mxtpu.llm.schedule", "llm") as ssp:
+                rows, plans = self._schedule(events)
+                if ssp.span_id is not None:
+                    ssp.set("admitted", sum(
+                        1 for kind, _ in events if kind == "admitted"))
+                    ssp.set("waiting", self.scheduler.num_waiting)
+            if rows:
+                self._run(tracer, sp, rows, plans, events)
+            else:
+                self._record_block_gauges()
+        return events
+
+    def _schedule(self, events):
+        """The host's half of an iteration before any launch: expire,
+        admit, plan and allocate each running sequence, propose
+        drafts. Returns ``(rows, plans)``; no rows, nothing to
+        dispatch."""
         self._expire(events)
         self._admit(events)
         running = sorted(self.scheduler.running(),
@@ -1880,48 +1918,55 @@ class LLMEngine:
             plans[seq] = plan
         rows = [s for s in running
                 if s.state == RUNNING and s in plans]
-        if not rows:
-            self._record_block_gauges()
-            return events
-        self._draft_propose(rows, plans)
-        rows = [s for s in rows if s.state == RUNNING]
-        if not rows:
-            self._record_block_gauges()
-            return events
-        t0 = time.monotonic()
-        with tracer.span("mxtpu.llm.step", "llm") as sp:
+        if rows:
+            self._draft_propose(rows, plans)
+            rows = [s for s in rows if s.state == RUNNING]
+        return rows, plans
+
+    def _run(self, tracer, sp, rows, plans, events):
+        """Dispatch ``rows`` and commit what comes back, under the
+        step span ``sp``."""
+        fl = self._flight
+        prefilling = None
+        if sp.span_id is not None or fl.enabled:
+            prefilling = sum(1 for s in rows
+                             if plans[s]["kind"] == "prefill")
+        if sp.span_id is not None:
             sp.set("running", len(rows))
-            sp.set("prefilling", sum(
-                1 for s in rows if plans[s]["kind"] == "prefill"))
-            try:
-                toks, n_acc = self._dispatch(rows, plans)
-            except Exception as exc:
-                if self._pages_deleted():
-                    raise       # KV pool gone: isolation impossible
-                sp.set("error", repr(exc))
-                self._record_breaker(rows, plans, False)
+            sp.set("prefilling", prefilling)
+            # (tokens fed, context after) of each row
+            sp.set("rows", [(len(plans[s]["tokens"]),
+                             s.seq_len + len(plans[s]["tokens"]))
+                            for s in rows])
+        t0 = time.monotonic()
+        try:
+            out = self._dispatch(rows, plans)
+        except Exception as exc:
+            if self._pages_deleted():
+                raise       # KV pool gone: isolation impossible
+            sp.set("error", repr(exc))
+            out = None
+        # commit, and the step's bookkeeping once its results are in
+        with tracer.span("mxtpu.llm.commit", "llm") as csp:
+            self._record_breaker(rows, plans, out is not None)
+            if out is None:
                 with tracer.span("mxtpu.llm.isolate", "llm") as isp:
                     isp.set("n", len(rows))
                     decoded = self._isolate(rows, plans, events)
             else:
-                self._record_breaker(rows, plans, True)
-                decoded = self._commit(rows, plans, toks, n_acc,
-                                       events)
-        step_s = time.monotonic() - t0
-        if self._stats and any(plans[s]["kind"] == "decode"
-                               for s in rows if s in plans):
-            self._stats.record_decode_step(decoded, step_s)
-        fl = self._flight
-        if fl.enabled:
-            fl.event("llm.step",
-                     attrs={"running": len(rows),
-                            "prefilling": sum(
-                                1 for s in rows
-                                if plans[s]["kind"] == "prefill"),
-                            "decoded": decoded,
-                            "step_ms": round(step_s * 1e3, 3)})
-        self._record_block_gauges()
-        return events
+                decoded = self._commit(rows, plans, *out, events)
+            csp.set("decoded", decoded)
+            step_s = time.monotonic() - t0
+            if self._stats and any(plans[s]["kind"] == "decode"
+                                   for s in rows if s in plans):
+                self._stats.record_decode_step(decoded, step_s)
+            if fl.enabled:
+                fl.event("llm.step",
+                         attrs={"running": len(rows),
+                                "prefilling": prefilling,
+                                "decoded": decoded,
+                                "step_ms": round(step_s * 1e3, 3)})
+            self._record_block_gauges()
 
     def pop_finished(self):
         """Drain the finished-but-unreported sequences. The server

@@ -16,6 +16,8 @@ The serving-economics headline numbers ("Fine-Tuning and Serving Gemma
   precise windows);
 - ``mxtpu_llm_ttft_seconds`` — time-to-first-token histogram (submit →
   first generated token, i.e. queue wait + prefill);
+- ``mxtpu_llm_queue_wait_seconds`` — the queue wait alone (submit →
+  first placement into a decode slot);
 - ``mxtpu_llm_kv_blocks_in_use`` / ``_total`` — paged-cache occupancy.
 """
 from __future__ import annotations
@@ -153,6 +155,12 @@ class LLMStats:
             "Time to first token: submit -> first generated token "
             "(queue wait + prefill).", lbl,
             buckets=_LATENCY_BUCKETS).labels(**s)
+        self._queue_wait = r.histogram(
+            "mxtpu_llm_queue_wait_seconds",
+            "Admission queue wait: submit -> first placement into a "
+            "decode slot (a preempted sequence's re-admission counts "
+            "under preemptions, not here).", lbl,
+            buckets=_LATENCY_BUCKETS).labels(**s)
         self._latency = r.histogram(
             "mxtpu_llm_request_seconds",
             "Per-request end-to-end latency (submit -> last token).",
@@ -288,6 +296,10 @@ class LLMStats:
     def record_prefill(self, prompt_tokens):
         self._prefills.inc()
         self._prefill_tokens.inc(prompt_tokens)
+
+    def record_admitted(self, wait_s):
+        """A request's first admission, ``wait_s`` after its submit."""
+        self._queue_wait.observe(wait_s)
 
     def record_first_token(self, ttft_s, exemplar=None):
         """``exemplar`` (optional ``(req, span_id)``): keep this
@@ -460,6 +472,8 @@ class LLMStats:
                 "prefill_tokens_saved": int(
                     self._prefill_saved.value),
                 "tokens_per_sec": self._tps.value,
+                "admissions": int(self._queue_wait.count),
+                "queue_wait_s_sum": self._queue_wait.sum,
                 "ttft_ms": {
                     "p50": self._ttft.percentile(50) * 1e3,
                     "p99": self._ttft.percentile(99) * 1e3,

@@ -47,7 +47,7 @@ class Sequence:
     __slots__ = ("seq_id", "prompt", "orig_prompt_len", "generated",
                  "max_new_tokens", "stop_token", "state", "slot",
                  "block_ids", "seq_len", "last_token", "t_submit",
-                 "t_first_token", "admit_index", "preemptions",
+                 "t_admit", "t_first_token", "admit_index", "preemptions",
                  "future", "span", "finish_reason", "deadline",
                  "cancelled", "tenant", "sampling", "draft_len",
                  "prefill_started", "prefix_hashes",
@@ -73,6 +73,7 @@ class Sequence:
         self.seq_len = 0          # tokens whose KV sits in the cache
         self.last_token = None    # next decode input
         self.t_submit = time.monotonic()
+        self.t_admit = None       # first placement into a slot
         self.t_first_token = None
         self.admit_index = None   # admission order; evict newest first
         self.preemptions = 0

@@ -40,10 +40,15 @@ Overload & failure semantics (docs/SERVING.md):
 
 Observability: per-request hand-off spans (``mxtpu.llm.request``
 opened under the caller's context, finished by the worker with
-ttft/token counts), engine prefill/decode/isolate spans, the
+queue-wait/ttft/token counts); on the worker thread one
+``mxtpu.llm.step`` an engine iteration with the children
+``mxtpu.llm.schedule``, ``.build``, ``.launch``, ``.fetch`` and
+``.commit`` (``mxtpu.llm.isolate`` under it on the failure path) and
+its sibling ``mxtpu.llm.resolve`` around this loop's flush; the
 ``mxtpu_llm_*`` registry series (:class:`~.metrics.LLMStats`) and the
 shared ``mxtpu_serving_{shed,deadline_expired,poison_isolated,
-breaker_state}`` overload series.
+breaker_state}`` overload series. Spans record while the tracer is on
+or any jax profiler capture runs (docs/OBSERVABILITY.md).
 """
 from __future__ import annotations
 
@@ -352,11 +357,12 @@ class LLMServer:
         seq.future = Future()
         seq.future._mxtpu_seq = seq        # generate-timeout cancel hook
         tracer = get_tracer()
-        if tracer.enabled:
-            seq.span = tracer.begin("mxtpu.llm.request", "llm",
-                                    tracer.current())
-            seq.span.set("seq_id", seq.seq_id)
-            seq.span.set("prompt", len(prompt))
+        span = tracer.begin("mxtpu.llm.request", "llm",
+                            tracer.current())
+        if span.span_id is not None:
+            seq.span = span
+            span.set("seq_id", seq.seq_id)
+            span.set("prompt", len(prompt))
         with self._cv:
             if self._closed:
                 if seq.span is not None:
@@ -653,6 +659,9 @@ class LLMServer:
         self._stats.record_tenant_tokens(seq.tenant, len(res.tokens))
         if seq.span is not None:
             seq.span.set("tokens", len(res.tokens))
+            if seq.t_admit is not None:
+                seq.span.set("queue_wait_ms", round(
+                    (seq.t_admit - seq.t_submit) * 1e3, 3))
             if ttft is not None:
                 seq.span.set("ttft_ms", round(ttft * 1e3, 3))
             seq.span.set("finish", seq.finish_reason)
@@ -719,14 +728,19 @@ class LLMServer:
     def _flush_engine(self):
         """Resolve everything every engine retired since the last
         call: completions, deadline/cancel expiries, poison
-        isolations."""
+        isolations. Returns how many it resolved."""
+        resolved = 0
         for eng in self._engines:
             for seq in eng.pop_finished():
                 self._resolve_finished(seq)
+                resolved += 1
             for seq, reason in eng.pop_dead():
                 self._resolve_dead(seq, reason)
+                resolved += 1
             for seq, exc in eng.pop_poison():
                 self._resolve_poison(seq, exc)
+                resolved += 1
+        return resolved
 
     def _fail_everything(self, exc):
         """Worker-death cleanup: resolve EVERY live Future (engine +
@@ -781,6 +795,7 @@ class LLMServer:
 
     def _run_loop_inner(self):
         engines = self._engines
+        tracer = get_tracer()
         while True:
             with self._cv:
                 while (not self._pending
@@ -814,6 +829,10 @@ class LLMServer:
                 if eng.has_work():
                     eng.step()
                     stepped = True
-            self._flush_engine()
+            # the sibling of mxtpu.llm.step on this thread: Futures
+            # resolve here, with their callbacks
+            with tracer.span("mxtpu.llm.resolve", "llm") as sp:
+                resolved = self._flush_engine()
+                sp.set("resolved", resolved)
             if not stepped:
                 continue

@@ -139,14 +139,38 @@ def test_attach_propagates_context_to_plain_thread(tracer):
     assert work["tid"] != by["producer"][0]["tid"]
 
 
-def test_step_annotation_goes_to_innermost_step_span(tracer, monkeypatch):
+@pytest.fixture
+def capture(tmp_path):
+    """A real ``jax.profiler`` capture on the CPU backend, started the
+    way an operator or the benchmark starts one: through jax, not
+    through ``mx.profiler``."""
+    import jax
+    jax.profiler.start_trace(str(tmp_path / "capture"))
+    try:
+        yield
+    finally:
+        if jax.profiler.TraceAnnotation.is_enabled():
+            jax.profiler.stop_trace()
+
+
+@pytest.fixture
+def tracer_off():
+    """The process tracer off and emptied, as a run without
+    ``MXNET_TPU_TRACE`` has it; emptied again afterwards."""
+    tr = get_tracer()
+    assert not tr.enabled
+    tr.clear()
+    yield tr
+    tr.clear()
+
+
+def test_step_annotation_goes_to_innermost_step_span(tracer, capture):
     """XLA step markers do not nest: while a profiler capture runs, only
     the OUTERMOST-at-open step-category span becomes a
     jax.profiler.StepTraceAnnotation — an enclosing epoch span or a
     trainer.step wrapped by CompiledTrainStep's fallback must not garble
     per-step device attribution."""
     import jax
-    monkeypatch.setattr(tracing_mod, "_profiler_running", lambda: True)
     Step = jax.profiler.StepTraceAnnotation
     with tracer.span("epoch", "epoch", attrs={"epoch": 0}) as ep:
         assert not isinstance(ep._ann, Step)
@@ -158,7 +182,8 @@ def test_step_annotation_goes_to_innermost_step_span(tracer, monkeypatch):
         with tracer.span("step2", "step", step=4) as nxt:
             assert isinstance(nxt._ann, Step), \
                 "depth must unwind when the outer step span finishes"
-    # the tracer-off bridge (_AnnSpan) obeys the same rule
+    # with the tracer off the capture alone makes spans real, and they
+    # obey the same rule
     tracer.disable()
     outer = tracer.span("step", "step", step=5)
     with outer:
@@ -167,6 +192,48 @@ def test_step_annotation_goes_to_innermost_step_span(tracer, monkeypatch):
         with inner:
             assert not isinstance(inner._ann, Step)
     tracer.enable()
+
+
+def test_capture_records_spans_with_the_tracer_off(tracer_off, capture):
+    """Tracer off and a capture that jax itself started: spans land in
+    the ring with parents, durations and attributes, same-thread spans
+    are annotations in the capture, hand-off spans are recorded and not
+    annotated; once the capture has stopped the shared no-op is back."""
+    import jax
+    tr = tracer_off
+    with tr.span("outer", "llm") as outer:
+        assert outer.span_id is not None and outer._ann is not None
+        with tr.span("inner", attrs={"k": 1}) as inner:
+            inner.set("x", "y")
+        handoff = tr.begin("handoff", parent=outer)
+        assert handoff.span_id is not None and handoff._ann is None
+    worker = threading.Thread(target=handoff.finish)
+    worker.start()
+    worker.join()
+    jax.profiler.stop_trace()
+    assert tr.span("after") is tr.begin("after")       # the shared _NULL
+    assert tr.span("after").span_id is None
+    by = _spans_by_name(tr)
+    assert set(by) == {"outer", "inner", "handoff"}
+    assert by["inner"][0]["parent_id"] == by["outer"][0]["span_id"]
+    assert by["handoff"][0]["parent_id"] == by["outer"][0]["span_id"]
+    assert by["outer"][0]["parent_id"] is None
+    assert by["inner"][0]["attrs"] == {"k": 1, "x": "y"}
+    assert by["outer"][0]["dur_us"] >= by["inner"][0]["dur_us"] >= 0
+    assert not tr.enabled
+
+
+def test_tracer_module_imports_without_jax():
+    """The off path asks ``sys.modules`` for jax and never imports it."""
+    import subprocess
+    code = ("import importlib.util, sys\n"
+            "spec = importlib.util.spec_from_file_location('t', sys.argv[1])\n"
+            "m = importlib.util.module_from_spec(spec)\n"
+            "spec.loader.exec_module(m)\n"
+            "assert m._capture_running() is False\n"
+            "assert 'jax' not in sys.modules\n")
+    subprocess.run([sys.executable, "-c", code, tracing_mod.__file__],
+                   check=True)
 
 
 def test_validator_rejects_malformed_documents():
@@ -315,6 +382,61 @@ def test_tracing_off_training_hot_path_allocates_no_spans():
         step(*b)
     assert started.value - c0 == 0, \
         "disabled tracing must not start spans on the step hot path"
+
+
+def test_tracing_off_engine_steps_allocate_no_spans():
+    """The same contract over the LLM engine: with the tracer off and
+    no capture, scheduling, dispatching and committing whole requests
+    start zero spans, and the queue wait is still counted."""
+    from mxnet_tpu.serving.llm import (DecoderConfig, LLMServer,
+                                       TinyDecoder)
+    tr = get_tracer()
+    assert not tr.enabled
+    started = get_registry().counter("mxtpu_trace_spans_started_total")
+    model = TinyDecoder(DecoderConfig(vocab_size=17, d_model=16,
+                                      num_layers=2, num_heads=2, d_ff=32,
+                                      max_context=64))
+    srv = LLMServer(model, model.init_params(seed=0), name="trace_off",
+                    max_seqs=2)
+    srv.warmup()
+    srv.start()
+    c0 = started.value
+    futures = [srv.submit([1, 2, 3, 4 + i], 6) for i in range(4)]
+    assert all(len(f.result(timeout=60).tokens) == 6 for f in futures)
+    stats = srv.stats()
+    srv.shutdown(drain=False)
+    assert started.value - c0 == 0, \
+        "disabled tracing must not start spans on the serving hot path"
+    assert stats["decode_steps"] > 0 and stats["admissions"] == 4
+    assert stats["queue_wait_s_sum"] > 0
+
+
+def test_capture_splits_the_compiled_training_step(tracer_off, capture):
+    """Tracer off, a capture running: every compiled step leaves
+    ``mxtpu.train_step`` with prepare, dispatch and rebind under it, in
+    that order, and the first one its compile."""
+    import jax
+    net = _build(22)
+    trainer = Trainer(net.collect_params(), "sgd",
+                      {"learning_rate": 0.05})
+    step = trainer.compile_step(lambda x, y: LOSS(net(x), y))
+    for b in _batches(3):
+        step(*b)
+    jax.profiler.stop_trace()
+    spans = tracer_off.snapshot()
+    steps = [s for s in spans if s["name"] == "mxtpu.train_step"]
+    assert [s["attrs"]["step"] for s in steps] == [0, 1, 2]
+    for i, st in enumerate(steps):
+        kids = sorted((s for s in spans
+                       if s["parent_id"] == st["span_id"]),
+                      key=lambda s: s["ts_us"])
+        want = ["prepare", "dispatch", "rebind"]
+        if i == 0:
+            want.insert(1, "compile")
+        assert [k["name"] for k in kids] == \
+            ["mxtpu.train_step." + w for w in want]
+        assert sum(k["dur_us"] for k in kids) <= st["dur_us"]
+        assert all(k["tid"] == st["tid"] for k in kids)
 
 
 # ------------------------------------------------------- acceptance --
