@@ -113,7 +113,8 @@ def ragged_chunk_attention_reference(q, k_pages, v_pages, block_tables,
 
 def ragged_flat_attention_reference(q, k_pages, v_pages, block_tables,
                                     seq_ids, positions, scale=None,
-                                    k_scales=None, v_scales=None):
+                                    k_scales=None, v_scales=None,
+                                    layer=None):
     """Gather-based oracle for the FLAT ragged layout: ``q`` is a
     packed ``[T, H, D]`` batch of query tokens from MANY sequences —
     token ``t`` belongs to row ``seq_ids[t]`` of ``block_tables`` and
@@ -129,17 +130,23 @@ def ragged_flat_attention_reference(q, k_pages, v_pages, block_tables,
     Quantized pages (ISSUE 13): with ``k_scales``/``v_scales``
     ``(N, bs, H)`` f32, the int8 pages are dequantized per slot+head
     right after the gather — ``k = int8 * scale`` — and everything
-    downstream runs in f32 exactly as the float path does."""
+    downstream runs in f32 exactly as the float path does.
+
+    With ``layer`` the pools (and scale pools) are the STACKED
+    ``(L, N, bs, H[, D])`` arrays and the gather itself picks the
+    layer — the same values ``pool[layer]`` would hand over, without
+    a layer-sized intermediate."""
     T, H, D = q.shape
-    bs = k_pages.shape[1]
+    bs = k_pages.shape[-3]
     MB = block_tables.shape[1]
     s = scale if scale is not None else float(1.0 / (D ** 0.5))
     tbl = block_tables[seq_ids]                       # (T, MB)
-    k = k_pages[tbl]
-    v = v_pages[tbl]
+    at = (tbl,) if layer is None else (layer, tbl)
+    k = k_pages[at]
+    v = v_pages[at]
     if k_scales is not None:
-        k = k.astype(jnp.float32) * k_scales[tbl][..., None]
-        v = v.astype(jnp.float32) * v_scales[tbl][..., None]
+        k = k.astype(jnp.float32) * k_scales[at][..., None]
+        v = v.astype(jnp.float32) * v_scales[at][..., None]
     k = k.reshape(T, MB * bs, H, D)
     v = v.reshape(T, MB * bs, H, D)
     logits = jnp.einsum("thd,tkhd->htk", q.astype(jnp.float32),
@@ -155,11 +162,20 @@ def ragged_flat_attention_reference(q, k_pages, v_pages, block_tables,
 # ----------------------------------------------------------- pallas --
 
 
-def _flat_kernel(sid_ref, pos_ref, bt_ref, q_ref, k_ref, v_ref, *refs,
-                 scale, block_size, num_blocks):
+def _flat_kernel(layer_ref, sid_ref, pos_ref, bt_ref, q_ref, k_ref,
+                 v_ref, *refs, scale, block_size, num_blocks):
     """Grid (T, MB): the decode kernel generalized to per-TOKEN
     sequence indirection — the page DMA for grid step (t, j) is
-    index-mapped through ``block_tables[seq_ids[t], j]``.
+    index-mapped through ``(layer, block_tables[seq_ids[t], j])``.
+
+    The K/V operands are the STACKED pools ``[L, N, bs, H, D]``,
+    whole: the layer is a scalar-prefetch operand that only the index
+    maps read (``layer_ref`` is unused in the body), so every layer
+    of a model shares this one kernel body and each ``(bs, H, D)``
+    tile is DMA'd straight out of the pool the step updates in place.
+    A per-layer ``pool[li]`` handed over instead is a copy: XLA
+    neither fuses a slice into a Mosaic custom call nor aliases it to
+    its operand.
 
     ``refs`` is ``(o, acc, m, l)`` for float pages, or ``(k_scales,
     v_scales, o, acc, m, l)`` for QUANTIZED pages: the K/V tiles then
@@ -212,36 +228,39 @@ def _flat_kernel(sid_ref, pos_ref, bt_ref, q_ref, k_ref, v_ref, *refs,
         o_ref[...] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnums=(8, 9))
+@functools.partial(jax.jit, static_argnums=(9, 10))
 def _ragged_flat_pallas(q, k_pages, v_pages, k_scales, v_scales,
-                        block_tables, seq_ids, positions, scale,
+                        block_tables, seq_ids, positions, layer, scale,
                         interpret):
     T, H, D = q.shape
-    bs = k_pages.shape[1]
+    bs = k_pages.shape[2]
     MB = block_tables.shape[1]
 
-    def tok(t, j, sid, pos, bt):
+    def tok(t, j, lyr, sid, pos, bt):
         return (t, 0, 0)
 
-    def page(t, j, sid, pos, bt):
-        return (bt[sid[t], j], 0, 0, 0)
+    def page(t, j, lyr, sid, pos, bt):
+        return (lyr[0], bt[sid[t], j], 0, 0, 0)
 
     in_specs = [
         pl.BlockSpec((None, H, D), tok, memory_space=pltpu.VMEM),
-        pl.BlockSpec((None, bs, H, D), page, memory_space=pltpu.VMEM),
-        pl.BlockSpec((None, bs, H, D), page, memory_space=pltpu.VMEM),
+        pl.BlockSpec((None, None, bs, H, D), page,
+                     memory_space=pltpu.VMEM),
+        pl.BlockSpec((None, None, bs, H, D), page,
+                     memory_space=pltpu.VMEM),
     ]
     args = [q, k_pages, v_pages]
     if k_scales is not None:
-        # the scale tiles ride the SAME scalar-prefetched block-table
-        # index map as their pages
+        # the scale tiles ride the same scalar-prefetched block-table
+        # index map as their pages, over ONE layer's (N, bs, H) scales
+        # (see ragged_flat_attention for why not the stacked pool)
         in_specs += [pl.BlockSpec(
             (None, bs, H),
-            lambda t, j, sid, pos, bt: (bt[sid[t], j], 0, 0),
+            lambda t, j, lyr, sid, pos, bt: (bt[sid[t], j], 0, 0),
             memory_space=pltpu.VMEM)] * 2
         args += [k_scales, v_scales]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(T, MB),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((None, H, D), tok,
@@ -261,40 +280,72 @@ def _ragged_flat_pallas(q, k_pages, v_pages, k_scales, v_scales,
         out_shape=jax.ShapeDtypeStruct((T, H, D), q.dtype,
                                        vma=jax.typeof(q).vma),
         interpret=interpret,
-    )(seq_ids.astype(jnp.int32), positions.astype(jnp.int32),
-      block_tables.astype(jnp.int32), *args)
+    )(layer.astype(jnp.int32).reshape(1), seq_ids.astype(jnp.int32),
+      positions.astype(jnp.int32), block_tables.astype(jnp.int32),
+      *args)
 
 
 def ragged_flat_attention(q, k_pages, v_pages, block_tables, seq_ids,
                           positions, scale=None, use_pallas=None,
-                          interpret=None, k_scales=None, v_scales=None):
+                          interpret=None, k_scales=None, v_scales=None,
+                          layer=None):
     """Flat-ragged paged attention entry point (packed
     ``[total_q_tokens]`` batch, per-token sequence/position
     indirection). Gated exactly like :func:`ragged_paged_attention`.
 
-    ``k_scales``/``v_scales`` ``(N, bs, H)`` f32 select the QUANTIZED
-    page variant: pages are int8 and are dequantized per slot+head
-    inside the kernel (fused after the page DMA on the Pallas path,
-    right after the gather on the reference path)."""
+    Pools: with ``layer`` (a Python int or a traced int32 scalar)
+    ``k_pages``/``v_pages`` are the STACKED pools ``[L, N, bs, H, D]``
+    of a whole model and the kernel reads layer ``layer`` of them in
+    place. That is the form a multi-layer step must use: a
+    ``pool[li]`` slice handed to the kernel's custom call is
+    materialized (one layer's pages copied out of the pool, for K
+    and again for V, on every call). Without ``layer`` the pools are
+    one layer's ``[N, bs, H, D]`` — a stacked pool of one layer
+    through the same kernel.
+
+    ``k_scales``/``v_scales`` (``[L, N, bs, H]`` with ``layer``, else
+    ``[N, bs, H]``; f32) select the QUANTIZED page variant: pages are
+    int8/fp8 and are dequantized per slot+head inside the kernel
+    (fused after the page DMA on the Pallas path, right after the
+    gather on the reference path)."""
     if use_pallas is None:
         use_pallas = _on_tpu()
     if scale is None:
         scale = float(1.0 / (q.shape[-1] ** 0.5))
     if (k_scales is None) != (v_scales is None):
         raise ValueError("pass both k_scales and v_scales or neither")
+    if k_pages.ndim != (4 if layer is None else 5):
+        raise ValueError(
+            "pools are [L, N, bs, H, D] with `layer`, [N, bs, H, D] "
+            f"without; got {k_pages.shape} and layer={layer!r}")
     if not use_pallas:
         return ragged_flat_attention_reference(
-            q, k_pages, v_pages, block_tables, jnp.asarray(seq_ids),
-            jnp.asarray(positions), scale, k_scales=k_scales,
-            v_scales=v_scales)
+            q, k_pages, v_pages, jnp.asarray(block_tables),
+            jnp.asarray(seq_ids), jnp.asarray(positions), scale,
+            k_scales=k_scales, v_scales=v_scales, layer=layer)
     if interpret is None:
         interpret = not _on_tpu()
     if k_scales is not None:
         k_scales, v_scales = jnp.asarray(k_scales), jnp.asarray(v_scales)
+        if layer is not None:
+            # the kernel takes ONE layer's scales, sliced out here.
+            # Unlike the pages, the stacked [L, N, bs, H] pool does
+            # not sit row-major on the device (XLA:TPU lays an f32
+            # array with a 16x16 minor face out N-minor), and the
+            # custom call takes row-major operands only: handed
+            # whole, the WHOLE scale pool is re-laid out on every
+            # call (seen in the compiled step: 48 copies of
+            # f32[24,1025,16,16], 201 MB each once padded). A
+            # layer's slice is 1 MB, re-laid as it always was.
+            k_scales, v_scales = k_scales[layer], v_scales[layer]
+    if layer is None:
+        layer = 0
+        k_pages, v_pages = k_pages[None], v_pages[None]
     return _ragged_flat_pallas(q, k_pages, v_pages, k_scales, v_scales,
                                jnp.asarray(block_tables),
                                jnp.asarray(seq_ids),
                                jnp.asarray(positions),
+                               jnp.asarray(layer),
                                float(scale), bool(interpret))
 
 
@@ -302,12 +353,14 @@ def ragged_flat_attention_sharded(q, k_pages, v_pages, block_tables,
                                   seq_ids, positions, axis_name=None,
                                   scale=None, use_pallas=None,
                                   interpret=None, k_scales=None,
-                                  v_scales=None):
+                                  v_scales=None, layer=None):
     """Head-sharded flat variant for ``shard_map`` bodies (ISSUE 19),
     incl. the quantized-page form: ``q [T, H_local, D]``, pages
-    ``(N, bs, H_local, D)`` and scale pools ``(N, bs, H_local)``
-    carry ONLY this shard's heads; ``block_tables/seq_ids/positions``
-    ride replicated (host-global block accounting).
+    ``([L,] N, bs, H_local, D)`` and scale pools
+    ``([L,] N, bs, H_local)`` carry ONLY this shard's heads (stacked
+    over layers with ``layer``, as in :func:`ragged_flat_attention`);
+    ``block_tables/seq_ids/positions`` ride replicated (host-global
+    block accounting).
 
     Attention is per-head independent and the softmax scale is
     ``1/sqrt(head_dim)`` — never head-count-dependent — so the local
@@ -324,7 +377,7 @@ def ragged_flat_attention_sharded(q, k_pages, v_pages, block_tables,
     return ragged_flat_attention(
         q, k_pages, v_pages, block_tables, seq_ids, positions,
         scale=scale, use_pallas=use_pallas, interpret=interpret,
-        k_scales=k_scales, v_scales=v_scales)
+        k_scales=k_scales, v_scales=v_scales, layer=layer)
 
 
 def _chunk_kernel(bt_ref, len_ref, qlen_ref, q_ref, k_ref, v_ref,

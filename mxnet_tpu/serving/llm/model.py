@@ -331,6 +331,15 @@ class TinyDecoder:
         order so later chunk tokens see earlier ones' writes.
         Returns (logits [T, V], k_pages, v_pages).
 
+        Pools: ``k_pages``/``v_pages`` are the stacked
+        ``[L, N, bs, H, D]`` pools. Each layer writes its tokens in
+        place (``.at[li, bidx, slot].set``) and hands the ragged
+        kernel the WHOLE pool with ``layer=li`` — never
+        ``k_pages[li]``: a slice handed to the kernel's custom call
+        is a copy of one layer's pages (134 MB each for K and V at
+        Cerebras-GPT-1.3B's pool, 48 times a step). The kernel's page
+        index map takes the layer instead.
+
         Quantized KV (ISSUE 13): with ``k_scales``/``v_scales``
         ``[L, N, bs, H]`` f32 the pages are int8 — each token's K/V is
         quantized symmetrically per (slot, head) on write (scale =
@@ -477,18 +486,16 @@ class TinyDecoder:
                 k_scales = k_scales.at[li, bidx, slot].set(ksc)
                 v_scales = v_scales.at[li, bidx, slot].set(vsc)
                 att = attn(
-                    q, k_pages[li], v_pages[li], block_tables,
-                    seq_ids, positions, k_scales=k_scales[li],
-                    v_scales=v_scales[li])
+                    q, k_pages, v_pages, block_tables, seq_ids,
+                    positions, k_scales=k_scales, v_scales=v_scales,
+                    layer=li)
             else:
                 k_pages = k_pages.at[li, bidx, slot].set(
                     k.astype(k_pages.dtype))
                 v_pages = v_pages.at[li, bidx, slot].set(
                     v.astype(v_pages.dtype))
-                att = attn(q, k_pages[li],
-                           v_pages[li],
-                           block_tables, seq_ids,
-                           positions)
+                att = attn(q, k_pages, v_pages, block_tables,
+                           seq_ids, positions, layer=li)
             att2d = att.reshape(T, heads_here * c.head_dim)
             o = _mm(att2d, lp["wo"], _lsc("wo"))
             if axis_name is not None:

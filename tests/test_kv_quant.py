@@ -121,6 +121,44 @@ def test_quant_pallas_kernel_matches_reference():
     assert float(jnp.max(jnp.abs(pal - ref))) < KERNEL_TOL
 
 
+@pytest.mark.parametrize("layer", [0, 1, 3],
+                         ids=["first", "middle", "last"])
+@pytest.mark.parametrize("path", ["reference", "pallas"])
+@pytest.mark.parametrize("kv", ["int8", "fp8"])
+def test_quant_stacked_pool_equals_layer_slice_bitwise(kv, path, layer):
+    """Quantized pages and their scales handed over as the stacked
+    ``[L, ...]`` pools with a layer index (ISSUE 27) equal the
+    per-layer-slice call bit for bit; the other layers hold garbage
+    (saturated pages under infinite scales) that must stay unread."""
+    from mxnet_tpu.serving.llm import fp8_supported
+    if kv == "fp8" and not fp8_supported():
+        pytest.skip("no fp8-e4m3 dtype on this backend")
+    rng = np.random.RandomState(4)
+    _, kq, ks = _quantize_pages(rng, 11, BS, 2, 8)
+    _, vq, vs = _quantize_pages(rng, 11, BS, 2, 8)
+    if kv == "fp8":
+        fp8 = jnp.dtype("float8_e4m3fn")
+        kq, vq = kq.astype(fp8), vq.astype(fp8)
+    q = rng.randn(8, 2, 8).astype(np.float32)
+    bt = np.array([[9, 2, 5, 1], [7, 10, 0, 0], [3, 8, 6, 4]], np.int32)
+    sid = np.array([0, 0, 0, 1, 1, 2, 2, 2], np.int32)
+    pos = np.array([0, 7, 25, 8, 15, 3, 17, 31], np.int32)
+
+    def stack(a, garbage):
+        out = np.full((4,) + a.shape, garbage, a.dtype)
+        out[layer] = a
+        return out
+    kw = dict(use_pallas=(path == "pallas"), interpret=True)
+    want = np.asarray(ragged_flat_attention(
+        q, kq, vq, bt, sid, pos, k_scales=ks, v_scales=vs, **kw))
+    got = np.asarray(ragged_flat_attention(
+        q, stack(kq, 127), stack(vq, 127), bt, sid, pos,
+        k_scales=stack(ks, np.inf), v_scales=stack(vs, np.inf),
+        layer=layer, **kw))
+    assert np.isfinite(want).all()
+    assert np.array_equal(got, want)
+
+
 def test_quant_requires_both_scales():
     q = np.zeros((1, 2, 8), np.float32)
     kp = np.zeros((2, BS, 2, 8), np.int8)

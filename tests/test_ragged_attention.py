@@ -247,6 +247,19 @@ def test_chunk_padded_tail_and_garbage_invisible():
         np.testing.assert_array_equal(base[i, :qn], got[i, :qn])
 
 
+def _pack_flat(q, kv_lens, q_lens):
+    """Pack the valid tokens of a chunk-shaped ``q [S, Q, H, D]`` flat:
+    (flat_q [T, H, D], seq_ids [T], positions [T]), rows in order."""
+    flat_q, sids, poss = [], [], []
+    for i, qn in enumerate(q_lens):
+        for t in range(qn):
+            flat_q.append(q[i, t])
+            sids.append(i)
+            poss.append(kv_lens[i] - qn + t)
+    return (np.stack(flat_q), np.asarray(sids, np.int32),
+            np.asarray(poss, np.int32))
+
+
 @pytest.mark.parametrize("path", ["reference", "pallas"])
 def test_flat_parity_vs_chunk_shape(path):
     """The FLAT packed layout (the engine's hot path) must agree with
@@ -260,20 +273,141 @@ def test_flat_parity_vs_chunk_shape(path):
     chunk = np.asarray(ragged_paged_attention(
         q, kp, vp, bt, kl, q_lens=ql,
         use_pallas=(path == "pallas"), interpret=True))
-    # pack the valid tokens flat
-    flat_q, sids, poss, want = [], [], [], []
-    for i, qn in enumerate(q_lens):
-        for t in range(qn):
-            flat_q.append(q[i, t])
-            sids.append(i)
-            poss.append(kv_lens[i] - qn + t)
-            want.append(chunk[i, t])
+    flat_q, sids, poss = _pack_flat(q, kv_lens, q_lens)
+    want = np.stack([chunk[i, t] for i, qn in enumerate(q_lens)
+                     for t in range(qn)])
     got = np.asarray(ragged_flat_attention(
-        np.stack(flat_q), kp, vp, bt,
-        np.asarray(sids, np.int32), np.asarray(poss, np.int32),
+        flat_q, kp, vp, bt, sids, poss,
         use_pallas=(path == "pallas"), interpret=True))
-    np.testing.assert_allclose(got, np.stack(want),
-                               rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+
+
+# -------------------------------------------- stacked pools (ISSUE 27) --
+
+STACK_L = 4
+
+
+def _flat_case(seed=31):
+    """A packed flat batch over one layer's pages: (q, kp, vp, bt,
+    seq_ids, positions)."""
+    kv_lens = [13, 5, 2 * BS]
+    q_lens = [5, 2, 1]
+    q, kp, vp, bt, _, _, _ = _chunk_case(kv_lens, q_lens, seed=seed)
+    flat_q, sids, poss = _pack_flat(q, kv_lens, q_lens)
+    return flat_q, kp, vp, bt, sids, poss
+
+
+def _stack_with_garbage(pages, layer, garbage=np.nan):
+    """``[STACK_L, *pages.shape]`` holding ``pages`` at ``layer`` and
+    ``garbage`` in every other layer: anything read from a wrong layer
+    poisons the output."""
+    out = np.full((STACK_L,) + pages.shape, garbage, pages.dtype)
+    out[layer] = pages
+    return out
+
+
+@pytest.mark.parametrize("layer", [0, 2, STACK_L - 1],
+                         ids=["first", "middle", "last"])
+@pytest.mark.parametrize("path", ["reference", "pallas"])
+def test_flat_stacked_pool_equals_layer_slice_bitwise(path, layer):
+    """Handing the kernel the whole ``[L, N, bs, H, D]`` pool with a
+    layer index is the per-layer-slice call, bit for bit: the layer
+    only moves where each page is fetched from."""
+    from mxnet_tpu.ops.ragged_attention import ragged_flat_attention
+    q, kp, vp, bt, sid, pos = _flat_case()
+    kw = dict(use_pallas=(path == "pallas"), interpret=True)
+    want = np.asarray(ragged_flat_attention(q, kp, vp, bt, sid, pos,
+                                            **kw))
+    got = np.asarray(ragged_flat_attention(
+        q, _stack_with_garbage(kp, layer),
+        _stack_with_garbage(vp, layer), bt, sid, pos, layer=layer,
+        **kw))
+    assert np.isfinite(want).all()
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("path", ["reference", "pallas"])
+def test_flat_stacked_pool_takes_a_traced_layer(path):
+    """The layer is data (a scalar-prefetch operand of the kernel,
+    an index of the reference's gather): one jitted program serves
+    every layer."""
+    import jax
+    from mxnet_tpu.ops.ragged_attention import ragged_flat_attention
+    q, kp, vp, bt, sid, pos = _flat_case(seed=32)
+    kw = dict(use_pallas=(path == "pallas"), interpret=True)
+    want = np.asarray(ragged_flat_attention(q, kp, vp, bt, sid, pos,
+                                            **kw))
+    fn = jax.jit(lambda ks, vs, lyr: ragged_flat_attention(
+        q, ks, vs, bt, sid, pos, layer=lyr, **kw))
+    for layer in (1, STACK_L - 1):
+        got = fn(_stack_with_garbage(kp, layer),
+                 _stack_with_garbage(vp, layer), jnp.int32(layer))
+        np.testing.assert_array_equal(np.asarray(got), want)
+    assert fn._cache_size() == 1
+
+
+@pytest.mark.parametrize("layer", [None, 0],
+                         ids=["stacked-without-layer",
+                              "layer-without-stack"])
+def test_flat_pool_rank_must_match_layer(layer):
+    from mxnet_tpu.ops.ragged_attention import ragged_flat_attention
+    q, kp, vp, bt, sid, pos = _flat_case()
+    if layer is None:
+        kp, vp = kp[None], vp[None]
+    with pytest.raises(ValueError, match="layer"):
+        ragged_flat_attention(q, kp, vp, bt, sid, pos, layer=layer)
+
+
+def _all_eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs nested in it (jit
+    bodies, the Pallas kernel's body)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in eqn.params.values():
+            for j in (sub if isinstance(sub, (list, tuple)) else (sub,)):
+                j = getattr(j, "jaxpr", j)
+                if hasattr(j, "eqns"):
+                    yield from _all_eqns(j)
+
+
+@pytest.mark.parametrize("kv_dtype", ["float32", "int8"])
+def test_decode_flat_hands_the_kernel_whole_pools(kv_dtype, monkeypatch):
+    """The structure that keeps a layer's pages from being copied out
+    of the pool (ISSUE 27): with the kernel on, every ``pallas_call``
+    of ``decode_flat`` takes K and V at their full
+    ``[L, N, bs, H, D]`` shape, and NO equation anywhere produces an
+    array shaped like one layer's pages — a slice handed to a custom
+    call is materialized. (Quantized pages: the scale pools are
+    sliced per layer on purpose, see ``ragged_flat_attention``.)"""
+    import jax
+    from mxnet_tpu.ops import ragged_attention as ra
+    from mxnet_tpu.serving.llm.model import DecoderConfig, TinyDecoder
+    monkeypatch.setattr(ra, "_on_tpu", lambda: True)
+    L, N, T, S, MB = 3, 9, 6, 4, 4
+    model = TinyDecoder(DecoderConfig(
+        vocab_size=17, d_model=32, num_layers=L, num_heads=2, d_ff=32,
+        max_context=MB * BS))
+    params = model.init_params(seed=0)
+    pool = jnp.zeros((L, N, BS, 2, 16), kv_dtype)
+    scales = {}
+    if kv_dtype != "float32":
+        sc = jnp.ones((L, N, BS, 2), jnp.float32)
+        scales = dict(k_scales=sc, v_scales=sc)
+    ints = jnp.zeros((T,), jnp.int32)
+    closed = jax.make_jaxpr(
+        lambda kp, vp: model.decode_flat(
+            params, ints, ints, ints, ints, kp, vp,
+            jnp.zeros((S, MB), jnp.int32), **scales))(pool, pool)
+    eqns = list(_all_eqns(closed.jaxpr))
+    calls = [e for e in eqns if e.primitive.name == "pallas_call"]
+    assert len(calls) == L
+    for e in calls:
+        whole = [v for v in e.invars
+                 if getattr(v.aval, "shape", None) == pool.shape]
+        assert len(whole) == 2, [v.aval for v in e.invars]
+    sliced = [e for e in eqns for v in e.outvars
+              if getattr(v.aval, "shape", None) == pool.shape[1:]]
+    assert not sliced, sliced
 
 
 def test_chunk_requires_q_lens():
