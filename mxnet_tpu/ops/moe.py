@@ -1,0 +1,226 @@
+"""Routed experts for the flat serving step: the ``noaux_tc`` sigmoid
+router of DeepSeek-V3-shaped models and the grouped products over the
+experts that a step's tokens hit.
+
+A step's ``T`` tokens each pick ``k`` of ``E`` experts: ``T * k`` (token,
+expert) *pairs*. An expert is a SwiGLU, ``(silu(x Wg) * (x Wu)) Wd``, of
+its own three matrices, so the layer's cost on a decoding step is the
+bytes of the experts that were hit, not of the ``E`` that are held. The
+products therefore run grouped:
+
+- pairs are sorted by expert; each expert's rows are padded to whole
+  tiles of ``TILE_ROWS`` rows, so every tile belongs to one expert;
+- :func:`_grouped_swiglu_pallas` walks the tiles: the tile's expert is a
+  scalar-prefetch operand that only the weights' index maps read, so
+  each grid step DMAs one column block of ONE expert's ``Wg``/``Wu`` and
+  the matching row block of its ``Wd`` out of the stacked ``[E, ...]``
+  weights and accumulates the tile's output in place. An expert that no
+  token hit is never read. Tiles past the last one in use are skipped
+  and map to the block already in VMEM, so they cost a grid step and no
+  bytes;
+- each pair's row is gathered back, scaled by its gate and summed into
+  its token.
+
+No capacity limit and no dropped pair: the number of tiles is sized for
+the worst imbalance (every pair on one expert, or every pair on an
+expert of its own). Rows of bucket padding (``valid == 0``) are given to
+no expert and come back as zeros.
+
+``moe_experts_reference`` is the plain ``jnp`` twin (every expert over
+every token, weighted by a dense ``[T, E]`` matrix of gates) that runs
+off the TPU and pins the kernel in tests.
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .registry import register
+from .flash_attention import _on_tpu
+
+__all__ = ["route_noaux_tc", "moe_experts", "moe_experts_reference",
+           "moe_load"]
+
+#: rows of one tile of the grouped products (a packed bf16 tile)
+TILE_ROWS = 16
+#: columns of an expert's width taken per grid step
+TILE_COLS = 128
+
+
+def route_noaux_tc(x, router, bias, k, norm_topk_prob=True, scaling=1.0):
+    """``(experts [T, k] int32, gates [T, k] float32)`` for ``x [T, d]``:
+    scores ``sigmoid(x router)`` in float32, the ``k`` experts with the
+    largest ``score + bias`` (the bias selects only), gates the selected
+    scores, renormalised to sum to one if ``norm_topk_prob``, times
+    ``scaling``. One group, so the published group limiting is the
+    identity."""
+    s = jax.nn.sigmoid(jnp.matmul(
+        x.astype(jnp.float32), router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    _, sel = jax.lax.top_k(s + bias.astype(jnp.float32), k)
+    gates = jnp.take_along_axis(s, sel, axis=1)
+    if norm_topk_prob:
+        gates = gates / (gates.sum(axis=1, keepdims=True) + 1e-20)
+    return sel.astype(jnp.int32), gates * scaling
+
+
+def moe_load(sel, valid, num_experts):
+    """``int32 [3]``: routed pairs, experts hit and the largest load of
+    one expert, over the rows with ``valid``."""
+    e = jnp.where(valid.astype(bool)[:, None], sel, num_experts)
+    counts = jnp.zeros((num_experts + 1,), jnp.int32).at[
+        e.reshape(-1)].add(1)[:num_experts]
+    return jnp.stack([counts.sum(), (counts > 0).sum(),
+                      counts.max()]).astype(jnp.int32)
+
+
+def _swiglu(x, wg, wu, wd):
+    g = jnp.dot(x, wg, preferred_element_type=jnp.float32)
+    u = jnp.dot(x, wu, preferred_element_type=jnp.float32)
+    return jnp.dot((jax.nn.silu(g) * u).astype(x.dtype), wd,
+                   preferred_element_type=jnp.float32)
+
+
+def moe_experts_reference(x, sel, gates, valid, w_gate, w_up, w_down):
+    """The expert layer's routed part, plainly: every expert over every
+    token, summed with each token's gate on it (0 where the token did
+    not select it, and on rows without ``valid``). ``[T, d]`` float32."""
+    T, E = x.shape[0], w_gate.shape[0]
+    weight = jnp.zeros((T, E), jnp.float32).at[
+        jnp.arange(T)[:, None], sel].add(gates)
+    weight = jnp.where(valid.astype(bool)[:, None], weight, 0.0)
+
+    def one(acc, e):
+        wg, wu, wd, w = e
+        return acc + w[:, None] * _swiglu(x, wg, wu, wd), None
+    out, _ = jax.lax.scan(one, jnp.zeros((T, w_down.shape[2]),
+                                         jnp.float32),
+                          (w_gate, w_up, w_down, weight.T))
+    return out
+
+
+def _grouped_kernel(te_ref, used_ref, x_ref, wg_ref, wu_ref, wd_ref,
+                    o_ref):
+    """Grid (tiles, column blocks): one tile of rows, all of one expert,
+    through one column block of that expert's SwiGLU; the tile's output
+    accumulates over the column blocks."""
+    t, j = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(t < used_ref[0])
+    def _body():
+        y = _swiglu(x_ref[...], wg_ref[...], wu_ref[...], wd_ref[...])
+
+        @pl.when(j == 0)
+        def _first():
+            o_ref[...] = y
+
+        @pl.when(j > 0)
+        def _rest():
+            o_ref[...] += y
+
+
+@functools.partial(jax.jit, static_argnums=(6,))
+def _grouped_swiglu_pallas(x_rows, tile_expert, tiles_used, w_gate, w_up,
+                           w_down, interpret):
+    R, d = x_rows.shape
+    m = w_gate.shape[2]
+    tn = TILE_COLS if m % TILE_COLS == 0 else m
+    nj = m // tn
+
+    def col(t, j, te, used):
+        # a tile past the last one in use stays on the block that is
+        # already there
+        return jnp.where(t < used[0], j, nj - 1)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(R // TILE_ROWS, nj),
+        in_specs=[
+            pl.BlockSpec((TILE_ROWS, d), lambda t, j, te, used: (t, 0),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((None, d, tn), lambda t, j, te, used:
+                         (te[t], 0, col(t, j, te, used)),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((None, d, tn), lambda t, j, te, used:
+                         (te[t], 0, col(t, j, te, used)),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((None, tn, d), lambda t, j, te, used:
+                         (te[t], col(t, j, te, used), 0),
+                         memory_space=pltpu.VMEM),
+        ],
+        out_specs=pl.BlockSpec((TILE_ROWS, d),
+                               lambda t, j, te, used: (t, 0),
+                               memory_space=pltpu.VMEM),
+    )
+    return pl.pallas_call(
+        _grouped_kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((R, d), jnp.float32),
+        interpret=interpret,
+    )(tile_expert, tiles_used, x_rows, w_gate, w_up, w_down)
+
+
+def moe_experts(x, sel, gates, valid, w_gate, w_up, w_down,
+                use_pallas=None, interpret=None):
+    """The routed part of an expert layer for a packed batch: ``x [T,
+    d]`` tokens, ``sel``/``gates`` ``[T, k]`` from the router, ``valid
+    [T]`` (rows of padding are routed nowhere), the experts' stacked
+    ``w_gate``/``w_up`` ``[E, d, m]`` and ``w_down [E, m, d]``. Returns
+    ``sum_i gates[t, i] * E_sel[t, i](x[t])`` as ``[T, d]`` float32.
+    Grouped Mosaic kernel on a TPU (only the experts hit are read), the
+    plain twin elsewhere; see the module docstring."""
+    if use_pallas is None:
+        use_pallas = _on_tpu()
+    if not use_pallas:
+        return moe_experts_reference(x, sel, gates, valid, w_gate, w_up,
+                                     w_down)
+    if interpret is None:
+        interpret = not _on_tpu()
+    T, k = sel.shape
+    E, d = w_gate.shape[0], x.shape[1]
+    P, tm = T * k, TILE_ROWS
+    n_tiles = min(P, E) + P // tm
+    R = n_tiles * tm
+    live = valid.astype(bool)
+    # pairs by expert; a pair of padding goes to the sentinel E, last
+    e = jnp.where(live[:, None], sel, E).reshape(P)
+    counts = jnp.zeros((E + 1,), jnp.int32).at[e].add(1)
+    tiles = (counts[:E] + tm - 1) // tm
+    tile_end = jnp.cumsum(tiles)
+    # first row of each expert's tiles; the sentinel's lies past the end
+    row0 = jnp.concatenate([(tile_end - tiles) * tm,
+                            jnp.full((1,), R, jnp.int32)])
+    first = jnp.cumsum(counts) - counts         # in the sorted order
+    order = jnp.argsort(e, stable=True)
+    e_sorted = e[order]
+    row_sorted = row0[e_sorted] + jnp.arange(P, dtype=jnp.int32) \
+        - first[e_sorted]
+    row_sorted = jnp.minimum(row_sorted, R)     # sentinel pairs: row R
+    # row -> the token it holds (T: the zero row appended below)
+    row_token = jnp.full((R + 1,), T, jnp.int32).at[row_sorted].set(
+        (order // k).astype(jnp.int32))[:R]
+    x_rows = jnp.concatenate([x, jnp.zeros((1, d), x.dtype)])[row_token]
+    used = tile_end[-1:].astype(jnp.int32)
+    tile_expert = jnp.searchsorted(
+        tile_end, jnp.minimum(jnp.arange(n_tiles), used[0] - 1),
+        side="right").astype(jnp.int32)
+    tile_expert = jnp.minimum(tile_expert, E - 1)
+    y_rows = _grouped_swiglu_pallas(x_rows, tile_expert, used, w_gate,
+                                    w_up, w_down, bool(interpret))
+    # each pair's row, in the pairs' own order
+    row = jnp.zeros((P,), jnp.int32).at[order].set(row_sorted)
+    y = jnp.take(y_rows, row, axis=0, mode="clip").reshape(T, k, d)
+    y = jnp.where(live[:, None, None], y, 0.0)
+    return (y * gates[..., None]).sum(axis=1)
+
+
+@register("moe_experts", differentiable=False)
+def _moe_experts_op(x, sel, gates, valid, w_gate, w_up, w_down, *,
+                    use_pallas=None):
+    """Registered routed-experts op: grouped Pallas kernel on TPU, the
+    dense masked twin elsewhere."""
+    return moe_experts(x, sel, gates, valid, w_gate, w_up, w_down,
+                       use_pallas=use_pallas)

@@ -380,6 +380,163 @@ def ragged_flat_attention_sharded(q, k_pages, v_pages, block_tables,
         k_scales=k_scales, v_scales=v_scales, layer=layer)
 
 
+# ------------------------------------------- latent (MLA) flat form --
+def mla_flat_attention_reference(q, pages, block_tables, seq_ids,
+                                 positions, scale, v_dim, layer=None):
+    """Gather-based oracle of :func:`mla_flat_attention`: every query
+    token's latent pages gathered through its sequence's table row,
+    one masked softmax, values the latents' first ``v_dim`` columns.
+    Everything in float32."""
+    T, H, W = q.shape
+    bs = pages.shape[-2]
+    MB = block_tables.shape[1]
+    tbl = block_tables[seq_ids]                       # (T, MB)
+    kv = pages[(tbl,) if layer is None else (layer, tbl)]
+    kv = kv.reshape(T, MB * bs, W).astype(jnp.float32)
+    logits = jnp.einsum("thw,tkw->htk", q.astype(jnp.float32), kv,
+                        precision=jax.lax.Precision.HIGHEST) * scale
+    pos = jnp.arange(MB * bs, dtype=jnp.int32)
+    mask = pos[None, None, :] <= positions[None, :, None]
+    probs = jax.nn.softmax(jnp.where(mask, logits, _NEG_INF), axis=-1)
+    out = jnp.einsum("htk,tkv->thv", probs, kv[..., :v_dim],
+                     precision=jax.lax.Precision.HIGHEST)
+    return out.astype(q.dtype)
+
+
+def _mla_flat_kernel(layer_ref, sid_ref, pos_ref, bt_ref, q_ref, kv_ref,
+                     o_ref, acc_ref, m_ref, l_ref, *, scale, block_size,
+                     num_blocks, v_dim):
+    """Grid (T, MB), the flat kernel's grid and page index map over ONE
+    pool of latents ``[L, N, bs, W]``: a page has no head axis, so all
+    ``H`` query heads of a token meet it in one matrix product,
+    ``(H, W) x (W, bs)``, and the page's first ``v_dim`` columns are the
+    values of the second, ``(H, bs) x (bs, v_dim)``. Online softmax in
+    float32 scratch across a token's pages, as in the flat kernel."""
+    t, j = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    qpos = pos_ref[t]
+    base = j * block_size
+
+    @pl.when(base <= qpos)
+    def _body():
+        q = q_ref[...].astype(jnp.float32)            # (H, W)
+        kv = kv_ref[...].astype(jnp.float32)          # (bs, W)
+        s = jax.lax.dot_general(
+            q, kv, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale   # (H, bs)
+        pos = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(pos <= qpos, s, _NEG_INF)
+        m_prev = m_ref[...]                           # (H, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)                        # masked -> 0.0
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1,
+                                                  keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
+            p, kv[:, :v_dim], preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
+
+    @pl.when(j == num_blocks - 1)
+    def _finalize():
+        l_safe = jnp.maximum(l_ref[...], 1e-30)
+        o_ref[...] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnums=(6, 7, 8))
+def _mla_flat_pallas(q, pages, block_tables, seq_ids, positions, layer,
+                     scale, v_dim, interpret):
+    T, H, W = q.shape
+    bs = pages.shape[2]
+    MB = block_tables.shape[1]
+
+    def tok(t, j, lyr, sid, pos, bt):
+        return (t, 0, 0)
+
+    def page(t, j, lyr, sid, pos, bt):
+        return (lyr[0], bt[sid[t], j], 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(T, MB),
+        in_specs=[
+            pl.BlockSpec((None, H, W), tok, memory_space=pltpu.VMEM),
+            pl.BlockSpec((None, None, bs, W), page,
+                         memory_space=pltpu.VMEM),
+        ],
+        out_specs=pl.BlockSpec((None, H, v_dim), tok,
+                               memory_space=pltpu.VMEM),
+        scratch_shapes=[
+            pltpu.VMEM((H, v_dim), jnp.float32),
+            pltpu.VMEM((H, 1), jnp.float32),
+            pltpu.VMEM((H, 1), jnp.float32),
+        ],
+    )
+    kernel = functools.partial(_mla_flat_kernel, scale=scale,
+                               block_size=bs, num_blocks=MB, v_dim=v_dim)
+    return pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((T, H, v_dim), q.dtype),
+        interpret=interpret,
+    )(layer.astype(jnp.int32).reshape(1), seq_ids.astype(jnp.int32),
+      positions.astype(jnp.int32), block_tables.astype(jnp.int32),
+      q, pages)
+
+
+def mla_flat_attention(q, pages, block_tables, seq_ids, positions,
+                       scale, v_dim, layer=None, use_pallas=None,
+                       interpret=None):
+    """Flat-ragged attention over a paged LATENT cache: multi-head
+    latent attention (MLA) in its absorbed form.
+
+    ``q [T, H, W]`` holds each head's query already in the cache's own
+    space (``q_nope`` through the key half of the up-projection, then
+    the rotary part); ``pages`` is the one pool of latents, ``[L, N,
+    bs, W]`` with ``layer`` (stacked, read in place: hand the whole
+    pool over, as :func:`ragged_flat_attention` says) or ``[N, bs,
+    W]`` without; every head attends over the SAME page, whose first
+    ``v_dim`` columns (the compressed part) are also the values.
+    ``scale`` is the softmax scale of the expanded form
+    (``(nope + rope) ** -0.5``), not of ``W``. Returns ``[T, H,
+    v_dim]``, to go through the value half of the up-projection.
+    Gated like :func:`ragged_flat_attention`: Mosaic on a TPU, the
+    gather reference elsewhere, interpret mode on request."""
+    if use_pallas is None:
+        use_pallas = _on_tpu()
+    if pages.ndim != (3 if layer is None else 4):
+        raise ValueError(
+            "the latent pool is [L, N, bs, W] with `layer`, [N, bs, W] "
+            f"without; got {pages.shape} and layer={layer!r}")
+    block_tables = jnp.asarray(block_tables)
+    seq_ids, positions = jnp.asarray(seq_ids), jnp.asarray(positions)
+    if not use_pallas:
+        return mla_flat_attention_reference(
+            q, pages, block_tables, seq_ids, positions, float(scale),
+            int(v_dim), layer=layer)
+    if interpret is None:
+        interpret = not _on_tpu()
+    if layer is None:
+        layer, pages = 0, pages[None]
+    return _mla_flat_pallas(q, pages, block_tables, seq_ids, positions,
+                            jnp.asarray(layer), float(scale), int(v_dim),
+                            bool(interpret))
+
+
+@register("mla_flat_attention", differentiable=False)
+def _mla_flat_op(q, pages, block_tables, seq_ids, positions, *, scale,
+                 v_dim, layer=None, use_pallas=None):
+    """Registered latent paged-attention op: Pallas kernel on TPU,
+    gather reference elsewhere."""
+    return mla_flat_attention(q, pages, block_tables, seq_ids, positions,
+                              scale, v_dim, layer=layer,
+                              use_pallas=use_pallas)
+
+
 def _chunk_kernel(bt_ref, len_ref, qlen_ref, q_ref, k_ref, v_ref,
                   o_ref, acc_ref, m_ref, l_ref, *, scale, block_size,
                   num_blocks, q_tokens):

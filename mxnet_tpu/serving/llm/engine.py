@@ -71,6 +71,7 @@ from .kv_cache import (PagedKVCache, KVCacheError, NULL_BLOCK,
                        prefix_block_hashes)
 from .quant import (QuantizedWeights, quantize_weights,
                     resolve_weight_dtype, fp8_supported, FP8_NAME)
+from .model import UnsupportedModelFeatureError
 from .scheduler import Scheduler, Sequence, RUNNING, FINISHED, EVICTED
 from .sampling import (TAG_SAMPLE, TAG_ACCEPT, TAG_DRAFT, row_keys,
                        sample_and_probs, spec_accept,
@@ -100,9 +101,16 @@ def _make_step_fn(model, spec_k, sampled, quantized=False, lora=False,
     int32 [S] (0 = plain row); sampling vectors [S] traced; counters
     int32 [S] = the ABSOLUTE index of the first token each row could
     emit (the PRNG anchor). Returns (tokens [S, K+1], n_accepted [S],
-    k_pages, v_pages): row i commits
-    ``tokens[i, :n_accepted[i] + 1]`` — for plain rows that is one
-    sampled/argmax token.
+    aux, *pools): row i commits ``tokens[i, :n_accepted[i] + 1]`` —
+    for plain rows that is one sampled/argmax token.
+
+    The pools are the model's own (``model.cache_layout``): K and V
+    pages, or ONE pool of latents; they follow the params, are donated
+    and come back in their order. ``aux`` is whatever
+    :meth:`model.decode_flat` returns after its pools (a model with
+    expert layers: their load, int32 ``[3]``), ``None`` (no output at
+    all) for a model that returns nothing there; it sits ahead of the
+    pools so that it comes back in the step's one fetch.
 
     ``quantized`` selects the int8-KV variant: the f32 scale pools
     ride the program right after the pages (donated with them) and
@@ -160,67 +168,30 @@ def _make_step_fn(model, spec_k, sampled, quantized=False, lora=False,
             win, draft_tokens, draft_probs, n_draft, temperature,
             top_k, top_p, accept_keys, sample_keys)
 
-    if quantized and lora:
-        def step(params, k_pages, v_pages, k_scales, v_scales,
-                 a_pages, b_pages, tokens, positions, seq_ids, valid,
-                 block_tables, win_idx, draft_tokens, draft_probs,
-                 n_draft, temperature, top_k, top_p, seeds, counters,
-                 a_tables, a_scales):
-            p, mkw = _wparams(params)
-            logits, kp2, vp2, ks2, vs2 = model.decode_flat(
-                p, tokens, positions, seq_ids, valid, k_pages,
-                v_pages, block_tables, k_scales=k_scales,
-                v_scales=v_scales,
-                adapter=(a_pages, b_pages, a_tables, a_scales), **mkw)
-            toks, n_acc = _accept(logits, win_idx, draft_tokens,
-                                  draft_probs, n_draft, temperature,
-                                  top_k, top_p, seeds, counters)
-            return toks, n_acc, kp2, vp2, ks2, vs2
-        return step
+    layout = getattr(model, "cache_layout", None) or {"kind": "kv"}
+    n_pools = 1 if layout["kind"] == "latent" else 4 if quantized else 2
 
-    if quantized:
-        def step(params, k_pages, v_pages, k_scales, v_scales, tokens,
-                 positions, seq_ids, valid, block_tables, win_idx,
-                 draft_tokens, draft_probs, n_draft, temperature,
-                 top_k, top_p, seeds, counters):
-            p, mkw = _wparams(params)
-            logits, kp2, vp2, ks2, vs2 = model.decode_flat(
-                p, tokens, positions, seq_ids, valid, k_pages,
-                v_pages, block_tables, k_scales=k_scales,
-                v_scales=v_scales, **mkw)
-            toks, n_acc = _accept(logits, win_idx, draft_tokens,
-                                  draft_probs, n_draft, temperature,
-                                  top_k, top_p, seeds, counters)
-            return toks, n_acc, kp2, vp2, ks2, vs2
-        return step
-
-    if lora:
-        def step(params, k_pages, v_pages, a_pages, b_pages, tokens,
-                 positions, seq_ids, valid, block_tables, win_idx,
-                 draft_tokens, draft_probs, n_draft, temperature,
-                 top_k, top_p, seeds, counters, a_tables, a_scales):
-            p, mkw = _wparams(params)
-            logits, k_pages2, v_pages2 = model.decode_flat(
-                p, tokens, positions, seq_ids, valid, k_pages,
-                v_pages, block_tables,
-                adapter=(a_pages, b_pages, a_tables, a_scales), **mkw)
-            toks, n_acc = _accept(logits, win_idx, draft_tokens,
-                                  draft_probs, n_draft, temperature,
-                                  top_k, top_p, seeds, counters)
-            return toks, n_acc, k_pages2, v_pages2
-        return step
-
-    def step(params, k_pages, v_pages, tokens, positions, seq_ids,
-             valid, block_tables, win_idx, draft_tokens, draft_probs,
-             n_draft, temperature, top_k, top_p, seeds, counters):
+    def step(params, *args):
+        # params, the pools, [the bank's A/B pools,] the 14 batch
+        # arrays [, a_tables, a_scales]
+        pools, args = args[:n_pools], args[n_pools:]
         p, mkw = _wparams(params)
-        logits, k_pages2, v_pages2 = model.decode_flat(
-            p, tokens, positions, seq_ids, valid, k_pages,
-            v_pages, block_tables, **mkw)
-        toks, n_acc = _accept(logits, win_idx, draft_tokens,
-                              draft_probs, n_draft, temperature,
-                              top_k, top_p, seeds, counters)
-        return toks, n_acc, k_pages2, v_pages2
+        if lora:
+            (a_pages, b_pages), args = args[:2], args[2:]
+            *args, a_tables, a_scales = args
+            mkw = dict(mkw, adapter=(a_pages, b_pages, a_tables,
+                                     a_scales))
+        if quantized:
+            mkw = dict(mkw, k_scales=pools[2], v_scales=pools[3])
+        tokens, positions, seq_ids, valid, block_tables, *accept = args
+        # a latent pool goes where the K pages go, and has no V pool
+        k_pages, v_pages = (*pools, None)[:2]
+        logits, *pools2 = model.decode_flat(
+            p, tokens, positions, seq_ids, valid, k_pages, v_pages,
+            block_tables, **mkw)
+        aux = pools2.pop() if len(pools2) > n_pools else None
+        toks, n_acc = _accept(logits, *accept)
+        return (toks, n_acc, aux, *pools2)
 
     return step
 
@@ -402,15 +373,16 @@ def _place_scales(scales, model, mesh):
             for k, v in scales.items()}
 
 
-def _spmd_wrap(fn, mesh, cache, param_specs, extra):
+def _spmd_wrap(fn, mesh, cache, param_specs, extra, aux=False):
     """Wrap a step/draft program body in ``shard_map`` over the
     engine's ``("tp",)`` mesh: params enter per ``param_specs``, the
     KV pools (and int8 scale pools) head-sharded, everything else —
     LoRA factor pools, the packed batch, block tables, sampling
     vectors — replicated. The first two outputs (tokens + accepts,
     or proposals + probs) come back replicated (the in-body psums
-    make every shard compute identical logits); the pools come back
-    sharded as they went in. The collectives live INSIDE ``fn``
+    make every shard compute identical logits), as does the step's
+    ``aux`` slot (``aux=True``); the pools come back sharded as they
+    went in. The collectives live INSIDE ``fn``
     (see ``TinyDecoder.decode_flat``), so jitting the wrapped fn
     yields the ONE donated whole-step program per (mesh, bucket,
     variant). ``extra`` = (replicated leading pool count, replicated
@@ -422,7 +394,7 @@ def _spmd_wrap(fn, mesh, cache, param_specs, extra):
     n_lora, n_batch = extra
     in_specs = tuple([param_specs] + pools
                      + [P()] * (n_lora + n_batch))
-    out_specs = tuple([P(), P()] + pools)
+    out_specs = tuple([P(), P()] + [P()] * aux + pools)
     return shard_map(fn, mesh=mesh, in_specs=in_specs,
                      out_specs=out_specs)
 
@@ -457,7 +429,11 @@ class LLMEngine:
         import jax
         import jax.numpy as jnp
         self.model = model
-        d_model = model.num_heads * model.head_dim
+        # what the model's block does not take is refused here, by
+        # the argument's name
+        self._refused = tuple(getattr(model, "refused_features", ()))
+        self._refuse_given("adapter_bank", adapter_bank is not None)
+        self._refuse_given("draft_model", draft_model is not None)
         # SPMD decode (ISSUE 19): constructor arg (Mesh or spec
         # string) > MXNET_TPU_LLM_MESH env > unsharded. The ENGINE
         # mesh is tensor-parallel only — dp replica groups are
@@ -472,7 +448,10 @@ class LLMEngine:
             tuple(dict(self.mesh.shape).items()),
             tuple(d.id for d in self.mesh.devices.flat))
         self.spmd_dispatches = 0
+        self._refuse_given("mesh", self.mesh is not None,
+                           "a tensor-parallel (tp) mesh")
         if adapter_bank is not None:
+            d_model = model.num_heads * model.head_dim
             if (adapter_bank.num_layers != model.num_layers
                     or adapter_bank.d_model != d_model):
                 raise ValueError(
@@ -573,9 +552,16 @@ class LLMEngine:
                 "fp8 KV requested but float8_e4m3fn is unavailable on "
                 "this backend; serving int8 KV instead", RuntimeWarning,
                 stacklevel=2)
-        self.cache = PagedKVCache(
-            model.num_layers, model.num_heads, model.head_dim,
-            block_size, num_blocks, max_context, dtype=kv_dtype,
+        # the pool's shape is the model's to say (cache_layout): K and
+        # V pools of [H, D] a slot, or one pool of latents, which is
+        # never quantized
+        layout = getattr(model, "cache_layout", None) or {"kind": "kv"}
+        if (layout["kind"] == "latent"
+                and kv_dtype in PagedKVCache.QUANTIZED_DTYPES):
+            raise UnsupportedModelFeatureError(
+                f"kv_dtype: a latent pool does not take {kv_dtype} pages")
+        self.cache = PagedKVCache.for_model(
+            model, block_size, num_blocks, max_context, dtype=kv_dtype,
             prefix_cache=self.prefix_enabled, mesh=self.mesh)
         self.quantized = self.cache.quantized
         self.scheduler = Scheduler(self.max_seqs)
@@ -600,6 +586,8 @@ class LLMEngine:
         qw = self._resolve_weight_input(params, weight_dtype,
                                         weight_calib,
                                         "MXNET_TPU_LLM_WEIGHT_DTYPE")
+        self._refuse_given("weight_dtype", qw is not None,
+                           "int8/fp8 weights")
         self.weight_dtype = "float32" if qw is None else qw.dtype
         self.weight_calib = None if qw is None else qw.method
         self.weight_quantized = qw is not None
@@ -608,12 +596,15 @@ class LLMEngine:
             if self.mesh is not None:
                 self._params = _place_param_tree(self._params, model,
                                                  self.mesh)
-            self.weight_bytes = int(sum(
-                np.asarray(a).nbytes for a in
-                jax.tree_util.tree_leaves(params)))
-            self.weight_params = int(sum(
-                np.asarray(a).size for a in
-                jax.tree_util.tree_leaves(params)))
+            # of the placed leaves: asking the given ones would bring
+            # every device-resident weight to the host to count it
+            leaves = jax.tree_util.tree_leaves(self._params)
+            self.weight_bytes = int(sum(a.nbytes for a in leaves))
+            self.weight_params = int(sum(a.size for a in leaves))
+            if leaves:
+                # unquantized weights serve in their own dtype
+                self.weight_dtype = str(
+                    max(leaves, key=lambda a: a.size).dtype)
         else:
             self.weight_bytes = qw.nbytes()
             self.weight_params = qw.num_params()
@@ -630,7 +621,7 @@ class LLMEngine:
         # donation is a TPU/HBM lever; CPU backends ignore it with a
         # warning per call site, so only request it where it works
         from ...ops.flash_attention import _on_tpu
-        n_pools = 4 if self.quantized else 2
+        n_pools = len(self.cache.pools())
         donate = tuple(range(1, 1 + n_pools)) if _on_tpu() else ()
         # two VARIANTS (greedy / sampled) x two widths of the one
         # step program — all warmed, so variant+width selection at
@@ -658,7 +649,7 @@ class LLMEngine:
                     pspecs = {"w": pspecs, "s": self._scale_spec_dict(
                         model, self._params["s"])}
                 fn = _spmd_wrap(fn, self.mesh, self.cache, pspecs,
-                                self._step_extra_specs(lora))
+                                self._step_extra_specs(lora), aux=True)
             return jax.jit(fn, donate_argnums=donate)
 
         self._step_jits = {
@@ -680,10 +671,9 @@ class LLMEngine:
             # the draft's pages are indexed by the SAME block ids the
             # target allocator hands out — its own allocator is never
             # touched, so there is exactly one strict accounting
-            self.draft_cache = PagedKVCache(
-                draft_model.num_layers, draft_model.num_heads,
-                draft_model.head_dim, block_size, num_blocks,
-                max_context, dtype=kv_dtype, mesh=self.mesh)
+            self.draft_cache = PagedKVCache.for_model(
+                draft_model, block_size, num_blocks, max_context,
+                dtype=kv_dtype, mesh=self.mesh)
             # int8 draft (ISSUE 20): the cheap-draft lever — draft
             # quality only moves the accept rate, never the committed
             # stream (the accept rule guarantees target-distribution
@@ -783,6 +773,9 @@ class LLMEngine:
         self._draft_bufs = {}
         self._arange = np.arange(self.q_tokens, dtype=np.int32)
         self._device_get = jax.device_get
+        # the last dispatch's expert load (a model with expert
+        # layers), host ints
+        self._moe_load = None
         # (source pools, replicated copies) — see _replicated_lora
         self._lora_placed = None
         if self.mesh is not None and self._stats is not None:
@@ -804,6 +797,12 @@ class LLMEngine:
         # (seq, exc) isolated out of a failing dispatch — the server
         # resolves them with the ORIGINAL exception
         self._poison_pending = []
+
+    def _refuse_given(self, arg, given, what=None):
+        if given and arg in self._refused:
+            raise UnsupportedModelFeatureError(
+                f"{arg}: the {self.model.config.model_type} block does "
+                f"not take {what or arg.replace('_', ' ')}")
 
     # -------------------------------------------- pool call helpers --
     def _resolve_weight_input(self, params, weight_dtype, weight_calib,
@@ -860,30 +859,17 @@ class LLMEngine:
     def _cow_arrays(self):
         """Every device pool array a COW copy must cover, in the fixed
         order the copy program was built for."""
-        arrs = [self.cache.k_pages, self.cache.v_pages]
-        if self.quantized:
-            arrs += [self.cache.k_scales, self.cache.v_scales]
+        arrs = list(self.cache.pools())
         if self.draft_cache is not None:
-            arrs += [self.draft_cache.k_pages, self.draft_cache.v_pages]
-            if self.quantized:
-                arrs += [self.draft_cache.k_scales,
-                         self.draft_cache.v_scales]
+            arrs += list(self.draft_cache.pools())
         return arrs
 
     def _cow_install(self, outs):
         outs = list(outs)
-        if self.quantized:
-            self.cache.swap(outs[0], outs[1], outs[2], outs[3])
-            rest = outs[4:]
-        else:
-            self.cache.swap(outs[0], outs[1])
-            rest = outs[2:]
+        n = len(self.cache.pools())
+        self.cache.swap(*outs[:n])
         if self.draft_cache is not None:
-            if self.quantized:
-                self.draft_cache.swap(rest[0], rest[1], rest[2],
-                                      rest[3])
-            else:
-                self.draft_cache.swap(rest[0], rest[1])
+            self.draft_cache.swap(*outs[n:])
 
     def _call_step(self, sampled, batch):
         """Dispatch one step program against the target pool, swapping
@@ -899,18 +885,10 @@ class LLMEngine:
             self.spmd_dispatches += 1
             if self._stats:
                 self._stats.record_spmd_dispatch()
-        if self.quantized:
-            toks, n_acc, kp, vp, ks, vs = jit(
-                self._params, self.cache.k_pages, self.cache.v_pages,
-                self.cache.k_scales, self.cache.v_scales, *lora,
-                *batch)
-            self.cache.swap(kp, vp, ks, vs)
-        else:
-            toks, n_acc, kp, vp = jit(
-                self._params, self.cache.k_pages, self.cache.v_pages,
-                *lora, *batch)
-            self.cache.swap(kp, vp)
-        return toks, n_acc
+        toks, n_acc, aux, *pools = jit(
+            self._params, *self.cache.pools(), *lora, *batch)
+        self.cache.swap(*pools)
+        return toks, n_acc, aux
 
     def _replicated_lora(self, pools):
         """Mesh-replicated snapshot of the bank's A/B factor pools.
@@ -1061,9 +1039,7 @@ class LLMEngine:
         ``tpu_custom_call``; the interpreted kernel and the ``jnp``
         reference do not — so this is where a caller reads which
         attention the engine's programs were built with."""
-        pools = [self.cache.k_pages, self.cache.v_pages]
-        if self.quantized:
-            pools += [self.cache.k_scales, self.cache.v_scales]
+        pools = self.cache.pools()
         lora = () if self.bank is None else self.bank.pools()
         batch = self._null_batch(self._t_buckets[0], self._mb_widths[0])
         return self._step_jits[sampled].lower(
@@ -1110,7 +1086,7 @@ class LLMEngine:
                 batch = self._null_batch(T, MB)
                 for sampled in (False, True):
                     t0 = time.monotonic()
-                    toks, n_acc = self._call_step(sampled, batch)
+                    toks = self._call_step(sampled, batch)[0]
                     np.asarray(toks)
                     tag = "sampled" if sampled else "greedy"
                     timings[f"step_t{T}mb{MB}_{tag}"] = \
@@ -1709,7 +1685,15 @@ class LLMEngine:
             sp.set("sampled", sampled)
             out = self._call_step(sampled, batch)
         with tracer.span("mxtpu.llm.fetch", "llm"):
-            return self._device_get(out)
+            # ONE fetch: the expert layers' load, where the model has
+            # any, rides with the tokens
+            toks, n_acc, load = self._device_get(out)
+        self._moe_load = None
+        if load is not None:
+            self._moe_load = tuple(int(v) for v in load)
+            if self._stats:
+                self._stats.record_moe(*self._moe_load)
+        return toks, n_acc
 
     def _sites(self, rows, plans):
         sites = set()
@@ -1955,6 +1939,12 @@ class LLMEngine:
                     decoded = self._isolate(rows, plans, events)
             else:
                 decoded = self._commit(rows, plans, *out, events)
+                if self._moe_load is not None and sp.span_id is not None:
+                    # over the step's expert layers: pairs and experts
+                    # hit summed, the largest load of one expert
+                    for key, v in zip(("moe_pairs", "moe_experts_hit",
+                                       "moe_max_load"), self._moe_load):
+                        sp.set(key, v)
             csp.set("decoded", decoded)
             step_s = time.monotonic() - t0
             if self._stats and any(plans[s]["kind"] == "decode"

@@ -326,16 +326,38 @@ class PagedKVCache:
     """
 
     QUANTIZED_DTYPES = ("int8", "float8_e4m3fn")
+    #: a latent pool's minor axis is padded to whole lanes of this many
+    LANES = 128
+
+    @classmethod
+    def for_model(cls, model, block_size, num_blocks, max_context, **kw):
+        """The pool a model's layers write: shaped by its
+        ``cache_layout`` (``TinyDecoder.cache_layout``), or K and V
+        pools of ``[num_heads, head_dim]`` for a model without one."""
+        layout = getattr(model, "cache_layout", None) or {
+            "kind": "kv", "num_heads": model.num_heads,
+            "head_dim": model.head_dim}
+        if layout["kind"] == "latent":
+            return cls(model.num_layers, 0, 0, block_size, num_blocks,
+                       max_context, latent_dim=layout["latent_dim"], **kw)
+        return cls(model.num_layers, layout["num_heads"],
+                   layout["head_dim"], block_size, num_blocks,
+                   max_context, **kw)
 
     def __init__(self, num_layers, num_heads, head_dim, block_size,
                  num_blocks, max_context, dtype="float32",
-                 prefix_cache=False, mesh=None, shard_axis="tp"):
+                 prefix_cache=False, mesh=None, shard_axis="tp",
+                 latent_dim=0):
         import jax.numpy as jnp
         if max_context < 1:
             raise ValueError(f"max_context must be >= 1, {max_context}")
         self.num_layers = int(num_layers)
         self.num_heads = int(num_heads)
         self.head_dim = int(head_dim)
+        # "kv": K and V pools of [num_heads, head_dim] a slot; "latent":
+        # ONE pool of ``latent_dim`` values a slot, no head axis
+        self.latent_dim = int(latent_dim)
+        self.kind = "latent" if self.latent_dim else "kv"
         self.block_size = int(block_size)
         self.num_blocks = int(num_blocks)
         self.max_context = int(max_context)
@@ -363,6 +385,26 @@ class PagedKVCache:
                     f"{self.shard_axis}={self.shards} — head-sharded "
                     f"pools need an even head split")
         self.heads_per_shard = self.num_heads // self.shards
+        self.k_pages = self.v_pages = self.latent_pages = None
+        self.k_scales = self.v_scales = None
+        if self.kind == "latent":
+            # [L, N, bs, Wp]: no V pool (the values are the latent's own
+            # first columns). ``latent_dim`` values a slot, zero-padded
+            # to whole lanes: at a minor axis of 576 XLA:TPU lays a
+            # pool this large out block-minor, and every kernel call,
+            # which takes row-major operands only, copies it whole
+            # (compiled off the chip: a 755 MB temporary a layer).
+            # Row-major tiles pad 576 to 640 in any case, so the padded
+            # pool is no larger than that.
+            if self.quantized or mesh is not None:
+                raise KVCacheError(
+                    "a latent pool is neither quantized nor sharded")
+            self.pool_width = -(-self.latent_dim // self.LANES) \
+                * self.LANES
+            self.latent_pages = jnp.zeros(
+                (self.num_layers, self.num_blocks, self.block_size,
+                 self.pool_width), dtype=jnp.dtype(self.dtype))
+            return
         shape = (self.num_layers, self.num_blocks, self.block_size,
                  self.num_heads, self.head_dim)
         self.k_pages = jnp.zeros(shape, dtype=jnp.dtype(self.dtype))
@@ -371,9 +413,6 @@ class PagedKVCache:
             sshape = shape[:-1]            # [L, N, bs, H]
             self.k_scales = jnp.ones(sshape, dtype=jnp.float32)
             self.v_scales = jnp.ones(sshape, dtype=jnp.float32)
-        else:
-            self.k_scales = None
-            self.v_scales = None
         if mesh is not None:
             # pools live head-sharded on the mesh from birth: every
             # chip holds [L, N, bs, H/shards, Dh] (scales
@@ -449,17 +488,47 @@ class PagedKVCache:
         return row
 
     # ------------------------------------------------------ storage --
-    def swap(self, k_pages, v_pages, k_scales=None, v_scales=None):
-        """Install the updated page buffers a donated program returned
-        (plus the quantization scales when the pool is quantized)."""
-        self.k_pages = k_pages
-        self.v_pages = v_pages
+    def pools(self):
+        """The device arrays a step program takes, donates and returns,
+        in its order: ``(k_pages, v_pages[, k_scales, v_scales])``, or
+        ``(latent_pages,)``."""
+        if self.kind == "latent":
+            return (self.latent_pages,)
         if self.quantized:
-            if k_scales is None or v_scales is None:
+            return (self.k_pages, self.v_pages, self.k_scales,
+                    self.v_scales)
+        return (self.k_pages, self.v_pages)
+
+    def layout(self):
+        """The pool's layout and what a token costs in it, for
+        ``stats()``: bytes over all layers, as allocated (a latent's
+        lane padding and a quantized pool's scales included)."""
+        itemsize = self.dtype.itemsize
+        if self.kind == "latent":
+            return {"kind": "latent", "values_per_token": self.latent_dim,
+                    "pool_shape": list(self.latent_pages.shape),
+                    "bytes_per_token": self.num_layers * self.pool_width
+                    * itemsize}
+        per_layer = 2 * self.num_heads * (
+            self.head_dim * itemsize + (4 if self.quantized else 0))
+        return {"kind": "kv",
+                "values_per_token": 2 * self.num_heads * self.head_dim,
+                "pool_shape": list(self.k_pages.shape),
+                "bytes_per_token": self.num_layers * per_layer}
+
+    def swap(self, *pools):
+        """Install the updated buffers a donated program returned, in
+        the order of :meth:`pools` (the quantization scales with the
+        pages when the pool is quantized)."""
+        if self.kind == "latent":
+            (self.latent_pages,) = pools
+            return
+        self.k_pages, self.v_pages = pools[:2]
+        if self.quantized:
+            if len(pools) != 4 or pools[2] is None or pools[3] is None:
                 raise KVCacheError(
                     "quantized pool swap() requires k_scales/v_scales")
-            self.k_scales = k_scales
-            self.v_scales = v_scales
+            self.k_scales, self.v_scales = pools[2:]
 
     # ------------------------------------------------- prefix index --
     def _on_reclaim(self, block):

@@ -235,6 +235,21 @@ class LLMStats:
             "fp8 weight/KV requests served as int8 because the "
             "backend lacks float8_e4m3fn (availability-guard "
             "fallbacks).", lbl).labels(**s)
+        self._moe_pairs = r.counter(
+            "mxtpu_llm_moe_routed_pairs_total",
+            "(token, expert) pairs routed by the step's expert "
+            "layers (valid rows only).", lbl).labels(**s)
+        self._moe_hit = r.counter(
+            "mxtpu_llm_moe_experts_hit_total",
+            "Experts that at least one token of a step hit, summed "
+            "over the step's expert layers: the experts whose weights "
+            "the step had to read.", lbl).labels(**s)
+        self._moe_max_load = r.histogram(
+            "mxtpu_llm_moe_max_expert_load",
+            "Largest number of pairs one expert took in one expert "
+            "layer of a step.", lbl,
+            buckets=(1, 2, 4, 8, 16, 32, 64, 128, 256, 512,
+                     1024)).labels(**s)
         # the overload/failure series share the single-shot server's
         # mxtpu_serving_* catalog (one dashboard for both front ends)
         self._overload = OverloadStats(r, self._server)
@@ -400,6 +415,14 @@ class LLMStats:
     def record_spmd_dispatch(self, n=1):
         self._spmd_dispatches.inc(n)
 
+    def record_moe(self, pairs, experts_hit, max_load):
+        """One dispatched step of a model with expert layers (the
+        histogram's count is the number of such steps: times the
+        experts the model holds, the denominator of the hit share)."""
+        self._moe_pairs.inc(pairs)
+        self._moe_hit.inc(experts_hit)
+        self._moe_max_load.observe(max_load)
+
     # --------------------------------------------- quantized weights --
     def record_weight_quant(self, dtype, weight_bytes,
                             params_per_chip):
@@ -497,6 +520,9 @@ class LLMStats:
                 "weight_params_per_chip": int(
                     self._weight_params_per_chip.value),
                 "quant_fallbacks": int(self._quant_fallbacks.value),
+                "moe_routed_pairs": int(self._moe_pairs.value),
+                "moe_experts_hit": int(self._moe_hit.value),
+                "moe_steps": int(self._moe_max_load.count),
                 "adapters_resident": int(
                     self._adapters_resident.value),
                 "adapter_publishes": int(

@@ -29,13 +29,25 @@ import numpy as np
 
 from ...ops.ragged_attention import (ragged_paged_attention,
                                      ragged_flat_attention,
-                                     ragged_flat_attention_sharded)
+                                     ragged_flat_attention_sharded,
+                                     mla_flat_attention)
+from ...ops.moe import route_noaux_tc, moe_experts, moe_load
 from ...ops.flash_attention import attention_reference
 from ...ops.lora import (paged_lora_delta, gather_adapter,
                          PROJ_Q, PROJ_K, PROJ_V, PROJ_O)
 from ...ops.quantization import quantized_matmul
 
-__all__ = ["DecoderConfig", "TinyDecoder", "greedy_decode_reference"]
+__all__ = ["DecoderConfig", "TinyDecoder", "greedy_decode_reference",
+           "UnsupportedModelFeatureError", "GPT2", "DEEPSEEK_V3"]
+
+#: the two blocks a :class:`DecoderConfig` can describe (``model_type``)
+GPT2, DEEPSEEK_V3 = "gpt2", "deepseek_v3"
+
+
+class UnsupportedModelFeatureError(ValueError):
+    """A model's block does not take a feature that was asked of it (an
+    adapter bank, quantized weights or pages, a tensor-parallel mesh, a
+    draft model, a decode entry point it does not implement)."""
 
 
 def _lora_all_rows(x2d, a_sel, b_sel, li, proj, scale):
@@ -57,13 +69,40 @@ def _lora_all_rows(x2d, a_sel, b_sel, li, proj, scale):
 
 
 class DecoderConfig:
-    """Shape of a :class:`TinyDecoder` (serializable for deploy)."""
+    """Shape of a :class:`TinyDecoder` (serializable for deploy).
+
+    The six ``FIELDS`` alone describe the GPT-2 block (``model_type``
+    ``"gpt2"``: learned positions, LayerNorm, ``num_heads`` equal heads
+    with a K and a V page each, a GELU MLP of width ``d_ff``). With
+    ``model_type="deepseek_v3"`` the ``BLOCK_FIELDS``, named after the
+    published ``config.json`` keys, describe the other block: RMSNorm,
+    rotary positions, multi-head latent attention over a cache of
+    ``kv_lora_rank + qk_rope_head_dim`` values a token, ``d_ff`` the
+    width of the SwiGLU MLP of the first ``first_k_dense_replace``
+    layers, every later layer ``n_routed_experts`` routed experts
+    (``num_experts_per_tok`` a token, sigmoid scores with a selection
+    bias) plus ``n_shared_experts`` shared ones, each of width
+    ``moe_intermediate_size``; ``torch_dtype`` is the dtype of the
+    weights (``float32`` or ``bfloat16``)."""
 
     FIELDS = ("vocab_size", "d_model", "num_layers", "num_heads",
               "d_ff", "max_context")
+    BLOCK_FIELDS = ("model_type", "kv_lora_rank", "qk_nope_head_dim",
+                    "qk_rope_head_dim", "v_head_dim", "rope_theta",
+                    "rms_norm_eps", "first_k_dense_replace",
+                    "n_routed_experts", "num_experts_per_tok",
+                    "n_shared_experts", "moe_intermediate_size",
+                    "routed_scaling_factor", "norm_topk_prob",
+                    "torch_dtype")
 
     def __init__(self, vocab_size=32, d_model=32, num_layers=2,
-                 num_heads=2, d_ff=64, max_context=128):
+                 num_heads=2, d_ff=64, max_context=128, model_type=GPT2,
+                 kv_lora_rank=0, qk_nope_head_dim=0, qk_rope_head_dim=0,
+                 v_head_dim=0, rope_theta=10000.0, rms_norm_eps=1e-5,
+                 first_k_dense_replace=0, n_routed_experts=0,
+                 num_experts_per_tok=0, n_shared_experts=0,
+                 moe_intermediate_size=0, routed_scaling_factor=1.0,
+                 norm_topk_prob=True, torch_dtype="float32"):
         self.vocab_size = int(vocab_size)
         self.d_model = int(d_model)
         self.num_layers = int(num_layers)
@@ -73,21 +112,77 @@ class DecoderConfig:
         for f in self.FIELDS:
             if getattr(self, f) < 1:
                 raise ValueError(f"{f} must be >= 1, got {getattr(self, f)}")
-        if self.d_model % self.num_heads:
-            raise ValueError(
-                f"d_model {d_model} not divisible by heads {num_heads}")
-        self.head_dim = self.d_model // self.num_heads
+        if model_type not in (GPT2, DEEPSEEK_V3):
+            raise ValueError(f"model_type {model_type!r} is neither "
+                             f"{GPT2!r} nor {DEEPSEEK_V3!r}")
+        self.model_type = model_type
+        self.kv_lora_rank = int(kv_lora_rank)
+        self.qk_nope_head_dim = int(qk_nope_head_dim)
+        self.qk_rope_head_dim = int(qk_rope_head_dim)
+        self.v_head_dim = int(v_head_dim)
+        self.rope_theta = float(rope_theta)
+        self.rms_norm_eps = float(rms_norm_eps)
+        self.first_k_dense_replace = int(first_k_dense_replace)
+        self.n_routed_experts = int(n_routed_experts)
+        self.num_experts_per_tok = int(num_experts_per_tok)
+        self.n_shared_experts = int(n_shared_experts)
+        self.moe_intermediate_size = int(moe_intermediate_size)
+        self.routed_scaling_factor = float(routed_scaling_factor)
+        self.norm_topk_prob = bool(norm_topk_prob)
+        # the dtype the weights are published in, and made in by
+        # init_params; the step reads them as they come
+        if torch_dtype not in ("float32", "bfloat16"):
+            raise ValueError("torch_dtype is float32 or bfloat16, got "
+                             f"{torch_dtype!r}")
+        self.torch_dtype = torch_dtype
+        if model_type == GPT2:
+            if self.d_model % self.num_heads:
+                raise ValueError(
+                    f"d_model {d_model} not divisible by heads "
+                    f"{num_heads}")
+            self.head_dim = self.d_model // self.num_heads
+            return
+        for f in ("kv_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim",
+                  "v_head_dim"):
+            if getattr(self, f) < 1:
+                raise ValueError(f"{f} must be >= 1 for {DEEPSEEK_V3}, "
+                                 f"got {getattr(self, f)}")
+        if self.qk_rope_head_dim % 2:
+            raise ValueError("qk_rope_head_dim must be even, got "
+                             f"{self.qk_rope_head_dim}")
+        if self.first_k_dense_replace < self.num_layers:
+            if not (1 <= self.num_experts_per_tok
+                    <= self.n_routed_experts):
+                raise ValueError(
+                    f"num_experts_per_tok {self.num_experts_per_tok} of "
+                    f"n_routed_experts {self.n_routed_experts}")
+            if self.moe_intermediate_size < 1 or self.n_shared_experts < 0:
+                raise ValueError("expert layers need "
+                                 "moe_intermediate_size >= 1 and "
+                                 "n_shared_experts >= 0")
+        # a head's query width (nope + rope)
+        self.head_dim = self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def latent_dim(self):
+        """Values a token leaves in the cache of a layer of the
+        ``deepseek_v3`` block: the compressed part and the rotary key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
 
     def to_dict(self):
-        return {f: getattr(self, f) for f in self.FIELDS}
+        fields = self.FIELDS if self.model_type == GPT2 \
+            else self.FIELDS + self.BLOCK_FIELDS
+        return {f: getattr(self, f) for f in fields}
 
     @classmethod
     def from_dict(cls, d):
-        return cls(**{f: d[f] for f in cls.FIELDS})
+        return cls(**{f: d[f] for f in cls.FIELDS + cls.BLOCK_FIELDS
+                      if f in d})
 
     def __repr__(self):
         return ("DecoderConfig(" + ", ".join(
-            f"{f}={getattr(self, f)}" for f in self.FIELDS) + ")")
+            f"{f}={v!r}" if isinstance(v, str) else f"{f}={v}"
+            for f, v in self.to_dict().items()) + ")")
 
 
 def _layer_norm(x, g, b, eps=1e-5):
@@ -95,6 +190,48 @@ def _layer_norm(x, g, b, eps=1e-5):
     v = ((x - m) ** 2).mean(axis=-1, keepdims=True)
     import jax.numpy as jnp
     return (x - m) / jnp.sqrt(v + eps) * g + b
+
+
+def _rms_norm(x, g, eps):
+    """RMSNorm in float32, whatever ``x`` and ``g`` are stored as."""
+    import jax
+    import jax.numpy as jnp
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt((x * x).mean(axis=-1, keepdims=True) + eps) \
+        * g.astype(jnp.float32)
+
+
+def _rope(x, positions, theta):
+    """Rotary positions on the last axis of ``x [..., T, (heads,) r]``
+    (float32), ``positions`` shaped like ``x`` up to and including its
+    token axis. Half-split pairing: element ``i`` turns with element
+    ``i + r/2`` (the published checkpoints store the rotary part
+    interleaved and the published code permutes it into this order;
+    with seeded weights the two are one fixed relabelling)."""
+    import jax.numpy as jnp
+    half = x.shape[-1] // 2
+    freqs = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    ang = positions.astype(jnp.float32)[..., None] * freqs
+    ang = ang.reshape(positions.shape
+                      + (1,) * (x.ndim - positions.ndim - 1) + (half,))
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    x1, x2 = x[..., :half], x[..., half:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1)
+
+
+def _wmm(x, w):
+    """``x @ w`` with the activations rounded to the weights' dtype and
+    float32 accumulation: a bf16 weight is read as bf16, and no float32
+    copy of it is made."""
+    import jax.numpy as jnp
+    return jnp.matmul(x.astype(w.dtype), w,
+                      preferred_element_type=jnp.float32)
+
+
+def _swiglu(x, w_gate, w_up, w_down):
+    import jax
+    return _wmm(jax.nn.silu(_wmm(x, w_gate)) * _wmm(x, w_up), w_down)
 
 
 class TinyDecoder:
@@ -124,6 +261,44 @@ class TinyDecoder:
     def max_context(self):
         return self.config.max_context
 
+    @property
+    def _deepseek(self):
+        return self.config.model_type == DEEPSEEK_V3
+
+    @property
+    def expert_slots(self):
+        """Routed experts held over all expert layers (0 for a model
+        without them): what a step's experts hit are a share of. A
+        model with any returns their load from :meth:`decode_flat`,
+        after the pools."""
+        c = self.config
+        return c.n_routed_experts * max(
+            0, c.num_layers - c.first_k_dense_replace)
+
+    @property
+    def cache_layout(self):
+        """What a token leaves in the paged cache of one layer, for the
+        engine to shape the pool by: ``{"kind": "kv", "num_heads": H,
+        "head_dim": D}`` (a K and a V pool of ``[H, D]`` a slot) or
+        ``{"kind": "latent", "latent_dim": W}`` (one pool of ``W``
+        values a slot, no head axis, no V pool)."""
+        if self._deepseek:
+            return {"kind": "latent", "latent_dim": self.config.latent_dim}
+        return {"kind": "kv", "num_heads": self.config.num_heads,
+                "head_dim": self.config.head_dim}
+
+    @property
+    def refused_features(self):
+        """The engine arguments this model's block does not take, for
+        the engine to refuse by name at construction."""
+        if self._deepseek:
+            return ("adapter_bank", "draft_model", "mesh", "weight_dtype")
+        return ()
+
+    def _refuse(self, what):
+        raise UnsupportedModelFeatureError(
+            f"the {self.config.model_type} block does not take {what}")
+
     # ------------------------------------------------------- params --
     def init_params(self, seed=0):
         """Deterministic random params (host numpy, float32)."""
@@ -133,6 +308,17 @@ class TinyDecoder:
         def w(*shape, scale=None):
             scale = scale if scale is not None else 1.0 / np.sqrt(shape[0])
             return (rs.randn(*shape) * scale).astype(np.float32)
+
+        if self._deepseek:
+            params = self._init_params_latent(w)
+            if c.torch_dtype == "float32":
+                return params
+            import jax
+            import jax.numpy as jnp
+            # the router stays float32, as the published code scores
+            return jax.tree_util.tree_map_with_path(
+                lambda path, a: jnp.asarray(a) if "router" in str(path[-1])
+                else jnp.asarray(a, c.torch_dtype), params)
 
         layers = []
         for _ in range(c.num_layers):
@@ -159,6 +345,47 @@ class TinyDecoder:
             "layers": layers,
         }
 
+    def _init_params_latent(self, w):
+        """The ``deepseek_v3`` tree: the leaves of
+        ``benchmark/reference/deepseek_v3_decoder.py``, and its one
+        rule of scale: fan-in, and the matrices that write to the
+        residual stream (``wo``, ``w_down``, ``experts_down``,
+        ``shared_down``) by 1/sqrt(2 x num_layers) besides (GPT-2's
+        residual scaling, at the model's own depth); the embedding 1."""
+        c = self.config
+        d, H, rank = c.d_model, c.num_heads, c.kv_lora_rank
+        nope, rope, vd = (c.qk_nope_head_dim, c.qk_rope_head_dim,
+                          c.v_head_dim)
+        E, m = c.n_routed_experts, c.moe_intermediate_size
+        sh = c.n_shared_experts * m
+        out = (2 * c.num_layers) ** -0.5
+        layers = []
+        for li in range(c.num_layers):
+            lp = {"norm1": np.ones(d, np.float32),
+                  "norm2": np.ones(d, np.float32),
+                  "norm_kv": np.ones(rank, np.float32),
+                  "wq": w(d, H * (nope + rope)),
+                  "wkv_a": w(d, rank + rope),
+                  "wkv_b": w(rank, H * (nope + vd)),
+                  "wo": w(H * vd, d, scale=out * (H * vd) ** -0.5)}
+            if li < c.first_k_dense_replace:
+                lp.update(w_gate=w(d, c.d_ff), w_up=w(d, c.d_ff),
+                          w_down=w(c.d_ff, d, scale=out * c.d_ff ** -0.5))
+            else:
+                lp.update(
+                    router=w(d, E), router_bias=w(E, scale=0.01),
+                    experts_gate=w(E, d, m, scale=d ** -0.5),
+                    experts_up=w(E, d, m, scale=d ** -0.5),
+                    experts_down=w(E, m, d, scale=out * m ** -0.5))
+                if sh:
+                    lp.update(shared_gate=w(d, sh), shared_up=w(d, sh),
+                              shared_down=w(sh, d,
+                                            scale=out * sh ** -0.5))
+            layers.append(lp)
+        return {"embed": w(c.vocab_size, d, scale=1.0),
+                "norm_f": np.ones(d, np.float32),
+                "head": w(d, c.vocab_size), "layers": layers}
+
     def param_specs(self, axis="tp"):
         """PartitionSpec pytree matching :meth:`init_params` for
         tensor-parallel placement over mesh axis ``axis`` — the
@@ -169,6 +396,8 @@ class TinyDecoder:
         vocab- or norm-shaped rides replicated. Structure is a tree
         PREFIX of the params pytree (one spec per weight leaf)."""
         from jax.sharding import PartitionSpec as P
+        if self._deepseek:
+            self._refuse("a tensor-parallel mesh")
         layer = {
             "ln1_g": P(), "ln1_b": P(),
             "wq": P(None, axis), "wk": P(None, axis),
@@ -214,6 +443,10 @@ class TinyDecoder:
         import jax
         import jax.numpy as jnp
         c = self.config
+        if self._deepseek:
+            if lora is not None:
+                self._refuse("LoRA factors")
+            return self._forward_latent(params, tokens)
         B, T = tokens.shape
         h = params["embed"][tokens] + params["pos"][:T][None, :, :]
         if lora is not None:
@@ -281,6 +514,9 @@ class TinyDecoder:
         import jax
         import jax.numpy as jnp
         c = self.config
+        if self._deepseek:
+            self._refuse("decode_chunk/decode_step: no engine path "
+                         "runs them; the flat step is decode_flat")
         S, Q = tokens.shape
         bs = k_pages.shape[2]
         valid = (jnp.arange(Q, dtype=jnp.int32)[None, :]
@@ -397,6 +633,17 @@ class TinyDecoder:
         import jax
         import jax.numpy as jnp
         c = self.config
+        if self._deepseek:
+            for given, what in ((k_scales, "quantized pages"),
+                                (v_scales, "quantized pages"),
+                                (adapter, "an adapter bank"),
+                                (axis_name, "a tensor-parallel mesh"),
+                                (w_scales, "quantized weights")):
+                if given is not None:
+                    self._refuse(what)
+            return self._decode_flat_latent(
+                params, tokens, positions, seq_ids, valid, k_pages,
+                block_tables)
         T = tokens.shape[0]
         bs = k_pages.shape[2]
         quantized = k_scales is not None
@@ -529,6 +776,139 @@ class TinyDecoder:
             return logits, k_pages, v_pages, k_scales, v_scales
         return logits, k_pages, v_pages
 
+    # ------------------------------------- the deepseek_v3 block --
+    def _mlp_or_experts(self, lp, x2, valid):
+        """The second half of a ``deepseek_v3`` layer for normed rows
+        ``x2 [T, d]`` (float32): ``(what is added to the residual
+        stream [T, d] float32, the routing's load int32 [3] or None)``.
+        A dense layer is one SwiGLU; an expert layer routes each row
+        with ``valid`` to ``num_experts_per_tok`` experts (no capacity
+        limit, no dropped pair) and adds the shared experts."""
+        c = self.config
+        if "w_gate" in lp:
+            return _swiglu(x2, lp["w_gate"], lp["w_up"],
+                           lp["w_down"]), None
+        sel, gates = route_noaux_tc(
+            x2, lp["router"], lp["router_bias"], c.num_experts_per_tok,
+            c.norm_topk_prob, c.routed_scaling_factor)
+        wg = lp["experts_gate"]
+        out = moe_experts(x2.astype(wg.dtype), sel, gates, valid, wg,
+                          lp["experts_up"], lp["experts_down"])
+        if "shared_gate" in lp:
+            out = out + _swiglu(x2, lp["shared_gate"], lp["shared_up"],
+                                lp["shared_down"])
+        return out, moe_load(sel, valid, c.n_routed_experts)
+
+    def _forward_latent(self, params, tokens):
+        """:meth:`forward` for the ``deepseek_v3`` block: latent
+        attention in its EXPANDED form (the latent through ``wkv_b``
+        into per-head keys and values, dense causal attention), the
+        expert layers through the op's plain twin off the TPU. Returns
+        ``(logits [B, T, V] float32, latents [L, B, T, W] float32,
+        None)``: what the paged path keeps of each token."""
+        import jax
+        import jax.numpy as jnp
+        c = self.config
+        B, T = tokens.shape
+        H, rank = c.num_heads, c.kv_lora_rank
+        nope, rope = c.qk_nope_head_dim, c.qk_rope_head_dim
+        eps, theta = c.rms_norm_eps, c.rope_theta
+        pos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
+        live = jnp.ones((B * T,), bool)
+        mask = jnp.tril(jnp.ones((T, T), bool))
+        h = params["embed"][tokens].astype(jnp.float32)
+        latents = []
+        for lp in params["layers"]:
+            x = _rms_norm(h, lp["norm1"], eps)
+            q = _wmm(x, lp["wq"]).reshape(B, T, H, nope + rope)
+            a = _wmm(x, lp["wkv_a"])
+            cc = _rms_norm(a[..., :rank], lp["norm_kv"], eps)
+            k_rope = _rope(a[..., rank:], pos, theta)
+            latents.append(jnp.concatenate([cc, k_rope], axis=-1))
+            kv = _wmm(cc, lp["wkv_b"]).reshape(B, T, H, -1)
+            s = jnp.einsum("bqhd,bkhd->bhqk", q[..., :nope],
+                           kv[..., :nope]) \
+                + jnp.einsum("bqhd,bkd->bhqk",
+                             _rope(q[..., nope:], pos, theta), k_rope)
+            s = jnp.where(mask, s * (nope + rope) ** -0.5, -1e30)
+            att = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1),
+                             kv[..., nope:]).reshape(B, T, -1)
+            h = h + _wmm(att, lp["wo"])
+            x2 = _rms_norm(h, lp["norm2"], eps)
+            add, _ = self._mlp_or_experts(
+                lp, x2.reshape(B * T, c.d_model), live)
+            h = h + add.reshape(B, T, c.d_model)
+        logits = _wmm(_rms_norm(h, params["norm_f"], eps), params["head"])
+        return logits, jnp.stack(latents), None
+
+    def _decode_flat_latent(self, params, tokens, positions, seq_ids,
+                            valid, pages, block_tables):
+        """:meth:`decode_flat` for the ``deepseek_v3`` block, the packed
+        batch and page arithmetic the same. ``pages`` is the one
+        stacked pool of latents ``[L, N, bs, Wp]``, ``Wp`` the latent's
+        ``kv_lora_rank + qk_rope_head_dim`` values padded with zeros to
+        whole lanes (``PagedKVCache``). Each layer writes its tokens'
+        ``(rms(c), rope(k_rope))`` in place and attends in the ABSORBED
+        form: ``q_nope`` goes through the key half of ``wkv_b`` into
+        the latent's space, the kernel takes the WHOLE pool with
+        ``layer=`` (a ``pages[li]`` handed to it would be a copy), and
+        its output goes through the value half. Weights are read in
+        their own dtype with float32 accumulation; norms, softmax,
+        rotary positions, router scores, gates and the residual stream
+        are float32. Returns ``(logits [T, V] float32, pages)`` and,
+        from a model with expert layers (``expert_slots``), ``load``
+        int32 ``[3]`` after them: routed pairs and experts hit summed
+        over the expert layers, and the largest load of one expert in
+        one layer, over the rows with ``valid``."""
+        import jax.numpy as jnp
+        c = self.config
+        T = tokens.shape[0]
+        bs, wp = pages.shape[2], pages.shape[3]
+        H, rank = c.num_heads, c.kv_lora_rank
+        nope, rope, vd = (c.qk_nope_head_dim, c.qk_rope_head_dim,
+                          c.v_head_dim)
+        eps, theta = c.rms_norm_eps, c.rope_theta
+        scale = float((nope + rope) ** -0.5)
+        vmask = valid.astype(bool)
+        bidx = jnp.where(
+            vmask, block_tables[seq_ids, positions // bs], 0)  # null
+        slot = jnp.where(vmask, positions % bs, 0)
+        pad = wp - rank - rope
+        load = jnp.zeros((3,), jnp.int32)
+        h = params["embed"][tokens].astype(jnp.float32)
+        for li, lp in enumerate(params["layers"]):
+            x = _rms_norm(h, lp["norm1"], eps)
+            q = _wmm(x, lp["wq"]).reshape(T, H, nope + rope)
+            a = _wmm(x, lp["wkv_a"])
+            latent = jnp.concatenate(
+                [_rms_norm(a[:, :rank], lp["norm_kv"], eps),
+                 _rope(a[:, rank:], positions, theta),
+                 jnp.zeros((T, pad), jnp.float32)], axis=-1)
+            pages = pages.at[li, bidx, slot].set(
+                latent.astype(pages.dtype))
+            wkv_b = lp["wkv_b"].reshape(rank, H, nope + vd)
+            q_lat = jnp.einsum(
+                "thn,rhn->thr", q[..., :nope].astype(wkv_b.dtype),
+                wkv_b[..., :nope], preferred_element_type=jnp.float32)
+            qq = jnp.concatenate(
+                [q_lat, _rope(q[..., nope:], positions, theta),
+                 jnp.zeros((T, H, pad), jnp.float32)], axis=-1)
+            o_lat = mla_flat_attention(qq, pages, block_tables, seq_ids,
+                                       positions, scale, rank, layer=li)
+            att = jnp.einsum(
+                "thr,rhv->thv", o_lat.astype(wkv_b.dtype),
+                wkv_b[..., nope:], preferred_element_type=jnp.float32)
+            h = h + _wmm(att.reshape(T, H * vd), lp["wo"])
+            add, hit = self._mlp_or_experts(
+                lp, _rms_norm(h, lp["norm2"], eps), vmask)
+            h = h + add
+            if hit is not None:
+                load = jnp.stack([load[0] + hit[0], load[1] + hit[1],
+                                  jnp.maximum(load[2], hit[2])])
+        logits = _wmm(_rms_norm(h, params["norm_f"], eps), params["head"])
+        return (logits, pages, load) if self.expert_slots \
+            else (logits, pages)
+
     def decode_step(self, params, tokens, positions, k_pages, v_pages,
                     block_tables, kv_lens):
         """One decode token per sequence: the Q=1 slice of
@@ -557,6 +937,9 @@ def _incremental_step(model, params, token, pos, k_cache, v_cache,
     import jax.numpy as jnp
     from ...ops.flash_attention import _NEG_INF
     c = model.config
+    if getattr(model, "_deepseek", False):
+        model._refuse("the incremental oracle: compare with "
+                      "TinyDecoder.forward over the whole sequence")
     scale = 1.0 / (c.head_dim ** 0.5)      # python float: config-time
     mask = jnp.arange(c.max_context, dtype=jnp.int32) <= pos
     if lora is not None:
@@ -624,6 +1007,9 @@ def greedy_decode_reference(model, params, prompt_tokens,
     """
     import jax
     import jax.numpy as jnp
+    if getattr(model, "_deepseek", False):
+        model._refuse("the incremental oracle: compare with "
+                      "TinyDecoder.forward over the whole sequence")
     toks = [int(t) for t in prompt_tokens]
     out = []
     ctx = model.max_context

@@ -482,6 +482,9 @@ class LLMServer:
         snap["max_seqs"] = self._engine.max_seqs
         snap["prefix_cache"] = self._engine.prefix_enabled
         snap["kv_dtype"] = self._engine.cache.dtype.name
+        # K and V pools of [H, D] or one pool of latents, and the
+        # bytes a token takes in it over all layers
+        snap["cache_layout"] = self._engine.cache.layout()
         snap["weight_dtype"] = self._engine.weight_dtype
         snap["weight_bytes"] = self._engine.weight_bytes
         snap["weight_params_per_chip"] = (

@@ -1,0 +1,382 @@
+"""The DeepSeek-V3 block of ``TinyDecoder`` (latent attention over a paged
+latent cache, routed and shared experts) at a toy size on the CPU: the
+dense oracle and the paged path against the benchmark's plain reference,
+the two new kernels in interpret mode against their ``jnp`` twins, and
+what the engine refuses for this block.
+"""
+import importlib
+import os
+import sys
+
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+import jax                                              # noqa: E402
+import jax.numpy as jnp                                 # noqa: E402
+
+from mxnet_tpu.ops import moe, registry                 # noqa: E402
+from mxnet_tpu.ops.ragged_attention import (            # noqa: E402
+    mla_flat_attention, mla_flat_attention_reference)
+from mxnet_tpu.serving.llm import (LLMEngine, LLMServer,  # noqa: E402
+                                   PagedKVCache)
+from mxnet_tpu.serving.llm.model import (               # noqa: E402
+    DecoderConfig, TinyDecoder, UnsupportedModelFeatureError,
+    greedy_decode_reference)
+
+ref = importlib.import_module("benchmark.reference.deepseek_v3_decoder")
+
+#: 2 expert layers after a dense one, 8 experts top-2, latent 16 + 8
+TOY = dict(vocab_size=97, d_model=64, num_layers=3, num_heads=4, d_ff=96,
+           max_context=64, model_type="deepseek_v3", kv_lora_rank=16,
+           qk_nope_head_dim=8, qk_rope_head_dim=8, v_head_dim=8,
+           rope_theta=50000.0, rms_norm_eps=1e-5, first_k_dense_replace=1,
+           n_routed_experts=8, num_experts_per_tok=2, n_shared_experts=2,
+           moe_intermediate_size=32, routed_scaling_factor=2.446,
+           norm_topk_prob=True)
+TOKENS = np.random.RandomState(0).randint(0, 97, 40)
+
+
+@pytest.fixture(scope="module")
+def model():
+    return TinyDecoder(DecoderConfig(**TOY))
+
+
+def paged_logits(model, params, tokens, dtype, chunk=8, block=4):
+    """``tokens`` through ``decode_flat`` in chunks, one sequence whose
+    blocks lie scattered in a pool of latents."""
+    pool = PagedKVCache.for_model(model, block, 24, 64, dtype=dtype)
+    assert pool.latent_pages.shape == (3, 24, block, 128)
+    assert pool.kind == "latent" and pool.k_pages is None
+    table = jnp.asarray([[3, 5, 7, 9, 11, 13, 15, 17, 2, 4] + [0] * 6],
+                        jnp.int32)
+    pages, out = pool.latent_pages, []
+    for start in range(0, len(tokens), chunk):
+        n = min(chunk, len(tokens) - start)
+        logits, pages, load = model.decode_flat(
+            params, jnp.asarray(tokens[start:start + n], jnp.int32),
+            jnp.arange(start, start + n, dtype=jnp.int32),
+            jnp.zeros(n, jnp.int32), jnp.ones(n, jnp.int32), pages, None,
+            table)
+        out.append(logits)
+        # every token of a chunk routes to two experts in each of the
+        # two expert layers; no expert can take more than the chunk
+        assert int(load[0]) == n * 2 * 2 and 1 <= int(load[2]) <= n
+        assert 2 <= int(load[1]) <= 16
+    return jnp.concatenate(out)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_oracle_and_paged_path_against_the_reference(model, dtype):
+    """(a), (b): ``forward`` attends in the expanded form and runs every
+    expert densely; ``decode_flat`` keeps 24 values a token in pages and
+    attends in the absorbed form. Both against the reference's logits."""
+    params = ref.init_params(5, TOY, dtype=jnp.dtype(dtype))
+    want = ref.forward(params, jnp.asarray(TOKENS, jnp.int32), TOY)
+    dense, latents, none = model.forward(params,
+                                         jnp.asarray(TOKENS[None]))
+    assert none is None and latents.shape == (3, 1, 40, 24)
+    paged = paged_logits(model, params, TOKENS, dtype)
+    dense_err = np.abs(np.asarray(dense[0] - want)).max(axis=1)
+    paged_err = np.abs(np.asarray(paged - want)).max(axis=1)
+    if dtype == "float32":
+        # the same arithmetic in float32, summed in another order
+        # (absorbed against expanded, grouped against masked experts)
+        assert dense_err.max() < 5e-5 and paged_err.max() < 5e-5
+        # absorbed against expanded, directly
+        assert np.abs(np.asarray(paged - dense[0])).max() < 5e-5
+    else:
+        # bf16 weights, products' operands and cache: 8 bits of mantissa
+        # over logits of 3 give 0.004 to 0.007 here. A rounding can swap
+        # the second of two experts at a near tie; under the reference's
+        # residual-scaled weights that moves a position's logits by a
+        # tenth or so, not by the 1 it does with branches as large as
+        # the stream
+        assert np.median(dense_err) < 0.02 and np.median(paged_err) < 0.02
+        assert dense_err.max() < 0.3 and paged_err.max() < 0.3
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_served_through_llmserver_against_the_reference(model, dtype):
+    """(a), the normal path: chunked prefill, then decoding through the
+    paged latent cache behind ``LLMServer``; every served token against
+    the reference's logits at its position."""
+    params = ref.init_params(5, TOY, dtype=jnp.dtype(dtype))
+    server = LLMServer(model, params, name="mla_moe_" + dtype,
+                       block_size=4, prefill_chunk=8, dtype=dtype)
+    server.start()
+    try:
+        rs = np.random.RandomState(1)
+        asked = []
+        for _ in range(8):
+            prompt = rs.randint(0, 97, rs.randint(3, 40)).tolist()
+            asked.append((prompt, server.submit(
+                prompt, int(rs.randint(2, 20)))))
+        worst = 0.0
+        for prompt, fut in asked:
+            served = list(fut.result(timeout=300).tokens)
+            gaps = ref.served_token_gaps(params, prompt, served, TOY, 128)
+            worst = max(worst, float(gaps.max()))
+        stats = server.stats()
+    finally:
+        server.shutdown(drain=False)
+    # float32: the served token is the reference's first choice but for
+    # round-off; bf16: within the hundredth the logits differ by, or
+    # what a swapped expert moves them by (see the test above)
+    assert worst <= (1e-4 if dtype == "float32" else 0.3)
+    layout = stats["cache_layout"]
+    assert layout["kind"] == "latent" and layout["values_per_token"] == 24
+    itemsize = 4 if dtype == "float32" else 2
+    assert layout["bytes_per_token"] == 3 * 128 * itemsize
+    assert stats["weight_dtype"] == dtype and stats["kv_dtype"] == dtype
+    # counters of the router: two pairs a token a layer, 16 experts
+    # held over the two expert layers of every dispatched step
+    assert stats["moe_routed_pairs"] > 0 and model.expert_slots == 16
+    assert 0 < stats["moe_experts_hit"] <= stats["moe_steps"] * 16
+
+
+def test_step_span_carries_the_routers_load(model):
+    """The expert load comes back in the step's one fetch and lands on
+    ``mxtpu.llm.step``."""
+    from mxnet_tpu.observability.tracing import get_tracer
+    params = ref.init_params(6, TOY, dtype=jnp.float32)
+    engine = LLMEngine(model, params, block_size=4, prefill_chunk=8)
+    fetches = []
+    real = engine._device_get
+    engine._device_get = lambda out: (fetches.append(len(out)),
+                                      real(out))[1]
+    from mxnet_tpu.serving.llm import Sequence
+    tracer = get_tracer()
+    was_on = tracer.enabled
+    tracer.clear()
+    tracer.enable()
+    try:
+        engine.add(Sequence(list(range(1, 12)), 3))
+        for _ in range(4):
+            engine.step()
+        spans = [s for s in tracer.snapshot()
+                 if s["name"] == "mxtpu.llm.step"
+                 and "moe_pairs" in s["attrs"]]
+    finally:
+        if not was_on:
+            tracer.disable()
+        tracer.clear()
+    assert fetches and set(fetches) == {3}       # one fetch, three arrays
+    assert len(spans) == len(fetches)
+    first = spans[0]["attrs"]
+    # the first chunk: 8 tokens x 2 experts x 2 expert layers
+    assert first["moe_pairs"] == 32 and first["rows"] == [(8, 8)]
+    assert 2 <= first["moe_experts_hit"] <= 16
+    assert 2 <= first["moe_max_load"] <= 8
+
+
+def test_latent_kernel_against_its_twin_with_garbage_invisible():
+    """(c): the Pallas kernel in interpret mode against the gather
+    reference; what lies past a token's position, in blocks no table
+    names and in the null block changes nothing."""
+    rs = np.random.RandomState(3)
+    L, N, bs, W, H, V, T = 2, 12, 4, 128, 4, 16, 11
+    pages = jnp.asarray(rs.randn(L, N, bs, W), jnp.float32)
+    q = jnp.asarray(rs.randn(T, H, W), jnp.float32)
+    tables = jnp.asarray([[3, 5, 7, 0], [9, 2, 0, 0], [4, 6, 8, 10]],
+                         jnp.int32)
+    seq_ids = jnp.asarray([0, 0, 0, 0, 1, 1, 2, 2, 2, 2, 2], jnp.int32)
+    positions = jnp.asarray([6, 7, 8, 9, 4, 5, 10, 11, 12, 13, 14],
+                            jnp.int32)
+    args = (tables, seq_ids, positions, 0.25, V)
+    want = mla_flat_attention_reference(q, pages, *args, layer=1)
+    got = mla_flat_attention(q, pages, *args, layer=1, use_pallas=True,
+                             interpret=True)
+    assert got.shape == (T, H, V)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    # one layer's pool without `layer`: the same kernel
+    single = mla_flat_attention(q, pages[1], *args, use_pallas=True,
+                                interpret=True)
+    np.testing.assert_array_equal(single, got)
+    dirty = np.array(pages)
+    dirty[:, 0] = 1e4                  # the null block
+    dirty[:, 1] = -1e4                 # a block no table names
+    dirty[0] = 1e4                     # another layer
+    dirty[1, 7, 2:] = 1e4              # sequence 0 past position 9
+    dirty[1, 2, 2:] = -1e4             # sequence 1 past position 5
+    dirty[1, 10, 3:] = 1e4             # sequence 2 past position 14
+    for fn in (lambda p: mla_flat_attention(
+            q, p, *args, layer=1, use_pallas=True, interpret=True),
+            lambda p: mla_flat_attention_reference(q, p, *args, layer=1)):
+        np.testing.assert_allclose(fn(jnp.asarray(dirty)), want,
+                                   rtol=2e-5, atol=2e-5)
+    with pytest.raises(ValueError, match="latent pool"):
+        mla_flat_attention(q, pages, *args)
+    assert registry.get("mla_flat_attention") is not None
+
+
+def per_token_loop(x, sel, gates, valid, wg, wu, wd):
+    out = np.zeros((x.shape[0], wd.shape[2]), np.float64)
+    for t in range(x.shape[0]):
+        if not valid[t]:
+            continue
+        for e, g in zip(np.asarray(sel[t]), np.asarray(gates[t])):
+            gate = np.asarray(x[t], np.float64) @ np.asarray(wg[e])
+            up = np.asarray(x[t], np.float64) @ np.asarray(wu[e])
+            out[t] += g * ((gate / (1 + np.exp(-gate)) * up)
+                           @ np.asarray(wd[e]))
+    return out
+
+
+@pytest.mark.parametrize("plant", ["routed", "all_to_one", "one_unused",
+                                   "all_padding"])
+def test_expert_layer_loses_no_pair_under_imbalance(plant):
+    """(d): the grouped kernel (interpret mode) and the plain twin
+    against a loop over each token's experts, under the router's own
+    choice and under planted imbalance."""
+    rs = np.random.RandomState(4)
+    T, d, E, m, k = 37, 64, 8, 32, 2
+    x = jnp.asarray(rs.randn(T, d), jnp.float32)
+    wg = jnp.asarray(rs.randn(E, d, m) * d ** -0.5, jnp.float32)
+    wu = jnp.asarray(rs.randn(E, d, m) * d ** -0.5, jnp.float32)
+    wd = jnp.asarray(rs.randn(E, m, d) * m ** -0.5, jnp.float32)
+    router = jnp.asarray(rs.randn(d, E) * d ** -0.5, jnp.float32)
+    bias = jnp.asarray(rs.randn(E) * 0.01, jnp.float32)
+    sel, gates = moe.route_noaux_tc(x, router, bias, k, True, 2.446)
+    np.testing.assert_allclose(gates.sum(axis=1), 2.446, rtol=1e-5)
+    valid = np.arange(T) < 33              # four rows of bucket padding
+    if plant == "all_to_one":              # every pair on expert 5
+        sel = jnp.full_like(sel, 5)
+    elif plant == "one_unused":            # expert 3 gets no token
+        sel = jnp.where(sel == 3, (sel + 1) % E, sel)
+    elif plant == "all_padding":
+        valid = np.zeros(T, bool)
+    want = per_token_loop(x, sel, gates, valid, wg, wu, wd)
+    args = (x, sel, gates, jnp.asarray(valid), wg, wu, wd)
+    twin = moe.moe_experts_reference(*args)
+    got = moe.moe_experts(*args, use_pallas=True, interpret=True)
+    np.testing.assert_allclose(twin, want, rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
+    assert not np.asarray(got)[~valid].any()    # padding comes back 0
+    pairs, hit, biggest = (int(v) for v in moe.moe_load(
+        sel, jnp.asarray(valid), E))
+    live = int(valid.sum())
+    assert pairs == live * k
+    if plant == "all_to_one":
+        assert (hit, biggest) == (1, live * k)
+    elif plant == "one_unused":
+        assert hit <= E - 1
+    elif plant == "all_padding":
+        assert (hit, biggest) == (0, 0)
+    assert registry.get("moe_experts") is not None
+
+
+def test_selection_bias_selects_and_does_not_weigh():
+    x = jnp.ones((1, 4), jnp.float32)
+    router = jnp.asarray(np.diag([3.0, 2.0, 1.0, 0.5]), jnp.float32)
+    sel, gates = moe.route_noaux_tc(x, router, jnp.zeros(4), 2, True, 1.0)
+    assert sorted(np.asarray(sel[0]).tolist()) == [0, 1]
+    biased, gates_b = moe.route_noaux_tc(
+        x, router, jnp.asarray([0.0, -1.0, 0.0, 1.0]), 2, True, 1.0)
+    assert sorted(np.asarray(biased[0]).tolist()) == [0, 3]
+    s = 1 / (1 + np.exp(-np.asarray([3.0, 0.5])))
+    np.testing.assert_allclose(sorted(np.asarray(gates_b[0])),
+                               sorted(s / s.sum()), rtol=1e-5)
+
+
+def test_default_config_still_builds_the_gpt2_program():
+    """(f): the six widths alone describe PR 27's model: the same
+    fields, tree, cache and step signature."""
+    config = DecoderConfig()
+    assert config.model_type == "gpt2" and config.head_dim == 16
+    assert config.to_dict() == {"vocab_size": 32, "d_model": 32,
+                                "num_layers": 2, "num_heads": 2,
+                                "d_ff": 64, "max_context": 128}
+    assert DecoderConfig.from_dict(config.to_dict()).to_dict() \
+        == config.to_dict()
+    toy = DecoderConfig(**TOY)
+    assert DecoderConfig.from_dict(toy.to_dict()).to_dict() == toy.to_dict()
+    assert toy.head_dim == 16 and toy.latent_dim == 24
+    model = TinyDecoder(config)
+    assert model.expert_slots == 0 and model.cache_layout == {
+        "kind": "kv", "num_heads": 2, "head_dim": 16}
+    params = model.init_params(0)
+    assert set(params) == {"embed", "pos", "lnf_g", "lnf_b", "head",
+                           "layers"}
+    assert set(params["layers"][0]) == {
+        "ln1_g", "ln1_b", "wq", "wk", "wv", "wo", "ln2_g", "ln2_b", "w1",
+        "b1", "w2", "b2"}
+    engine = LLMEngine(model, params, block_size=16)
+    assert engine.cache.kind == "kv"
+    assert [p.shape for p in engine.cache.pools()] == [(2, 65, 16, 2, 16)] * 2
+    text = engine.step_program_text()
+    # params' 29 leaves, the K and the V pool, the batch's 14 arrays
+    assert text.count("%arg") >= 45
+    assert engine.cache.layout() == {
+        "kind": "kv", "values_per_token": 64,
+        "pool_shape": [2, 65, 16, 2, 16], "bytes_per_token": 512}
+    want = greedy_decode_reference(model, params, [1, 2, 3], 4)
+    from mxnet_tpu.serving.llm import Sequence
+    seq = Sequence([1, 2, 3], 4)
+    engine.add(seq)
+    while engine.has_work():
+        engine.step()
+    assert list(seq.generated) == want
+
+
+def test_a_latent_cache_without_experts_serves():
+    """The cache's layout and the router's load are separate facts: a
+    dense MLA model (every layer's MLP dense) keeps the latent pool,
+    returns no load and serves through the same step."""
+    model = TinyDecoder(DecoderConfig(**dict(TOY, first_k_dense_replace=3)))
+    assert model.expert_slots == 0
+    assert model.cache_layout["kind"] == "latent"
+    params = model.init_params(0)
+    engine = LLMEngine(model, params, block_size=4)
+    assert [p.shape for p in engine.cache.pools()] == [(3, 129, 4, 128)]
+    from mxnet_tpu.serving.llm import Sequence
+    seq = Sequence([int(t) for t in TOKENS[:9]], 3)
+    engine.add(seq)
+    while engine.has_work():
+        engine.step()
+    assert engine._moe_load is None
+    tokens = jnp.asarray([(seq.prompt + seq.generated)[:12]], jnp.int32)
+    logits = model.forward(params, tokens)[0][0]
+    assert list(seq.generated) == [int(t) for t in
+                                   jnp.argmax(logits[8:11], axis=-1)]
+
+
+def _toy_draft():
+    return TinyDecoder(DecoderConfig(vocab_size=97, max_context=64))
+
+
+@pytest.mark.parametrize("kwargs,named", [
+    (lambda: {"adapter_bank": object()}, "adapter_bank"),
+    (lambda: {"weight_dtype": "int8"}, "weight_dtype"),
+    (lambda: {"weight_dtype": "fp8"}, "weight_dtype"),
+    (lambda: {"kv_dtype": "int8"}, "kv_dtype"),
+    (lambda: {"kv_dtype": "fp8"}, "kv_dtype"),
+    (lambda: {"mesh": "tp=2"}, "mesh"),
+    (lambda: {"draft_model": _toy_draft(),
+              "draft_params": _toy_draft().init_params(0)}, "draft_model"),
+])
+def test_engine_refuses_what_the_block_does_not_take(model, kwargs, named):
+    params = model.init_params(0)
+    with pytest.raises(UnsupportedModelFeatureError, match=named):
+        LLMEngine(model, params, block_size=4, **kwargs())
+
+
+def test_entry_points_no_engine_path_runs_raise(model):
+    params = model.init_params(0)
+    z = jnp.zeros((1, 1), jnp.int32)
+    for call in (
+            lambda: model.decode_chunk(params, z, z, z[0], None, None, z,
+                                       z[0]),
+            lambda: model.decode_step(params, z[0], z[0], None, None, z,
+                                      z[0]),
+            lambda: greedy_decode_reference(model, params, [1, 2], 2),
+            lambda: model.param_specs()):
+        with pytest.raises(UnsupportedModelFeatureError):
+            call()
+    with pytest.raises(ValueError, match="model_type"):
+        DecoderConfig(model_type="mamba")
+    with pytest.raises(ValueError, match="kv_lora_rank"):
+        DecoderConfig(model_type="deepseek_v3")
