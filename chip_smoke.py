@@ -10,7 +10,10 @@ supports, with random weights made from a seed:
               eight requests whose steps mix prefill chunks with decodes;
 - **kernels** every Pallas kernel the package dispatches to by default on
               a TPU, compiled by Mosaic and compared with its ``jnp``
-              reference on the chip.
+              reference on the chip;
+- **latent**  the flat latent (MLA) attention kernel alone, at the widths
+              and the three packed shapes of the ``moonlight_16b_a3b``
+              cell, against its reference on a few rows, timed a call.
 
 Every phase checks what came out and prints one JSON line; a failed check
 raises, so the exit code is non-zero. The last line of standard output is
@@ -19,6 +22,7 @@ the builder, not metrics.
 
     python chip_smoke.py             # one chip
     python chip_smoke.py --chips 4   # the same two hot paths over a mesh
+    python chip_smoke.py --phases latent   # only the phases named
 
 It refuses to run where JAX finds no TPU. The phases are plain functions
 that take their sizes as arguments, so ``tests/test_chip_smoke.py``
@@ -30,14 +34,19 @@ import json
 import sys
 import time
 
+PHASES = ("train", "serve", "kernels", "latent")
+
 #: kernel-vs-reference bounds, fixed from the dtype before any chip run.
 #: bf16 flash attention: the repo's own bf16 bound (tests/test_attention.py).
 #: f32 ragged attention: VPU arithmetic, so only exp/divide differ from XLA.
 #: int8-weight matmul: bounded by a bf16 pass over f32 activations, which is
 #: below the 1/127 step of the weights themselves.
+#: latent attention: float32 operands through the MXU at the backend's
+#: default precision, one bf16 pass like the flash kernel's.
 FLASH_BF16_TOL = 0.05
 RAGGED_F32_TOL = 1e-4
 WQ_MATMUL_TOL = 1e-2
+LATENT_TOL = 0.05
 
 
 def check(ok, what):
@@ -413,6 +422,123 @@ def kernels_phase(attn_shape, ragged_shape, matmul_shape, interpret=False):
         rel_err={k: float(f"{e:.3g}") for k, e in errs.items()})
 
 
+# ----------------------------------------------------------------- latent --
+
+def latent_batch(rs, T, MB, block_size, slots=8, chunk=16):
+    """A packed step of ``T`` query tokens over ``slots`` sequences as
+    ``LLMEngine._build_batch`` lays it out: each sequence's tokens side
+    by side at ascending positions, as many whole prefill chunks of
+    ``chunk`` tokens as fit while the other slots decode one token
+    each (T=8: eight decodes; 23: a chunk and seven; 128: eight
+    chunks). Contexts are drawn as the Moonlight cell's traffic draws
+    its prompts (lognormal about 1,046 tokens, sigma 0.9), cut to the
+    table, and a chunk stands somewhere inside its prompt: most tables
+    are far from full. The engine keeps one pair of buffers a shape and
+    never clears them, so the step is drawn twice: an earlier one that
+    fills the buffers, then the step itself with slot 0 idle and the
+    others in another admit order, written over the front. What is left
+    of the earlier step is the stale tail, a token or a chunk of a
+    sequence that the step also holds further up. Returns ``(seq_ids,
+    positions, valid)``, ``valid`` the step's own tokens; ``T`` is 2
+    or more."""
+    import numpy as np
+    chunks = min(slots, max(0, T - slots) // (chunk - 1))
+    lens = [chunk] * chunks + [1] * min(slots - chunks, T)
+    limit = MB * block_size
+
+    def step(order):
+        sids, poss = [], []
+        for slot, n in zip(order, lens):
+            ctx = int(np.clip(rs.lognormal(np.log(1046.0), 0.9), n, limit))
+            if n > 1:                   # a chunk inside the prompt
+                ctx = int(rs.randint(n, ctx + 1))
+            sids += [slot] * n
+            poss += list(range(ctx - n, ctx))
+        return sids, poss
+
+    sids, poss = step(range(len(lens)))
+    pad = T - len(sids)                 # a first step's tail: zeros
+    sids, poss = sids + [0] * pad, poss + [0] * pad
+    order = 1 + rs.permutation(len(lens) - 1)
+    if order[-1] == len(lens) - 1:      # the tail's sequence: not beside it
+        order = order[::-1]
+    now = step(order)
+    valid = len(now[0])
+    sids[:valid], poss[:valid] = now
+    return np.asarray(sids, np.int32), np.asarray(poss, np.int32), valid
+
+
+def latent_phase(cases, layers, num_blocks, block_size, width, heads, v_dim,
+                 dtype="bfloat16", reps=50, interpret=False):
+    """The flat latent kernel alone over a pool ``[layers, num_blocks,
+    block_size, width]``: each ``(T, MB)`` of ``cases`` on a batch from
+    :func:`latent_batch`, every row of the step's own tokens against
+    the gather reference (the stale tail's rows only finite), then
+    ``reps`` calls timed."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from mxnet_tpu.ops.ragged_attention import (
+        mla_flat_attention, mla_flat_attention_reference)
+
+    started, c0 = time.monotonic(), _counters()
+    scale, layer, slots = 192 ** -0.5, layers // 2, 8
+    keys = jax.random.split(jax.random.PRNGKey(5), 2)
+    pool = jax.random.normal(
+        keys[0], (layers, num_blocks, block_size, width)).astype(dtype)
+    ms, errs, ctx = {}, {}, {}
+    run_s = 0.0
+    for T, MB in cases:
+        name = f"T{T}_MB{MB}"
+        rs = np.random.RandomState(1000 * T + MB)
+        q = jax.random.normal(keys[1], (T, heads, width), jnp.float32)
+        check(slots * MB < num_blocks, f"latent {name}: the pool is too "
+              f"small for {slots} tables of {MB} pages")
+        tables = jnp.asarray(1 + rs.permutation(num_blocks - 1)[
+            :slots * MB].reshape(slots, MB), jnp.int32)
+        sids, poss, valid = latent_batch(rs, T, MB, block_size, slots)
+        sids, poss = jnp.asarray(sids), jnp.asarray(poss)
+
+        def attend(q, pool, tables, sids, poss, layer):
+            return mla_flat_attention(
+                q, pool, tables, sids, poss, scale, v_dim, layer=layer,
+                use_pallas=True, interpret=interpret)
+
+        # `reps` calls inside ONE program, a layer after the other as a
+        # step makes them: a call from the host costs 0.2 ms before the
+        # kernel starts, more than the kernel takes
+        many = jax.jit(lambda *a: jax.lax.fori_loop(
+            0, reps, lambda i, _: attend(*a, i % layers),
+            jnp.zeros((T, heads, v_dim), jnp.float32)))
+        args = (q, pool, tables, sids, poss)
+        got = jax.block_until_ready(jax.jit(attend)(*args, layer))
+        jax.block_until_ready(many(*args))          # compile
+        t0 = time.monotonic()
+        jax.block_until_ready(many(*args))
+        dt = time.monotonic() - t0
+        run_s += dt
+        ms[name] = round(dt / reps * 1e3, 4)
+        ctx[name] = round(float(poss.mean()) + 1, 1)
+        # the reference gathers a whole table a row: 32 rows a time
+        with jax.default_matmul_precision("highest"):
+            want = jnp.concatenate([mla_flat_attention_reference(
+                q[r:r + 32], pool, tables, sids[r:r + 32], poss[r:r + 32],
+                scale, v_dim, layer=layer) for r in range(0, valid, 32)])
+        check(got.shape == (T, heads, v_dim)
+              and bool(np.isfinite(np.asarray(got)).all()),
+              f"latent {name}: non-finite or mis-shaped output")
+        errs[name] = _rel_err(got[:valid], want[:valid])
+        check(errs[name] <= LATENT_TOL,
+              f"latent {name}: error {errs[name]:.3g} over bound "
+              f"{LATENT_TOL}")
+    return _report(
+        "latent", started, c0, time.monotonic() - started - run_s, run_s,
+        interpret=interpret, pool=list(pool.shape), dtype=dtype,
+        heads=heads, v_dim=v_dim, reps=reps, ms_per_call=ms,
+        mean_context=ctx,
+        rel_err={k: float(f"{e:.3g}") for k, e in errs.items()})
+
+
 # ------------------------------------------------------------------- main --
 
 def main(argv=None):
@@ -420,7 +546,14 @@ def main(argv=None):
     ap.add_argument("--chips", type=int, default=1, choices=(1, 4),
                     help="4 runs train (dp=4) and serve (tp=4) over a "
                          "four-chip mesh in this one process")
+    ap.add_argument("--phases", default=",".join(PHASES),
+                    help="the phases to run, comma separated, of "
+                         + ", ".join(PHASES) + " (kernels and latent: "
+                         "one chip only)")
     args = ap.parse_args(argv)
+    phases = args.phases.split(",")
+    if set(phases) - set(PHASES):
+        ap.error(f"--phases takes {PHASES}, got {args.phases!r}")
 
     import jax
     devices = jax.devices()
@@ -437,23 +570,31 @@ def main(argv=None):
           flush=True)
 
     many = args.chips > 1
-    train_phase(
-        lambda: vision.resnet50_v1(layout="NHWC", stem_s2d=True),
-        image=224, batch=128 * args.chips,
-        mesh=f"dp={args.chips}" if many else None)
-    serve_phase(
-        DecoderConfig(vocab_size=32000, d_model=1024, num_layers=8,
-                      num_heads=8, d_ff=4096, max_context=2048),
-        prompt_lens=(16, 48, 96, 160, 240, 320, 416, 512), new_tokens=32,
-        mesh=f"tp={args.chips}" if many else None,
-        # the TPU's default float32 matmul rounds operands to bf16, and
-        # the paged step and the dense reference round different
-        # intermediates; pinned so that equal means equal
-        precision="highest")
-    if not many:
+    if "train" in phases:
+        train_phase(
+            lambda: vision.resnet50_v1(layout="NHWC", stem_s2d=True),
+            image=224, batch=128 * args.chips,
+            mesh=f"dp={args.chips}" if many else None)
+    if "serve" in phases:
+        serve_phase(
+            DecoderConfig(vocab_size=32000, d_model=1024, num_layers=8,
+                          num_heads=8, d_ff=4096, max_context=2048),
+            prompt_lens=(16, 48, 96, 160, 240, 320, 416, 512),
+            new_tokens=32, mesh=f"tp={args.chips}" if many else None,
+            # the TPU's default float32 matmul rounds operands to bf16,
+            # and the paged step and the dense reference round different
+            # intermediates; pinned so that equal means equal
+            precision="highest")
+    if "kernels" in phases and not many:
         kernels_phase(attn_shape=(8, 12, 512, 64),
                       ragged_shape=(64, 8, 128, 16, 257, 32),
                       matmul_shape=(256, 1024, 4096))
+    if "latent" in phases and not many:
+        # the moonlight_16b_a3b cell's pool and widths; its steps pack 8,
+        # 23, 38 or 128 tokens over tables of 256 or 512 pages
+        latent_phase(cases=((8, 256), (23, 512), (128, 512)), layers=9,
+                     num_blocks=4097, block_size=16, width=640, heads=16,
+                     v_dim=512)
     dev = devices[0]
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,

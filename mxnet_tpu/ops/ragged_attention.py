@@ -36,20 +36,20 @@ to 1 over the null block and DISCARD their outputs — the null block
 accumulates stale K/V from padded writes, so those rows are
 unspecified values, not zeros.
 
-Multi-token queries (ISSUE 12): the same kernel generalizes from one
-query token per sequence to a CHUNK of ``Q`` query tokens per sequence
-— ``q`` shaped ``[S, Q, H, D]`` with a scalar-prefetched ``q_lens[i]``
-giving each row's valid token count (``0 <= q_lens[i] <= Q``; padded
-tail tokens and whole inactive rows produce DISCARDED outputs). Query
-token ``t`` of row ``i`` sits at absolute position
-``kv_lens[i] - q_lens[i] + t`` and attends CAUSALLY over the paged
-history: positions ``<= kv_lens[i] - q_lens[i] + t`` only, masked
-inside the kernel, online softmax unchanged. This one shape is
-chunked prefill (``q_lens[i]`` prompt tokens whose KV was just
-written), decode (``q_lens[i] == 1``) and speculative verify
-(``q_lens[i] == K + 1`` draft positions scored in one dispatch) — the
-"Ragged Paged Attention" unification (PAPERS.md): prefill and decode
-are the same multi-query-token kernel over the paged cache.
+Multi-token queries (ISSUE 12): ``q`` shaped ``[S, Q, H, D]`` with a
+scalar-prefetched ``q_lens[i]`` (``0 <= q_lens[i] <= Q``; padded tail
+tokens and inactive rows produce DISCARDED outputs). Query token ``t``
+of row ``i`` sits at position ``kv_lens[i] - q_lens[i] + t`` and
+attends CAUSALLY, masked in the kernel: chunked prefill, decode and
+speculative verify are one multi-query-token kernel (PAPERS.md).
+
+The flat LATENT kernel (``mla_flat_attention``, ISSUE 29) iterates
+otherwise: a grid over blocks of packed query tokens; in a block, each
+RUN of consecutive tokens of one sequence walks that sequence's pages
+once, all its tokens' heads the rows of one matrix, by manual DMA from
+the pool in HBM, several pages a loop step, double buffered, and only
+as far as the run's largest position. A step costs what its contexts
+hold, not ``T x MB``; see ``_mla_flat_kernel``.
 """
 from __future__ import annotations
 
@@ -403,49 +403,146 @@ def mla_flat_attention_reference(q, pages, block_tables, seq_ids,
     return out.astype(q.dtype)
 
 
-def _mla_flat_kernel(layer_ref, sid_ref, pos_ref, bt_ref, q_ref, kv_ref,
-                     o_ref, acc_ref, m_ref, l_ref, *, scale, block_size,
-                     num_blocks, v_dim):
-    """Grid (T, MB), the flat kernel's grid and page index map over ONE
-    pool of latents ``[L, N, bs, W]``: a page has no head axis, so all
-    ``H`` query heads of a token meet it in one matrix product,
-    ``(H, W) x (W, bs)``, and the page's first ``v_dim`` columns are the
-    values of the second, ``(H, bs) x (bs, v_dim)``. Online softmax in
-    float32 scratch across a token's pages, as in the flat kernel."""
-    t, j = pl.program_id(0), pl.program_id(1)
+def _mla_tiles(T, H, bs, MB):
+    """``(query tokens a block, pages a loop step)`` of the flat latent
+    kernel, from the shapes alone.
 
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    Query tokens: as many as put 256 rows (tokens x heads) before the
+    MXU at once, two passes of its 128 rows and, at 16 heads, one
+    prefill chunk of 16 tokens; the block's rows a multiple of the 8
+    sublanes unless the block is the whole batch. Pages: as many as
+    make 256 keys a loop step, so that both products have an
+    MXU-shaped minor dimension and the loop's fixed cost (the DMA
+    descriptors, the rescaling of the accumulator) is paid once per
+    256 keys; never more than the table is wide."""
+    qb = max(1, min(T, 256 // H))
+    if qb < T:
+        # tokens to a whole sublane tile
+        per = next(n for n in (1, 2, 4, 8) if n * H % 8 == 0)
+        qb = qb // per * per or T
+    return qb, max(1, min(MB, 256 // bs))
 
-    qpos = pos_ref[t]
-    base = j * block_size
 
-    @pl.when(base <= qpos)
-    def _body():
-        q = q_ref[...].astype(jnp.float32)            # (H, W)
-        kv = kv_ref[...].astype(jnp.float32)          # (bs, W)
-        s = jax.lax.dot_general(
-            q, kv, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale   # (H, bs)
-        pos = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(pos <= qpos, s, _NEG_INF)
-        m_prev = m_ref[...]                           # (H, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)                        # masked -> 0.0
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1,
-                                                  keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
-            p, kv[:, :v_dim], preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+def _mla_flat_kernel(layer_ref, sid_ref, pos_ref, bt_ref, q_ref, pool_ref,
+                     o_ref, kv_buf, sems, acc_ref, m_ref, l_ref, qpos_ref,
+                     *, scale, heads, block_size, v_dim):
+    """Grid ``(query blocks,)``: one grid step holds ``QB`` packed query
+    tokens, all their heads as the ``R = QB x H`` rows of one matrix
+    (a latent page has no head axis). The block's tokens are taken RUN
+    by run, a run being consecutive tokens of one sequence: the run's
+    pages, and only up to the page of its largest position, come from
+    the pool in HBM (``pool_ref``, whole, never sliced) by explicit
+    DMA through the scalar-prefetched block table, ``PP`` pages a loop
+    step into one of two buffers while the other is computed on. Each
+    step is two products, ``(R, W) x (W, keys)`` and ``(R, keys) x
+    (keys, v_dim)``, under one mask: a row sees a key if the row is one
+    of the RUN's own (rows ``[i x H, end x H)``; another run of the
+    same sequence in the block walks for itself) and the key's position
+    is not past its token's. Online softmax in float32 scratch as in
+    the flat kernel; rows of other runs pass through a step unchanged
+    (their ``p`` is zeroed by the mask, not by ``exp``: a row that has
+    met no key yet still has ``m == _NEG_INF``). The loops over a block's
+    tokens and a step's pages are ``fori_loop``s, not Python loops:
+    every step program traces and lowers this body anew, and unrolled
+    it made a warm ``warmup()`` of 17 programs 8 s longer."""
+    R = q_ref.shape[0]
+    QB = R // heads
+    KB = kv_buf.shape[1]
+    PP = KB // block_size
+    MB = bt_ref.shape[1]
+    t0 = pl.program_id(0) * QB
+    lyr = layer_ref[0]
+    last_key = MB * block_size - 1      # the reference's table ends here
 
-    @pl.when(j == num_blocks - 1)
-    def _finalize():
-        l_safe = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[...] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+    def position_to_rows(i, carry):     # each token's position, per row
+        rows = pl.ds(pl.multiple_of(i * heads, heads), heads)
+        qpos_ref[rows, :] = jnp.full(
+            (heads, 1), jnp.minimum(pos_ref[t0 + i], last_key), jnp.int32)
+        return carry
+
+    jax.lax.fori_loop(0, QB, position_to_rows, 0)
+    row = jax.lax.broadcasted_iota(jnp.int32, (R, 1), 0)
+
+    def pages(seq, k, slot, go):
+        """Loop step ``k`` of sequence ``seq``: start (``go``) or await
+        the DMA of its ``PP`` pages into buffer ``slot``. Past the
+        table's width the last entry again; past the run's own pages
+        whatever the table names (the null block in the engine's
+        tables): masked either way."""
+        def page(p, carry):
+            copy = pltpu.make_async_copy(
+                pool_ref.at[lyr,
+                            bt_ref[seq, jnp.minimum(k * PP + p, MB - 1)]],
+                kv_buf.at[slot, pl.ds(pl.multiple_of(p * block_size,
+                                                     block_size),
+                                      block_size)],
+                sems.at[slot])
+            if go:
+                copy.start()
+            else:
+                copy.wait()
+            return carry
+
+        jax.lax.fori_loop(0, PP, page, 0)
+
+    def run(i):
+        seq = sid_ref[t0 + i]
+
+        def same_seq(c):
+            j, _ = c
+            return jnp.logical_and(
+                j < QB, sid_ref[t0 + jnp.minimum(j, QB - 1)] == seq)
+
+        def take(c):
+            j, last = c
+            return j + 1, jnp.maximum(last, pos_ref[t0 + j])
+
+        end, last = jax.lax.while_loop(same_seq, take,
+                                       (i + 1, pos_ref[t0 + i]))
+        steps = jnp.minimum(last, last_key) // KB + 1
+        # the last key each row may see in this run: none for the rows
+        # of other runs, the same sequence's among them
+        mine = jnp.logical_and(row >= i * heads, row < end * heads)
+        limit = jnp.where(mine, qpos_ref[...], -1)
+        pages(seq, 0, 0, True)
+
+        def step(k, carry):
+            slot = jax.lax.rem(k, 2)
+
+            @pl.when(k + 1 < steps)
+            def _next():
+                pages(seq, k + 1, 1 - slot, True)
+
+            pages(seq, k, slot, False)
+            q = q_ref[...].astype(jnp.float32)            # (R, W)
+            kv = kv_buf[slot].astype(jnp.float32)         # (KB, W)
+            s = jax.lax.dot_general(
+                q, kv, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale   # (R, KB)
+            kpos = k * KB + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            mask = kpos <= limit
+            s = jnp.where(mask, s, _NEG_INF)
+            m_prev = m_ref[...]                           # (R, 1)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1,
+                                                      keepdims=True)
+            acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
+                p, kv[:, :v_dim], preferred_element_type=jnp.float32)
+            m_ref[...] = m_new
+            return carry
+
+        jax.lax.fori_loop(0, steps, step, 0)
+        return end
+
+    jax.lax.while_loop(lambda i: i < QB, run, 0)
+    l_safe = jnp.maximum(l_ref[...], 1e-30)
+    o_ref[...] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnums=(6, 7, 8))
@@ -453,39 +550,47 @@ def _mla_flat_pallas(q, pages, block_tables, seq_ids, positions, layer,
                      scale, v_dim, interpret):
     T, H, W = q.shape
     bs = pages.shape[2]
-    MB = block_tables.shape[1]
+    S, MB = block_tables.shape
+    qb, pp = _mla_tiles(T, H, bs, MB)
+    blocks = -(-T // qb)
+    # the scalars of the last block's rows past T: the last token's
+    # sequence at position 0, so that they lengthen its run and cost no
+    # walk of their own; their rows are never written back
+    pad = (0, blocks * qb - T)
+    sid = jnp.pad(jnp.clip(seq_ids.astype(jnp.int32), 0, S - 1), pad,
+                  mode="edge")
+    pos = jnp.pad(jnp.maximum(positions.astype(jnp.int32), 0), pad)
+    R = qb * H
 
-    def tok(t, j, lyr, sid, pos, bt):
-        return (t, 0, 0)
-
-    def page(t, j, lyr, sid, pos, bt):
-        return (lyr[0], bt[sid[t], j], 0, 0)
+    def rows(b, lyr, sid, pos, bt):
+        return (b, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(T, MB),
+        grid=(blocks,),
         in_specs=[
-            pl.BlockSpec((None, H, W), tok, memory_space=pltpu.VMEM),
-            pl.BlockSpec((None, None, bs, W), page,
-                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((R, W), rows, memory_space=pltpu.VMEM),
+            pl.BlockSpec(memory_space=pl.ANY),        # the pool, in HBM
         ],
-        out_specs=pl.BlockSpec((None, H, v_dim), tok,
-                               memory_space=pltpu.VMEM),
+        out_specs=pl.BlockSpec((R, v_dim), rows, memory_space=pltpu.VMEM),
         scratch_shapes=[
-            pltpu.VMEM((H, v_dim), jnp.float32),
-            pltpu.VMEM((H, 1), jnp.float32),
-            pltpu.VMEM((H, 1), jnp.float32),
+            pltpu.VMEM((2, pp * bs, W), pages.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.VMEM((R, v_dim), jnp.float32),
+            pltpu.VMEM((R, 1), jnp.float32),
+            pltpu.VMEM((R, 1), jnp.float32),
+            pltpu.VMEM((R, 1), jnp.int32),
         ],
     )
-    kernel = functools.partial(_mla_flat_kernel, scale=scale,
-                               block_size=bs, num_blocks=MB, v_dim=v_dim)
-    return pl.pallas_call(
+    kernel = functools.partial(_mla_flat_kernel, scale=scale, heads=H,
+                               block_size=bs, v_dim=v_dim)
+    out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((T, H, v_dim), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((T * H, v_dim), q.dtype),
         interpret=interpret,
-    )(layer.astype(jnp.int32).reshape(1), seq_ids.astype(jnp.int32),
-      positions.astype(jnp.int32), block_tables.astype(jnp.int32),
-      q, pages)
+    )(layer.astype(jnp.int32).reshape(1), sid, pos,
+      block_tables.astype(jnp.int32), q.reshape(T * H, W), pages)
+    return out.reshape(T, H, v_dim)
 
 
 def mla_flat_attention(q, pages, block_tables, seq_ids, positions,
@@ -505,7 +610,25 @@ def mla_flat_attention(q, pages, block_tables, seq_ids, positions,
     (``(nope + rope) ** -0.5``), not of ``W``. Returns ``[T, H,
     v_dim]``, to go through the value half of the up-projection.
     Gated like :func:`ragged_flat_attention`: Mosaic on a TPU, the
-    gather reference elsewhere, interpret mode on request."""
+    gather reference elsewhere, interpret mode on request.
+
+    What the kernel makes of the packed batch (``_mla_flat_kernel``):
+    blocks of query tokens (:func:`_mla_tiles`), and in a block every
+    RUN of neighbouring tokens with one ``seq_ids`` value walks that
+    sequence's pages once, up to the page of the run's largest
+    position. The result is the reference's for ANY ``seq_ids`` and
+    ``positions`` (a row takes keys only from its own run's walk, up
+    to its own token's position, so a sequence met again further on in
+    a block, as a valid token beside a stale one, is two runs that do
+    not touch; ids are clipped to the table's rows, positions to its
+    width, as the reference's gather and mask do), but the cost
+    follows the layout: ``LLMEngine._build_batch`` packs a sequence's
+    tokens side by side, so a prefill chunk reads its pages once, while
+    tokens of one sequence scattered over the batch are so many runs
+    and walks. Nothing tells the kernel which tokens are padding: a
+    tail that still holds an earlier step's ids and positions is
+    walked like any token (rows finite, to be discarded), a tail of
+    zeros costs one loop step."""
     if use_pallas is None:
         use_pallas = _on_tpu()
     if pages.ndim != (3 if layer is None else 4):

@@ -49,6 +49,42 @@ def test_serve_phase_toy():
     assert line["attention"] == "jnp_reference"
 
 
+@pytest.mark.parametrize("T,lens", [
+    (8, [1] * 8), (23, [16] + [1] * 7), (38, [16, 16] + [1] * 6),
+    (128, [16] * 8), (5, [1] * 5)])
+def test_latent_batch_packs_chunks_beside_decodes(T, lens):
+    """The step's own tokens: every slot but 0, chunks first, a row's
+    positions ascending; behind them the tail an earlier step left,
+    whose sequence the step holds too (what `_build_batch` leaves)."""
+    import numpy as np
+    sids, poss, valid = chip_smoke.latent_batch(
+        np.random.RandomState(T), T, 32, 16)
+    assert len(sids) == len(poss) == T
+    assert valid == sum(lens[:-1]) and 0 not in sids[:valid]
+    at = 0
+    for n in lens[:-1]:
+        assert len(set(sids[at:at + n])) == 1
+        assert (np.diff(poss[at:at + n]) == 1).all()
+        assert 0 <= poss[at] and poss[at + n - 1] < 32 * 16
+        at += n
+    assert len(set(sids[:valid])) == len(lens) - 1
+    assert set(sids[valid:]) == {len(lens) - 1}      # the stale tail
+    assert len(lens) - 1 in sids[:valid]
+
+
+def test_latent_phase_toy():
+    """The phase's control flow, the kernel in interpret mode: a pool of
+    pages of 4, a step of 23 tokens (a chunk astride two query blocks at
+    16 heads) and one of 8."""
+    line = chip_smoke.latent_phase(
+        cases=((8, 8), (23, 16)), layers=2, num_blocks=129, block_size=4,
+        width=128, heads=16, v_dim=64, dtype="float32", reps=1,
+        interpret=True)
+    assert line["phase"] == "latent" and line["interpret"] is True
+    assert set(line["ms_per_call"]) == {"T8_MB8", "T23_MB16"}
+    assert max(line["rel_err"].values()) < 1e-4
+
+
 def test_refuses_to_run_without_a_tpu():
     p = subprocess.run(
         [sys.executable, os.path.join(REPO, "chip_smoke.py")],
@@ -71,6 +107,22 @@ def test_every_kernel_lowers_for_tpu_at_head_dim_128():
         names.append(name)
     assert {"flash_fwd", "flash_bwd_bias", "ragged_flat_f32",
             "ragged_flat_int8", "quantized_matmul_int8"} <= set(names)
+
+
+@pytest.mark.parametrize("T,MB", [(8, 256), (23, 512), (128, 512)])
+def test_latent_kernel_lowers_for_tpu_at_the_cells_shapes(T, MB):
+    import jax.numpy as jnp
+    from mxnet_tpu.ops.ragged_attention import mla_flat_attention
+
+    def kernel(q, pool, tables, sids, poss):
+        return mla_flat_attention(q, pool, tables, sids, poss, 192 ** -0.5,
+                                  512, layer=4, use_pallas=True,
+                                  interpret=False)
+    args = [jax.ShapeDtypeStruct(s, d) for s, d in (
+        ((T, 16, 640), jnp.float32), ((9, 4097, 16, 640), jnp.bfloat16),
+        ((8, MB), jnp.int32), ((T,), jnp.int32), ((T,), jnp.int32))]
+    exported = jax.export.export(jax.jit(kernel), platforms=["tpu"])(*args)
+    assert "tpu_custom_call" in exported.mlir_module()
 
 
 @pytest.fixture

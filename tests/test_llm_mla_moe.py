@@ -213,6 +213,119 @@ def test_latent_kernel_against_its_twin_with_garbage_invisible():
     assert registry.get("mla_flat_attention") is not None
 
 
+def _rows(*runs):
+    """``(seq_ids, positions)`` of a packed batch from ``(sequence, first
+    position, tokens)`` runs laid side by side."""
+    sids = [s for s, _, n in runs for _ in range(n)]
+    poss = [p + i for _, p, n in runs for i in range(n)]
+    return np.asarray(sids, np.int32), np.asarray(poss, np.int32)
+
+
+#: name -> (H, W, v_dim, block_size, MB, page dtype, runs, valid tokens).
+#: At 4 heads a query block is the whole batch (or 64 tokens), at 16 heads
+#: 16 tokens; a loop step takes 256 keys, never more than the table holds.
+LATENT_CASES = {
+    # two chunks of 16 and three decodes, one query block
+    "mixed_step": (4, 128, 16, 4, 16, "float32",
+                   [(0, 9, 16), (2, 40, 16), (1, 5, 1), (3, 63, 1),
+                    (4, 0, 1)], 35),
+    # 256 keys a step are 8 pages of 32: sequences of 11 pages (no
+    # multiple), of 1 page, of exactly 8 and of 9
+    "page_counts": (4, 128, 16, 32, 12, "float32",
+                    [(0, 330, 3), (1, 2, 2), (2, 255, 1), (3, 256, 1)], 7),
+    # 16 heads: blocks of 16 tokens, chunks astride their edges, a run
+    # longer than a block, the last block partly past T
+    "straddle": (16, 128, 16, 4, 16, "float32",
+                 [(1, 3, 7), (0, 20, 16), (2, 0, 20), (3, 50, 1),
+                  (1, 33, 2)], 46),
+    # the first 10 tokens are the step; the tail is what an earlier,
+    # longer step left in the buffers
+    "stale_tail": (16, 128, 16, 4, 16, "float32",
+                   [(0, 12, 8), (3, 30, 2), (2, 57, 6), (1, 11, 1),
+                    (0, 40, 3), (3, 3, 3)], 10),
+    # the cell's own tiles: pages of 16 in bf16, W 640, 16 heads, 512
+    # values; a chunk, decodes, a second chunk astride the block edge
+    "cell_tiles": (16, 640, 512, 16, 24, "bfloat16",
+                   [(0, 100, 16), (1, 383, 1), (2, 15, 1), (3, 200, 16),
+                    (4, 0, 4)], 38),
+    # the same tokens scattered: no two neighbours of one sequence, so
+    # every token is a run of its own
+    "scattered": (4, 128, 16, 4, 16, "float32",
+                  [(0, 9, 1), (2, 40, 1), (0, 10, 1), (1, 5, 1),
+                   (2, 3, 1), (0, 2, 1), (1, 63, 1)], 7),
+    # The three below at the cell's tiles (16 tokens a block, 256 keys a
+    # loop step) over tables of 768 keys, so that two runs of one
+    # sequence walk DIFFERENT numbers of loop steps: a row that took keys
+    # from another run's walk would count its first keys twice.
+    # one block holds A, B, A: A's runs end in the third and the first
+    # loop step; the same again with the short run first
+    "runs_apart": (16, 640, 512, 16, 48, "bfloat16",
+                   [(0, 700, 5), (1, 300, 3), (0, 100, 4), (2, 20, 4),
+                    (3, 90, 2), (1, 610, 1), (3, 520, 3)], 22),
+    # T = 23: the last block is 7 tokens and 9 rows of the wrapper's own
+    # padding; sequence 0 and the last token's sequence each have a
+    # token past the first loop step there, away from the padding
+    "padded_block": (16, 128, 16, 16, 48, "float32",
+                     [(1, 50, 16), (0, 600, 1), (2, 400, 1), (3, 300, 1),
+                      (4, 700, 3), (2, 10, 1)], 23),
+    # three decode tokens are the step; the tail an earlier step left
+    # holds chunks of the same sequences at other depths, another
+    # sequence's token between
+    "stale_tail_deep": (16, 128, 16, 16, 48, "float32",
+                        [(0, 600, 1), (1, 20, 1), (2, 300, 1), (0, 280, 8),
+                         (1, 500, 3), (2, 5, 4), (0, 40, 5)], 3),
+}
+
+
+@pytest.mark.parametrize("case", sorted(LATENT_CASES))
+def test_latent_kernel_walks_runs_of_a_packed_step(case):
+    """The kernel's iteration (query blocks, runs of one sequence, page
+    steps to the run's own length) in interpret mode against the gather
+    reference, every valid row; rows past the step's tokens finite."""
+    H, W, V, bs, MB, dtype, runs, valid = LATENT_CASES[case]
+    rs = np.random.RandomState(len(case))
+    S, L = 5, 2
+    N = S * MB + 1
+    pages = jnp.asarray(rs.randn(L, N, bs, W), dtype)
+    tables = jnp.asarray(
+        1 + rs.permutation(N - 1).reshape(S, MB), jnp.int32)
+    sids, poss = (jnp.asarray(a) for a in _rows(*runs))
+    q = jnp.asarray(rs.randn(len(sids), H, W), jnp.float32)
+    scale = W ** -0.5
+    want = mla_flat_attention_reference(q, pages, tables, sids, poss,
+                                        scale, V, layer=1)
+    got = mla_flat_attention(q, pages, tables, sids, poss, scale, V,
+                             layer=1, use_pallas=True, interpret=True)
+    assert got.shape == (len(sids), H, V) and got.dtype == q.dtype
+    np.testing.assert_allclose(got[:valid], want[:valid], rtol=2e-5,
+                               atol=2e-5)
+    assert np.isfinite(np.asarray(got)).all()
+    # what the step's own tokens read is all that reaches their rows: a
+    # tail of other sequences' tokens changes none of them
+    if valid < len(sids):
+        alone = mla_flat_attention(
+            q[:valid], pages, tables, sids[:valid], poss[:valid], scale,
+            V, layer=1, use_pallas=True, interpret=True)
+        np.testing.assert_allclose(got[:valid], alone, rtol=1e-6,
+                                   atol=1e-6)
+
+
+def test_latent_tiles_follow_the_shapes():
+    """No knob picks the tiles: 256 rows and 256 keys where the shapes
+    allow them."""
+    from mxnet_tpu.ops.ragged_attention import _mla_tiles
+    # the cell: 16 heads, pages of 16
+    assert _mla_tiles(8, 16, 16, 256) == (8, 16)
+    assert _mla_tiles(23, 16, 16, 512) == (16, 16)
+    assert _mla_tiles(128, 16, 16, 512) == (16, 16)
+    # few heads: more tokens a block, in whole sublane tiles, or all
+    assert _mla_tiles(11, 4, 4, 4) == (11, 4)
+    assert _mla_tiles(100, 4, 4, 512) == (64, 64)
+    assert _mla_tiles(100, 3, 16, 512) == (80, 16)
+    assert _mla_tiles(2, 128, 256, 8) == (2, 1)
+    assert _mla_tiles(40, 512, 16, 64) == (1, 16)
+
+
 def per_token_loop(x, sel, gates, valid, wg, wu, wd):
     out = np.zeros((x.shape[0], wd.shape[2]), np.float64)
     for t in range(x.shape[0]):
