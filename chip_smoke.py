@@ -113,9 +113,8 @@ def _check_spread(arrays, n_devices, what):
 def train_phase(make_net, image, batch, dtype="bfloat16", warmup=2,
                 steps=10, mesh=None):
     """Step ``make_net()`` through ``Trainer.compile_step`` on batches
-    already on the device, built as ``bench.py:bench_train_compiled``
-    builds it. ``mesh`` (e.g. ``"dp=4"``) shards the step; ``batch`` is
-    then the global batch."""
+    already on the device. ``mesh`` (e.g. ``"dp=4"``) shards the
+    step; ``batch`` is then the global batch."""
     import jax
     import numpy as np
     import mxnet_tpu as mx

@@ -3,8 +3,8 @@
 
 Reference example: example/image-classification/train_cifar10.py. Data
 is synthetic CIFAR-shaped (no egress); the flags show the TPU path:
---layout NHWC --dtype bfloat16 --stem-s2d run the same configuration
-bench.py measures.
+--layout NHWC --dtype bfloat16 --stem-s2d run the configuration the
+benchmark's training cell measures (BENCHMARK.json).
 
   python examples/train_cifar_gluon.py --steps 20 --layout NHWC
 """

@@ -184,7 +184,7 @@ class Rule:
 # --------------------------------------------------------------------------
 
 DEFAULT_CONFIG = {
-    "paths": ["mxnet_tpu", "tools", "bench.py"],
+    "paths": ["mxnet_tpu", "tools"],
     "exclude": ["__pycache__", "native/_build", ".git", "build",
                 "dist", ".eggs"],
     "baseline": "tools/mxlint_baseline.json",

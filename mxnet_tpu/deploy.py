@@ -144,8 +144,8 @@ def load_predictor(path_or_bytes, donate_input=False):
 #
 # Autoregressive serving cannot ship a single fixed forward the way the
 # predictor artifact does: the LLM engine needs the model in DECODE
-# shape — the prefill forward plus the per-token paged decode_step
-# (serving/llm/model.py) — with the paged-KV geometry riding along. The
+# shape — the packed paged step, decode_flat (serving/llm/model.py) —
+# with the paged-KV geometry riding along. The
 # artifact therefore serializes the decoder CONFIG + parameter pytree
 # (npz, CRC-free: the loader rebuilds the jitted programs, which the
 # server warmup then pre-compiles per bucket); the loaded pair plugs

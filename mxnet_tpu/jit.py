@@ -241,7 +241,6 @@ class CompiledTrainStep:
         self._obs = None
         self._lock = threading.Lock()
         self.last_reason = None      # fallback reason of the last call
-        self.last_cost_analysis = None
 
     # ------------------------------------------------------ eligibility --
     def _why_ineligible(self):
@@ -608,8 +607,9 @@ class CompiledTrainStep:
         Two passes: the first lowering discovers parameters the loss
         reads or writes outside the Trainer's set; those are promoted to
         program inputs and the step re-lowered, so e.g. frozen-backbone
-        BN stats never bake stale constants. AOT (lower/compile) instead
-        of plain jit so the executable's cost_analysis feeds bench MFU.
+        BN stats never bake stale constants. AOT (lower/compile): a
+        trace failure and a backend compile failure are told apart, and
+        each takes its own way back to the eager path.
         Returns (compiled, meta)."""
         import jax
         from .optimizer.fused import bind_entries
@@ -679,12 +679,6 @@ class CompiledTrainStep:
                 self._exec_failures >= self.MAX_EXEC_FAILURES else \
                 "exec_retry"
             raise _Fallback(reason) from None
-        try:
-            cost = compiled.cost_analysis()
-            self.last_cost_analysis = (cost[0] if isinstance(
-                cost, (list, tuple)) else cost)
-        except Exception:
-            pass
         return compiled, meta
 
     def _make_fn(self, entries, trainables, nts, in_fmt, flags, opaque,
@@ -891,11 +885,6 @@ class CompiledTrainStep:
     # ------------------------------------------------------- introspect --
     def cache_size(self):
         return len(self._cache)
-
-    def cost_analysis(self):
-        """XLA cost analysis of the most recently built step program
-        (None before the first compile) — feeds bench.py's MFU."""
-        return self.last_cost_analysis
 
 
 def _dtype_of(a):

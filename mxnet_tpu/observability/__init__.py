@@ -55,8 +55,7 @@ histogram buckets so a breach names its offending requests.
 Causality lives next door: :mod:`.tracing` (:func:`get_tracer`) records
 nested host spans across the same subsystems — one step / one serving
 request readable end to end, exported as Chrome-trace/Perfetto JSON and
-bridged onto the XLA device timeline while a profiler capture runs —
-and :mod:`.rollup` attributes captured device traces to op families.
+bridged onto the XLA device timeline while a profiler capture runs.
 
 See docs/OBSERVABILITY.md for the metric catalog and the tracing guide.
 """

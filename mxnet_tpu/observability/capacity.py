@@ -28,9 +28,9 @@ snapshot):
 false, the report still carries the measured rates but marks them
 ``"over capacity"`` — the run demanded more than the SLO affords, so
 the sustainable rate is an upper bound read from the good-rate, not a
-proof. ``tools/perf_capture.emit_capacity_snapshot`` commits the
-report as ``CAPACITY_rNN.json`` under the same stale/skip refusal
-contract as the BENCH trajectory.
+proof. ``tools/perf_capture.emit_capacity_snapshot`` writes the
+report as ``CAPACITY_rNN.json``; an unhealthy replay is written with
+``"skipped"`` set and no value.
 """
 from __future__ import annotations
 

@@ -33,8 +33,7 @@ Three integration rules keep the tracer honest:
   never claims one). The ring then holds the spans of the captured
   window with their host durations and parents, and the same spans
   stand in the xplane beside the device's events on the capture's
-  clock — the host/device join the rollup (:mod:`.rollup`)
-  quantifies.
+  clock (``benchmark/trace.py`` reads both for the per-layer metrics).
 
 Cross-thread propagation is explicit: contextvars do not follow work
 onto other threads, so producers capture ``tracer.current()`` at

@@ -1,55 +1,47 @@
-"""Ragged paged attention for TPU LLM decoding.
+"""Flat ragged attention over a paged cache: one kernel per cache kind.
 
-The decode half of "Ragged Paged Attention: A High-Performance and
-Flexible LLM Inference Kernel for TPU" (PAPERS.md): a batch of
-sequences with *different* lengths attends over a paged KV cache — a
-fixed pool of ``[num_blocks, block_size, heads, head_dim]`` blocks —
-indirected through per-sequence block tables, so no sequence ever owns
-contiguous KV storage and the batch shape never depends on the length
-mix. One query token per sequence (the continuous-batching decode
-shape: ``[max_seqs, 1]``), keys/values gathered block-by-block.
+A serving step hands attention ONE packed batch of query tokens,
+``q [T, ...]``, drawn from many sequences: prefill chunks, decode
+tokens and speculative verify positions side by side, no per-sequence
+padding (the ``[total_q_tokens]`` shape of "Ragged Paged Attention",
+PAPERS.md). Token ``t`` belongs to row ``seq_ids[t]`` of
+``block_tables [S, MB]`` and sits at absolute position
+``positions[t]``; the row names, in order, the pages of a fixed pool
+that hold its sequence, so no sequence owns contiguous storage and the
+batch's shape never depends on the length mix. The token's own key is
+in its page before the call; it attends causally over positions ``<=
+positions[t]`` of its own sequence. A model's pools are stacked over
+layers and handed over whole with ``layer=`` (a ``pool[li]`` given to
+a custom call is a copy).
 
-Two paths, gated exactly like :mod:`.flash_attention`:
+Two kernel families, each with the plain ``jnp`` reference it is
+pinned against (tests/test_ragged_attention.py, test_llm_mla_moe.py)
+and that runs in its place off the TPU; ``use_pallas=True`` off the
+TPU runs the kernel in interpret mode:
 
-- ``ragged_attention_reference`` — a gather-based plain-``jnp`` oracle:
-  gather every sequence's blocks, mask positions ``>= kv_len``, one
-  masked softmax. This is the path the decode engine runs off-TPU and
-  the oracle the Pallas kernel is pinned against
-  (tests/test_ragged_attention.py).
-- ``_ragged_decode_pallas`` — a Pallas kernel, grid
-  ``(num_seqs, blocks_per_seq)``: the block table and the ragged
-  lengths ride in as SCALAR-PREFETCH operands
-  (``pltpu.PrefetchScalarGridSpec``), so each grid step's KV page DMA
-  is index-mapped through ``block_tables[i, j]`` before the kernel body
-  runs — the gather never materializes in HBM. Online softmax
-  (running max / denominator in VMEM scratch, f32) across a sequence's
-  block steps; fully-masked blocks (``j*block_size >= kv_len``) skip
-  their compute. Off-TPU the same kernel runs in interpret mode.
+- ``ragged_flat_attention`` (``_sharded`` for ``shard_map`` bodies,
+  ``_reference``): a K and a V page per head, ``[L, N, bs, H, D]``,
+  float or int8/fp8 with per-slot per-head scale pools. Grid ``(T,
+  MB)``; the tables and the per-token scalars are scalar-prefetch
+  operands, so the page DMA of step ``(t, j)`` is index-mapped through
+  ``block_tables[seq_ids[t], j]`` and the gather never exists in HBM;
+  online softmax in float32 scratch across a token's pages; pages past
+  the token's position skip their compute. See ``_flat_kernel``.
+- ``mla_flat_attention`` (``_reference``): one pool of latents ``[L,
+  N, bs, W]`` that every head reads (MLA, absorbed form). A grid over
+  blocks of query tokens; in a block each RUN of consecutive tokens of
+  one sequence walks that sequence's pages once, by manual DMA from
+  the pool in HBM, double buffered, and only as far as the run's
+  largest position: a step costs what its contexts hold, not ``T x
+  MB``. See ``_mla_flat_kernel``.
 
-Lengths semantics: ``kv_lens[i]`` counts the VALID tokens of sequence
-``i`` (the current decode token's KV must already be written to its
-page). The masking guarantee runs one way: data beyond ``kv_lens[i]``
-— and anything in the null block — can never leak into row ``i``'s
-output (pinned by the garbage-invisibility test). Rows with
-``kv_lens[i] == 0`` are undefined; callers keep inactive rows clamped
-to 1 over the null block and DISCARD their outputs — the null block
-accumulates stale K/V from padded writes, so those rows are
-unspecified values, not zeros.
-
-Multi-token queries (ISSUE 12): ``q`` shaped ``[S, Q, H, D]`` with a
-scalar-prefetched ``q_lens[i]`` (``0 <= q_lens[i] <= Q``; padded tail
-tokens and inactive rows produce DISCARDED outputs). Query token ``t``
-of row ``i`` sits at position ``kv_lens[i] - q_lens[i] + t`` and
-attends CAUSALLY, masked in the kernel: chunked prefill, decode and
-speculative verify are one multi-query-token kernel (PAPERS.md).
-
-The flat LATENT kernel (``mla_flat_attention``, ISSUE 29) iterates
-otherwise: a grid over blocks of packed query tokens; in a block, each
-RUN of consecutive tokens of one sequence walks that sequence's pages
-once, all its tokens' heads the rows of one matrix, by manual DMA from
-the pool in HBM, several pages a loop step, double buffered, and only
-as far as the run's largest position. A step costs what its contexts
-hold, not ``T x MB``; see ``_mla_flat_kernel``.
+The masking guarantee runs one way, and preemption, the prefix cache
+and padded steps rely on it: data past a token's position, in blocks
+its table row does not name, or in the null block (``NULL_BLOCK``,
+where padded writes land) never reaches a valid row. A padded or stale
+token (one past the step's own, whatever ``seq_ids`` and ``positions``
+an earlier step left there) changes no valid row either; its own row
+is unspecified and the caller discards it.
 """
 from __future__ import annotations
 
@@ -62,53 +54,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .registry import register
 from .flash_attention import _NEG_INF, _on_tpu
-
-
-def ragged_attention_reference(q, k_pages, v_pages, block_tables,
-                               kv_lens, scale=None):
-    """Gather-based oracle. q: (S, H, D); pages: (N, bs, H, D);
-    block_tables: (S, MB) int32; kv_lens: (S,) int32."""
-    S, H, D = q.shape
-    bs = k_pages.shape[1]
-    MB = block_tables.shape[1]
-    s = scale if scale is not None else float(1.0 / (D ** 0.5))
-    k = k_pages[block_tables].reshape(S, MB * bs, H, D)
-    v = v_pages[block_tables].reshape(S, MB * bs, H, D)
-    logits = jnp.einsum("shd,skhd->shk", q.astype(jnp.float32),
-                        k.astype(jnp.float32)) * s
-    pos = jnp.arange(MB * bs, dtype=jnp.int32)
-    mask = pos[None, None, :] < kv_lens[:, None, None]
-    logits = jnp.where(mask, logits, _NEG_INF)
-    probs = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("shk,skhd->shd", probs, v.astype(jnp.float32))
-    return out.astype(q.dtype)
-
-
-def ragged_chunk_attention_reference(q, k_pages, v_pages, block_tables,
-                                     kv_lens, q_lens, scale=None):
-    """Gather-based oracle for the multi-token chunk shape.
-
-    q: (S, Q, H, D); pages: (N, bs, H, D); block_tables: (S, MB)
-    int32; kv_lens/q_lens: (S,) int32. Query token ``t`` of row ``i``
-    attends over positions ``<= kv_lens[i] - q_lens[i] + t``. Outputs
-    for ``t >= q_lens[i]`` are unspecified (callers discard them)."""
-    S, Q, H, D = q.shape
-    bs = k_pages.shape[1]
-    MB = block_tables.shape[1]
-    s = scale if scale is not None else float(1.0 / (D ** 0.5))
-    k = k_pages[block_tables].reshape(S, MB * bs, H, D)
-    v = v_pages[block_tables].reshape(S, MB * bs, H, D)
-    logits = jnp.einsum("sqhd,skhd->shqk", q.astype(jnp.float32),
-                        k.astype(jnp.float32)) * s
-    pos = jnp.arange(MB * bs, dtype=jnp.int32)            # kv position
-    qpos = (kv_lens[:, None] - q_lens[:, None]
-            + jnp.arange(Q, dtype=jnp.int32)[None, :])    # (S, Q)
-    mask = pos[None, None, None, :] <= qpos[:, None, :, None]
-    logits = jnp.where(mask, logits, _NEG_INF)
-    probs = jax.nn.softmax(logits, axis=-1)
-    # fully-masked (impossible for valid t; padded t attends somewhere)
-    out = jnp.einsum("shqk,skhd->sqhd", probs, v.astype(jnp.float32))
-    return out.astype(q.dtype)
 
 
 def ragged_flat_attention_reference(q, k_pages, v_pages, block_tables,
@@ -164,9 +109,9 @@ def ragged_flat_attention_reference(q, k_pages, v_pages, block_tables,
 
 def _flat_kernel(layer_ref, sid_ref, pos_ref, bt_ref, q_ref, k_ref,
                  v_ref, *refs, scale, block_size, num_blocks):
-    """Grid (T, MB): the decode kernel generalized to per-TOKEN
-    sequence indirection — the page DMA for grid step (t, j) is
-    index-mapped through ``(layer, block_tables[seq_ids[t], j])``.
+    """Grid (T, MB): one query token against one page of its own
+    sequence — the page DMA for grid step (t, j) is index-mapped
+    through ``(layer, block_tables[seq_ids[t], j])``.
 
     The K/V operands are the STACKED pools ``[L, N, bs, H, D]``,
     whole: the layer is a scalar-prefetch operand that only the index
@@ -291,7 +236,8 @@ def ragged_flat_attention(q, k_pages, v_pages, block_tables, seq_ids,
                           layer=None):
     """Flat-ragged paged attention entry point (packed
     ``[total_q_tokens]`` batch, per-token sequence/position
-    indirection). Gated exactly like :func:`ragged_paged_attention`.
+    indirection): the Pallas kernel on a TPU, the gather reference
+    elsewhere; ``use_pallas=True`` off the TPU is interpret mode.
 
     Pools: with ``layer`` (a Python int or a traced int32 scalar)
     ``k_pages``/``v_pages`` are the STACKED pools ``[L, N, bs, H, D]``
@@ -658,246 +604,3 @@ def _mla_flat_op(q, pages, block_tables, seq_ids, positions, *, scale,
     return mla_flat_attention(q, pages, block_tables, seq_ids, positions,
                               scale, v_dim, layer=layer,
                               use_pallas=use_pallas)
-
-
-def _chunk_kernel(bt_ref, len_ref, qlen_ref, q_ref, k_ref, v_ref,
-                  o_ref, acc_ref, m_ref, l_ref, *, scale, block_size,
-                  num_blocks, q_tokens):
-    """Grid (S, MB): one (chunk x KV-page) tile per step. Scratch
-    carries the online softmax across a row's page steps; the causal
-    mask (query t at absolute position kv_len - q_len + t) is applied
-    in-kernel so one program covers prefill chunks, decode and
-    speculative verify."""
-    i, j = pl.program_id(0), pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    kv_len = len_ref[i]
-    q_len = qlen_ref[i]
-    base = j * block_size
-
-    # a block whose first position is past the LAST query's causal
-    # horizon (kv_len - 1) is fully masked for every query token:
-    # skip its compute (the page DMA still streams)
-    @pl.when(base < kv_len)
-    def _body():
-        q = q_ref[...].astype(jnp.float32)            # (Q, H, D)
-        k = k_ref[...].astype(jnp.float32)            # (bs, H, D)
-        v = v_ref[...].astype(jnp.float32)
-        # batch over heads: (Q, H, D) x (bs, H, D) -> (H, Q, bs)
-        s = jax.lax.dot_general(
-            q, k, (((2,), (2,)), ((1,), (1,))),
-            preferred_element_type=jnp.float32) * scale
-        pos = base + jax.lax.broadcasted_iota(
-            jnp.int32, (1, 1, block_size), 2)         # kv position
-        qpos = (kv_len - q_len + jax.lax.broadcasted_iota(
-            jnp.int32, (1, q_tokens, 1), 1))          # query position
-        mask = pos <= qpos                            # (1, Q, bs)
-        s = jnp.where(mask, s, _NEG_INF)
-        m_prev = m_ref[...]                           # (H, Q, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
-        # early query tokens see NOTHING in later pages: their whole
-        # tile row is masked and m stays at _NEG_INF — zero p
-        # explicitly instead of trusting exp(-inf - -inf)
-        p = jnp.exp(s - m_new) * mask.astype(jnp.float32)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=2,
-                                                  keepdims=True)
-        # (H, Q, bs) x (bs, H, D) batched over H -> (H, Q, D)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p, v, (((2,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
-
-    @pl.when(j == num_blocks - 1)
-    def _finalize():
-        l_safe = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[...] = jnp.transpose(
-            acc_ref[...] / l_safe, (1, 0, 2)).astype(o_ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnums=(6, 7))
-def _ragged_chunk_pallas(q, k_pages, v_pages, block_tables, kv_lens,
-                         q_lens, scale, interpret):
-    S, Q, H, D = q.shape
-    bs = k_pages.shape[1]
-    MB = block_tables.shape[1]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(S, MB),
-        in_specs=[
-            pl.BlockSpec((None, Q, H, D),
-                         lambda i, j, bt, ln, ql: (i, 0, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((None, bs, H, D),
-                         lambda i, j, bt, ln, ql: (bt[i, j], 0, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((None, bs, H, D),
-                         lambda i, j, bt, ln, ql: (bt[i, j], 0, 0, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((None, Q, H, D),
-                               lambda i, j, bt, ln, ql: (i, 0, 0, 0),
-                               memory_space=pltpu.VMEM),
-        scratch_shapes=[
-            pltpu.VMEM((H, Q, D), jnp.float32),
-            pltpu.VMEM((H, Q, 1), jnp.float32),
-            pltpu.VMEM((H, Q, 1), jnp.float32),
-        ],
-    )
-    kernel = functools.partial(_chunk_kernel, scale=scale,
-                               block_size=bs, num_blocks=MB,
-                               q_tokens=Q)
-    return pl.pallas_call(
-        kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, Q, H, D), q.dtype),
-        interpret=interpret,
-    )(block_tables.astype(jnp.int32), kv_lens.astype(jnp.int32),
-      q_lens.astype(jnp.int32), q, k_pages, v_pages)
-
-
-def _decode_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                   acc_ref, m_ref, l_ref, *, scale, block_size,
-                   num_blocks):
-    i, j = pl.program_id(0), pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    kv_len = len_ref[i]
-    # a block whose first position is past the ragged length is fully
-    # masked: skip its compute (the page DMA still streams)
-    base = j * block_size
-
-    @pl.when(base < kv_len)
-    def _body():
-        q = q_ref[...].astype(jnp.float32)            # (H, D)
-        k = k_ref[...].astype(jnp.float32)            # (bs, H, D)
-        v = v_ref[...].astype(jnp.float32)
-        # batch over heads: (H, D) x (bs, H, D) -> (H, bs)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (2,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32) * scale
-        pos = base + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_size), 1)            # (1, bs)
-        s = jnp.where(pos < kv_len, s, _NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)                        # masked -> 0.0
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1,
-                                                  keepdims=True)
-        # (H, bs) x (bs, H, D) batched over H -> (H, D)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
-
-    @pl.when(j == num_blocks - 1)
-    def _finalize():
-        l_safe = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[...] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnums=(5, 6))
-def _ragged_decode_pallas(q, k_pages, v_pages, block_tables, kv_lens,
-                          scale, interpret):
-    S, H, D = q.shape
-    bs = k_pages.shape[1]
-    MB = block_tables.shape[1]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(S, MB),
-        in_specs=[
-            pl.BlockSpec((None, H, D), lambda i, j, bt, ln: (i, 0, 0),
-                         memory_space=pltpu.VMEM),
-            # one KV page per grid step, index-mapped through the
-            # scalar-prefetched block table: the DMA for block j of
-            # sequence i fetches page block_tables[i, j]
-            pl.BlockSpec((None, bs, H, D),
-                         lambda i, j, bt, ln: (bt[i, j], 0, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((None, bs, H, D),
-                         lambda i, j, bt, ln: (bt[i, j], 0, 0, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((None, H, D),
-                               lambda i, j, bt, ln: (i, 0, 0),
-                               memory_space=pltpu.VMEM),
-        scratch_shapes=[
-            pltpu.VMEM((H, D), jnp.float32),
-            pltpu.VMEM((H, 1), jnp.float32),
-            pltpu.VMEM((H, 1), jnp.float32),
-        ],
-    )
-    kernel = functools.partial(_decode_kernel, scale=scale,
-                               block_size=bs, num_blocks=MB)
-    return pl.pallas_call(
-        kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, H, D), q.dtype),
-        interpret=interpret,
-    )(block_tables.astype(jnp.int32), kv_lens.astype(jnp.int32),
-      q, k_pages, v_pages)
-
-
-def ragged_paged_attention(q, k_pages, v_pages, block_tables, kv_lens,
-                           q_lens=None, scale=None, use_pallas=None,
-                           interpret=None):
-    """Paged attention entry point — decode AND chunk shapes.
-
-    q: (S, H, D) — one query token per sequence (decode) — or
-    (S, Q, H, D) — a chunk of up to Q query tokens per sequence with
-    ``q_lens`` (S,) int32 valid counts (chunked prefill / speculative
-    verify). k_pages/v_pages: (N, bs, H, D); block_tables: (S, MB)
-    int32 page indices (pad unused entries with the null block 0);
-    kv_lens: (S,) int32 valid-token counts (>= 1; keep inactive rows
-    at 1 over the null block).
-
-    ``use_pallas`` defaults to the flash_attention gate: the Pallas
-    kernel on TPU, the gather reference elsewhere. Forcing
-    ``use_pallas=True`` off-TPU runs the kernel in interpret mode
-    (the parity-test configuration).
-    """
-    if use_pallas is None:
-        use_pallas = _on_tpu()
-    if scale is None:
-        scale = float(1.0 / (q.shape[-1] ** 0.5))
-    chunked = getattr(q, "ndim", len(getattr(q, "shape", ()))) == 4
-    if chunked and q_lens is None:
-        raise ValueError("chunk-shaped q (S, Q, H, D) requires q_lens")
-    if not use_pallas:
-        if chunked:
-            return ragged_chunk_attention_reference(
-                q, k_pages, v_pages, block_tables, kv_lens,
-                jnp.asarray(q_lens), scale)
-        return ragged_attention_reference(q, k_pages, v_pages,
-                                          block_tables, kv_lens, scale)
-    if interpret is None:
-        interpret = not _on_tpu()
-    if chunked:
-        return _ragged_chunk_pallas(q, k_pages, v_pages,
-                                    jnp.asarray(block_tables),
-                                    jnp.asarray(kv_lens),
-                                    jnp.asarray(q_lens),
-                                    float(scale), bool(interpret))
-    return _ragged_decode_pallas(q, k_pages, v_pages,
-                                 jnp.asarray(block_tables),
-                                 jnp.asarray(kv_lens),
-                                 float(scale), bool(interpret))
-
-
-@register("ragged_paged_attention", differentiable=False)
-def _ragged_op(q, k_pages, v_pages, block_tables, kv_lens, *,
-               q_lens=None, scale=None, use_pallas=None):
-    """Registered paged-attention op (decode + chunk shapes): Pallas
-    kernel on TPU, gather reference elsewhere."""
-    return ragged_paged_attention(q, k_pages, v_pages, block_tables,
-                                  kv_lens, q_lens=q_lens, scale=scale,
-                                  use_pallas=use_pallas)

@@ -58,7 +58,7 @@ def data_parallel_spec(ndim: int) -> PartitionSpec:
 
 def parse_mesh(spec, devices=None) -> Mesh:
     """Build a Mesh from a compact string spec — the CLI/env spelling of
-    :func:`make_mesh` (``bench.py --mesh``, ``MXNET_TPU_MESH``):
+    :func:`make_mesh` (``MXNET_TPU_MESH``, ``chip_smoke.py``):
 
     - ``"8"``            → 1-axis dp mesh over 8 devices
     - ``"dp=4,tp=2"``    → named axis extents (unnamed axes default 1)
@@ -85,7 +85,7 @@ def parse_mesh(spec, devices=None) -> Mesh:
 
 def llm_mesh(spec, devices=None) -> Mesh:
     """Build the serving mesh from a compact string spec — the
-    CLI/env spelling for the LLM engine (``llm_bench.py --mesh``,
+    CLI/env spelling for the LLM engine (``LLMServer(mesh=)``,
     ``MXNET_TPU_LLM_MESH``). Same axis grammar as :func:`parse_mesh`
     but with SERVING defaults: only ``dp``/``tp`` axes exist, a bare
     integer means tensor-parallel width, and ``dp`` defaults to 1

@@ -402,11 +402,37 @@ def _spmd_wrap(fn, mesh, cache, param_specs, extra, aux=False):
 class LLMEngine:
     """Token-level scheduler + ONE fixed-shape jitted chunked step.
 
-    ``model`` provides ``num_layers/num_heads/head_dim/vocab_size/
-    max_context`` plus the pure function ``decode_chunk(params,
-    tokens, positions, q_lens, k_pages, v_pages, block_tables,
-    kv_lens)`` (see :class:`~.model.TinyDecoder`, the reference
-    implementation). ``params`` is its pytree.
+    The model protocol: everything the engine calls and reads of
+    ``model`` (:class:`~.model.TinyDecoder` is the reference
+    implementation; ``params`` is its pytree, opaque to the engine).
+
+    - ``decode_flat(params, tokens, positions, seq_ids, valid,
+      k_pages, v_pages, block_tables)``, a pure, jittable function:
+      the packed step. ``tokens/positions/seq_ids/valid`` int32
+      ``[T]`` (``valid[t] == 0`` is bucket padding, written to the
+      null block, its output discarded; ``seq_ids[t]`` is a row of
+      ``block_tables`` int32 ``[S, MB]``); the pools stacked over
+      layers, ``[L, N, bs, ...]``, ``v_pages`` ``None`` for a latent
+      cache. Returns ``(logits [T, V], *pools[, aux])``: the updated
+      pools in the order they came (donated), then at most one more
+      array, fetched with the step's tokens (expert load, see
+      :func:`_make_step_fn`). Keywords are passed only with the
+      feature that needs them: ``k_scales=, v_scales=`` (quantized
+      pages; their updated scale pools come back after the pages),
+      ``adapter=`` (an adapter bank), ``axis_name=`` (a tp mesh),
+      ``w_scales=`` (quantized weights).
+    - ``cache_layout``, what a token leaves in one layer of the cache:
+      ``{"kind": "kv", "num_heads": H, "head_dim": D}`` or ``{"kind":
+      "latent", "latent_dim": W}``; absent, K and V pools of
+      ``[num_heads, head_dim]``.
+    - ``vocab_size``, ``max_context``, ``num_layers``, ``num_heads``,
+      ``head_dim``: ints.
+    - Optional: ``param_specs(axis=)`` and ``weight_scale_specs(
+      axis=)`` (PartitionSpec pytrees, read under a tp mesh);
+      ``refused_features`` (names of engine arguments the model's
+      block does not take, refused at construction).
+
+    Nothing else: no dense ``forward``, no per-sequence decode.
 
     Config resolution: constructor arg > ``MXNET_TPU_LLM_*`` env var >
     default. ``max_context`` must be a multiple of ``block_size`` (a

@@ -1,25 +1,27 @@
 """A small decoder-only transformer in paged-decode form.
 
-Two entry points per model, mirroring the prefill/decode split every
-LLM serving stack runs:
+What the serving engine calls and reads of a model is stated once, in
+:class:`~.engine.LLMEngine`'s docstring (the model protocol);
+:class:`TinyDecoder` is its reference implementation, for two blocks
+(``DecoderConfig.model_type``). Its entry points:
 
-- :meth:`TinyDecoder.forward` — dense causal forward over a whole
-  token prefix ``[B, T]``, returning logits AND the per-layer K/V it
-  computed. The engine runs this once per admitted sequence (prefill)
-  and writes the K/V into the paged cache; it is also the eager oracle
-  (:func:`greedy_decode_reference`).
-- :meth:`TinyDecoder.decode_step` — one token per sequence ``[S]``
-  against the paged KV cache: each layer writes the new token's K/V
-  into its page slot (block table + position), then attends over the
-  block-table-indirected history via
-  :func:`mxnet_tpu.ops.ragged_attention.ragged_paged_attention`.
+- :meth:`TinyDecoder.decode_flat`: the step. A packed ``[T]`` batch of
+  query tokens from many sequences against the paged cache; each layer
+  writes its tokens' K/V (or latents) into their page slots, then
+  attends over the block-table-indirected history through
+  :func:`~mxnet_tpu.ops.ragged_attention.ragged_flat_attention` or
+  :func:`~mxnet_tpu.ops.ragged_attention.mla_flat_attention`. A pure
+  function of ``(params, inputs)``; the engine jits it with donated
+  pools, and prefill, decode and speculative verify are all this one
+  call.
+- :meth:`TinyDecoder.forward`: the eager oracle. A dense causal forward
+  over a whole prefix ``[B, T]``, returning logits and the per-layer
+  K/V (or latents) it computed. The engine never calls it; the serving
+  tests compare the step with it, and :func:`greedy_decode_reference`
+  is built on it and on :func:`_incremental_step`.
 
-Both are pure functions of ``(params, inputs)`` — the engine jits them
-with donated page buffers. The architecture is deliberately small
-(learned absolute positions, pre-LN blocks, GELU MLP) — the subsystem
-under test is the serving engine, not the model zoo — but the
-interface (``num_layers/num_heads/head_dim/vocab_size`` + the two
-methods above) is what any decoder backend must provide.
+The architecture is deliberately small (the subsystem under test is
+the serving engine, not a model zoo).
 """
 from __future__ import annotations
 
@@ -27,8 +29,7 @@ import functools
 
 import numpy as np
 
-from ...ops.ragged_attention import (ragged_paged_attention,
-                                     ragged_flat_attention,
+from ...ops.ragged_attention import (ragged_flat_attention,
                                      ragged_flat_attention_sharded,
                                      mla_flat_attention)
 from ...ops.moe import route_noaux_tc, moe_experts, moe_load
@@ -489,64 +490,6 @@ class TinyDecoder:
         return logits, jnp.stack(ks), jnp.stack(vs)
 
     # ------------------------------------------------------- decode --
-    def decode_chunk(self, params, tokens, positions, q_lens, k_pages,
-                     v_pages, block_tables, kv_lens):
-        """Up to Q tokens per sequence against the paged cache — the
-        ONE multi-query-token step chunked prefill, plain decode
-        (Q-slice of 1) and speculative verify all run through.
-
-        tokens/positions: int32 [S, Q]; q_lens: int32 [S] valid token
-        counts (0 = inactive row); pages: [L, N, bs, H, Dh];
-        block_tables: int32 [S, MB]; kv_lens: int32 [S] — the valid
-        length INCLUDING this chunk's tokens (so token ``t`` of row
-        ``i`` sits at absolute position ``kv_lens[i] - q_lens[i] + t``
-        and ``positions[i, t]`` must equal that for ``t < q_lens[i]``;
-        padded tails must carry an in-range position — the engine
-        clamps them to 0 and routes their K/V writes at the null
-        block).
-
-        Each layer first scatters the chunk's K/V at
-        ``(block_tables[i, pos // bs], pos % bs)`` — padded tokens and
-        inactive rows target the null block — then attends CAUSALLY
-        over the paged history through the chunk kernel. Returns
-        (logits [S, Q, V], k_pages, v_pages).
-        """
-        import jax
-        import jax.numpy as jnp
-        c = self.config
-        if self._deepseek:
-            self._refuse("decode_chunk/decode_step: no engine path "
-                         "runs them; the flat step is decode_flat")
-        S, Q = tokens.shape
-        bs = k_pages.shape[2]
-        valid = (jnp.arange(Q, dtype=jnp.int32)[None, :]
-                 < q_lens[:, None])                    # [S, Q]
-        bidx = jnp.where(valid,
-                         jnp.take_along_axis(block_tables,
-                                             positions // bs, axis=1),
-                         0)                            # null block
-        slot = jnp.where(valid, positions % bs, 0)
-        h = params["embed"][tokens] + params["pos"][positions]
-        for li, lp in enumerate(params["layers"]):
-            x = _layer_norm(h, lp["ln1_g"], lp["ln1_b"])
-            q = (x @ lp["wq"]).reshape(S, Q, c.num_heads, c.head_dim)
-            k = (x @ lp["wk"]).reshape(S, Q, c.num_heads, c.head_dim)
-            v = (x @ lp["wv"]).reshape(S, Q, c.num_heads, c.head_dim)
-            k_pages = k_pages.at[li, bidx, slot].set(
-                k.astype(k_pages.dtype))
-            v_pages = v_pages.at[li, bidx, slot].set(
-                v.astype(v_pages.dtype))
-            att = ragged_paged_attention(q, k_pages[li], v_pages[li],
-                                         block_tables, kv_lens,
-                                         q_lens=q_lens)
-            h = h + att.reshape(S, Q, c.d_model) @ lp["wo"]
-            x2 = _layer_norm(h, lp["ln2_g"], lp["ln2_b"])
-            h = h + jax.nn.gelu(x2 @ lp["w1"] + lp["b1"]) @ lp["w2"] \
-                + lp["b2"]
-        logits = _layer_norm(h, params["lnf_g"],
-                             params["lnf_b"]) @ params["head"]
-        return logits, k_pages, v_pages
-
     def decode_flat(self, params, tokens, positions, seq_ids, valid,
                     k_pages, v_pages, block_tables, k_scales=None,
                     v_scales=None, adapter=None, axis_name=None,
@@ -908,20 +851,6 @@ class TinyDecoder:
         logits = _wmm(_rms_norm(h, params["norm_f"], eps), params["head"])
         return (logits, pages, load) if self.expert_slots \
             else (logits, pages)
-
-    def decode_step(self, params, tokens, positions, k_pages, v_pages,
-                    block_tables, kv_lens):
-        """One decode token per sequence: the Q=1 slice of
-        :meth:`decode_chunk` (kept for the single-token callers;
-        tokens/positions int32 [S]). Returns (logits [S, V],
-        k_pages, v_pages)."""
-        import jax.numpy as jnp
-        S = tokens.shape[0]
-        logits, k_pages, v_pages = self.decode_chunk(
-            params, tokens[:, None], positions[:, None],
-            jnp.ones(S, jnp.int32), k_pages, v_pages, block_tables,
-            kv_lens)
-        return logits[:, 0], k_pages, v_pages
 
 
 def _incremental_step(model, params, token, pos, k_cache, v_cache,

@@ -131,27 +131,6 @@ def test_serve_bench_smoke():
     assert "SMOKE PASS" in p.stdout
 
 
-@pytest.mark.slow   # ~34s on 1 CPU (tier-1 budget); the llm serving
-# bit-exactness/zero-recompile gates in tests/test_llm_serving.py and
-# test_metrics_dump_smoke keep fast coverage of the same invariants
-def test_llm_bench_smoke():
-    """tools/llm_bench.py --smoke: the continuous-batching decode load
-    generator must complete losslessly with zero recompiles during
-    load AND emit a BENCH json carrying tokens/sec, TTFT p50/p99 and
-    KV occupancy (it exits 1 otherwise)."""
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    tools = os.path.join(os.path.dirname(EXAMPLES), "tools")
-    p = subprocess.run(
-        [sys.executable, os.path.join(tools, "llm_bench.py"),
-         "--smoke"],
-        capture_output=True, text=True, timeout=600, env=env)
-    assert p.returncode == 0, \
-        f"llm_bench --smoke failed:\n{p.stdout[-2000:]}\n" \
-        f"{p.stderr[-2000:]}"
-    assert "SMOKE PASS" in p.stdout
-
-
 @pytest.mark.slow   # ~32s on 1 CPU (tier-1 budget); the exposition
 # path keeps fast coverage via test_metrics_dump_smoke and the fleet
 # replay variant stays pinned in test_fleet's slow tier

@@ -481,10 +481,6 @@ def test_entry_points_no_engine_path_runs_raise(model):
     params = model.init_params(0)
     z = jnp.zeros((1, 1), jnp.int32)
     for call in (
-            lambda: model.decode_chunk(params, z, z, z[0], None, None, z,
-                                       z[0]),
-            lambda: model.decode_step(params, z[0], z[0], None, None, z,
-                                      z[0]),
             lambda: greedy_decode_reference(model, params, [1, 2], 2),
             lambda: model.param_specs()):
         with pytest.raises(UnsupportedModelFeatureError):

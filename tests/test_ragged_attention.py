@@ -1,13 +1,17 @@
-"""Ragged/paged attention parity + KV block allocator accounting.
+"""Flat ragged attention parity + KV block allocator accounting.
 
-The kernel contract (ISSUE 8): attention over block-table-indirected
-paged KV for a batch of different-length sequences must match the
-dense oracle ``ops/flash_attention.py:attention_reference`` on every
-ragged length mix — including block-boundary edges (len = block_size
-- 1, block_size, block_size + 1) and fragmented (non-contiguous,
-shuffled) block tables — on BOTH paths (gather-based jnp reference and
-the Pallas kernel in interpret mode). The block allocator must never
-leak or double-free across randomized admit/evict schedules.
+The kernel contract: ``ragged_flat_attention`` (the kernel every GPT-2
+serving step runs) over block-table-indirected paged KV, for a packed
+batch of tokens from different-length sequences, must match the dense
+oracle ``ops/flash_attention.py:attention_reference`` run per token
+over exactly its causal prefix — on every ragged length mix, including
+block-boundary edges (len = block_size - 1, block_size, block_size +
+1), fragmented (non-contiguous, shuffled) block tables, int8 pages, and
+whatever garbage lies past a token's position, in blocks no table
+names, or in the packed batch's tail — on BOTH paths (the gather-based
+jnp reference and the Pallas kernel in interpret mode). The block
+allocator must never leak or double-free across randomized admit/evict
+schedules.
 """
 import os
 import sys
@@ -21,7 +25,7 @@ import jax.numpy as jnp  # noqa: E402
 
 from mxnet_tpu.ops.flash_attention import attention_reference  # noqa: E402
 from mxnet_tpu.ops.ragged_attention import (  # noqa: E402
-    ragged_attention_reference, ragged_paged_attention)
+    ragged_flat_attention)
 from mxnet_tpu.serving.llm.kv_cache import (  # noqa: E402
     BlockAllocator, PagedKVCache, NoFreeBlocksError,
     BlockAccountingError, NULL_BLOCK)
@@ -31,13 +35,24 @@ from mxnet_tpu.serving.bucketing import (  # noqa: E402
 BS = 8          # block size
 H, D = 2, 16    # heads, head dim
 
+both_paths = pytest.mark.parametrize("path", ["reference", "pallas"])
 
-def _paged_case(lens, num_blocks=64, seed=0, fragment=True):
-    """Build a paged cache holding one ragged batch: returns
-    (q, k_pages, v_pages, block_tables, kv_lens, per-seq dense k/v)."""
+
+def _flat(path, q, kp, vp, bt, sids, poss, **kw):
+    return np.asarray(ragged_flat_attention(
+        q, kp, vp, bt, sids, poss, use_pallas=(path == "pallas"),
+        interpret=True, **kw))
+
+
+def _chunk_case(kv_lens, q_lens, num_blocks=64, seed=0, fragment=True):
+    """A paged cache + a [S, Q, H, D] query chunk: queries are the
+    LAST q_lens[i] positions of each sequence (the chunked-prefill /
+    verify layout). Returns (q, k_pages, v_pages, block_tables,
+    kv_lens, q_lens, per-seq dense k/v)."""
     rng = np.random.RandomState(seed)
-    S = len(lens)
-    MB = max(-(-int(t) // BS) for t in lens)
+    S = len(kv_lens)
+    Q = max(q_lens)
+    MB = max(-(-int(t) // BS) for t in kv_lens)
     k_pages = np.zeros((num_blocks, BS, H, D), np.float32)
     v_pages = np.zeros((num_blocks, BS, H, D), np.float32)
     tables = np.full((S, MB), NULL_BLOCK, np.int32)
@@ -47,120 +62,6 @@ def _paged_case(lens, num_blocks=64, seed=0, fragment=True):
     pool = list(range(1, num_blocks))
     if fragment:
         np.random.RandomState(seed + 1000).shuffle(pool)
-    it = iter(pool)
-    dense = []
-    q = rng.randn(S, H, D).astype(np.float32)
-    for i, t in enumerate(lens):
-        t = int(t)
-        k_seq = rng.randn(t, H, D).astype(np.float32)
-        v_seq = rng.randn(t, H, D).astype(np.float32)
-        dense.append((k_seq, v_seq))
-        nb = -(-t // BS)
-        for j in range(nb):
-            b = next(it)
-            tables[i, j] = b
-            chunk = k_seq[j * BS:(j + 1) * BS]
-            k_pages[b, :len(chunk)] = chunk
-            chunk = v_seq[j * BS:(j + 1) * BS]
-            v_pages[b, :len(chunk)] = chunk
-    return (q, k_pages, v_pages, tables,
-            np.asarray(lens, np.int32), dense)
-
-
-def _oracle(q, dense):
-    """Per-sequence dense attention via the flash oracle."""
-    outs = []
-    for i, (k_seq, v_seq) in enumerate(dense):
-        o = attention_reference(
-            jnp.asarray(q[i][None, :, None, :]),          # (1, H, 1, D)
-            jnp.asarray(k_seq.transpose(1, 0, 2)[None]),  # (1, H, t, D)
-            jnp.asarray(v_seq.transpose(1, 0, 2)[None]))
-        outs.append(np.asarray(o)[0, :, 0, :])
-    return np.stack(outs)
-
-
-# block-boundary edges around BS plus interior/multi-block lengths
-EDGE_MIXES = [
-    [BS - 1, BS, BS + 1],
-    [1, BS - 1, 2 * BS, 2 * BS + 1, 3 * BS - 1],
-    [5, 11, 17, 24],
-]
-
-
-@pytest.mark.parametrize("lens", EDGE_MIXES, ids=["edges", "multi", "mix"])
-@pytest.mark.parametrize("path", ["reference", "pallas"])
-def test_parity_vs_dense_oracle(lens, path):
-    q, kp, vp, bt, kl, dense = _paged_case(lens)
-    want = _oracle(q, dense)
-    got = ragged_paged_attention(q, kp, vp, bt, kl,
-                                 use_pallas=(path == "pallas"),
-                                 interpret=True)
-    np.testing.assert_allclose(np.asarray(got), want,
-                               rtol=2e-5, atol=2e-6)
-
-
-def test_fragmented_table_equals_contiguous():
-    """A shuffled block table must read identically to a contiguous
-    one — the kernel sees only the table, never block adjacency."""
-    lens = [BS + 3, 2 * BS, 3]
-    q, kp, vp, bt, kl, dense = _paged_case(lens, fragment=True, seed=3)
-    q2, kp2, vp2, bt2, kl2, dense2 = _paged_case(lens, fragment=False,
-                                                 seed=3)
-    a = np.asarray(ragged_paged_attention(q, kp, vp, bt, kl))
-    b = np.asarray(ragged_paged_attention(q2, kp2, vp2, bt2, kl2))
-    np.testing.assert_array_equal(a, b)
-
-
-def test_pallas_matches_reference_path_bitwise_inputs():
-    """Both paths over the SAME buffers: allclose at f32 ulp level."""
-    lens = [2, BS, 19]
-    q, kp, vp, bt, kl, _ = _paged_case(lens, seed=7)
-    ref = np.asarray(ragged_attention_reference(
-        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
-        jnp.asarray(bt), jnp.asarray(kl)))
-    pal = np.asarray(ragged_paged_attention(
-        q, kp, vp, bt, kl, use_pallas=True, interpret=True))
-    np.testing.assert_allclose(ref, pal, rtol=1e-5, atol=1e-6)
-
-
-def test_garbage_in_unreferenced_blocks_is_invisible():
-    """Stale KV beyond kv_len and in never-referenced blocks must not
-    leak into any output — the masking contract preemption relies on."""
-    lens = [5, 9]
-    q, kp, vp, bt, kl, dense = _paged_case(lens, seed=11)
-    base = np.asarray(ragged_paged_attention(q, kp, vp, bt, kl))
-    kp2, vp2 = kp.copy(), vp.copy()
-    # poison the null block, every free block, and the tail slots of
-    # each sequence's last block
-    used = set(bt.ravel().tolist()) - {NULL_BLOCK}
-    for b in range(kp.shape[0]):
-        if b not in used:
-            kp2[b] = 1e6
-            vp2[b] = -1e6
-    for i, t in enumerate(lens):
-        last = bt[i, (t - 1) // BS]
-        kp2[last, t % BS or BS:] = 1e6
-        vp2[last, t % BS or BS:] = -1e6
-    got = np.asarray(ragged_paged_attention(q, kp2, vp2, bt, kl))
-    np.testing.assert_array_equal(base, got)
-
-
-# ------------------------------------------ multi-token chunk shape --
-
-
-def _chunk_case(kv_lens, q_lens, num_blocks=64, seed=0):
-    """A paged cache + a [S, Q, H, D] query chunk: queries are the
-    LAST q_lens[i] positions of each sequence (the chunked-prefill /
-    verify layout)."""
-    rng = np.random.RandomState(seed)
-    S = len(kv_lens)
-    Q = max(q_lens)
-    MB = max(-(-int(t) // BS) for t in kv_lens)
-    k_pages = np.zeros((num_blocks, BS, H, D), np.float32)
-    v_pages = np.zeros((num_blocks, BS, H, D), np.float32)
-    tables = np.full((S, MB), NULL_BLOCK, np.int32)
-    pool = list(range(1, num_blocks))
-    np.random.RandomState(seed + 1000).shuffle(pool)
     it = iter(pool)
     dense = []
     q = rng.randn(S, Q, H, D).astype(np.float32)
@@ -181,75 +82,22 @@ def _chunk_case(kv_lens, q_lens, num_blocks=64, seed=0):
             np.asarray(q_lens, np.int32), dense)
 
 
-@pytest.mark.parametrize("path", ["reference", "pallas"])
-def test_chunk_parity_vs_dense_causal_oracle(path):
-    """Multi-token queries: token t of row i (absolute position
-    kv_len - q_len + t) must equal single-query dense attention over
-    exactly its causal prefix — across block-boundary kv lengths,
-    chunk sizes from 1 (decode) to full-prefill, fragmented tables."""
-    kv_lens = [13, 5, 2 * BS, BS + 1]
-    q_lens = [5, 2, 1, BS + 1]       # verify-, decode- and prefill-like
-    q, kp, vp, bt, kl, ql, dense = _chunk_case(kv_lens, q_lens)
-    got = np.asarray(ragged_paged_attention(
-        q, kp, vp, bt, kl, q_lens=ql,
-        use_pallas=(path == "pallas"), interpret=True))
-    for i, (k_seq, v_seq) in enumerate(dense):
-        t, qn = int(kl[i]), int(ql[i])
-        for tt in range(qn):
-            pos = t - qn + tt
-            o = attention_reference(
-                jnp.asarray(q[i, tt][None, :, None, :]),
-                jnp.asarray(k_seq[:pos + 1].transpose(1, 0, 2)[None]),
-                jnp.asarray(v_seq[:pos + 1].transpose(1, 0, 2)[None]))
-            want = np.asarray(o)[0, :, 0, :]
-            np.testing.assert_allclose(got[i, tt], want,
-                                       rtol=2e-5, atol=2e-6)
+def _paged_case(lens, num_blocks=64, seed=0, fragment=True):
+    """Decode rows, one query token a sequence: (q [S, H, D], k_pages,
+    v_pages, block_tables, kv_lens, per-seq dense k/v)."""
+    q, kp, vp, bt, kl, _, dense = _chunk_case(
+        lens, [1] * len(lens), num_blocks, seed, fragment)
+    return q[:, 0], kp, vp, bt, kl, dense
 
 
-def test_chunk_q_len_one_matches_decode_kernel():
-    """The decode shape is the Q=1 slice of the chunk shape: both
-    kernels over the same buffers agree at f32 tolerance."""
-    lens = [5, 11, 24]
-    q, kp, vp, bt, kl, _ = _paged_case(lens, seed=13)
-    dec = np.asarray(ragged_paged_attention(
-        q, kp, vp, bt, kl, use_pallas=True, interpret=True))
-    chk = np.asarray(ragged_paged_attention(
-        q[:, None], kp, vp, bt, kl,
-        q_lens=np.ones(len(lens), np.int32),
-        use_pallas=True, interpret=True))[:, 0]
-    np.testing.assert_allclose(dec, chk, rtol=1e-5, atol=1e-6)
-
-
-def test_chunk_padded_tail_and_garbage_invisible():
-    """Padded query tokens (t >= q_len) and KV garbage beyond kv_len
-    must not perturb any VALID output row."""
-    kv_lens = [9, 17]
-    q_lens = [3, 5]
-    q, kp, vp, bt, kl, ql, _ = _chunk_case(kv_lens, q_lens, seed=5)
-    base = np.asarray(ragged_paged_attention(q, kp, vp, bt, kl,
-                                             q_lens=ql))
-    # poison everything the mask must hide: free blocks, null block,
-    # tail slots past kv_len, and the padded q rows themselves
-    used = set(bt.ravel().tolist()) - {NULL_BLOCK}
-    kp2, vp2, q2 = kp.copy(), vp.copy(), q.copy()
-    for b in range(kp.shape[0]):
-        if b not in used:
-            kp2[b] = 1e6
-            vp2[b] = -1e6
-    for i, t in enumerate(kv_lens):
-        last = bt[i, (t - 1) // BS]
-        kp2[last, t % BS or BS:] = 1e6
-        vp2[last, t % BS or BS:] = -1e6
-        q2[i, q_lens[i]:] = 1e6
-    got = np.asarray(ragged_paged_attention(q2, kp2, vp2, bt, kl,
-                                            q_lens=ql))
-    for i, qn in enumerate(q_lens):
-        np.testing.assert_array_equal(base[i, :qn], got[i, :qn])
-
-
-def _pack_flat(q, kv_lens, q_lens):
+def _pack_flat(q, kv_lens, q_lens=None):
     """Pack the valid tokens of a chunk-shaped ``q [S, Q, H, D]`` flat:
-    (flat_q [T, H, D], seq_ids [T], positions [T]), rows in order."""
+    (flat_q [T, H, D], seq_ids [T], positions [T]), rows in order, a
+    row's tokens its sequence's LAST ``q_lens[i]`` positions. Without
+    ``q_lens`` ``q`` is ``[S, H, D]``, decode rows: one token a
+    sequence, at ``kv_len - 1``."""
+    if q_lens is None:
+        q, q_lens = q[:, None], [1] * len(kv_lens)
     flat_q, sids, poss = [], [], []
     for i, qn in enumerate(q_lens):
         for t in range(qn):
@@ -260,26 +108,183 @@ def _pack_flat(q, kv_lens, q_lens):
             np.asarray(poss, np.int32))
 
 
-@pytest.mark.parametrize("path", ["reference", "pallas"])
-def test_flat_parity_vs_chunk_shape(path):
-    """The FLAT packed layout (the engine's hot path) must agree with
-    the per-row chunk shape over the same buffers: packing the valid
-    tokens of every row into one [T] batch with per-token
-    seq_ids/positions changes the layout, never the math."""
-    from mxnet_tpu.ops.ragged_attention import ragged_flat_attention
-    kv_lens = [13, 5, 2 * BS]
-    q_lens = [5, 2, 1]
-    q, kp, vp, bt, kl, ql, _ = _chunk_case(kv_lens, q_lens, seed=21)
-    chunk = np.asarray(ragged_paged_attention(
-        q, kp, vp, bt, kl, q_lens=ql,
-        use_pallas=(path == "pallas"), interpret=True))
-    flat_q, sids, poss = _pack_flat(q, kv_lens, q_lens)
-    want = np.stack([chunk[i, t] for i, qn in enumerate(q_lens)
-                     for t in range(qn)])
-    got = np.asarray(ragged_flat_attention(
-        flat_q, kp, vp, bt, sids, poss,
-        use_pallas=(path == "pallas"), interpret=True))
-    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+def _oracle(flat_q, sids, poss, dense):
+    """Dense attention via the flash oracle, a packed token at a time
+    over exactly its causal prefix of its own sequence."""
+    outs = []
+    for q, i, pos in zip(flat_q, sids, poss):
+        k_seq, v_seq = (x[:pos + 1] for x in dense[i])
+        o = attention_reference(
+            jnp.asarray(q[None, :, None, :]),             # (1, H, 1, D)
+            jnp.asarray(k_seq.transpose(1, 0, 2)[None]),  # (1, H, t, D)
+            jnp.asarray(v_seq.transpose(1, 0, 2)[None]))
+        outs.append(np.asarray(o)[0, :, 0, :])
+    return np.stack(outs)
+
+
+def _int8(x):
+    """Symmetric int8 per slot and head, as the cache holds quantized
+    pages: (int8 values, float32 scales ``x.shape[:-1]``)."""
+    scale = np.abs(x).max(axis=-1) / 127.0
+    safe = np.where(scale > 0, scale, 1.0)[..., None]
+    return (np.clip(np.round(x / safe), -127, 127).astype(np.int8),
+            scale.astype(np.float32))
+
+
+def _poison_what_no_token_may_read(kp, vp, bt, kv_lens):
+    """Copies of the pools with garbage in the null block, in every
+    block no table names, and in the slots past each sequence's
+    length in its last block."""
+    kp, vp = kp.copy(), vp.copy()
+    used = set(bt.ravel().tolist()) - {NULL_BLOCK}
+    for b in range(kp.shape[0]):
+        if b not in used:
+            kp[b] = 1e6
+            vp[b] = -1e6
+    for i, t in enumerate(kv_lens):
+        last = bt[i, (t - 1) // BS]
+        kp[last, t % BS or BS:] = 1e6
+        vp[last, t % BS or BS:] = -1e6
+    return kp, vp
+
+
+# block-boundary edges around BS plus interior/multi-block lengths
+EDGE_MIXES = [
+    [BS - 1, BS, BS + 1],
+    [1, BS - 1, 2 * BS, 2 * BS + 1, 3 * BS - 1],
+    [5, 11, 17, 24],
+]
+
+
+@pytest.mark.parametrize("lens,pages", [
+    (EDGE_MIXES[0], "float32"), (EDGE_MIXES[1], "float32"),
+    (EDGE_MIXES[2], "float32"), (EDGE_MIXES[1], "int8")],
+    ids=["edges", "multi", "mix", "multi-int8"])
+@both_paths
+def test_parity_vs_dense_oracle(lens, pages, path):
+    """Decode rows, one token a sequence at its last position. Int8
+    pages are dequantized slot by slot and head by head where they are
+    read: the oracle runs on the dequantized sequences."""
+    q, kp, vp, bt, kl, dense = _paged_case(lens)
+    kw = {}
+    if pages == "int8":
+        (kp, ks), (vp, vs) = _int8(kp), _int8(vp)
+        kw = dict(k_scales=ks, v_scales=vs)
+        dense = [tuple(xq * xs[..., None] for xq, xs in map(_int8, kv))
+                 for kv in dense]
+    flat_q, sids, poss = _pack_flat(q, kl)
+    got = _flat(path, flat_q, kp, vp, bt, sids, poss, **kw)
+    np.testing.assert_allclose(got, _oracle(flat_q, sids, poss, dense),
+                               rtol=2e-5, atol=2e-6)
+
+
+@both_paths
+def test_fragmented_table_equals_contiguous(path):
+    """A shuffled block table must read identically to a contiguous
+    one — the kernel sees only the table, never block adjacency."""
+    lens = [BS + 3, 2 * BS, 3]
+    out = []
+    for fragment in (True, False):
+        q, kp, vp, bt, kl, _ = _paged_case(lens, fragment=fragment,
+                                           seed=3)
+        flat_q, sids, poss = _pack_flat(q, kl)
+        out.append(_flat(path, flat_q, kp, vp, bt, sids, poss))
+    np.testing.assert_array_equal(*out)
+
+
+def test_pallas_matches_reference_path_bitwise_inputs():
+    """Both paths over the SAME buffers: allclose at f32 ulp level."""
+    lens = [2, BS, 19]
+    q, kp, vp, bt, kl, _ = _paged_case(lens, seed=7)
+    flat_q, sids, poss = _pack_flat(q, kl)
+    ref = _flat("reference", flat_q, kp, vp, bt, sids, poss)
+    pal = _flat("pallas", flat_q, kp, vp, bt, sids, poss)
+    np.testing.assert_allclose(ref, pal, rtol=1e-5, atol=1e-6)
+
+
+@both_paths
+def test_garbage_in_unreferenced_blocks_is_invisible(path):
+    """Stale KV beyond kv_len, in never-referenced blocks and in the
+    null block must not leak into any output — the masking contract
+    preemption relies on."""
+    lens = [5, 9]
+    q, kp, vp, bt, kl, _ = _paged_case(lens, seed=11)
+    flat_q, sids, poss = _pack_flat(q, kl)
+    base = _flat(path, flat_q, kp, vp, bt, sids, poss)
+    kp2, vp2 = _poison_what_no_token_may_read(kp, vp, bt, lens)
+    got = _flat(path, flat_q, kp2, vp2, bt, sids, poss)
+    np.testing.assert_array_equal(base, got)
+
+
+# ------------------------------------------------ multi-token runs --
+
+
+@pytest.mark.parametrize("kv_lens,q_lens", [
+    # verify-, decode- and prefill-like rows, block-boundary lengths
+    ([13, 5, 2 * BS, BS + 1], [5, 2, 1, BS + 1]),
+    # the engine's mixed step: a chunk, a verify window, a decode
+    ([13, 5, 2 * BS], [5, 2, 1])], ids=["chunks", "mixed"])
+@both_paths
+def test_packed_runs_vs_dense_causal_oracle(kv_lens, q_lens, path):
+    """Multi-token runs: token t of row i (absolute position kv_len -
+    q_len + t) must equal single-query dense attention over exactly
+    its causal prefix, though the keys of the run's later tokens are
+    already in its pages — across block-boundary kv lengths, runs from
+    1 token (decode) to a whole prompt, fragmented tables."""
+    q, kp, vp, bt, kl, ql, dense = _chunk_case(kv_lens, q_lens, seed=21)
+    flat_q, sids, poss = _pack_flat(q, kl, ql)
+    got = _flat(path, flat_q, kp, vp, bt, sids, poss)
+    np.testing.assert_allclose(got, _oracle(flat_q, sids, poss, dense),
+                               rtol=2e-5, atol=2e-6)
+
+
+@both_paths
+def test_one_packed_run_matches_a_token_a_call(path):
+    """A sequence's chunk fed as one packed run is the same tokens fed
+    one a call: packing changes the layout, never the math."""
+    q, kp, vp, bt, kl, ql, _ = _chunk_case([5, 2 * BS + 3], [2, 11],
+                                           seed=13)
+    flat_q, sids, poss = _pack_flat(q, kl, ql)
+    run = _flat(path, flat_q, kp, vp, bt, sids, poss)
+    one = np.concatenate([
+        _flat(path, flat_q[t:t + 1], kp, vp, bt, sids[t:t + 1],
+              poss[t:t + 1]) for t in range(len(sids))])
+    np.testing.assert_allclose(run, one, rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("tail", ["padded", "stale"])
+@both_paths
+def test_tail_behind_the_valid_tokens_is_invisible(tail, path):
+    """The packed batch is a bucket wider than the step's tokens. What
+    lies behind them must not perturb any VALID row, bit for bit:
+    ``padded``: garbage queries on sequence 0 at position 0, with KV
+    garbage wherever the mask must hide it; ``stale``: an earlier
+    step's ``seq_ids`` and ``positions`` as ``_build_batch`` leaves
+    them (only ``valid`` is reset a step): a chunk of a sequence the
+    step also holds, further on than it is now, and tokens of a slot
+    whose table row is all null."""
+    kv_lens, q_lens = [9, 17], [3, 5]
+    q, kp, vp, bt, kl, ql, _ = _chunk_case(kv_lens, q_lens, seed=5)
+    bt = np.concatenate([bt, np.full((1,) + bt.shape[1:], NULL_BLOCK,
+                                     np.int32)])     # an idle slot
+    flat_q, sids, poss = _pack_flat(q, kl, ql)
+    n, T = len(sids), 16
+
+    def bucket(x, behind):
+        return np.concatenate([x, np.asarray(behind, x.dtype)])
+
+    zeros = np.zeros(T - n, np.int32)
+    base = _flat(path, bucket(flat_q, np.zeros((T - n, H, D))), kp, vp,
+                 bt, bucket(sids, zeros), bucket(poss, zeros))
+    if tail == "padded":
+        kp, vp = _poison_what_no_token_may_read(kp, vp, bt[:2], kv_lens)
+        behind = zeros, zeros
+    else:
+        behind = ([1] * 5 + [2] * 3,
+                  [20, 21, 22, 23, 24, 3, 4, 5])
+    got = _flat(path, bucket(flat_q, np.full((T - n, H, D), 1e6)), kp,
+                vp, bt, bucket(sids, behind[0]), bucket(poss, behind[1]))
+    np.testing.assert_array_equal(base[:n], got[:n])
 
 
 # -------------------------------------------- stacked pools (ISSUE 27) --
@@ -408,12 +413,6 @@ def test_decode_flat_hands_the_kernel_whole_pools(kv_dtype, monkeypatch):
     sliced = [e for e in eqns for v in e.outvars
               if getattr(v.aval, "shape", None) == pool.shape[1:]]
     assert not sliced, sliced
-
-
-def test_chunk_requires_q_lens():
-    q, kp, vp, bt, kl, _ = _paged_case([5], seed=1)
-    with pytest.raises(ValueError, match="q_lens"):
-        ragged_paged_attention(q[:, None], kp, vp, bt, kl)
 
 
 # ------------------------------------------------------- allocator --

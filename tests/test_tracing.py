@@ -477,156 +477,3 @@ def test_estimator_fit_epoch_exports_attributable_trace(tracer, tmp_path):
             "mxtpu.train_step.compile",
             "mxtpu.train_step.dispatch"} <= names
     assert tracer.stats()["open"] == 0
-
-
-def test_bench_trend_classifies_artifacts(tmp_path):
-    """tools/bench_trend.py: rc!=0 / suspect / skipped / stale rounds
-    are never rendered as evidence; only fresh rc=0 values are valid."""
-    sys.path.insert(0, os.path.join(REPO, "tools"))
-    try:
-        import bench_trend as bt
-    finally:
-        sys.path.pop(0)
-    rounds = {
-        1: {"n": 1, "rc": 1, "parsed": None},
-        2: {"n": 2, "rc": 0, "parsed": {"suspect": True, "value": 99.0}},
-        3: {"n": 3, "rc": 0, "parsed": {"skipped": "tpu_unavailable",
-                                        "value": None}},
-        4: {"n": 4, "rc": 0,
-            "parsed": {"value": 30.47, "unit": "% of bf16 peak",
-                       "stale": True,
-                       "extra": {"train_img_s": 2676.0}}},
-        5: {"n": 5, "rc": 0,
-            "parsed": {"value": 31.0, "unit": "% of bf16 peak",
-                       "extra": {"train_img_s": 2722.0}}},
-    }
-    for n, rec in rounds.items():
-        (tmp_path / f"BENCH_r{n:02d}.json").write_text(json.dumps(rec))
-    rows = {r["round"]: r for r in bt.scan(str(tmp_path))}
-    assert rows[1]["status"] == "invalid"
-    assert rows[2]["status"] == "invalid" and rows[2]["mfu"] is None
-    assert rows[3]["status"] == "skipped"
-    assert rows[4]["status"] == "stale"
-    assert rows[5]["status"] == "valid" and rows[5]["mfu"] == 31.0
-    table = bt.render(sorted(rows.values(), key=lambda r: r["round"]))
-    assert "Best verified MFU: **31.00%**" in table
-    doc = tmp_path / "PERF.md"
-    bt.splice(str(doc), table)
-    text = doc.read_text()
-    assert bt.BEGIN in text and bt.END in text
-    # splice is idempotent: re-running replaces, not appends
-    bt.splice(str(doc), table)
-    assert doc.read_text().count(bt.BEGIN) == 1
-
-
-def test_bench_trend_multichip_classification(tmp_path):
-    """tools/bench_trend.py MULTICHIP trajectory: legacy replica-loop
-    dryruns render as structure-only rows, failed/skipped rounds are
-    never evidence, and the SPMD points table labels tolerance-gated
-    parity honestly (never as bit-exact)."""
-    sys.path.insert(0, os.path.join(REPO, "tools"))
-    try:
-        import bench_trend as bt
-    finally:
-        sys.path.pop(0)
-    rounds = {
-        1: {"n_devices": 8, "rc": 1, "ok": False},          # failed dryrun
-        2: {"n_devices": 8, "rc": 0, "ok": True},           # legacy dryrun
-        3: {"round": 3, "ok": False, "skipped": False, "value": None,
-            "points": [], "errors": ["devices=8: boom"]},   # failed SPMD
-        4: {"round": 4, "ok": True, "skipped": False, "value": 1.0,
-            "tag": "spmd", "timing_evidence": False,
-            "points": [
-                {"devices": 1, "mesh": {"dp": 1}, "step_ms": 2.0,
-                 "dispatches_per_step": 1.0, "speedup_vs_1dev": 1.0,
-                 "parity_ok": True, "parity_kind": "bitexact"},
-                # legacy key (pre-rename artifacts): renders the same
-                {"devices": 8, "mesh": {"dp": 4, "tp": 2}, "step_ms": 6.0,
-                 "dispatches_per_step": 1.0, "scaling_efficiency": 0.33,
-                 "parity_ok": True, "parity_kind": "tolerance"},
-            ]},
-    }
-    for n, rec in rounds.items():
-        (tmp_path / f"MULTICHIP_r{n:02d}.json").write_text(json.dumps(rec))
-    rows = {r["round"]: r for r in bt.scan_multichip(str(tmp_path))}
-    assert rows[1]["status"] == "invalid"
-    assert rows[2]["status"] == "legacy" and not rows[2]["points"]
-    assert rows[3]["status"] == "invalid"
-    assert rows[4]["status"] == "valid" and len(rows[4]["points"]) == 2
-    table = bt.render_multichip(
-        sorted(rows.values(), key=lambda r: r["round"]))
-    lines = table.splitlines()
-    dp_row = next(l for l in lines if "1 (dp1)" in l)
-    tp_row = next(l for l in lines if "8 (dp4×tp2)" in l)
-    assert "bit-exact" in dp_row
-    assert "tol" in tp_row and "bit-exact" not in tp_row
-    assert "structure evidence only" in table
-    assert "1.0 dispatch/step" in table
-    doc = tmp_path / "PERF.md"
-    bt.splice(str(doc), table, begin=bt.MC_BEGIN, end=bt.MC_END,
-              heading=bt.MC_HEADING)
-    bt.splice(str(doc), table, begin=bt.MC_BEGIN, end=bt.MC_END,
-              heading=bt.MC_HEADING)
-    assert doc.read_text().count(bt.MC_BEGIN) == 1
-
-
-def test_rollup_library_diff_report(tmp_path):
-    """observability.rollup: per-op-family attribution + the A/B diff
-    report perf levers are judged on (device-lane only, scan wrapper
-    excluded)."""
-    import gzip
-    from mxnet_tpu.observability import rollup as ru
-
-    def capture(d, fusion_us, conv_us):
-        events = [
-            {"ph": "M", "name": "process_name", "pid": 7, "tid": 0,
-             "args": {"name": "/device:TPU:0"}},
-            {"ph": "M", "name": "thread_name", "pid": 7, "tid": 1,
-             "args": {"name": "XLA Ops"}},
-            {"ph": "M", "name": "process_name", "pid": 9, "tid": 0,
-             "args": {"name": "Host threads"}},
-            # host lane noise that must NOT count
-            {"ph": "X", "name": "fusion.999", "pid": 9, "tid": 1,
-             "ts": 0, "dur": 10 ** 6},
-            # scan wrapper double-counts its body: excluded
-            {"ph": "X", "name": "while.3", "pid": 7, "tid": 1,
-             "ts": 0, "dur": 10 ** 6},
-            {"ph": "X", "name": "fusion.12", "pid": 7, "tid": 1,
-             "ts": 0, "dur": fusion_us},
-            {"ph": "X", "name": "fusion.7", "pid": 7, "tid": 1,
-             "ts": 5, "dur": fusion_us},
-            {"ph": "X", "name": "convolution.2", "pid": 7, "tid": 1,
-             "ts": 9, "dur": conv_us},
-        ]
-        p = os.path.join(d, "x.trace.json.gz")
-        os.makedirs(d, exist_ok=True)
-        with gzip.open(p, "wt") as f:
-            json.dump({"traceEvents": events}, f)
-        return d
-
-    a = capture(str(tmp_path / "a"), 1000, 4000)
-    b = capture(str(tmp_path / "b"), 1000, 2000)
-    fam, total = ru.rollup(a)
-    assert fam["fusion"] == 2000 and fam["convolution"] == 4000
-    assert total == 6000
-    report = ru.diff(a, b, steps=50)
-    assert report["families"][0]["family"] == "convolution"
-    assert report["total_delta_ms_per_step"] == pytest.approx(-0.04)
-    assert "convolution" in ru.format_diff(report)
-    s = ru.summary(b, steps=50)
-    assert s["device_ms_per_step"] == pytest.approx(4000 / 1e3 / 50)
-    assert {f["family"] for f in s["families"]} == \
-        {"fusion", "convolution"}
-    empty = str(tmp_path / "empty")
-    os.makedirs(empty)
-    with pytest.raises(ru.RollupError):
-        ru.rollup(empty)                # no trace file anywhere under it
-    host_only = str(tmp_path / "h")
-    os.makedirs(host_only)
-    import gzip as _g
-    with _g.open(os.path.join(host_only, "h.trace.json.gz"), "wt") as f:
-        json.dump({"traceEvents": [
-            {"ph": "M", "name": "process_name", "pid": 1, "tid": 0,
-             "args": {"name": "Host threads"}}]}, f)
-    with pytest.raises(ru.RollupError):
-        ru.rollup(host_only)            # not a TPU device capture

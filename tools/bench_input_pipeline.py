@@ -1,7 +1,8 @@
 """Input-pipeline throughput: can the decode/augment path outrun the
 device? (reference protocol: the C++ ImageRecordIter is benchmarked by
 tools/bandwidth checks; here the bar is the device-side train img/s
-from bench.py — the pipeline must exceed it or it becomes the
+of the benchmark's training cell, ``train.images_per_s`` in
+PERF_LEDGER.jsonl — the pipeline must exceed it or it becomes the
 bottleneck on real data.)
 
 Packs synthetic 480x480 JPEGs (ImageNet-scale decode cost) into a .rec,

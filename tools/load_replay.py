@@ -22,9 +22,10 @@ SLOs (availability = served/(served+shed+expired), latency-percentile
 bound, TTFT bound for decode) with multi-window burn-rate status, and
 :mod:`mxnet_tpu.observability.capacity` derives sustainable QPS/chip,
 tokens/sec/chip and chips-per-M-users — every number read back out of
-registry snapshots, never hand-entered — emitted as a committed
-``CAPACITY_rNN.json`` via ``tools/perf_capture.emit_capacity_snapshot``
-(same stale/skip refusal contract as the BENCH trajectory).
+registry snapshots, never hand-entered — written as a
+``CAPACITY_rNN.json`` where ``--out`` says via
+``tools/perf_capture.emit_capacity_snapshot`` (an unhealthy replay is
+written with ``"skipped"`` set and no value).
 
 Determinism contract: a fixed ``--seed`` produces a BIT-IDENTICAL
 request schedule (asserted by ``tests/test_slo_capacity.py`` and

@@ -648,8 +648,8 @@ def smoke():
         return 1
     span_names = {s["name"] for s in tracer.snapshot()}
     # mxtpu.llm.step is the unified chunked-prefill/decode/verify
-    # launch (ISSUE 12 folded the old prefill + decode_step spans
-    # into it)
+    # launch (ISSUE 12 folded the old prefill and decode spans into
+    # it)
     for needed in ("mxtpu.train_step", "mxtpu.train_step.dispatch",
                    "mxtpu.serving.request", "mxtpu.ckpt.write",
                    "mxtpu.llm.request", "mxtpu.llm.step"):
