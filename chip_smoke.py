@@ -13,7 +13,10 @@ supports, with random weights made from a seed:
               reference on the chip;
 - **latent**  the flat latent (MLA) attention kernel alone, at the widths
               and the three packed shapes of the ``moonlight_16b_a3b``
-              cell, against its reference on a few rows, timed a call.
+              cell, against its reference on a few rows, timed a call;
+- **ragged**  the flat K/V attention kernel alone, at the widths and the
+              four packed shapes of the ``cerebras_gpt_1b3`` cells, over
+              float32 and int8 pages, likewise.
 
 Every phase checks what came out and prints one JSON line; a failed check
 raises, so the exit code is non-zero. The last line of standard output is
@@ -34,17 +37,20 @@ import json
 import sys
 import time
 
-PHASES = ("train", "serve", "kernels", "latent")
+PHASES = ("train", "serve", "kernels", "latent", "ragged")
 
 #: kernel-vs-reference bounds, fixed from the dtype before any chip run.
 #: bf16 flash attention: the repo's own bf16 bound (tests/test_attention.py).
-#: f32 ragged attention: VPU arithmetic, so only exp/divide differ from XLA.
+#: f32 ragged attention: a run of several tokens takes both products through
+#: the MXU at the backend's default precision, one bf16 pass over float32
+#: operands (2**-9 an operand, a few of them a logit; the latent kernel reads
+#: 0.003); a run of one token and int8 pages stay on the VPU, exact in float32.
 #: int8-weight matmul: bounded by a bf16 pass over f32 activations, which is
 #: below the 1/127 step of the weights themselves.
 #: latent attention: float32 operands through the MXU at the backend's
 #: default precision, one bf16 pass like the flash kernel's.
 FLASH_BF16_TOL = 0.05
-RAGGED_F32_TOL = 1e-4
+RAGGED_F32_TOL = 0.02
 WQ_MATMUL_TOL = 1e-2
 LATENT_TOL = 0.05
 
@@ -421,18 +427,18 @@ def kernels_phase(attn_shape, ragged_shape, matmul_shape, interpret=False):
         rel_err={k: float(f"{e:.3g}") for k, e in errs.items()})
 
 
-# ----------------------------------------------------------------- latent --
+# ------------------------------------------------ the flat kernels alone --
 
-def latent_batch(rs, T, MB, block_size, slots=8, chunk=16):
+def latent_batch(rs, T, MB, block_size, slots=8, chunk=16, median=1046.0):
     """A packed step of ``T`` query tokens over ``slots`` sequences as
     ``LLMEngine._build_batch`` lays it out: each sequence's tokens side
     by side at ascending positions, as many whole prefill chunks of
     ``chunk`` tokens as fit while the other slots decode one token
     each (T=8: eight decodes; 23: a chunk and seven; 128: eight
-    chunks). Contexts are drawn as the Moonlight cell's traffic draws
-    its prompts (lognormal about 1,046 tokens, sigma 0.9), cut to the
-    table, and a chunk stands somewhere inside its prompt: most tables
-    are far from full. The engine keeps one pair of buffers a shape and
+    chunks). Contexts are drawn as the conversation cells' traffic
+    draws its prompts (lognormal about ``median`` tokens, sigma 0.9),
+    cut to the table, and a chunk stands somewhere inside its prompt:
+    most tables are far from full. The engine keeps one pair of buffers a shape and
     never clears them, so the step is drawn twice: an earlier one that
     fills the buffers, then the step itself with slot 0 idle and the
     others in another admit order, written over the front. What is left
@@ -448,7 +454,7 @@ def latent_batch(rs, T, MB, block_size, slots=8, chunk=16):
     def step(order):
         sids, poss = [], []
         for slot, n in zip(order, lens):
-            ctx = int(np.clip(rs.lognormal(np.log(1046.0), 0.9), n, limit))
+            ctx = int(np.clip(rs.lognormal(np.log(median), 0.9), n, limit))
             if n > 1:                   # a chunk inside the prompt
                 ctx = int(rs.randint(n, ctx + 1))
             sids += [slot] * n
@@ -467,75 +473,140 @@ def latent_batch(rs, T, MB, block_size, slots=8, chunk=16):
     return np.asarray(sids, np.int32), np.asarray(poss, np.int32), valid
 
 
-def latent_phase(cases, layers, num_blocks, block_size, width, heads, v_dim,
-                 dtype="bfloat16", reps=50, interpret=False):
-    """The flat latent kernel alone over a pool ``[layers, num_blocks,
-    block_size, width]``: each ``(T, MB)`` of ``cases`` on a batch from
-    :func:`latent_batch`, every row of the step's own tokens against
-    the gather reference (the stale tail's rows only finite), then
-    ``reps`` calls timed."""
+def _flat_kernel_alone(phase, cases, q_shape, pools, attend, reference, tol,
+                       reps, **facts):
+    """A flat attention kernel alone over ``pools`` (the first
+    ``[layers, num_blocks, block_size, ...]``): for each ``(T, MB,
+    median context)`` of ``cases`` a batch from :func:`latent_batch`
+    over 8 tables of ``MB`` pages; ``attend(q, tables, seq_ids,
+    positions, layer, *pools)`` on it, every row of the step's own tokens against
+    ``reference`` (same arguments; the stale tail's rows only finite);
+    then ``reps`` calls timed twice: with that stale tail, as the engine
+    left it before PR 31, and with the tail it writes since (the last
+    valid token's slot at position 0)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from mxnet_tpu.ops.ragged_attention import (
-        mla_flat_attention, mla_flat_attention_reference)
 
     started, c0 = time.monotonic(), _counters()
-    scale, layer, slots = 192 ** -0.5, layers // 2, 8
-    keys = jax.random.split(jax.random.PRNGKey(5), 2)
-    pool = jax.random.normal(
-        keys[0], (layers, num_blocks, block_size, width)).astype(dtype)
-    ms, errs, ctx = {}, {}, {}
+    layers, num_blocks, block_size = pools[0].shape[:3]
+    layer, slots = layers // 2, 8
+    ms, ms_clean, errs, ctx = {}, {}, {}, {}
     run_s = 0.0
-    for T, MB in cases:
+    for T, MB, median in cases:
         name = f"T{T}_MB{MB}"
         rs = np.random.RandomState(1000 * T + MB)
-        q = jax.random.normal(keys[1], (T, heads, width), jnp.float32)
-        check(slots * MB < num_blocks, f"latent {name}: the pool is too "
+        q = jax.random.normal(jax.random.PRNGKey(T), (T,) + q_shape,
+                              jnp.float32)
+        check(slots * MB < num_blocks, f"{phase} {name}: the pool is too "
               f"small for {slots} tables of {MB} pages")
         tables = jnp.asarray(1 + rs.permutation(num_blocks - 1)[
             :slots * MB].reshape(slots, MB), jnp.int32)
-        sids, poss, valid = latent_batch(rs, T, MB, block_size, slots)
+        sids, poss, valid = latent_batch(rs, T, MB, block_size, slots,
+                                         median=median)
+        clean = sids.copy(), poss.copy()
+        clean[0][valid:], clean[1][valid:] = sids[valid - 1], 0
         sids, poss = jnp.asarray(sids), jnp.asarray(poss)
-
-        def attend(q, pool, tables, sids, poss, layer):
-            return mla_flat_attention(
-                q, pool, tables, sids, poss, scale, v_dim, layer=layer,
-                use_pallas=True, interpret=interpret)
-
+        got = jax.block_until_ready(
+            jax.jit(attend)(q, tables, sids, poss, layer, *pools))
         # `reps` calls inside ONE program, a layer after the other as a
         # step makes them: a call from the host costs 0.2 ms before the
         # kernel starts, more than the kernel takes
         many = jax.jit(lambda *a: jax.lax.fori_loop(
-            0, reps, lambda i, _: attend(*a, i % layers),
-            jnp.zeros((T, heads, v_dim), jnp.float32)))
-        args = (q, pool, tables, sids, poss)
-        got = jax.block_until_ready(jax.jit(attend)(*args, layer))
-        jax.block_until_ready(many(*args))          # compile
-        t0 = time.monotonic()
-        jax.block_until_ready(many(*args))
-        dt = time.monotonic() - t0
-        run_s += dt
-        ms[name] = round(dt / reps * 1e3, 4)
-        ctx[name] = round(float(poss.mean()) + 1, 1)
-        # the reference gathers a whole table a row: 32 rows a time
+            0, reps, lambda i, _: attend(*a[:4], i % layers, *a[4:]),
+            jnp.zeros_like(got)))
+        jax.block_until_ready(many(q, tables, sids, poss, *pools))  # compile
+        for into, tail in ((ms, (sids, poss)), (ms_clean, clean)):
+            tail = [jnp.asarray(x) for x in tail]
+            t0 = time.monotonic()
+            jax.block_until_ready(many(q, tables, *tail, *pools))
+            dt = time.monotonic() - t0
+            run_s += dt
+            into[name] = round(dt / reps * 1e3, 4)
+        ctx[name] = round(float(poss[:valid].mean()) + 1, 1)
+        # the reference gathers a whole table a row: 16 rows a time
         with jax.default_matmul_precision("highest"):
-            want = jnp.concatenate([mla_flat_attention_reference(
-                q[r:r + 32], pool, tables, sids[r:r + 32], poss[r:r + 32],
-                scale, v_dim, layer=layer) for r in range(0, valid, 32)])
-        check(got.shape == (T, heads, v_dim)
+            want = jnp.concatenate([jax.jit(reference)(
+                q[r:r + 16], tables, sids[r:r + 16], poss[r:r + 16], layer,
+                *pools) for r in range(0, valid, 16)])
+        check(got.shape[:2] == q.shape[:2]
               and bool(np.isfinite(np.asarray(got)).all()),
-              f"latent {name}: non-finite or mis-shaped output")
+              f"{phase} {name}: non-finite or mis-shaped output")
         errs[name] = _rel_err(got[:valid], want[:valid])
-        check(errs[name] <= LATENT_TOL,
-              f"latent {name}: error {errs[name]:.3g} over bound "
-              f"{LATENT_TOL}")
+        check(errs[name] <= tol,
+              f"{phase} {name}: error {errs[name]:.3g} over bound {tol}")
     return _report(
-        "latent", started, c0, time.monotonic() - started - run_s, run_s,
-        interpret=interpret, pool=list(pool.shape), dtype=dtype,
-        heads=heads, v_dim=v_dim, reps=reps, ms_per_call=ms,
-        mean_context=ctx,
-        rel_err={k: float(f"{e:.3g}") for k, e in errs.items()})
+        phase, started, c0, time.monotonic() - started - run_s, run_s,
+        pool=list(pools[0].shape), dtype=str(pools[0].dtype), reps=reps,
+        ms_per_call=ms, ms_per_call_clean_tail=ms_clean, mean_context=ctx,
+        rel_err={k: float(f"{e:.3g}") for k, e in errs.items()}, **facts)
+
+
+def latent_phase(cases, layers, num_blocks, block_size, width, heads, v_dim,
+                 dtype="bfloat16", reps=50, interpret=False):
+    """The flat latent kernel alone over a pool ``[layers, num_blocks,
+    block_size, width]`` (see :func:`_flat_kernel_alone`); contexts
+    about 1,046 tokens, as the Moonlight cell draws them."""
+    import jax
+    from mxnet_tpu.ops.ragged_attention import (
+        mla_flat_attention, mla_flat_attention_reference)
+
+    scale = 192 ** -0.5
+    pool = jax.random.normal(
+        jax.random.PRNGKey(5),
+        (layers, num_blocks, block_size, width)).astype(dtype)
+
+    def attend(q, tables, sids, poss, layer, pool):
+        return mla_flat_attention(
+            q, pool, tables, sids, poss, scale, v_dim, layer=layer,
+            use_pallas=True, interpret=interpret)
+
+    def reference(q, tables, sids, poss, layer, pool):
+        return mla_flat_attention_reference(
+            q, pool, tables, sids, poss, scale, v_dim, layer=layer)
+
+    return _flat_kernel_alone(
+        "latent", [(T, MB, 1046.0) for T, MB in cases], (heads, width),
+        (pool,), attend, reference, LATENT_TOL, reps, interpret=interpret,
+        heads=heads, v_dim=v_dim)
+
+
+def ragged_phase(cases, layers, num_blocks, block_size, heads, head_dim,
+                 dtype="float32", reps=50, interpret=False):
+    """The flat K/V kernel alone over pools ``[layers, num_blocks,
+    block_size, heads, head_dim]`` of ``dtype`` (``int8``: with
+    per-slot per-head scale pools), ``cases`` being ``(T, MB, median
+    context)`` (see :func:`_flat_kernel_alone`)."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.ops.ragged_attention import (
+        ragged_flat_attention, ragged_flat_attention_reference)
+
+    shape = (layers, num_blocks, block_size, heads, head_dim)
+    keys = jax.random.split(jax.random.PRNGKey(7), 4)
+    if dtype == "int8":
+        pools = tuple(jax.random.randint(k, shape, -127, 128, jnp.int8)
+                      for k in keys[:2])
+        pools += tuple(jax.random.uniform(k, shape[:-1], jnp.float32, 0.5,
+                                          1.5) / 127.0 for k in keys[2:])
+    else:
+        pools = tuple(jax.random.normal(k, shape, jnp.dtype(dtype))
+                      for k in keys[:2])
+
+    def attend(q, tables, sids, poss, layer, kp, vp, *scales):
+        return ragged_flat_attention(
+            q, kp, vp, tables, sids, poss, layer=layer, use_pallas=True,
+            interpret=interpret, **dict(zip(("k_scales", "v_scales"),
+                                            scales)))
+
+    def reference(q, tables, sids, poss, layer, kp, vp, *scales):
+        return ragged_flat_attention_reference(
+            q, kp, vp, tables, sids, poss, layer=layer,
+            **dict(zip(("k_scales", "v_scales"), scales)))
+
+    return _flat_kernel_alone(
+        "ragged", cases, (heads, head_dim), pools, attend, reference,
+        RAGGED_F32_TOL, reps, interpret=interpret)
 
 
 # ------------------------------------------------------------------- main --
@@ -547,7 +618,7 @@ def main(argv=None):
                          "four-chip mesh in this one process")
     ap.add_argument("--phases", default=",".join(PHASES),
                     help="the phases to run, comma separated, of "
-                         + ", ".join(PHASES) + " (kernels and latent: "
+                         + ", ".join(PHASES) + " (kernels, latent and ragged: "
                          "one chip only)")
     args = ap.parse_args(argv)
     phases = args.phases.split(",")
@@ -594,6 +665,16 @@ def main(argv=None):
         latent_phase(cases=((8, 256), (23, 512), (128, 512)), layers=9,
                      num_blocks=4097, block_size=16, width=640, heads=16,
                      v_dim=512)
+    if "ragged" in phases and not many:
+        # the cerebras_gpt_1b3 cells' pools and widths; their steps pack
+        # 8, 23, 38 or 128 tokens over tables of 64 (Alpaca: contexts
+        # about 75) or 128 pages (history: about 1,020). One dtype at a
+        # time: the float32 pools are 6.4 GB
+        cases = ((8, 64, 75.0), (23, 128, 1020.0), (38, 128, 1020.0),
+                 (128, 128, 1020.0))
+        for dtype in ("float32", "int8"):
+            ragged_phase(cases, layers=24, num_blocks=1025, block_size=16,
+                         heads=16, head_dim=128, dtype=dtype)
     dev = devices[0]
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,

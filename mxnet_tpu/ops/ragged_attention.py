@@ -21,19 +21,22 @@ TPU runs the kernel in interpret mode:
 
 - ``ragged_flat_attention`` (``_sharded`` for ``shard_map`` bodies,
   ``_reference``): a K and a V page per head, ``[L, N, bs, H, D]``,
-  float or int8/fp8 with per-slot per-head scale pools. Grid ``(T,
-  MB)``; the tables and the per-token scalars are scalar-prefetch
-  operands, so the page DMA of step ``(t, j)`` is index-mapped through
-  ``block_tables[seq_ids[t], j]`` and the gather never exists in HBM;
-  online softmax in float32 scratch across a token's pages; pages past
-  the token's position skip their compute. See ``_flat_kernel``.
+  float or int8/fp8 with per-slot per-head scale pools. See
+  ``_flat_kernel``.
 - ``mla_flat_attention`` (``_reference``): one pool of latents ``[L,
-  N, bs, W]`` that every head reads (MLA, absorbed form). A grid over
-  blocks of query tokens; in a block each RUN of consecutive tokens of
-  one sequence walks that sequence's pages once, by manual DMA from
-  the pool in HBM, double buffered, and only as far as the run's
-  largest position: a step costs what its contexts hold, not ``T x
-  MB``. See ``_mla_flat_kernel``.
+  N, bs, W]`` that every head reads (MLA, absorbed form). See
+  ``_mla_flat_kernel``.
+
+Both kernels walk the same way. A grid over blocks of query tokens;
+the tables and the per-token scalars are scalar-prefetch operands; in
+a block each RUN of consecutive tokens of one sequence walks that
+sequence's pages once, by manual DMA from the pool in HBM through
+``block_tables[seq, j]`` (the gather never exists in HBM), double
+buffered, and only as far as the page of the run's largest position;
+online softmax in float32 scratch across a run's steps. A step costs
+what its contexts hold, not ``T x MB``, and the cost follows the
+layout of runs (``LLMEngine._build_batch`` packs a sequence's tokens
+side by side and names the tail behind them so that it walks nothing).
 
 The masking guarantee runs one way, and preemption, the prefix cache
 and padded steps rely on it: data past a token's position, in blocks
@@ -107,70 +110,283 @@ def ragged_flat_attention_reference(q, k_pages, v_pages, block_tables,
 # ----------------------------------------------------------- pallas --
 
 
-def _flat_kernel(layer_ref, sid_ref, pos_ref, bt_ref, q_ref, k_ref,
-                 v_ref, *refs, scale, block_size, num_blocks):
-    """Grid (T, MB): one query token against one page of its own
-    sequence — the page DMA for grid step (t, j) is index-mapped
-    through ``(layer, block_tables[seq_ids[t], j])``.
+def _block_scalars(seq_ids, positions, table_rows, total):
+    """The per-token scalars a flat kernel prefetches: ids clipped to
+    the table's rows and positions to zero, as the reference's gather
+    and mask take them, and behind them up to ``total`` (whole query
+    blocks) the last token's sequence at position 0: tokens that
+    lengthen its run and cost no walk of their own, whose rows are
+    never written back."""
+    sid = jax.lax.clamp(0, seq_ids.astype(jnp.int32), table_rows - 1)
+    pos = jax.lax.max(positions.astype(jnp.int32), 0)
+    pad = total - sid.shape[0]
+    if pad:
+        sid = jnp.concatenate([sid, jnp.broadcast_to(sid[-1:], (pad,))])
+        pos = jnp.concatenate([pos, jnp.zeros((pad,), jnp.int32)])
+    return sid, pos
 
-    The K/V operands are the STACKED pools ``[L, N, bs, H, D]``,
-    whole: the layer is a scalar-prefetch operand that only the index
-    maps read (``layer_ref`` is unused in the body), so every layer
-    of a model shares this one kernel body and each ``(bs, H, D)``
-    tile is DMA'd straight out of the pool the step updates in place.
-    A per-layer ``pool[li]`` handed over instead is a copy: XLA
-    neither fuses a slice into a Mosaic custom call nor aliases it to
-    its operand.
 
-    ``refs`` is ``(o, acc, m, l)`` for float pages, or ``(k_scales,
-    v_scales, o, acc, m, l)`` for QUANTIZED pages: the K/V tiles then
-    arrive int8/fp8 with per-slot per-head f32 scale tiles (same index
-    map), and ``page * scale`` is fused right where the tile lands in
-    VMEM — the f32 pages never materialize in HBM.
+def _pages_sliceable(pages):
+    """Whether Mosaic slices one ``(bs, H, D)`` page out of a pool in
+    HBM for a DMA: it slices an operand by whole tiles, 128 lanes by
+    one 32-bit sublane (refused at D 64, at two int8 heads and at a
+    page of scales ``(bs, 16)``; jax 0.9.0, libtpu 0.0.34)."""
+    H, D = pages.shape[-2:]
+    return D % 128 == 0 and H * pages.dtype.itemsize % 4 == 0
 
-    One query token against one page is a matrix-vector product per
-    head, so both contractions run on the VPU in the page's own
-    ``(bs, H, D)`` layout (multiply, then reduce lanes for q.k and
-    the leading axis for p.v): Mosaic has no batched matmul with the
-    batch axis in the middle of one operand and no free axis on the
-    other, and this form needs no in-kernel transpose."""
-    *scale_refs, o_ref, acc_ref, m_ref, l_ref = refs
-    t, j = pl.program_id(0), pl.program_id(1)
 
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+def _flat_tiles(T, bs, MB):
+    """``(query tokens a block, pages a loop step)`` of the flat K/V
+    kernel, from the shapes alone.
 
-    qpos = pos_ref[t]
-    base = j * block_size
+    Query tokens: the whole packed batch up to 64 tokens, else blocks
+    of 64. A run that crosses a block boundary is walked once a side,
+    so a block should hold a step's runs whole (8 slots of a token or a
+    chunk of 16 each: 8 to 128 tokens); the per-head products take all
+    of a block's tokens as their rows, so a block far wider than its
+    runs pays for rows it masks. Pages: as many as make 128 keys a loop
+    step: an MXU-shaped minor dimension for ``q.k``, and the loop's
+    fixed cost (DMA descriptors, rescaling the accumulator) once per
+    128 keys; never more than the table is wide.
 
-    @pl.when(base <= qpos)
-    def _body():
-        q = q_ref[...].astype(jnp.float32)            # (H, D)
-        k = k_ref[...].astype(jnp.float32)            # (bs, H, D)
-        v = v_ref[...].astype(jnp.float32)
-        if scale_refs:
-            ks_ref, vs_ref = scale_refs               # (bs, H)
-            k = k * ks_ref[...][..., None]
-            v = v * vs_ref[...][..., None]
-        s = jnp.sum(q[None] * k, axis=-1,
-                    keepdims=True) * scale            # (bs, H, 1)
-        pos = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        s = jnp.where(pos <= qpos, s, _NEG_INF)
-        m_prev = m_ref[...]                           # (H, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
-        p = jnp.exp(s - m_new[None])
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=0)
-        acc_ref[...] = acc_ref[...] * alpha + jnp.sum(p * v, axis=0)
-        m_ref[...] = m_new
+    VMEM: a page pair is ``2 x bs x H x D`` elements, 262 KB at 16 x 16
+    x 128 float32 (a latent page: 20 KB). Two buffers of 128 keys for K
+    and for V are 4 MB; the query and output blocks (double buffered by
+    the pipeline) and the accumulator are 2.5 MB more at 64 tokens:
+    under the 16 MB a kernel may take by default, so no
+    ``vmem_limit_bytes``. 256 keys a step would be 8 MB of buffers."""
+    qb = T if T <= 64 else 64
+    return qb, max(1, min(MB, 128 // bs))
 
-    @pl.when(j == num_blocks - 1)
-    def _finalize():
-        l_safe = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[...] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
+
+def _flat_kernel(layer_ref, sid_ref, pos_ref, bt_ref, q_ref, k_pool,
+                 v_pool, *refs, scale, block_size, per_head):
+    """Grid ``(query blocks,)``: one grid step holds ``QB`` packed query
+    tokens ``(QB, H, D)``. The walk is ``_mla_flat_kernel``'s: the
+    block's tokens are taken RUN by run, a run being consecutive tokens
+    of one sequence; the run's K and V pages, and only up to the page
+    of its largest position, come from the STACKED pools ``[L, N, bs,
+    H, D]`` in HBM (whole: a ``pool[li]`` handed to a custom call is a
+    copy; the layer is a scalar-prefetch operand) by explicit DMA
+    through the scalar-prefetched block table, ``PP`` pages of each a
+    loop step into one of two buffers ``(PP x bs, H, D)`` while the
+    other is computed on. Online softmax in float32 scratch across a
+    run's steps, a row per token and head.
+
+    While a run's last step computes, the next run's first pages are
+    already on their way; only a block's first copy is exposed.
+
+    A page is ``(bs, H, D)``: a head's keys are every ``H``-th row of
+    a buffer seen as ``(keys x H, D)``; queries, output and the
+    softmax state are ``(QB x H, D)`` likewise, a row a token and head.
+    Two inner forms read a buffer, chosen a loop step by how many of
+    the run's tokens reach it:
+
+    - ONE token alone (a decode row; the longest token of a run whose
+      others stop short, such as a tail at position 0) takes the
+      whole buffer at once on the VPU (multiply, then reduce lanes for
+      ``q.k`` and the key axis for ``p.v``): a matrix-vector product a
+      head, bound by the page bytes, exact in float32 (measured: 4 us
+      a step of 128 keys, whose 2 MB take 2.6 us; a head at a time
+      8.5 us);
+    - several tokens (a prefill chunk, a verify window), where
+      ``per_head``, take a head at a time on the MXU: ``(QB, D) x (D,
+      keys)`` and ``(QB, keys) x (keys, D)``, the head's keys, queries
+      and state rows by strided sublane loads (``ref[h::H, :]``). All
+      the block's tokens are rows; a row takes keys only if it is one
+      of the RUN's own (tokens ``[i, end)``; another run of the same
+      sequence in the block walks for itself) and the key is not past
+      its token's position, and ``p`` is zeroed by that mask, not by
+      ``exp`` (a row that has met no key yet still has ``m ==
+      _NEG_INF``). A chunk of 16 costs what one token costs this way,
+      a third of sixteen passes of the first form.
+
+    Without ``per_head`` (Mosaic loads strided only four-byte rows of
+    exactly 128 lanes: quantized pages, two-byte floats, a head size
+    other than 128 or heads no multiple of the 8 sublanes) every token
+    of a run that reaches a step takes the first form in turn.
+    ``refs`` is ``(o, k_buf, v_buf, sems, acc, m, l)`` for float pages
+    and ``(k_scales, v_scales, o, k_buf, v_buf, ks_buf, vs_buf, sems,
+    acc, m, l)`` for QUANTIZED pages: ONE layer's per-slot per-head
+    float32 scales ``[N, bs, lanes]`` in HBM, copied page by page
+    beside their pages, ``page x scale`` fused where the tile lands.
+    Every step program traces and lowers this body anew (17 programs
+    a warm-up, 0.3 s each on the chip's host in this kernel's first
+    form), so it is kept small: the loops over tokens, pages and heads
+    are ``fori_loop``s, and scalar and elementwise work is written in
+    ``lax`` primitives (``jnp.where``, ``jnp.minimum`` and their kin
+    each go through a jitted call's dispatch when traced)."""
+    if len(refs) == 7:
+        scale_pools = scale_bufs = ()
+        o_ref, k_buf, v_buf, sems, acc_ref, m_ref, l_ref = refs
+    else:
+        (*scale_pools, o_ref, k_buf, v_buf, ks_buf, vs_buf, sems,
+         acc_ref, m_ref, l_ref) = refs
+        scale_bufs = (ks_buf, vs_buf)
+    KB, H = k_buf.shape[1:3]
+    QB = q_ref.shape[0] // H
+    PP = KB // block_size
+    MB = bt_ref.shape[1]
+    t0 = pl.program_id(0) * QB
+    lyr = layer_ref[0]
+    last_key = MB * block_size - 1      # the reference's table ends here
+
+    def neg_inf(shape):
+        return jnp.full(shape, _NEG_INF, jnp.float32)
+
+    acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+    m_ref[...] = jnp.full(m_ref.shape, _NEG_INF, jnp.float32)
+    l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+
+    def pages(seq, k, slot, go):
+        """Loop step ``k`` of sequence ``seq``: start (``go``) or await
+        the DMA of its ``PP`` pages of K and of V (and of their scales)
+        into buffer ``slot``. Past the table's width the last entry
+        again; past the run's own pages whatever the table names (the
+        null block in the engine's tables): masked either way."""
+        def page(p, carry):
+            blk = bt_ref[seq, jax.lax.min(k * PP + p, MB - 1)]
+            into = pl.ds(pl.multiple_of(p * block_size, block_size),
+                         block_size)
+            copies = [(k_pool.at[lyr, blk], k_buf), (v_pool.at[lyr, blk],
+                                                     v_buf)]
+            copies += [(pool.at[blk], buf)
+                       for pool, buf in zip(scale_pools, scale_bufs)]
+            for n, (src, buf) in enumerate(copies):
+                copy = pltpu.make_async_copy(src, buf.at[slot, into],
+                                             sems.at[slot, n])
+                if go:
+                    copy.start()
+                else:
+                    copy.wait()
+            return carry
+
+        jax.lax.fori_loop(0, PP, page, 0)
+
+    def one_token(t, k, slot):
+        """Token ``t`` of the block against buffer ``slot``, every
+        head at once on the VPU. Its first step holds key 0, so ``m``
+        is finite from then on and ``exp`` alone zeroes what the mask
+        hides."""
+        rows = pl.ds(pl.multiple_of(t * H, H), H)
+        q = q_ref[rows, :].astype(jnp.float32)            # (H, D)
+        kk = k_buf[slot].astype(jnp.float32)              # (KB, H, D)
+        vv = v_buf[slot].astype(jnp.float32)
+        if scale_bufs:
+            kk = kk * ks_buf[slot, :, :H][..., None]
+            vv = vv * vs_buf[slot, :, :H][..., None]
+        s = jnp.sum(q[None] * kk, axis=-1,
+                    keepdims=True) * scale                # (KB, H, 1)
+        kpos = k * KB + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        s = jax.lax.select(kpos <= jax.lax.min(pos_ref[t0 + t], last_key),
+                           s, neg_inf(s.shape))
+        m_prev = m_ref[rows, :]                           # (H, 1)
+        m_new = jax.lax.max(m_prev, jnp.max(s, axis=0))
+        p = jax.lax.exp(s - m_new[None])
+        alpha = jax.lax.exp(m_prev - m_new)
+        l_ref[rows, :] = l_ref[rows, :] * alpha + jnp.sum(p, axis=0)
+        acc_ref[rows, :] = acc_ref[rows, :] * alpha + jnp.sum(p * vv,
+                                                              axis=0)
+        m_ref[rows, :] = m_new
+
+    def one_head(h, k, slot, limit):
+        """Head ``h`` of every token of the block against its keys in
+        buffer ``slot``, on the MXU; ``limit (QB, 1)`` is the last key
+        each token may take in this run, -1 for tokens of other runs."""
+        rows = pl.ds(h, QB, stride=H)   # the head's row of every token
+        keys = pl.ds(h, KB, stride=H)
+        acc_h, m_h, l_h = (r.at[rows, :] for r in (acc_ref, m_ref, l_ref))
+        q = q_ref[rows, :].astype(jnp.float32)            # (QB, D)
+        kk, vv = (buf.at[slot].reshape(KB * H, buf.shape[-1])[keys, :]
+                  .astype(jnp.float32) for buf in (k_buf, v_buf))
+        s = jax.lax.dot_general(
+            q, kk, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale   # (QB, KB)
+        kpos = k * KB + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        mask = kpos <= limit
+        s = jax.lax.select(mask, s, neg_inf(s.shape))
+        m_prev = m_h[...]                                 # (QB, 1)
+        m_new = jax.lax.max(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jax.lax.select(mask, jax.lax.exp(s - m_new),
+                           jnp.zeros(s.shape, jnp.float32))
+        alpha = jax.lax.exp(m_prev - m_new)
+        l_h[...] = l_h[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_h[...] = acc_h[...] * alpha + jax.lax.dot_general(
+            p, vv, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_h[...] = m_new
+
+    tok = jax.lax.broadcasted_iota(jnp.int32, (QB, 1), 0)
+
+    def run(c):
+        i, slot0 = c
+        seq = sid_ref[t0 + i]
+
+        def same_seq(c):
+            return jax.lax.bitwise_and(
+                c[0] < QB, sid_ref[t0 + jax.lax.min(c[0], QB - 1)] == seq)
+
+        def take(c):
+            j, last, second, top, limit = c
+            pos = jax.lax.min(pos_ref[t0 + j], last_key)
+            ahead = pos > last
+            return (j + 1, jax.lax.max(last, pos),
+                    jax.lax.select(ahead, last, jax.lax.max(second, pos)),
+                    jax.lax.select(ahead, j, top),
+                    jax.lax.select(tok == j,
+                                   jax.lax.broadcast(pos, limit.shape),
+                                   limit))
+
+        # the run's end; its largest position, its second largest (-1:
+        # none) and the token `top` that holds the largest; the last
+        # key each of its tokens may take (-1: the tokens of other
+        # runs, the same sequence's among them)
+        end, last, second, top, limit = jax.lax.while_loop(
+            same_seq, take,
+            (i, -1, -1, i, jnp.full((QB, 1), -1, jnp.int32)))
+        steps = jax.lax.div(last, KB) + 1
+        # what follows this run's last step: the next run's first
+        follows = sid_ref[t0 + jax.lax.min(end, QB - 1)]
+
+        def step(k, carry):
+            slot = jax.lax.rem(slot0 + k, 2)
+            more = k + 1 < steps
+
+            # the next step's pages, or the next run's first, while
+            # this step computes
+            @pl.when(jax.lax.bitwise_or(more, end < QB))
+            def _next():
+                pages(jax.lax.select(more, seq, follows),
+                      jax.lax.select(more, k + 1, 0), 1 - slot, True)
+
+            pages(seq, k, slot, False)
+
+            if per_head:
+                def heads():
+                    jax.lax.fori_loop(
+                        0, H,
+                        lambda h, c: (one_head(h, k, slot, limit), c)[1], 0)
+
+                # one token alone reaches this step (a decode row; a
+                # run's tail at position 0 shares its first only)
+                jax.lax.cond(second < k * KB,
+                             lambda: one_token(top, k, slot), heads)
+            else:
+                def token(t, carry):    # if it reaches this step
+                    pl.when(pos_ref[t0 + t] >= k * KB)(
+                        lambda: one_token(t, k, slot))
+                    return carry
+
+                jax.lax.fori_loop(i, end, token, 0)
+            return carry
+
+        jax.lax.fori_loop(0, steps, step, 0)
+        return end, jax.lax.rem(slot0 + steps, 2)
+
+    pages(sid_ref[t0], 0, 0, True)      # the block's first: nothing hides it
+    jax.lax.while_loop(lambda c: c[0] < QB, run, (0, 0))
+    l_safe = jax.lax.max(l_ref[...], jnp.float32(1e-30))
+    o_ref[...] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnums=(9, 10))
@@ -179,55 +395,68 @@ def _ragged_flat_pallas(q, k_pages, v_pages, k_scales, v_scales,
                         interpret):
     T, H, D = q.shape
     bs = k_pages.shape[2]
-    MB = block_tables.shape[1]
+    S, MB = block_tables.shape
+    qb, pp = _flat_tiles(T, bs, MB)
+    blocks = -(-T // qb)
+    sid, pos = _block_scalars(seq_ids, positions, S, blocks * qb)
+    quantized = k_scales is not None
+    if quantized:
+        # a page's scales (bs, H) are no whole tile, and Mosaic slices
+        # an operand in HBM by whole tiles only: heads padded to the
+        # 128 lanes, as the (N, bs, H) operand is laid out anyway
+        lanes = ((0, 0), (0, 0), (0, -H % 128))
+        k_scales, v_scales = jnp.pad(k_scales, lanes), jnp.pad(v_scales,
+                                                               lanes)
+    # a head at a time on the MXU where Mosaic loads a head's rows
+    # strided: four-byte pages, heads in whole sublane tiles, a head
+    # exactly the 128 lanes wide
+    per_head = (not quantized and k_pages.dtype == jnp.float32
+                and H % 8 == 0 and D == 128)
 
-    def tok(t, j, lyr, sid, pos, bt):
-        return (t, 0, 0)
+    def rows(b, lyr, sid, pos, bt):
+        return (b, 0)
 
-    def page(t, j, lyr, sid, pos, bt):
-        return (lyr[0], bt[sid[t], j], 0, 0, 0)
-
-    in_specs = [
-        pl.BlockSpec((None, H, D), tok, memory_space=pltpu.VMEM),
-        pl.BlockSpec((None, None, bs, H, D), page,
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((None, None, bs, H, D), page,
-                     memory_space=pltpu.VMEM),
-    ]
-    args = [q, k_pages, v_pages]
-    if k_scales is not None:
-        # the scale tiles ride the same scalar-prefetched block-table
-        # index map as their pages, over ONE layer's (N, bs, H) scales
-        # (see ragged_flat_attention for why not the stacked pool)
-        in_specs += [pl.BlockSpec(
-            (None, bs, H),
-            lambda t, j, lyr, sid, pos, bt: (bt[sid[t], j], 0, 0),
-            memory_space=pltpu.VMEM)] * 2
-        args += [k_scales, v_scales]
+    # the pools, in HBM; the scales by name: a layer's, 1 MB, the
+    # compiler would else hold in VMEM, where a page's (bs, H) face is
+    # no whole tile to slice
+    pools = [pl.BlockSpec(memory_space=pl.ANY)] * 2
+    if quantized:
+        pools += [pl.BlockSpec(memory_space=pltpu.HBM)] * 2
+    streams = len(pools)
+    scratch = [pltpu.VMEM((2, pp * bs, H, D), k_pages.dtype),
+               pltpu.VMEM((2, pp * bs, H, D), v_pages.dtype)]
+    if quantized:
+        scratch += [pltpu.VMEM((2, pp * bs, k_scales.shape[-1]),
+                               jnp.float32)] * 2
+    scratch += [pltpu.SemaphoreType.DMA((2, streams)),
+                pltpu.VMEM((qb * H, D), jnp.float32),
+                pltpu.VMEM((qb * H, 1), jnp.float32),
+                pltpu.VMEM((qb * H, 1), jnp.float32)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(T, MB),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((None, H, D), tok,
-                               memory_space=pltpu.VMEM),
-        scratch_shapes=[
-            pltpu.VMEM((H, D), jnp.float32),
-            pltpu.VMEM((H, 1), jnp.float32),
-            pltpu.VMEM((H, 1), jnp.float32),
-        ],
+        grid=(blocks,),
+        in_specs=[pl.BlockSpec((qb * H, D), rows, memory_space=pltpu.VMEM)]
+        + pools,
+        out_specs=pl.BlockSpec((qb * H, D), rows, memory_space=pltpu.VMEM),
+        scratch_shapes=scratch,
     )
-    kernel = functools.partial(_flat_kernel, scale=scale,
-                               block_size=bs, num_blocks=MB)
+    kernel = functools.partial(_flat_kernel, scale=scale, block_size=bs,
+                               per_head=per_head)
+    args = [q.reshape(T * H, D), k_pages, v_pages]
+    if quantized:
+        # ONE layer's (N, bs, H) scales (see ragged_flat_attention for
+        # why not the stacked pool)
+        args += [k_scales, v_scales]
     # inside a shard_map body the output varies over the mesh axes
     # its heads do (none outside one)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((T, H, D), q.dtype,
+        out_shape=jax.ShapeDtypeStruct((T * H, D), q.dtype,
                                        vma=jax.typeof(q).vma),
         interpret=interpret,
-    )(layer.astype(jnp.int32).reshape(1), seq_ids.astype(jnp.int32),
-      positions.astype(jnp.int32), block_tables.astype(jnp.int32),
-      *args)
+    )(layer.astype(jnp.int32).reshape(1), sid, pos,
+      block_tables.astype(jnp.int32), *args)
+    return out.reshape(T, H, D)
 
 
 def ragged_flat_attention(q, k_pages, v_pages, block_tables, seq_ids,
@@ -253,9 +482,33 @@ def ragged_flat_attention(q, k_pages, v_pages, block_tables, seq_ids,
     ``[N, bs, H]``; f32) select the QUANTIZED page variant: pages are
     int8/fp8 and are dequantized per slot+head inside the kernel
     (fused after the page DMA on the Pallas path, right after the
-    gather on the reference path)."""
+    gather on the reference path).
+
+    What the kernel makes of the packed batch (``_flat_kernel``):
+    blocks of query tokens (:func:`_flat_tiles`), and in a block every
+    RUN of neighbouring tokens with one ``seq_ids`` value walks that
+    sequence's K and V pages once, up to the page of the run's largest
+    position. The result is the reference's for ANY ``seq_ids`` and
+    ``positions`` (a row takes keys only from its own run's walk, up
+    to its own token's position, so a sequence met again further on in
+    a block, as a valid token beside a stale one, is two runs that do
+    not touch; ids are clipped to the table's rows, positions to its
+    width, as the reference's gather and mask do), but the cost
+    follows the layout: ``LLMEngine._build_batch`` packs a sequence's
+    tokens side by side, so a prefill chunk reads its pages once,
+    while tokens of one sequence scattered over the batch are so many
+    runs and walks. Nothing tells the kernel which tokens are padding:
+    a tail that still holds an earlier step's ids and positions is
+    walked like any token (rows finite, to be discarded); a tail that
+    names the last token's sequence at position 0, as the engine
+    writes it, lengthens the last run and walks nothing.
+
+    On a TPU the kernel is taken where Mosaic can copy a page out of
+    the pool by itself (a head size in whole 128-lane tiles, a page's
+    heads in whole 32-bit sublanes: any count of float32 heads, four
+    of int8); other shapes take the reference there."""
     if use_pallas is None:
-        use_pallas = _on_tpu()
+        use_pallas = _on_tpu() and _pages_sliceable(k_pages)
     if scale is None:
         scale = float(1.0 / (q.shape[-1] ** 0.5))
     if (k_scales is None) != (v_scales is None):
@@ -499,13 +752,7 @@ def _mla_flat_pallas(q, pages, block_tables, seq_ids, positions, layer,
     S, MB = block_tables.shape
     qb, pp = _mla_tiles(T, H, bs, MB)
     blocks = -(-T // qb)
-    # the scalars of the last block's rows past T: the last token's
-    # sequence at position 0, so that they lengthen its run and cost no
-    # walk of their own; their rows are never written back
-    pad = (0, blocks * qb - T)
-    sid = jnp.pad(jnp.clip(seq_ids.astype(jnp.int32), 0, S - 1), pad,
-                  mode="edge")
-    pos = jnp.pad(jnp.maximum(positions.astype(jnp.int32), 0), pad)
+    sid, pos = _block_scalars(seq_ids, positions, S, blocks * qb)
     R = qb * H
 
     def rows(b, lyr, sid, pos, bt):

@@ -1490,6 +1490,7 @@ class LLMEngine:
             seeds[i] = sp.seed
             counters[i] = counters_v.get(seq, 0)
             off += n
+        self._name_tail(seq_ids, positions, off)
         # chaos-harness site: scripted raises / injected latency /
         # worker death mid-verify
         faults.check("llm.draft")
@@ -1635,6 +1636,18 @@ class LLMEngine:
             self._bufs[(t, mb)] = bufs
         return bufs
 
+    @staticmethod
+    def _name_tail(seq_ids, positions, off):
+        """Behind a packed batch's ``off`` tokens: the last valid
+        token's slot at position 0, not what an earlier step left in
+        the buffers. The flat kernels take the batch run by run and
+        walk a run's pages up to its largest position: this tail
+        lengthens the last run and walks nothing (any tail gives the
+        same valid rows; a stale one is walked like a token)."""
+        if off:
+            seq_ids[off:] = seq_ids[off - 1]
+            positions[off:] = 0
+
     def _build_batch(self, rows, plans, t, mb):
         bufs = self._batch_buffers(t, mb)
         (tokens, positions, seq_ids, valid, tables, win_idx, d_toks,
@@ -1685,6 +1698,7 @@ class LLMEngine:
                     a_tables[i] = h.pages_padded
                     a_scales[i] = h.scale
             off += n
+        self._name_tail(seq_ids, positions, off)
         return bufs
 
     def _dispatch(self, rows, plans):

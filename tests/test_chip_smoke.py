@@ -9,8 +9,9 @@ What the CPU can hold to account:
 - that ``python chip_smoke.py`` refuses to run without a TPU, before
   anything is built;
 - that every kernel of the kernels phase still LOWERS for a TPU at
-  head_dim 128 (``jax.export`` cross-lowering: the Pallas front end, not
-  Mosaic's own compile, which needs the chip);
+  head_dim 128, and both flat kernels at their cells' shapes
+  (``jax.export`` cross-lowering: the Pallas front end, not Mosaic's
+  own compile);
 - the compile-cache placement rule of ``runtime.enable_compile_cache``.
 """
 import json
@@ -85,6 +86,21 @@ def test_latent_phase_toy():
     assert max(line["rel_err"].values()) < 1e-4
 
 
+@pytest.mark.parametrize("dtype", ["float32", "int8"])
+def test_ragged_phase_toy(dtype):
+    """The phase's control flow, the kernel in interpret mode at 8 heads
+    of 128 (so a chunk takes a head at a time, a decode row the whole
+    buffer): pages of 4, a step of 23 tokens and one of 8."""
+    line = chip_smoke.ragged_phase(
+        cases=((8, 8, 12.0), (23, 40, 100.0)), layers=2, num_blocks=321,
+        block_size=4, heads=8, head_dim=128, dtype=dtype, reps=1,
+        interpret=True)
+    assert line["phase"] == "ragged" and line["dtype"] == dtype
+    assert set(line["ms_per_call"]) == set(line["ms_per_call_clean_tail"]) \
+        == {"T8_MB8", "T23_MB40"}
+    assert max(line["rel_err"].values()) < 1e-4
+
+
 def test_refuses_to_run_without_a_tpu():
     p = subprocess.run(
         [sys.executable, os.path.join(REPO, "chip_smoke.py")],
@@ -121,6 +137,26 @@ def test_latent_kernel_lowers_for_tpu_at_the_cells_shapes(T, MB):
     args = [jax.ShapeDtypeStruct(s, d) for s, d in (
         ((T, 16, 640), jnp.float32), ((9, 4097, 16, 640), jnp.bfloat16),
         ((8, MB), jnp.int32), ((T,), jnp.int32), ((T,), jnp.int32))]
+    exported = jax.export.export(jax.jit(kernel), platforms=["tpu"])(*args)
+    assert "tpu_custom_call" in exported.mlir_module()
+
+
+@pytest.mark.parametrize("pages", ["float32", "int8"])
+@pytest.mark.parametrize("T,MB", [(8, 64), (23, 128), (38, 128), (128, 128)])
+def test_flat_kernel_lowers_for_tpu_at_the_cells_shapes(T, MB, pages):
+    import jax.numpy as jnp
+    from mxnet_tpu.ops.ragged_attention import ragged_flat_attention
+
+    def kernel(q, kp, vp, tables, sids, poss, *scales):
+        kw = dict(zip(("k_scales", "v_scales"), scales))
+        return ragged_flat_attention(q, kp, vp, tables, sids, poss, layer=11,
+                                     use_pallas=True, interpret=False, **kw)
+    pool = (24, 1025, 16, 16, 128)
+    args = [jax.ShapeDtypeStruct(s, d) for s, d in (
+        ((T, 16, 128), jnp.float32), (pool, pages), (pool, pages),
+        ((8, MB), jnp.int32), ((T,), jnp.int32), ((T,), jnp.int32))]
+    if pages == "int8":
+        args += [jax.ShapeDtypeStruct(pool[:-1], jnp.float32)] * 2
     exported = jax.export.export(jax.jit(kernel), platforms=["tpu"])(*args)
     assert "tpu_custom_call" in exported.mlir_module()
 

@@ -8,11 +8,14 @@ over exactly its causal prefix — on every ragged length mix, including
 block-boundary edges (len = block_size - 1, block_size, block_size +
 1), fragmented (non-contiguous, shuffled) block tables, int8 pages, and
 whatever garbage lies past a token's position, in blocks no table
-names, or in the packed batch's tail — on BOTH paths (the gather-based
+names, or in the packed batch's tail; on tables of several loop steps
+of the kernel's walk, whatever runs the batch's tokens form — on BOTH
+paths (the gather-based
 jnp reference and the Pallas kernel in interpret mode). The block
 allocator must never leak or double-free across randomized admit/evict
 schedules.
 """
+import functools
 import os
 import sys
 
@@ -44,7 +47,8 @@ def _flat(path, q, kp, vp, bt, sids, poss, **kw):
         interpret=True, **kw))
 
 
-def _chunk_case(kv_lens, q_lens, num_blocks=64, seed=0, fragment=True):
+def _chunk_case(kv_lens, q_lens, num_blocks=64, seed=0, fragment=True,
+                H=H, D=D):
     """A paged cache + a [S, Q, H, D] query chunk: queries are the
     LAST q_lens[i] positions of each sequence (the chunked-prefill /
     verify layout). Returns (q, k_pages, v_pages, block_tables,
@@ -131,6 +135,16 @@ def _int8(x):
             scale.astype(np.float32))
 
 
+def _int8_case(kp, vp, dense):
+    """The case over int8 pages: (k_pages, v_pages, the scale pools as
+    keyword arguments, the dense sequences dequantized as the kernel
+    reads them)."""
+    (kp, ks), (vp, vs) = _int8(kp), _int8(vp)
+    dense = [tuple(xq * xs[..., None] for xq, xs in map(_int8, kv))
+             for kv in dense]
+    return kp, vp, dict(k_scales=ks, v_scales=vs), dense
+
+
 def _poison_what_no_token_may_read(kp, vp, bt, kv_lens):
     """Copies of the pools with garbage in the null block, in every
     block no table names, and in the slots past each sequence's
@@ -168,10 +182,7 @@ def test_parity_vs_dense_oracle(lens, pages, path):
     q, kp, vp, bt, kl, dense = _paged_case(lens)
     kw = {}
     if pages == "int8":
-        (kp, ks), (vp, vs) = _int8(kp), _int8(vp)
-        kw = dict(k_scales=ks, v_scales=vs)
-        dense = [tuple(xq * xs[..., None] for xq, xs in map(_int8, kv))
-                 for kv in dense]
+        kp, vp, kw, dense = _int8_case(kp, vp, dense)
     flat_q, sids, poss = _pack_flat(q, kl)
     got = _flat(path, flat_q, kp, vp, bt, sids, poss, **kw)
     np.testing.assert_allclose(got, _oracle(flat_q, sids, poss, dense),
@@ -287,6 +298,164 @@ def test_tail_behind_the_valid_tokens_is_invisible(tail, path):
     np.testing.assert_array_equal(base[:n], got[:n])
 
 
+# ------------------------------- the walk, run by run (ISSUE 31) --
+# The kernel takes a block of the packed batch RUN by run (neighbouring
+# tokens of one sequence) and walks a run's pages 128 keys a loop step.
+# Tables of 55 pages of 8 are four steps, the last one past the table's
+# width; only such tables tell a run's walk from another's. Three
+# geometries, each through both inner forms' conditions: ``heads``
+# (8 heads of 128, float32: a run of several tokens a head at a time,
+# a run of one the whole buffer at once), ``tokens`` (2 heads of 16:
+# every token in turn) and ``int8`` (8 heads of 128, quantized pages
+# with their scale pools copied page by page).
+
+WALK_LENS = [440, 37, 290, 130]                 # 55, 5, 37 and 17 pages
+WALK_GEOMETRY = {"heads": (8, 128, False), "tokens": (2, 16, False),
+                 "int8": (8, 128, True)}
+
+
+def _run(seq, first, n=1):
+    """``n`` packed tokens of ``seq`` at positions ``first...``."""
+    return [(seq, first + t) for t in range(n)]
+
+
+WALK_BATCHES = {
+    # runs of 5, 1, 16 and 1 tokens in one block, walks of 4, 1, 3 and
+    # 2 steps
+    "mixed-runs": (_run(0, 435, 5) + _run(1, 36) + _run(2, 270, 16)
+                   + _run(3, 129)),
+    # a sequence twice in one block, apart: its valid tokens, then
+    # another's, then tokens an earlier step left (shorter and longer
+    # walks than the valid run's)
+    "twice-apart": (_run(2, 130, 3) + _run(1, 20) + _run(2, 280, 2)
+                    + _run(0, 300) + _run(2, 5, 2)),
+    # 70 tokens are two blocks of 64: the second run crosses from one
+    # into the other
+    "crosses-a-block": _run(0, 380, 58) + _run(2, 200, 12),
+    # 67 tokens: a last block of three, the runs whole on either side
+    "partial-last-block": (_run(3, 60, 48) + _run(1, 0, 16)
+                           + _run(0, 437, 3)),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _walk_case(geometry):
+    """The pools of one geometry, shared by its cases: (k_pages,
+    v_pages, kwargs with the scale pools, tables [4, 55], dense k/v
+    as the kernel reads them)."""
+    heads, dim, quantized = WALK_GEOMETRY[geometry]
+    _, kp, vp, bt, _, _, dense = _chunk_case(
+        WALK_LENS, [1] * 4, num_blocks=160, seed=41, H=heads, D=dim)
+    kw = {}
+    if quantized:
+        kp, vp, kw, dense = _int8_case(kp, vp, dense)
+    return kp, vp, kw, bt, dense
+
+
+def _dense_oracle(flat_q, sids, poss, dense):
+    """``_oracle`` in numpy and float64 (seventy prefix lengths are
+    seventy programs to ``attention_reference``): a packed token at a
+    time, one softmax over exactly its causal prefix."""
+    outs = []
+    for q, i, pos in zip(flat_q.astype(np.float64), sids, poss):
+        k_seq, v_seq = (x[:pos + 1].astype(np.float64) for x in dense[i])
+        logits = np.einsum("hd,thd->ht", q, k_seq) / np.sqrt(q.shape[-1])
+        p = np.exp(logits - logits.max(axis=1, keepdims=True))
+        outs.append(np.einsum("ht,thd->hd",
+                              p / p.sum(axis=1, keepdims=True), v_seq))
+    return np.stack(outs)
+
+
+def _walk_queries(geometry, batch):
+    heads, dim, _ = WALK_GEOMETRY[geometry]
+    sids, poss = (np.asarray(x, np.int32) for x in zip(*batch))
+    q = np.random.RandomState(len(batch)).randn(
+        len(batch), heads, dim).astype(np.float32)
+    return q, sids, poss
+
+
+@pytest.mark.parametrize("batch", list(WALK_BATCHES))
+@pytest.mark.parametrize("geometry", list(WALK_GEOMETRY))
+@both_paths
+def test_walk_run_by_run_vs_dense_oracle(geometry, batch, path):
+    """Every token of the packed batch, whatever run it stands in and
+    however far that run walks, equals single-query dense attention
+    over its own causal prefix."""
+    kp, vp, kw, bt, dense = _walk_case(geometry)
+    q, sids, poss = _walk_queries(geometry, WALK_BATCHES[batch])
+    got = _flat(path, q, kp, vp, bt, sids, poss, **kw)
+    np.testing.assert_allclose(got, _dense_oracle(q, sids, poss, dense),
+                               rtol=2e-5, atol=5e-6)
+
+
+@pytest.mark.parametrize("geometry", list(WALK_GEOMETRY))
+@both_paths
+def test_ids_and_positions_past_the_table_are_clipped(geometry, path):
+    """A position past the table's width attends over the whole table
+    and a sequence id past its rows reads the last row, as the
+    reference's gather and mask do: the same rows as the clipped
+    batch, which the oracle can follow."""
+    kp, vp, kw, bt, dense = _walk_case(geometry)
+    # sequence 0 holds 440 keys in a table of 55 x 8: the table's last
+    # position, 439, is its last key
+    wild = (_run(0, 439) + _run(0, 440) + _run(0, 10 ** 6)
+            + _run(3, 100, 2) + _run(4, 129) + _run(99, 129))
+    clipped = (_run(0, 439) * 3 + _run(3, 100, 2) + _run(3, 129) * 2)
+    q, sids, poss = _walk_queries(geometry, wild)
+    _, sids_in, poss_in = _walk_queries(geometry, clipped)
+    got = _flat(path, q, kp, vp, bt, sids, poss, **kw)
+    np.testing.assert_allclose(
+        got, _dense_oracle(q, sids_in, poss_in, dense), rtol=2e-5,
+        atol=5e-6)
+
+
+def test_build_batch_names_the_tail_and_any_tail_serves_the_same(
+        monkeypatch):
+    """``LLMEngine._build_batch`` leaves behind a step's tokens the
+    last valid token's slot at position 0 (a tail that lengthens the
+    kernel's last run and walks nothing), and what is served does not
+    depend on it: an engine whose tail keeps what earlier steps left,
+    or holds garbage, serves the same tokens."""
+    from mxnet_tpu.serving.llm import (DecoderConfig, LLMEngine, Sequence,
+                                       TinyDecoder)
+    model = TinyDecoder(DecoderConfig(
+        vocab_size=17, d_model=16, num_layers=2, num_heads=2, d_ff=32,
+        max_context=64))
+    params = model.init_params(seed=0)
+    rng = np.random.RandomState(4)
+    cases = [(rng.randint(0, 17, size=n).tolist(), m)
+             for n, m in ((21, 6), (3, 9), (9, 4), (30, 7), (1, 5))]
+
+    def serve(name_tail):
+        monkeypatch.setattr(LLMEngine, "_name_tail",
+                            staticmethod(name_tail))
+        eng = LLMEngine(model, params, max_seqs=4, block_size=BS,
+                        max_context=64)
+        seqs = [Sequence(prompt, n) for prompt, n in cases]
+        tails = []
+        for s in seqs:
+            eng.add(s)
+        while eng.has_work():
+            eng.step()
+            for _, positions, seq_ids, valid, *_ in eng._bufs.values():
+                n = int(valid.sum())
+                tails.append((n, seq_ids.copy(), positions.copy()))
+        return [s.output_tokens() for s in seqs], tails
+
+    def dirty(seq_ids, positions, off):
+        seq_ids[off:] = rng.randint(0, 4, len(seq_ids) - off)
+        positions[off:] = rng.randint(0, 64, len(seq_ids) - off)
+
+    named = LLMEngine._name_tail
+    want, tails = serve(named)
+    assert any(0 < n < len(sids) for n, sids, _ in tails)
+    for n, sids, poss in tails:
+        if n:
+            assert (sids[n:] == sids[n - 1]).all() and not poss[n:].any()
+    assert serve(lambda *a: None)[0] == want        # what steps left
+    assert serve(dirty)[0] == want
+
+
 # -------------------------------------------- stacked pools (ISSUE 27) --
 
 STACK_L = 4
@@ -383,20 +552,22 @@ def test_decode_flat_hands_the_kernel_whole_pools(kv_dtype, monkeypatch):
     ``[L, N, bs, H, D]`` shape, and NO equation anywhere produces an
     array shaped like one layer's pages — a slice handed to a custom
     call is materialized. (Quantized pages: the scale pools are
-    sliced per layer on purpose, see ``ragged_flat_attention``.)"""
+    sliced per layer on purpose, see ``ragged_flat_attention``.) Four
+    heads of 128: a page Mosaic can copy by itself, which is where the
+    kernel is taken on a TPU."""
     import jax
     from mxnet_tpu.ops import ragged_attention as ra
     from mxnet_tpu.serving.llm.model import DecoderConfig, TinyDecoder
     monkeypatch.setattr(ra, "_on_tpu", lambda: True)
     L, N, T, S, MB = 3, 9, 6, 4, 4
     model = TinyDecoder(DecoderConfig(
-        vocab_size=17, d_model=32, num_layers=L, num_heads=2, d_ff=32,
+        vocab_size=17, d_model=512, num_layers=L, num_heads=4, d_ff=32,
         max_context=MB * BS))
     params = model.init_params(seed=0)
-    pool = jnp.zeros((L, N, BS, 2, 16), kv_dtype)
+    pool = jnp.zeros((L, N, BS, 4, 128), kv_dtype)
     scales = {}
     if kv_dtype != "float32":
-        sc = jnp.ones((L, N, BS, 2), jnp.float32)
+        sc = jnp.ones((L, N, BS, 4), jnp.float32)
         scales = dict(k_scales=sc, v_scales=sc)
     ints = jnp.zeros((T,), jnp.int32)
     closed = jax.make_jaxpr(
