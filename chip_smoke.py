@@ -16,7 +16,12 @@ supports, with random weights made from a seed:
               cell, against its reference on a few rows, timed a call;
 - **ragged**  the flat K/V attention kernel alone, at the widths and the
               four packed shapes of the ``cerebras_gpt_1b3`` cells, over
-              float32 and int8 pages, likewise.
+              float32 and int8 pages, likewise; then grouped queries (32
+              heads over 8 of 64, bf16 packed rows) at the
+              ``granite_4_0_h_micro`` cell's;
+- **ssd**     the state-space step kernel alone over that cell's state
+              pool (36 layers x 32 slots x 2 MB), against the per-token
+              recurrence, timed a call.
 
 Every phase checks what came out and prints one JSON line; a failed check
 raises, so the exit code is non-zero. The last line of standard output is
@@ -37,7 +42,7 @@ import json
 import sys
 import time
 
-PHASES = ("train", "serve", "kernels", "latent", "ragged")
+PHASES = ("train", "serve", "kernels", "latent", "ragged", "ssd")
 
 #: kernel-vs-reference bounds, fixed from the dtype before any chip run.
 #: bf16 flash attention: the repo's own bf16 bound (tests/test_attention.py).
@@ -53,6 +58,9 @@ FLASH_BF16_TOL = 0.05
 RAGGED_F32_TOL = 0.02
 WQ_MATMUL_TOL = 1e-2
 LATENT_TOL = 0.05
+#: the state-space step: the same float32 operations in kernel and
+#: recurrence, but for the order of the sum over the state's 128 columns
+SSD_TOL = 1e-5
 
 
 def check(ok, what):
@@ -609,6 +617,115 @@ def ragged_phase(cases, layers, num_blocks, block_size, heads, head_dim,
         RAGGED_F32_TOL, reps, interpret=interpret)
 
 
+def gqa_phase(cases, layers, num_blocks, block_size, heads, kv_heads,
+              head_dim, dtype="bfloat16", reps=50, interpret=False):
+    """Grouped queries alone over ONE pool of packed rows ``[layers,
+    num_blocks, block_size, 2 x kv_heads x head_dim]`` (see
+    :func:`_flat_kernel_alone`), contexts as the conversation cells draw
+    them, the scale Granite's 1/64."""
+    import jax
+    from mxnet_tpu.ops.ragged_attention import (
+        gqa_flat_attention, gqa_flat_attention_reference)
+
+    scale = 1.0 / 64
+    pool = jax.random.normal(
+        jax.random.PRNGKey(9), (layers, num_blocks, block_size,
+                                2 * kv_heads * head_dim)).astype(dtype)
+
+    def attend(q, tables, sids, poss, layer, pool):
+        # queries as large as the cell's, whose wq is four times fan-in
+        return gqa_flat_attention(
+            4 * q, pool, tables, sids, poss, scale, kv_heads, layer=layer,
+            use_pallas=True, interpret=interpret)
+
+    def reference(q, tables, sids, poss, layer, pool):
+        return gqa_flat_attention_reference(
+            4 * q, pool, tables, sids, poss, scale, kv_heads, layer=layer)
+
+    return _flat_kernel_alone(
+        "ragged_gqa", [(T, MB, 1046.0) for T, MB in cases],
+        (heads, head_dim), (pool,), attend, reference, LATENT_TOL, reps,
+        interpret=interpret, heads=heads, kv_heads=kv_heads)
+
+
+def ssd_phase(cases, layers, slots, heads, head_dim, state, chunk=16,
+              reps=36, interpret=False):
+    """The state-space step kernel alone over a pool ``[layers, slots,
+    state, heads x head_dim]``: for each ``T`` of ``cases`` a packed step
+    as the engine lays it out (whole prefill chunks of ``chunk`` tokens
+    in the first slots, some from position 0, one decode token in each
+    of the others, the rest padding), the kernel against the per-token
+    recurrence on one layer (outputs of the step's own rows, that layer
+    of the pool, and every other layer untouched), then ``reps`` calls
+    in one program, a layer after the other, timed."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from mxnet_tpu.ops.ssd import ssd_state_step
+
+    started, c0 = time.monotonic(), _counters()
+    width = heads * head_dim
+    pool = 0.1 * jax.random.normal(jax.random.PRNGKey(3),
+                                   (layers, slots, state, width),
+                                   jnp.float32)
+    layer = layers // 2
+    ms, errs, run_s = {}, {}, 0.0
+    for T in cases:
+        rs = np.random.RandomState(T)
+        chunks = min(slots, max(0, T - slots) // (chunk - 1))
+        lens = [chunk] * chunks + [1] * min(slots - chunks, T)
+        sids, poss = [], []
+        for slot, n in zip(rs.permutation(slots), lens):
+            at = 0 if n > 1 and rs.rand() < 0.5 else int(rs.randint(1, 4000))
+            sids += [int(slot)] * n
+            poss += list(range(at, at + n))
+        valid = len(sids)
+        pad = T - valid
+        sids, poss = sids + [sids[-1]] * pad, poss + [0] * pad
+        live = jnp.asarray([1] * valid + [0] * pad, jnp.int32)
+        sids, poss = jnp.asarray(sids, jnp.int32), jnp.asarray(poss,
+                                                               jnp.int32)
+        ks = jax.random.split(jax.random.PRNGKey(T), 4)
+        x = jax.random.normal(ks[0], (T, heads, head_dim), jnp.float32)
+        dt = jax.nn.softplus(jax.random.normal(ks[1], (T, heads)) - 4.0)
+        A = -jnp.linspace(1.0, 16.0, heads)
+        B, C = (jax.random.normal(k, (T, state), jnp.float32)
+                for k in ks[2:])
+
+        def step(pool, lyr, use_pallas):
+            return ssd_state_step(x, dt, A, B, C, pool, sids, poss, live,
+                                  lyr, use_pallas=use_pallas,
+                                  interpret=interpret)
+
+        got_y, got = jax.jit(lambda p: step(p, layer, True))(pool)
+        want_y, want = jax.jit(lambda p: step(p, layer, False))(pool)
+        name = f"T{T}"
+        errs[name] = max(_rel_err(got_y[:valid], want_y[:valid]),
+                         _rel_err(got[layer], want[layer]))
+        check(errs[name] <= SSD_TOL,
+              f"ssd {name}: error {errs[name]:.3g} over bound {SSD_TOL}")
+        others = [i for i in range(layers) if i != layer]
+        check(bool(jnp.array_equal(got[jnp.asarray(others)],
+                                   pool[jnp.asarray(others)])),
+              f"ssd {name}: a layer that the step did not name changed")
+        del got, want
+        many = jax.jit(lambda p: jax.lax.fori_loop(
+            0, reps, lambda i, c: (step(c[0], i % layers, True)[1],
+                                   c[1] + 1), (p, 0))[0],
+            donate_argnums=(0,))
+        pool = jax.block_until_ready(many(pool))            # compile
+        t0 = time.monotonic()
+        pool = jax.block_until_ready(many(pool))
+        dt_s = time.monotonic() - t0
+        run_s += dt_s
+        ms[name] = round(dt_s / reps * 1e3, 4)
+    return _report(
+        "ssd", started, c0, time.monotonic() - started - run_s, run_s,
+        pool=list(pool.shape), reps=reps, ms_per_call=ms,
+        rel_err={k: float(f"{e:.3g}") for k, e in errs.items()},
+        interpret=interpret)
+
+
 # ------------------------------------------------------------------- main --
 
 def main(argv=None):
@@ -618,8 +735,8 @@ def main(argv=None):
                          "four-chip mesh in this one process")
     ap.add_argument("--phases", default=",".join(PHASES),
                     help="the phases to run, comma separated, of "
-                         + ", ".join(PHASES) + " (kernels, latent and ragged: "
-                         "one chip only)")
+                         + ", ".join(PHASES) + " (kernels, latent, ragged and "
+                         "ssd: one chip only)")
     args = ap.parse_args(argv)
     phases = args.phases.split(",")
     if set(phases) - set(PHASES):
@@ -675,6 +792,16 @@ def main(argv=None):
         for dtype in ("float32", "int8"):
             ragged_phase(cases, layers=24, num_blocks=1025, block_size=16,
                          heads=16, head_dim=128, dtype=dtype)
+        # the granite_4_0_h_micro cell's pool of packed rows; its steps
+        # pack 32, 47, 62 or 512 tokens over tables of 256 or 512 pages
+        gqa_phase(cases=((32, 256), (47, 512), (512, 512)), layers=4,
+                  num_blocks=16385, block_size=16, heads=32, kv_heads=8,
+                  head_dim=64)
+    if "ssd" in phases and not many:
+        # the same cell's state pool: 32 decode rows, one chunk beside 31
+        # decodes, 32 chunks
+        ssd_phase(cases=(32, 47, 512), layers=36, slots=32, heads=64,
+                  head_dim=64, state=128)
     dev = devices[0]
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,

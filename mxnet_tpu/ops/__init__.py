@@ -21,6 +21,7 @@ from . import optimizer_ops  # noqa: F401
 from . import quantization  # noqa: F401
 from . import lora          # noqa: F401
 from . import moe           # noqa: F401
+from . import ssd           # noqa: F401
 from .invoke import apply_op, apply_fn  # noqa: F401
 # mx.operator registers the 'Custom' op (user Python ops over
 # jax.pure_callback); import it before the nd namespace is generated

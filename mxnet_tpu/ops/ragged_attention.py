@@ -14,7 +14,7 @@ positions[t]`` of its own sequence. A model's pools are stacked over
 layers and handed over whole with ``layer=`` (a ``pool[li]`` given to
 a custom call is a copy).
 
-Two kernel families, each with the plain ``jnp`` reference it is
+Two kernels, three entries, each with the plain ``jnp`` reference it is
 pinned against (tests/test_ragged_attention.py, test_llm_mla_moe.py)
 and that runs in its place off the TPU; ``use_pallas=True`` off the
 TPU runs the kernel in interpret mode:
@@ -26,6 +26,11 @@ TPU runs the kernel in interpret mode:
 - ``mla_flat_attention`` (``_reference``): one pool of latents ``[L,
   N, bs, W]`` that every head reads (MLA, absorbed form). See
   ``_mla_flat_kernel``.
+
+- ``gqa_flat_attention`` (``_reference``): grouped queries at any head
+  size over ONE pool of packed rows ``[L, N, bs, 2 x kv_heads x D]``
+  (a token's value heads, then its key heads), through
+  ``_mla_flat_kernel``'s walk.
 
 Both kernels walk the same way. A grid over blocks of query tokens;
 the tables and the per-token scalars are scalar-prefetch operands; in
@@ -744,9 +749,10 @@ def _mla_flat_kernel(layer_ref, sid_ref, pos_ref, bt_ref, q_ref, pool_ref,
     o_ref[...] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnums=(6, 7, 8))
-def _mla_flat_pallas(q, pages, block_tables, seq_ids, positions, layer,
-                     scale, v_dim, interpret):
+def _latent_walk(q, pages, block_tables, seq_ids, positions, layer, scale,
+                 v_dim, interpret):
+    """The call of ``_mla_flat_kernel``, for the two jitted entries that
+    give it their names in a device trace."""
     T, H, W = q.shape
     bs = pages.shape[2]
     S, MB = block_tables.shape
@@ -784,6 +790,13 @@ def _mla_flat_pallas(q, pages, block_tables, seq_ids, positions, layer,
     )(layer.astype(jnp.int32).reshape(1), sid, pos,
       block_tables.astype(jnp.int32), q.reshape(T * H, W), pages)
     return out.reshape(T, H, v_dim)
+
+
+@functools.partial(jax.jit, static_argnums=(6, 7, 8))
+def _mla_flat_pallas(q, pages, block_tables, seq_ids, positions, layer,
+                     scale, v_dim, interpret):
+    return _latent_walk(q, pages, block_tables, seq_ids, positions, layer,
+                        scale, v_dim, interpret)
 
 
 def mla_flat_attention(q, pages, block_tables, seq_ids, positions,
@@ -841,6 +854,103 @@ def mla_flat_attention(q, pages, block_tables, seq_ids, positions,
     return _mla_flat_pallas(q, pages, block_tables, seq_ids, positions,
                             jnp.asarray(layer), float(scale), int(v_dim),
                             bool(interpret))
+
+
+# ------------------------------- grouped queries over packed K/V rows --
+def gqa_flat_attention_reference(q, pages, block_tables, seq_ids,
+                                 positions, scale, kv_heads, layer=None):
+    """Gather-based oracle of :func:`gqa_flat_attention`: every query
+    token's pages gathered through its sequence's table row and split
+    into the ``kv_heads`` value and key heads, query head ``j`` against
+    kv head ``j // (H // kv_heads)``, one masked softmax. Everything in
+    float32."""
+    T, H, D = q.shape
+    G = kv_heads
+    bs = pages.shape[-2]
+    MB = block_tables.shape[1]
+    tbl = block_tables[seq_ids]                       # (T, MB)
+    kv = pages[(tbl,) if layer is None else (layer, tbl)]
+    kv = kv.reshape(T, MB * bs, 2, G, D).astype(jnp.float32)
+    qg = q.astype(jnp.float32).reshape(T, G, H // G, D)
+    logits = jnp.einsum("tgmd,tkgd->gmtk", qg, kv[:, :, 1],
+                        precision=jax.lax.Precision.HIGHEST) * scale
+    pos = jnp.arange(MB * bs, dtype=jnp.int32)
+    mask = pos[None, None, None, :] <= positions[None, None, :, None]
+    probs = jax.nn.softmax(jnp.where(mask, logits, _NEG_INF), axis=-1)
+    out = jnp.einsum("gmtk,tkgd->tgmd", probs, kv[:, :, 0],
+                     precision=jax.lax.Precision.HIGHEST)
+    return out.reshape(T, H, D).astype(q.dtype)
+
+
+@functools.partial(jax.jit, static_argnums=(6, 7, 8))
+def _gqa_flat_pallas(q, pages, block_tables, seq_ids, positions, layer,
+                     scale, kv_heads, interpret):
+    """Grouped queries through the latent kernel's walk: a page row is
+    ``[V | K]``, each the ``G`` kv heads side by side, and every query
+    head is laid out over the row's width with zeros everywhere but
+    under its own kv head's keys. The kernel's one product over the
+    row's width is then ``q_j . k_(j // m)``, its values the row's
+    first half, and head ``j`` keeps its kv head's ``D`` columns of
+    what comes back. One page DMA serves all the heads; the products
+    are ``G`` times wider than the group needs (MXU work on zeros)."""
+    T, H, D = q.shape
+    G = kv_heads
+    eye = jnp.eye(G, dtype=q.dtype)
+    wide = jnp.einsum("tgmd,gk->tgmkd", q.reshape(T, G, H // G, D), eye)
+    wide = wide.reshape(T, H, G * D)
+    out = _latent_walk(
+        jnp.concatenate([jnp.zeros_like(wide), wide], axis=-1), pages,
+        block_tables, seq_ids, positions, layer, scale, G * D, interpret)
+    out = out.reshape(T, G, H // G, G, D)
+    return jnp.einsum("tgmkd,gk->tgmd", out, eye).reshape(T, H, D)
+
+
+def gqa_flat_attention(q, pages, block_tables, seq_ids, positions, scale,
+                       kv_heads, layer=None, use_pallas=None,
+                       interpret=None):
+    """Flat-ragged attention of ``H`` query heads over ``kv_heads``
+    shared K/V heads of any head size (grouped-query attention; ``H ==
+    kv_heads`` is plain multi-head attention).
+
+    ``q [T, H, D]``; ``pages`` ONE pool of rows ``[L, N, bs, 2 *
+    kv_heads * D]`` with ``layer`` (stacked, read in place) or ``[N,
+    bs, ...]`` without: a token's row is its value heads, then its key
+    heads, each ``kv_heads * D`` wide, so that a page is whole lane
+    tiles at any head size (Mosaic copies a page out of a pool by whole
+    tiles, and refuses ``[bs, heads, 64]``) and one copy brings K and V
+    of every head. Query head ``j`` attends kv head ``j // (H //
+    kv_heads)`` causally over positions ``<= positions[t]`` of its
+    sequence; ``scale`` is the model's to say. Returns ``[T, H, D]``.
+
+    The packed batch is walked as :func:`mla_flat_attention` walks it
+    (the same kernel body, ``_mla_flat_kernel``, under this entry's own
+    name in a device trace): a run's pages once for all its query
+    tokens and heads, only up to the page of its largest position, and
+    the same guarantees for padded and stale tokens. Gated alike: Mosaic
+    on a TPU, the gather reference elsewhere, interpret mode on
+    request."""
+    if use_pallas is None:
+        use_pallas = _on_tpu()
+    if pages.ndim != (3 if layer is None else 4) \
+            or pages.shape[-1] != 2 * kv_heads * q.shape[-1] \
+            or q.shape[1] % kv_heads:
+        raise ValueError(
+            "the pool is [L, N, bs, 2 * kv_heads * D] with `layer`, [N, "
+            f"bs, ...] without, and kv_heads divides H; got q {q.shape}, "
+            f"pages {pages.shape}, kv_heads={kv_heads}, layer={layer!r}")
+    block_tables = jnp.asarray(block_tables)
+    seq_ids, positions = jnp.asarray(seq_ids), jnp.asarray(positions)
+    if not use_pallas:
+        return gqa_flat_attention_reference(
+            q, pages, block_tables, seq_ids, positions, float(scale),
+            int(kv_heads), layer=layer)
+    if interpret is None:
+        interpret = not _on_tpu()
+    if layer is None:
+        layer, pages = 0, pages[None]
+    return _gqa_flat_pallas(q, pages, block_tables, seq_ids, positions,
+                            jnp.asarray(layer), float(scale),
+                            int(kv_heads), bool(interpret))
 
 
 @register("mla_flat_attention", differentiable=False)

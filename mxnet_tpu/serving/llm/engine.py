@@ -105,8 +105,10 @@ def _make_step_fn(model, spec_k, sampled, quantized=False, lora=False,
     for plain rows that is one sampled/argmax token.
 
     The pools are the model's own (``model.cache_layout``): K and V
-    pages, or ONE pool of latents; they follow the params, are donated
-    and come back in their order. ``aux`` is whatever
+    pages, or ONE pool of latents or of packed K/V rows, then the slot
+    state pools of a layout that has ``state`` (handed to the model as
+    ``state=``); they follow the params, are donated and come back in
+    their order. ``aux`` is whatever
     :meth:`model.decode_flat` returns after its pools (a model with
     expert layers: their load, int32 ``[3]``), ``None`` (no output at
     all) for a model that returns nothing there; it sits ahead of the
@@ -169,7 +171,8 @@ def _make_step_fn(model, spec_k, sampled, quantized=False, lora=False,
             top_k, top_p, accept_keys, sample_keys)
 
     layout = getattr(model, "cache_layout", None) or {"kind": "kv"}
-    n_pools = 1 if layout["kind"] == "latent" else 4 if quantized else 2
+    n_pages = 4 if quantized else 2 if layout["kind"] == "kv" else 1
+    n_pools = n_pages + len(layout.get("state", ()))
 
     def step(params, *args):
         # params, the pools, [the bank's A/B pools,] the 14 batch
@@ -183,9 +186,12 @@ def _make_step_fn(model, spec_k, sampled, quantized=False, lora=False,
                                      a_scales))
         if quantized:
             mkw = dict(mkw, k_scales=pools[2], v_scales=pools[3])
+        if n_pools > n_pages:
+            mkw = dict(mkw, state=pools[n_pages:])
         tokens, positions, seq_ids, valid, block_tables, *accept = args
-        # a latent pool goes where the K pages go, and has no V pool
-        k_pages, v_pages = (*pools, None)[:2]
+        # a pool of rows (latents, packed K/V) goes where the K pages
+        # go, and has no V pool
+        k_pages, v_pages = (*pools[:n_pages], None)[:2]
         logits, *pools2 = model.decode_flat(
             p, tokens, positions, seq_ids, valid, k_pages, v_pages,
             block_tables, **mkw)
@@ -420,11 +426,27 @@ class LLMEngine:
       feature that needs them: ``k_scales=, v_scales=`` (quantized
       pages; their updated scale pools come back after the pages),
       ``adapter=`` (an adapter bank), ``axis_name=`` (a tp mesh),
-      ``w_scales=`` (quantized weights).
+      ``w_scales=`` (quantized weights), ``state=`` (the slot state
+      pools of a layout that has any, in the layout's order; they come
+      back after the pages, in that order).
     - ``cache_layout``, what a token leaves in one layer of the cache:
-      ``{"kind": "kv", "num_heads": H, "head_dim": D}`` or ``{"kind":
-      "latent", "latent_dim": W}``; absent, K and V pools of
-      ``[num_heads, head_dim]``.
+      ``{"kind": "kv", "num_heads": H, "head_dim": D}``, ``{"kind":
+      "latent", "latent_dim": W}`` or ``{"kind": "kv_packed",
+      "num_heads": G, "head_dim": D}`` (one pool of rows of ``2 x G x
+      D``); absent, K and V pools of ``[num_heads, head_dim]``. With
+      ``"layers": n`` only ``n`` layers leave pages. With ``"state":
+      {name: (layers, *shape)}`` every SLOT (a row of
+      ``block_tables``, what ``seq_ids`` names) also holds a fixed
+      float32 state a layer that its tokens overwrite: the engine keeps
+      the pools ``[layers, max_seqs, *shape]``, donates them through
+      the step like the pages and never clears a slot. The model starts
+      a run whose first token sits at position 0 from zeros (an
+      admission, also after preemption: both restart at position 0)
+      and lets a token with ``valid == 0`` touch nothing. Such state
+      can neither be resumed from a prefix hit nor rolled back after a
+      rejected draft: the engine keeps the prefix cache off for the
+      model (``prefix_cache=None``) and its ``refused_features`` name
+      ``prefix_cache`` and ``draft_model``.
     - ``vocab_size``, ``max_context``, ``num_layers``, ``num_heads``,
       ``head_dim``: ints.
     - Optional: ``param_specs(axis=)`` and ``weight_scale_specs(
@@ -557,9 +579,15 @@ class LLMEngine:
         # MXNET_TPU_LLM_PREFIX_CACHE env > on. Hits only rewrite host
         # state (block tables, start offsets) — cache hit vs miss can
         # never change a program shape.
+        # A model whose slots hold state has nothing to resume a
+        # prefix hit from: asked for, the cache is refused; left to
+        # the default, it is off for that model (``stats()`` says so).
+        self._refuse_given("prefix_cache", prefix_cache is True,
+                           "a prefix cache (a hit has no slot state to "
+                           "resume from)")
         if prefix_cache is None:
-            prefix_cache = bool(_env_int("MXNET_TPU_LLM_PREFIX_CACHE",
-                                         1))
+            prefix_cache = "prefix_cache" not in self._refused and bool(
+                _env_int("MXNET_TPU_LLM_PREFIX_CACHE", 1))
         self.prefix_enabled = bool(prefix_cache)
         # quantized KV storage: constructor arg >
         # MXNET_TPU_LLM_KV_DTYPE env > the float `dtype` arg. "fp8"
@@ -582,12 +610,14 @@ class LLMEngine:
         # V pools of [H, D] a slot, or one pool of latents, which is
         # never quantized
         layout = getattr(model, "cache_layout", None) or {"kind": "kv"}
-        if (layout["kind"] == "latent"
+        if (layout["kind"] != "kv"
                 and kv_dtype in PagedKVCache.QUANTIZED_DTYPES):
             raise UnsupportedModelFeatureError(
-                f"kv_dtype: a latent pool does not take {kv_dtype} pages")
+                f"kv_dtype: a {layout['kind']} pool does not take "
+                f"{kv_dtype} pages")
         self.cache = PagedKVCache.for_model(
-            model, block_size, num_blocks, max_context, dtype=kv_dtype,
+            model, block_size, num_blocks, max_context,
+            max_seqs=self.max_seqs, dtype=kv_dtype,
             prefix_cache=self.prefix_enabled, mesh=self.mesh)
         self.quantized = self.cache.quantized
         self.scheduler = Scheduler(self.max_seqs)
@@ -802,6 +832,14 @@ class LLMEngine:
         # the last dispatch's expert load (a model with expert
         # layers), host ints
         self._moe_load = None
+        # a model whose slots hold state: rows whose state a step
+        # advanced, and runs begun at position 0, over the engine's life
+        self._has_state = bool(self.cache.state_pools)
+        self.state_rows = 0
+        self.state_starts = 0
+        if self._has_state and self._stats is not None:
+            self._stats.record_state_pool(
+                self.max_seqs, self.cache.state_stats()["bytes"])
         # (source pools, replicated copies) — see _replicated_lora
         self._lora_placed = None
         if self.mesh is not None and self._stats is not None:
@@ -1890,7 +1928,7 @@ class LLMEngine:
         for cache in (self.cache, self.draft_cache):
             if cache is None:
                 continue
-            is_del = getattr(cache.k_pages, "is_deleted", None)
+            is_del = getattr(cache.pools()[0], "is_deleted", None)
             try:
                 if bool(is_del and is_del()):
                     return True
@@ -1978,6 +2016,8 @@ class LLMEngine:
                     isp.set("n", len(rows))
                     decoded = self._isolate(rows, plans, events)
             else:
+                if self._has_state:
+                    self._record_state(sp, rows, plans)
                 decoded = self._commit(rows, plans, *out, events)
                 if self._moe_load is not None and sp.span_id is not None:
                     # over the step's expert layers: pairs and experts
@@ -1997,6 +2037,30 @@ class LLMEngine:
                                 "decoded": decoded,
                                 "step_ms": round(step_s * 1e3, 3)})
             self._record_block_gauges()
+
+    def state_pool_stats(self):
+        """The slot state pools and what the steps did to them, for
+        ``stats()`` and ``debug_status()``: slots, shapes and bytes
+        (all zero or empty for a model without slot state), rows
+        advanced and runs started over the engine's life."""
+        return dict(self.cache.state_stats(), rows=self.state_rows,
+                    starts=self.state_starts)
+
+    def _record_state(self, sp, rows, plans):
+        """What a dispatched step did to the slot state pools, before
+        the commit moves ``seq_len``: every row's state advanced, a row
+        at position 0 began a run from zeros (an admission or a
+        re-admission), a row of more than one token ran a prefill
+        chunk through the recurrence."""
+        starts = sum(1 for s in rows if s.seq_len == 0)
+        self.state_rows += len(rows)
+        self.state_starts += starts
+        if self._stats:
+            self._stats.record_state_step(len(rows), starts)
+        if sp.span_id is not None:
+            sp.set("state_rows", len(rows))
+            sp.set("state_runs_prefill", sum(
+                1 for s in rows if len(plans[s]["tokens"]) > 1))
 
     def pop_finished(self):
         """Drain the finished-but-unreported sequences. The server
@@ -2080,6 +2144,7 @@ class LLMEngine:
                          "step_variants": len(self._step_jits),
                          "spec_k": self.spec_k,
                          "prefill_chunk": self.prefill_chunk},
+            "state_pool": self.state_pool_stats(),
             "prefix_cache": {"enabled": self.prefix_enabled,
                              "lookups": self.prefix_lookups,
                              "hits": self.prefix_hits,

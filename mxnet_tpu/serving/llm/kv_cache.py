@@ -318,6 +318,16 @@ class PagedKVCache:
     layout at fp8 width — the write path scales into ±448 and lets
     the cast round, the dequant multiply is identical.
 
+    ``latent_dim`` makes the pool ONE array of rows ``[num_layers,
+    num_blocks, block_size, W]`` with no head axis and no V pool: a
+    latent cache, or (``kind="kv_packed"``) a token's value heads and
+    then its key heads. ``state`` adds, beside the pages, float32 pools
+    ``[layers, state_slots, *shape]`` of what each SLOT (a sequence's
+    row of the block tables) holds and overwrites token by token (a
+    state-space layer's recurrent state, its convolution's window):
+    :meth:`pools` lists them after the pages and :meth:`swap` takes them
+    back; no block, table or hash knows of them.
+
     ``prefix_cache=True`` enables the content-addressed prefix index:
     :meth:`register` maps a chained block hash to a live block,
     :meth:`prefix_get` answers hit lookups, and LRU reclaims (the
@@ -330,34 +340,46 @@ class PagedKVCache:
     LANES = 128
 
     @classmethod
-    def for_model(cls, model, block_size, num_blocks, max_context, **kw):
-        """The pool a model's layers write: shaped by its
+    def for_model(cls, model, block_size, num_blocks, max_context,
+                  max_seqs=0, **kw):
+        """The pools a model's layers write: shaped by its
         ``cache_layout`` (``TinyDecoder.cache_layout``), or K and V
-        pools of ``[num_heads, head_dim]`` for a model without one."""
+        pools of ``[num_heads, head_dim]`` for a model without one.
+        ``max_seqs`` is how many slots a layout's ``state`` is held
+        for."""
         layout = getattr(model, "cache_layout", None) or {
             "kind": "kv", "num_heads": model.num_heads,
             "head_dim": model.head_dim}
+        layers = layout.get("layers", model.num_layers)
+        if "state" in layout:
+            kw.update(state=layout["state"], state_slots=max_seqs)
         if layout["kind"] == "latent":
-            return cls(model.num_layers, 0, 0, block_size, num_blocks,
-                       max_context, latent_dim=layout["latent_dim"], **kw)
-        return cls(model.num_layers, layout["num_heads"],
-                   layout["head_dim"], block_size, num_blocks,
-                   max_context, **kw)
+            return cls(layers, 0, 0, block_size, num_blocks, max_context,
+                       latent_dim=layout["latent_dim"], **kw)
+        if layout["kind"] == "kv_packed":
+            # one pool of rows, no head axis: the value heads, then the
+            # key heads
+            return cls(layers, 0, 0, block_size, num_blocks, max_context,
+                       latent_dim=2 * layout["num_heads"]
+                       * layout["head_dim"], kind="kv_packed", **kw)
+        return cls(layers, layout["num_heads"], layout["head_dim"],
+                   block_size, num_blocks, max_context, **kw)
 
     def __init__(self, num_layers, num_heads, head_dim, block_size,
                  num_blocks, max_context, dtype="float32",
                  prefix_cache=False, mesh=None, shard_axis="tp",
-                 latent_dim=0):
+                 latent_dim=0, kind=None, state=None, state_slots=0):
         import jax.numpy as jnp
         if max_context < 1:
             raise ValueError(f"max_context must be >= 1, {max_context}")
         self.num_layers = int(num_layers)
         self.num_heads = int(num_heads)
         self.head_dim = int(head_dim)
-        # "kv": K and V pools of [num_heads, head_dim] a slot; "latent":
-        # ONE pool of ``latent_dim`` values a slot, no head axis
+        # "kv": K and V pools of [num_heads, head_dim] a slot; "latent"
+        # and "kv_packed": ONE pool of ``latent_dim`` values a slot, no
+        # head axis
         self.latent_dim = int(latent_dim)
-        self.kind = "latent" if self.latent_dim else "kv"
+        self.kind = kind or ("latent" if self.latent_dim else "kv")
         self.block_size = int(block_size)
         self.num_blocks = int(num_blocks)
         self.max_context = int(max_context)
@@ -387,7 +409,24 @@ class PagedKVCache:
         self.heads_per_shard = self.num_heads // self.shards
         self.k_pages = self.v_pages = self.latent_pages = None
         self.k_scales = self.v_scales = None
-        if self.kind == "latent":
+        # what a SLOT holds beside its pages (a state-space layer's
+        # recurrent state, its convolution's window): float32 pools
+        # ``[layers, state_slots, *shape]`` that a sequence's tokens
+        # overwrite, carried through every token of a sequence and so
+        # never in a narrower dtype. A run that starts at position 0
+        # reads zeros, so no slot is ever cleared here.
+        self.state_slots = int(state_slots)
+        self.state_pools = {}
+        if state:
+            if self.state_slots < 1 or mesh is not None or prefix_cache:
+                raise KVCacheError(
+                    "slot state needs state_slots >= 1 and is neither "
+                    "sharded nor shared by a prefix cache")
+            self.state_pools = {
+                name: jnp.zeros((shape[0], self.state_slots)
+                                + tuple(shape[1:]), jnp.float32)
+                for name, shape in state.items()}
+        if self.latent_dim:
             # [L, N, bs, Wp]: no V pool (the values are the latent's own
             # first columns). ``latent_dim`` values a slot, zero-padded
             # to whole lanes: at a minor axis of 576 XLA:TPU lays a
@@ -399,8 +438,11 @@ class PagedKVCache:
             if self.quantized or mesh is not None:
                 raise KVCacheError(
                     "a latent pool is neither quantized nor sharded")
-            self.pool_width = -(-self.latent_dim // self.LANES) \
-                * self.LANES
+            # (packed K/V rows are whole lanes at the widths served:
+            # the attention op takes them unpadded)
+            self.pool_width = self.latent_dim \
+                if self.kind == "kv_packed" \
+                else -(-self.latent_dim // self.LANES) * self.LANES
             self.latent_pages = jnp.zeros(
                 (self.num_layers, self.num_blocks, self.block_size,
                  self.pool_width), dtype=jnp.dtype(self.dtype))
@@ -490,22 +532,26 @@ class PagedKVCache:
     # ------------------------------------------------------ storage --
     def pools(self):
         """The device arrays a step program takes, donates and returns,
-        in its order: ``(k_pages, v_pages[, k_scales, v_scales])``, or
-        ``(latent_pages,)``."""
-        if self.kind == "latent":
-            return (self.latent_pages,)
-        if self.quantized:
-            return (self.k_pages, self.v_pages, self.k_scales,
-                    self.v_scales)
-        return (self.k_pages, self.v_pages)
+        in its order: the pools of pages, ``(k_pages, v_pages[,
+        k_scales, v_scales])`` or ``(latent_pages,)``, then the slot
+        state pools in the layout's order."""
+        if self.latent_dim:
+            pages = (self.latent_pages,)
+        elif self.quantized:
+            pages = (self.k_pages, self.v_pages, self.k_scales,
+                     self.v_scales)
+        else:
+            pages = (self.k_pages, self.v_pages)
+        return pages + tuple(self.state_pools.values())
 
     def layout(self):
         """The pool's layout and what a token costs in it, for
         ``stats()``: bytes over all layers, as allocated (a latent's
         lane padding and a quantized pool's scales included)."""
         itemsize = self.dtype.itemsize
-        if self.kind == "latent":
-            return {"kind": "latent", "values_per_token": self.latent_dim,
+        if self.latent_dim:
+            return {"kind": self.kind,
+                    "values_per_token": self.latent_dim,
                     "pool_shape": list(self.latent_pages.shape),
                     "bytes_per_token": self.num_layers * self.pool_width
                     * itemsize}
@@ -520,7 +566,11 @@ class PagedKVCache:
         """Install the updated buffers a donated program returned, in
         the order of :meth:`pools` (the quantization scales with the
         pages when the pool is quantized)."""
-        if self.kind == "latent":
+        if self.state_pools:
+            n = len(pools) - len(self.state_pools)
+            self.state_pools = dict(zip(self.state_pools, pools[n:]))
+            pools = pools[:n]
+        if self.latent_dim:
             (self.latent_pages,) = pools
             return
         self.k_pages, self.v_pages = pools[:2]
@@ -617,3 +667,13 @@ class PagedKVCache:
             "shards": self.shards,
             "heads_per_shard": self.heads_per_shard,
         }
+
+    def state_stats(self):
+        """The slot state pools, for ``stats()``: how many slots, each
+        pool's shape, and the bytes of all of them (what a deployment
+        pays for its sessions whatever their contexts)."""
+        return {"slots": self.state_slots if self.state_pools else 0,
+                "pools": {k: list(v.shape)
+                          for k, v in self.state_pools.items()},
+                "bytes": int(sum(v.nbytes
+                                 for v in self.state_pools.values()))}

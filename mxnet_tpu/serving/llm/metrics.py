@@ -250,6 +250,29 @@ class LLMStats:
             "layer of a step.", lbl,
             buckets=(1, 2, 4, 8, 16, 32, 64, 128, 256, 512,
                      1024)).labels(**s)
+        self._state_rows = r.counter(
+            "mxtpu_llm_state_rows_total",
+            "Rows (sequences) whose slot state a dispatched step "
+            "advanced (models whose slots hold state).",
+            lbl).labels(**s)
+        self._state_steps = r.counter(
+            "mxtpu_llm_state_steps_total",
+            "Dispatched steps of a model whose slots hold state: what "
+            "mxtpu_llm_state_rows_total is a mean over.",
+            lbl).labels(**s)
+        self._state_starts = r.counter(
+            "mxtpu_llm_state_starts_total",
+            "Runs begun at position 0, from a state of zeros: "
+            "admissions and re-admissions after preemption.",
+            lbl).labels(**s)
+        self._state_slots = r.gauge(
+            "mxtpu_llm_state_slots",
+            "Slots the state pools hold a state for (max_seqs; 0 for "
+            "a model without slot state).", lbl).labels(**s)
+        self._state_bytes = r.gauge(
+            "mxtpu_llm_state_pool_bytes",
+            "Device bytes of the slot state pools, all layers and "
+            "slots.", lbl).labels(**s)
         # the overload/failure series share the single-shot server's
         # mxtpu_serving_* catalog (one dashboard for both front ends)
         self._overload = OverloadStats(r, self._server)
@@ -423,6 +446,18 @@ class LLMStats:
         self._moe_hit.inc(experts_hit)
         self._moe_max_load.observe(max_load)
 
+    def record_state_pool(self, slots, nbytes):
+        """Engine construction, a model whose slots hold state."""
+        self._state_slots.set(int(slots))
+        self._state_bytes.set(int(nbytes))
+
+    def record_state_step(self, rows, starts):
+        """One dispatched step of such a model: the rows whose state it
+        advanced, and those of them that began at position 0."""
+        self._state_rows.inc(rows)
+        self._state_steps.inc()
+        self._state_starts.inc(starts)
+
     # --------------------------------------------- quantized weights --
     def record_weight_quant(self, dtype, weight_bytes,
                             params_per_chip):
@@ -523,6 +558,9 @@ class LLMStats:
                 "moe_routed_pairs": int(self._moe_pairs.value),
                 "moe_experts_hit": int(self._moe_hit.value),
                 "moe_steps": int(self._moe_max_load.count),
+                "state_rows": int(self._state_rows.value),
+                "state_starts": int(self._state_starts.value),
+                "state_steps": int(self._state_steps.value),
                 "adapters_resident": int(
                     self._adapters_resident.value),
                 "adapter_publishes": int(

@@ -2,18 +2,21 @@
 
 What the serving engine calls and reads of a model is stated once, in
 :class:`~.engine.LLMEngine`'s docstring (the model protocol);
-:class:`TinyDecoder` is its reference implementation, for two blocks
-(``DecoderConfig.model_type``). Its entry points:
+:class:`TinyDecoder` is its reference implementation, for three blocks
+(``DecoderConfig.model_type``; each entry point below goes to the
+block's own method through ``TinyDecoder._of_block``). Its entry points:
 
 - :meth:`TinyDecoder.decode_flat`: the step. A packed ``[T]`` batch of
-  query tokens from many sequences against the paged cache; each layer
-  writes its tokens' K/V (or latents) into their page slots, then
-  attends over the block-table-indirected history through
-  :func:`~mxnet_tpu.ops.ragged_attention.ragged_flat_attention` or
-  :func:`~mxnet_tpu.ops.ragged_attention.mla_flat_attention`. A pure
-  function of ``(params, inputs)``; the engine jits it with donated
-  pools, and prefill, decode and speculative verify are all this one
-  call.
+  query tokens from many sequences against the paged cache; each
+  attention layer writes its tokens' K/V (or latents) into their page
+  slots, then attends over the block-table-indirected history through
+  :func:`~mxnet_tpu.ops.ragged_attention.ragged_flat_attention`,
+  :func:`~mxnet_tpu.ops.ragged_attention.mla_flat_attention` or
+  :func:`~mxnet_tpu.ops.ragged_attention.gqa_flat_attention`; each
+  state-space layer advances its sequences' slot states through
+  :func:`~mxnet_tpu.ops.ssd.ssd_state_step`. A pure function of
+  ``(params, inputs)``; the engine jits it with donated pools, and
+  prefill, decode and speculative verify are all this one call.
 - :meth:`TinyDecoder.forward`: the eager oracle. A dense causal forward
   over a whole prefix ``[B, T]``, returning logits and the per-layer
   K/V (or latents) it computed. The engine never calls it; the serving
@@ -31,7 +34,9 @@ import numpy as np
 
 from ...ops.ragged_attention import (ragged_flat_attention,
                                      ragged_flat_attention_sharded,
-                                     mla_flat_attention)
+                                     mla_flat_attention,
+                                     gqa_flat_attention)
+from ...ops.ssd import ssd_state_step, slot_runs
 from ...ops.moe import route_noaux_tc, moe_experts, moe_load
 from ...ops.flash_attention import attention_reference
 from ...ops.lora import (paged_lora_delta, gather_adapter,
@@ -39,10 +44,14 @@ from ...ops.lora import (paged_lora_delta, gather_adapter,
 from ...ops.quantization import quantized_matmul
 
 __all__ = ["DecoderConfig", "TinyDecoder", "greedy_decode_reference",
-           "UnsupportedModelFeatureError", "GPT2", "DEEPSEEK_V3"]
+           "UnsupportedModelFeatureError", "GPT2", "DEEPSEEK_V3",
+           "GRANITE_HYBRID"]
 
-#: the two blocks a :class:`DecoderConfig` can describe (``model_type``)
+#: the blocks a :class:`DecoderConfig` can describe (``model_type``)
 GPT2, DEEPSEEK_V3 = "gpt2", "deepseek_v3"
+GRANITE_HYBRID = "granitemoehybrid"
+#: the kinds of layer a ``granitemoehybrid`` model's ``layer_types`` name
+MAMBA, ATTENTION = "mamba", "attention"
 
 
 class UnsupportedModelFeatureError(ValueError):
@@ -84,17 +93,45 @@ class DecoderConfig:
     (``num_experts_per_tok`` a token, sigmoid scores with a selection
     bias) plus ``n_shared_experts`` shared ones, each of width
     ``moe_intermediate_size``; ``torch_dtype`` is the dtype of the
-    weights (``float32`` or ``bfloat16``)."""
+    weights (``float32`` or ``bfloat16``).
+
+    ``model_type="granitemoehybrid"`` (Granite 4.0-H; its dense members,
+    ``num_local_experts`` 0) is a third block, again under the published
+    keys: ``layer_types`` names each layer's mixer, ``"mamba"`` (a
+    Mamba-2 state-space layer of ``mamba_n_heads`` heads of
+    ``mamba_d_head``, state size ``mamba_d_state``, ``mamba_n_groups``
+    1, a causal convolution ``mamba_d_conv`` wide) or ``"attention"``
+    (``num_heads`` query heads over ``num_key_value_heads`` K/V heads,
+    no positions of any kind, scores times ``attention_multiplier``);
+    every layer then a SwiGLU MLP of width ``d_ff`` (the published
+    ``shared_intermediate_size``); RMSNorm; the embedding times
+    ``embedding_multiplier``, each residual branch times
+    ``residual_multiplier``, the logits over ``logits_scaling``, the
+    head the embedding itself (``tie_word_embeddings``)."""
 
     FIELDS = ("vocab_size", "d_model", "num_layers", "num_heads",
               "d_ff", "max_context")
-    BLOCK_FIELDS = ("model_type", "kv_lora_rank", "qk_nope_head_dim",
-                    "qk_rope_head_dim", "v_head_dim", "rope_theta",
-                    "rms_norm_eps", "first_k_dense_replace",
-                    "n_routed_experts", "num_experts_per_tok",
-                    "n_shared_experts", "moe_intermediate_size",
-                    "routed_scaling_factor", "norm_topk_prob",
-                    "torch_dtype")
+    #: what :meth:`to_dict` holds beside ``FIELDS``, by block
+    FIELDS_OF = {
+        GPT2: (),
+        DEEPSEEK_V3: (
+            "model_type", "kv_lora_rank", "qk_nope_head_dim",
+            "qk_rope_head_dim", "v_head_dim", "rope_theta",
+            "rms_norm_eps", "first_k_dense_replace", "n_routed_experts",
+            "num_experts_per_tok", "n_shared_experts",
+            "moe_intermediate_size", "routed_scaling_factor",
+            "norm_topk_prob", "torch_dtype"),
+        GRANITE_HYBRID: (
+            "model_type", "layer_types", "num_key_value_heads",
+            "mamba_n_heads", "mamba_d_head", "mamba_d_state",
+            "mamba_d_conv", "mamba_n_groups", "mamba_expand",
+            "embedding_multiplier", "attention_multiplier",
+            "residual_multiplier", "logits_scaling",
+            "tie_word_embeddings", "rms_norm_eps", "torch_dtype"),
+    }
+    #: every block's own fields, each once
+    BLOCK_FIELDS = tuple(dict.fromkeys(
+        f for fields in FIELDS_OF.values() for f in fields))
 
     def __init__(self, vocab_size=32, d_model=32, num_layers=2,
                  num_heads=2, d_ff=64, max_context=128, model_type=GPT2,
@@ -103,7 +140,13 @@ class DecoderConfig:
                  first_k_dense_replace=0, n_routed_experts=0,
                  num_experts_per_tok=0, n_shared_experts=0,
                  moe_intermediate_size=0, routed_scaling_factor=1.0,
-                 norm_topk_prob=True, torch_dtype="float32"):
+                 norm_topk_prob=True, torch_dtype="float32",
+                 layer_types=(), num_key_value_heads=0, mamba_n_heads=0,
+                 mamba_d_head=0, mamba_d_state=0, mamba_d_conv=4,
+                 mamba_n_groups=1, mamba_expand=2,
+                 embedding_multiplier=1.0, attention_multiplier=1.0,
+                 residual_multiplier=1.0, logits_scaling=1.0,
+                 tie_word_embeddings=True):
         self.vocab_size = int(vocab_size)
         self.d_model = int(d_model)
         self.num_layers = int(num_layers)
@@ -113,9 +156,9 @@ class DecoderConfig:
         for f in self.FIELDS:
             if getattr(self, f) < 1:
                 raise ValueError(f"{f} must be >= 1, got {getattr(self, f)}")
-        if model_type not in (GPT2, DEEPSEEK_V3):
-            raise ValueError(f"model_type {model_type!r} is neither "
-                             f"{GPT2!r} nor {DEEPSEEK_V3!r}")
+        if model_type not in self.FIELDS_OF:
+            raise ValueError(f"model_type {model_type!r} is none of "
+                             f"{sorted(self.FIELDS_OF)}")
         self.model_type = model_type
         self.kv_lora_rank = int(kv_lora_rank)
         self.qk_nope_head_dim = int(qk_nope_head_dim)
@@ -136,6 +179,23 @@ class DecoderConfig:
             raise ValueError("torch_dtype is float32 or bfloat16, got "
                              f"{torch_dtype!r}")
         self.torch_dtype = torch_dtype
+        self.layer_types = tuple(layer_types)
+        self.num_key_value_heads = int(num_key_value_heads)
+        self.mamba_n_heads = int(mamba_n_heads)
+        self.mamba_d_head = int(mamba_d_head)
+        self.mamba_d_state = int(mamba_d_state)
+        self.mamba_d_conv = int(mamba_d_conv)
+        self.mamba_n_groups = int(mamba_n_groups)
+        self.mamba_expand = int(mamba_expand)
+        self.embedding_multiplier = float(embedding_multiplier)
+        self.attention_multiplier = float(attention_multiplier)
+        self.residual_multiplier = float(residual_multiplier)
+        self.logits_scaling = float(logits_scaling)
+        self.tie_word_embeddings = bool(tie_word_embeddings)
+        if model_type == GRANITE_HYBRID:
+            self._check_hybrid()
+            self.head_dim = self.d_model // self.num_heads
+            return
         if model_type == GPT2:
             if self.d_model % self.num_heads:
                 raise ValueError(
@@ -164,6 +224,52 @@ class DecoderConfig:
         # a head's query width (nope + rope)
         self.head_dim = self.qk_nope_head_dim + self.qk_rope_head_dim
 
+    def _check_hybrid(self):
+        """The ``granitemoehybrid`` keys against one another."""
+        if len(self.layer_types) != self.num_layers or any(
+                k not in (MAMBA, ATTENTION) for k in self.layer_types):
+            raise ValueError(
+                f"layer_types names {len(self.layer_types)} layers of "
+                f"{sorted(set(self.layer_types))}; it takes "
+                f"{self.num_layers} of {MAMBA!r} or {ATTENTION!r}")
+        if self.d_model % self.num_heads or self.num_key_value_heads < 1 \
+                or self.num_heads % self.num_key_value_heads:
+            raise ValueError(
+                f"d_model {self.d_model} over num_heads {self.num_heads} "
+                f"over num_key_value_heads {self.num_key_value_heads}: "
+                "each must divide the one before")
+        if MAMBA in self.layer_types:
+            for f in ("mamba_n_heads", "mamba_d_head", "mamba_d_state"):
+                if getattr(self, f) < 1:
+                    raise ValueError(f"{f} must be >= 1 for a {MAMBA} "
+                                     f"layer, got {getattr(self, f)}")
+            if self.mamba_n_groups != 1:
+                raise ValueError("mamba_n_groups is 1 (all heads share B "
+                                 f"and C), got {self.mamba_n_groups}")
+            if self.mamba_d_conv < 2:
+                raise ValueError("mamba_d_conv must be >= 2, got "
+                                 f"{self.mamba_d_conv}")
+            if self.mamba_expand * self.d_model != self.mamba_inner:
+                raise ValueError(
+                    f"mamba_expand {self.mamba_expand} x d_model "
+                    f"{self.d_model} is not mamba_n_heads x mamba_d_head "
+                    f"= {self.mamba_inner}")
+        if not self.tie_word_embeddings:
+            raise ValueError("the granitemoehybrid block's head is its "
+                             "embedding (tie_word_embeddings)")
+
+    @property
+    def mamba_inner(self):
+        """Channels of a state-space layer's inner stream (heads x head
+        size)."""
+        return self.mamba_n_heads * self.mamba_d_head
+
+    @property
+    def mamba_conv_dim(self):
+        """Channels the causal convolution runs over: the inner stream,
+        then ``B`` and ``C`` of the one group."""
+        return self.mamba_inner + 2 * self.mamba_d_state
+
     @property
     def latent_dim(self):
         """Values a token leaves in the cache of a layer of the
@@ -171,9 +277,11 @@ class DecoderConfig:
         return self.kv_lora_rank + self.qk_rope_head_dim
 
     def to_dict(self):
-        fields = self.FIELDS if self.model_type == GPT2 \
-            else self.FIELDS + self.BLOCK_FIELDS
-        return {f: getattr(self, f) for f in fields}
+        out = {f: getattr(self, f)
+               for f in self.FIELDS + self.FIELDS_OF[self.model_type]}
+        if "layer_types" in out:
+            out["layer_types"] = list(self.layer_types)     # as JSON has it
+        return out
 
     @classmethod
     def from_dict(cls, d):
@@ -238,8 +346,36 @@ def _swiglu(x, w_gate, w_up, w_down):
 class TinyDecoder:
     """Decoder-only transformer with paged-decode support."""
 
+    #: each block's own methods end in its name here
+    #: (``_forward_<name>``, ``_decode_flat_<name>``, ...), and
+    #: ``_TAKES`` is what its step takes beside the packed batch
+    _BLOCK = {GPT2: "gpt2", DEEPSEEK_V3: "latent", GRANITE_HYBRID: "hybrid"}
+    _TAKES = {GPT2: ("k_scales", "v_scales", "adapter", "axis_name",
+                     "w_scales"),
+              DEEPSEEK_V3: (), GRANITE_HYBRID: ("state",)}
+    #: what each of those keywords stands for, when a block refuses it
+    _STANDS_FOR = {"k_scales": "quantized pages",
+                   "v_scales": "quantized pages",
+                   "adapter": "an adapter bank",
+                   "axis_name": "a tensor-parallel mesh",
+                   "w_scales": "quantized weights",
+                   "state": "slot state pools"}
+    #: the engine arguments a block does not take, refused by name at
+    #: the engine's construction
+    _REFUSED = {GPT2: (),
+                DEEPSEEK_V3: ("adapter_bank", "draft_model", "mesh",
+                              "weight_dtype"),
+                GRANITE_HYBRID: ("adapter_bank", "draft_model", "mesh",
+                                 "weight_dtype", "kv_dtype",
+                                 "prefix_cache")}
+
     def __init__(self, config=None, **kw):
         self.config = config if config is not None else DecoderConfig(**kw)
+
+    def _of_block(self, what):
+        """The configured block's own ``what``: the one place a model
+        type is looked up."""
+        return getattr(self, f"_{what}_{self._BLOCK[self.config.model_type]}")
 
     # engine-facing shape attributes
     @property
@@ -278,23 +414,41 @@ class TinyDecoder:
 
     @property
     def cache_layout(self):
-        """What a token leaves in the paged cache of one layer, for the
-        engine to shape the pool by: ``{"kind": "kv", "num_heads": H,
-        "head_dim": D}`` (a K and a V pool of ``[H, D]`` a slot) or
-        ``{"kind": "latent", "latent_dim": W}`` (one pool of ``W``
-        values a slot, no head axis, no V pool)."""
+        """What the model keeps between steps, for the engine to shape
+        the pools by. What a token leaves in the paged cache of one
+        layer: ``{"kind": "kv", "num_heads": H, "head_dim": D}`` (a K
+        and a V pool of ``[H, D]`` a slot), ``{"kind": "latent",
+        "latent_dim": W}`` (one pool of ``W`` values a slot, no head
+        axis, no V pool) or ``{"kind": "kv_packed", "num_heads": G,
+        "head_dim": D}`` (one pool of rows, a token's ``G`` value heads
+        and then its ``G`` key heads: whole lane tiles at any head
+        size). ``"layers"``, where fewer layers than the model has leave
+        pages. ``"state"``, where a sequence also holds a fixed state
+        that its tokens overwrite: ``{name: (layers, *shape of one
+        slot's)}`` float32, in the order the step takes and returns the
+        pools, after the pages."""
+        c = self.config
+        if c.model_type == GRANITE_HYBRID:
+            mamba = c.layer_types.count(MAMBA)
+            layout = {"kind": "kv_packed",
+                      "num_heads": c.num_key_value_heads,
+                      "head_dim": c.head_dim,
+                      "layers": c.layer_types.count(ATTENTION)}
+            if mamba:
+                layout["state"] = {
+                    "ssm": (mamba, c.mamba_d_state, c.mamba_inner),
+                    "conv": (mamba, c.mamba_d_conv - 1, c.mamba_conv_dim)}
+            return layout
         if self._deepseek:
-            return {"kind": "latent", "latent_dim": self.config.latent_dim}
-        return {"kind": "kv", "num_heads": self.config.num_heads,
-                "head_dim": self.config.head_dim}
+            return {"kind": "latent", "latent_dim": c.latent_dim}
+        return {"kind": "kv", "num_heads": c.num_heads,
+                "head_dim": c.head_dim}
 
     @property
     def refused_features(self):
         """The engine arguments this model's block does not take, for
         the engine to refuse by name at construction."""
-        if self._deepseek:
-            return ("adapter_bank", "draft_model", "mesh", "weight_dtype")
-        return ()
+        return self._REFUSED[self.config.model_type]
 
     def _refuse(self, what):
         raise UnsupportedModelFeatureError(
@@ -302,25 +456,31 @@ class TinyDecoder:
 
     # ------------------------------------------------------- params --
     def init_params(self, seed=0):
-        """Deterministic random params (host numpy, float32)."""
-        c = self.config
+        """Deterministic random params from ``seed``: host numpy
+        float32 draws, in the configuration's ``torch_dtype`` where the
+        block has one."""
         rs = np.random.RandomState(seed)
 
         def w(*shape, scale=None):
             scale = scale if scale is not None else 1.0 / np.sqrt(shape[0])
             return (rs.randn(*shape) * scale).astype(np.float32)
 
-        if self._deepseek:
-            params = self._init_params_latent(w)
-            if c.torch_dtype == "float32":
-                return params
-            import jax
-            import jax.numpy as jnp
-            # the router stays float32, as the published code scores
-            return jax.tree_util.tree_map_with_path(
-                lambda path, a: jnp.asarray(a) if "router" in str(path[-1])
-                else jnp.asarray(a, c.torch_dtype), params)
+        return self._of_block("init_params")(w)
 
+    def _published_dtype(self, params):
+        """``params`` (host float32) in the configuration's
+        ``torch_dtype``; a router's leaves stay float32, as the
+        published code computes its scores."""
+        if self.config.torch_dtype == "float32":
+            return params
+        import jax
+        import jax.numpy as jnp
+        return jax.tree_util.tree_map_with_path(
+            lambda path, a: jnp.asarray(a) if "router" in str(path[-1])
+            else jnp.asarray(a, self.config.torch_dtype), params)
+
+    def _init_params_gpt2(self, w):
+        c = self.config
         layers = []
         for _ in range(c.num_layers):
             layers.append({
@@ -383,9 +543,10 @@ class TinyDecoder:
                               shared_down=w(sh, d,
                                             scale=out * sh ** -0.5))
             layers.append(lp)
-        return {"embed": w(c.vocab_size, d, scale=1.0),
-                "norm_f": np.ones(d, np.float32),
-                "head": w(d, c.vocab_size), "layers": layers}
+        return self._published_dtype(
+            {"embed": w(c.vocab_size, d, scale=1.0),
+             "norm_f": np.ones(d, np.float32),
+             "head": w(d, c.vocab_size), "layers": layers})
 
     def param_specs(self, axis="tp"):
         """PartitionSpec pytree matching :meth:`init_params` for
@@ -397,7 +558,7 @@ class TinyDecoder:
         vocab- or norm-shaped rides replicated. Structure is a tree
         PREFIX of the params pytree (one spec per weight leaf)."""
         from jax.sharding import PartitionSpec as P
-        if self._deepseek:
+        if self.config.model_type != GPT2:
             self._refuse("a tensor-parallel mesh")
         layer = {
             "ln1_g": P(), "ln1_b": P(),
@@ -441,13 +602,16 @@ class TinyDecoder:
         applied to every row (the per-adapter oracle of the flat
         step's per-token dispatch).
         """
+        if lora is None:
+            return self._of_block("forward")(params, tokens)
+        if self.config.model_type != GPT2:
+            self._refuse("LoRA factors")
+        return self._forward_gpt2(params, tokens, lora)
+
+    def _forward_gpt2(self, params, tokens, lora=None):
         import jax
         import jax.numpy as jnp
         c = self.config
-        if self._deepseek:
-            if lora is not None:
-                self._refuse("LoRA factors")
-            return self._forward_latent(params, tokens)
         B, T = tokens.shape
         h = params["embed"][tokens] + params["pos"][:T][None, :, :]
         if lora is not None:
@@ -493,7 +657,7 @@ class TinyDecoder:
     def decode_flat(self, params, tokens, positions, seq_ids, valid,
                     k_pages, v_pages, block_tables, k_scales=None,
                     v_scales=None, adapter=None, axis_name=None,
-                    w_scales=None):
+                    w_scales=None, state=None):
         """The FLAT ragged step: a packed ``[T]`` batch of query
         tokens from many sequences — no per-sequence padding, so a
         mixed prefill/decode/verify step computes exactly the tokens
@@ -572,21 +736,36 @@ class TinyDecoder:
         is bitwise the single-device result. At axis extent 1 every
         collective is the identity — bit-exact vs the unsharded
         program by construction.
+
+        All of the above is the GPT-2 block's. The other blocks take
+        none of those keywords (each is refused by what it stands for)
+        and ONE pool of pages, ``k_pages``, with ``v_pages`` ``None``:
+        :meth:`_decode_flat_latent`, and :meth:`_decode_flat_hybrid`,
+        which takes ``state``, the pools of its sequences' slot states,
+        and returns them after the pages.
         """
+        given = {k: v for k, v in (
+            ("k_scales", k_scales), ("v_scales", v_scales),
+            ("adapter", adapter), ("axis_name", axis_name),
+            ("w_scales", w_scales), ("state", state)) if v is not None}
+        for name in given:
+            if name not in self._TAKES[self.config.model_type]:
+                self._refuse(self._STANDS_FOR[name])
+        if v_pages is not None:
+            given["v_pages"] = v_pages
+        return self._of_block("decode_flat")(
+            params, tokens, positions, seq_ids, valid, k_pages,
+            block_tables, **given)
+
+    def _decode_flat_gpt2(self, params, tokens, positions, seq_ids, valid,
+                          k_pages, block_tables, v_pages=None,
+                          k_scales=None, v_scales=None, adapter=None,
+                          axis_name=None, w_scales=None):
+        """:meth:`decode_flat` for the GPT-2 block, as documented
+        there."""
         import jax
         import jax.numpy as jnp
         c = self.config
-        if self._deepseek:
-            for given, what in ((k_scales, "quantized pages"),
-                                (v_scales, "quantized pages"),
-                                (adapter, "an adapter bank"),
-                                (axis_name, "a tensor-parallel mesh"),
-                                (w_scales, "quantized weights")):
-                if given is not None:
-                    self._refuse(what)
-            return self._decode_flat_latent(
-                params, tokens, positions, seq_ids, valid, k_pages,
-                block_tables)
         T = tokens.shape[0]
         bs = k_pages.shape[2]
         quantized = k_scales is not None
@@ -852,6 +1031,271 @@ class TinyDecoder:
         return (logits, pages, load) if self.expert_slots \
             else (logits, pages)
 
+    # -------------------------------- the granitemoehybrid block --
+    def _init_params_hybrid(self, w):
+        """The ``granitemoehybrid`` tree: the leaves of
+        ``benchmark/reference/granite_hybrid_decoder.py``, and its rule
+        of scale. Every matrix by its fan-in; the embedding by
+        ``d_model ** -0.5 / embedding_multiplier`` (the stream starts
+        at norm 1): the head is the embedding itself, and at any larger
+        scale a token's own row makes it every position's first choice
+        by a margin that no fault in the layers could move; ``wq`` and
+        ``wk`` four times their fan-in, so that scores
+        under the published ``attention_multiplier`` (1/64 at a head of
+        64, an eighth of the usual ``1/sqrt``) spread by about 2 over
+        the keys and attention picks keys, as a trained model's does.
+        The state-space layer's vectors: ``dt_bias`` the inverse
+        softplus of steps from 0.001 to 0.1 (geometric over the heads)
+        and ``D`` 1, as the published code starts them; ``A_log`` the
+        logarithms of 1/16 to 1 over the heads (the published start, 1
+        to 16, forgets within a few hundred tokens under random
+        weights; here a head's state fades over 10 to 16,000 tokens, as
+        a trained model's remembers over its context, so that a state
+        that was not reset still shows behind a long prompt); the
+        convolution's bias normal at 0.1 so that its path is exercised;
+        norm gains 1."""
+        c = self.config
+        d, H, G, D = (c.d_model, c.num_heads, c.num_key_value_heads,
+                      c.head_dim)
+        di, N, Hm = c.mamba_inner, c.mamba_d_state, c.mamba_n_heads
+        cd, K = c.mamba_conv_dim, c.mamba_d_conv
+        steps = np.exp(np.linspace(np.log(1e-3), np.log(1e-1), max(Hm, 1)))
+        layers = []
+        for kind in c.layer_types:
+            lp = {"norm1": np.ones(d, np.float32),
+                  "norm2": np.ones(d, np.float32)}
+            if kind == ATTENTION:
+                lp.update(wq=w(d, H * D, scale=4 * d ** -0.5),
+                          wk=w(d, G * D, scale=4 * d ** -0.5),
+                          wv=w(d, G * D), wo=w(H * D, d))
+            else:
+                lp.update(
+                    w_in=w(d, 2 * di + 2 * N + Hm),
+                    conv_w=w(cd, K, scale=K ** -0.5),
+                    conv_b=w(cd, scale=0.1),
+                    dt_bias=np.log(np.expm1(steps)).astype(np.float32),
+                    A_log=np.log(np.linspace(1.0 / 16, 1.0, Hm)).astype(
+                        np.float32),
+                    D=np.ones(Hm, np.float32),
+                    norm_y=np.ones(di, np.float32), w_out=w(di, d))
+            lp.update(w_gate=w(d, c.d_ff), w_up=w(d, c.d_ff),
+                      w_down=w(c.d_ff, d))
+            layers.append(lp)
+        return self._published_dtype(
+            {"embed": w(c.vocab_size, d,
+                        scale=d ** -0.5 / c.embedding_multiplier),
+             "norm_f": np.ones(d, np.float32), "layers": layers})
+
+    def _mamba_inputs(self, lp, x):
+        """A state-space layer's input projection of normed rows ``x
+        [..., d]``: ``(z, xBC before its convolution, dt before its bias
+        and softplus)``."""
+        c = self.config
+        di, cd = c.mamba_inner, c.mamba_conv_dim
+        zxbcdt = _wmm(x, lp["w_in"])
+        return zxbcdt[..., :di], zxbcdt[..., di:di + cd], \
+            zxbcdt[..., di + cd:]
+
+    def _mamba_scan_inputs(self, lp, conved, dt_raw):
+        """``(x as heads, dt, A, B, C)`` of the recurrence from the
+        convolution's sum ``conved [..., conv_dim]`` (bias not yet
+        added) and the raw ``dt [..., H]``, all float32."""
+        import jax
+        import jax.numpy as jnp
+        c = self.config
+        di, N = c.mamba_inner, c.mamba_d_state
+        f32 = jnp.float32
+        xbc = jax.nn.silu(conved + lp["conv_b"].astype(f32))
+        xs = xbc[..., :di].reshape(
+            xbc.shape[:-1] + (c.mamba_n_heads, c.mamba_d_head))
+        dt = jax.nn.softplus(dt_raw + lp["dt_bias"].astype(f32))
+        return (xs, dt, -jnp.exp(lp["A_log"].astype(f32)),
+                xbc[..., di:di + N], xbc[..., di + N:])
+
+    def _mamba_output(self, lp, y, xs, z):
+        """A state-space layer's way out: the skip ``D x``, the gate
+        BEFORE the norm (one group over the whole inner stream), the
+        output projection."""
+        import jax
+        import jax.numpy as jnp
+        c = self.config
+        y = y + lp["D"].astype(jnp.float32)[:, None] * xs
+        g = y.reshape(y.shape[:-2] + (c.mamba_inner,)) * jax.nn.silu(z)
+        return _wmm(_rms_norm(g, lp["norm_y"], c.rms_norm_eps),
+                    lp["w_out"])
+
+    def _head_hybrid(self, params, h):
+        """Logits of the stream ``h [..., d]``: the final norm against
+        the embedding itself (no second copy of it), over
+        ``logits_scaling``."""
+        import jax
+        import jax.numpy as jnp
+        c = self.config
+        x = _rms_norm(h, params["norm_f"], c.rms_norm_eps)
+        embed = params["embed"]
+        return jax.lax.dot_general(
+            x.astype(embed.dtype), embed,
+            (((x.ndim - 1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) / c.logits_scaling
+
+    def _forward_hybrid(self, params, tokens):
+        """:meth:`forward` for the ``granitemoehybrid`` block: dense
+        causal grouped-query attention, the state-space layers as one
+        sequential scan over the positions from a state of zeros.
+        Returns ``(logits [B, T, V] float32, None, None)``."""
+        import jax
+        import jax.numpy as jnp
+        c = self.config
+        B, T = tokens.shape
+        H, G, D = c.num_heads, c.num_key_value_heads, c.head_dim
+        K, eps, r = c.mamba_d_conv, c.rms_norm_eps, c.residual_multiplier
+        mask = jnp.tril(jnp.ones((T, T), bool))
+        h = params["embed"][tokens].astype(jnp.float32) \
+            * c.embedding_multiplier
+        for kind, lp in zip(c.layer_types, params["layers"]):
+            x = _rms_norm(h, lp["norm1"], eps)
+            if kind == ATTENTION:
+                q = _wmm(x, lp["wq"]).reshape(B, T, G, H // G, D)
+                k = _wmm(x, lp["wk"]).reshape(B, T, G, D)
+                v = _wmm(x, lp["wv"]).reshape(B, T, G, D)
+                s = jnp.einsum("bqgmd,bkgd->bgmqk", q, k) \
+                    * c.attention_multiplier
+                p = jax.nn.softmax(jnp.where(mask, s, -1e30), -1)
+                att = jnp.einsum("bgmqk,bkgd->bqgmd", p, v)
+                mix = _wmm(att.reshape(B, T, H * D), lp["wo"])
+            else:
+                z, xbc, dt_raw = self._mamba_inputs(lp, x)
+                w = lp["conv_w"].astype(jnp.float32)
+                before = jnp.pad(xbc, ((0, 0), (K - 1, 0), (0, 0)))
+                conved = sum(w[:, j] * before[:, j:j + T]
+                             for j in range(K))
+                xs, dt, A, Bm, Cm = self._mamba_scan_inputs(
+                    lp, conved, dt_raw)
+
+                def token(S, at):
+                    x_t, dt_t, b_t, c_t = at
+                    S = jnp.exp(dt_t * A)[..., None, None] * S \
+                        + (dt_t[..., None] * x_t)[..., None] \
+                        * b_t[:, None, None, :]
+                    return S, jnp.einsum("bhpn,bn->bhp", S, c_t)
+
+                _, y = jax.lax.scan(
+                    token, jnp.zeros((B,) + xs.shape[2:]
+                                     + (c.mamba_d_state,), jnp.float32),
+                    tuple(jnp.moveaxis(a, 1, 0)
+                          for a in (xs, dt, Bm, Cm)))
+                mix = self._mamba_output(lp, jnp.moveaxis(y, 0, 1), xs, z)
+            h = h + r * mix
+            h = h + r * _swiglu(_rms_norm(h, lp["norm2"], eps),
+                                lp["w_gate"], lp["w_up"], lp["w_down"])
+        return self._head_hybrid(params, h), None, None
+
+    def _decode_flat_hybrid(self, params, tokens, positions, seq_ids,
+                            valid, pages, block_tables, state):
+        """:meth:`decode_flat` for the ``granitemoehybrid`` block, the
+        packed batch and page arithmetic the same.
+
+        ``pages`` is the ONE pool of the attention layers alone, ``[La,
+        N, bs, 2 x G x D]``: attention layer ``a`` (in the order the
+        layers come) writes each token's ``G`` value heads and then its
+        ``G`` key heads as one row and attends through
+        :func:`~mxnet_tpu.ops.ragged_attention.gqa_flat_attention` with
+        ``layer=a`` and the published ``attention_multiplier`` as the
+        scale; no positions enter anything but the causal mask.
+
+        ``state`` is ``(ssm [Lm, S, N, H x P], conv [Lm, S, K - 1,
+        conv_dim])`` float32, indexed by state-space layer and by SLOT
+        (``seq_ids``, the row of ``block_tables``). A slot's valid
+        tokens lie side by side in the batch (a run). The convolution
+        takes each token's ``K - 1`` predecessors from its run or, for
+        the run's first tokens, from the slot's window; the window then
+        becomes the last ``K - 1`` inputs of window and run together (a
+        run shorter than the window shifts it through). The recurrence
+        is :func:`~mxnet_tpu.ops.ssd.ssd_state_step` on the WHOLE pool
+        with ``layer=``. A run whose first token sits at position 0
+        reads a window and a state of zeros, whatever the slot's last
+        occupant left, and a token with ``valid == 0`` touches neither.
+
+        Weights are read in their own dtype with float32 accumulation;
+        norms, softmax, softplus, the decay, the recurrence, the gate
+        and the residual stream are float32. Returns ``(logits [T, V]
+        float32, pages, ssm, conv)``."""
+        import jax.numpy as jnp
+        c = self.config
+        ssm, conv = state
+        T, S = tokens.shape[0], block_tables.shape[0]
+        bs = pages.shape[2]
+        H, G, D = c.num_heads, c.num_key_value_heads, c.head_dim
+        K, eps, r = c.mamba_d_conv, c.rms_norm_eps, c.residual_multiplier
+        vmask = valid.astype(bool)
+        bidx = jnp.where(
+            vmask, block_tables[seq_ids, positions // bs], 0)  # null
+        slot = jnp.where(vmask, positions % bs, 0)
+        # where the convolution finds each token's predecessors, once
+        # for all layers: rows of [the step's inputs | every slot's
+        # window | one row of zeros]
+        start, length = slot_runs(seq_ids, valid, S)
+        fresh = jnp.logical_and(length > 0, positions[start] == 0)
+        t = jnp.arange(T, dtype=jnp.int32)
+        place = t - start[seq_ids]                  # in its slot's run
+        zero_row = T + S * (K - 1)
+        taps = []                                   # oldest first
+        for back in range(K - 1, 0, -1):
+            held = T + seq_ids * (K - 1) + (K - 1 - back) + place
+            taps.append(jnp.clip(jnp.where(
+                place >= back, t - back,
+                jnp.where(fresh[seq_ids], zero_row, held)), 0, zero_row))
+        # and what each slot's window holds after the step
+        j = length[:, None] + jnp.arange(K - 1, dtype=jnp.int32)[None]
+        s_row = jnp.arange(S, dtype=jnp.int32)[:, None]
+        keeps = jnp.where(
+            j >= K - 1, start[:, None] + j - (K - 1),
+            jnp.where(fresh[:, None], zero_row,
+                      T + s_row * (K - 1) + j))
+        h = params["embed"][tokens].astype(jnp.float32) \
+            * c.embedding_multiplier
+        att_layer = ssm_layer = 0
+        for kind, lp in zip(c.layer_types, params["layers"]):
+            x = _rms_norm(h, lp["norm1"], eps)
+            if kind == ATTENTION:
+                q = _wmm(x, lp["wq"]).reshape(T, H, D)
+                row = jnp.concatenate(
+                    [_wmm(x, lp["wv"]), _wmm(x, lp["wk"])], axis=-1)
+                pages = pages.at[att_layer, bidx, slot].set(
+                    row.astype(pages.dtype))
+                att = gqa_flat_attention(
+                    q, pages, block_tables, seq_ids, positions,
+                    c.attention_multiplier, G, layer=att_layer)
+                mix = _wmm(att.reshape(T, H * D), lp["wo"])
+                att_layer += 1
+            else:
+                z, xbc, dt_raw = self._mamba_inputs(lp, x)
+                rows = jnp.concatenate(
+                    [xbc, conv[ssm_layer].reshape(S * (K - 1), -1),
+                     jnp.zeros((1, xbc.shape[1]), jnp.float32)])
+                w = lp["conv_w"].astype(jnp.float32)
+                conved = w[:, K - 1] * xbc + sum(
+                    w[:, i] * rows[tap] for i, tap in enumerate(taps))
+                conv = conv.at[ssm_layer].set(rows[keeps])
+                xs, dt, A, Bm, Cm = self._mamba_scan_inputs(
+                    lp, conved, dt_raw)
+                y, ssm = ssd_state_step(xs, dt, A, Bm, Cm, ssm, seq_ids,
+                                        positions, valid, ssm_layer)
+                mix = self._mamba_output(lp, y, xs, z)
+                ssm_layer += 1
+            h = h + r * mix
+            h = h + r * _swiglu(_rms_norm(h, lp["norm2"], eps),
+                                lp["w_gate"], lp["w_up"], lp["w_down"])
+        return self._head_hybrid(params, h), pages, ssm, conv
+
+
+def _gpt2_oracle_only(model):
+    """The incremental oracle below is the GPT-2 block's (a model that
+    is no :class:`TinyDecoder` is taken for one)."""
+    if getattr(getattr(model, "config", None), "model_type", GPT2) != GPT2:
+        model._refuse("the incremental oracle: compare with "
+                      "TinyDecoder.forward over the whole sequence")
+
 
 def _incremental_step(model, params, token, pos, k_cache, v_cache,
                       lora=None):
@@ -866,9 +1310,7 @@ def _incremental_step(model, params, token, pos, k_cache, v_cache,
     import jax.numpy as jnp
     from ...ops.flash_attention import _NEG_INF
     c = model.config
-    if getattr(model, "_deepseek", False):
-        model._refuse("the incremental oracle: compare with "
-                      "TinyDecoder.forward over the whole sequence")
+    _gpt2_oracle_only(model)
     scale = 1.0 / (c.head_dim ** 0.5)      # python float: config-time
     mask = jnp.arange(c.max_context, dtype=jnp.int32) <= pos
     if lora is not None:
@@ -936,9 +1378,7 @@ def greedy_decode_reference(model, params, prompt_tokens,
     """
     import jax
     import jax.numpy as jnp
-    if getattr(model, "_deepseek", False):
-        model._refuse("the incremental oracle: compare with "
-                      "TinyDecoder.forward over the whole sequence")
+    _gpt2_oracle_only(model)
     toks = [int(t) for t in prompt_tokens]
     out = []
     ctx = model.max_context

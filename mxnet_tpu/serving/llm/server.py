@@ -485,6 +485,9 @@ class LLMServer:
         # K and V pools of [H, D] or one pool of latents, and the
         # bytes a token takes in it over all layers
         snap["cache_layout"] = self._engine.cache.layout()
+        # what the slots hold beside pages (empty for most models), and
+        # what the steps did to it
+        snap["state_pool"] = self._engine.state_pool_stats()
         snap["weight_dtype"] = self._engine.weight_dtype
         snap["weight_bytes"] = self._engine.weight_bytes
         snap["weight_params_per_chip"] = (

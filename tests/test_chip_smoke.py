@@ -101,6 +101,31 @@ def test_ragged_phase_toy(dtype):
     assert max(line["rel_err"].values()) < 1e-4
 
 
+def test_gqa_phase_toy():
+    """The grouped case of the ``ragged`` phase, interpret mode: 8 query
+    heads over 2 of 16, packed rows of 64, a step of 23 tokens and one
+    of 8."""
+    line = chip_smoke.gqa_phase(
+        cases=((8, 8), (23, 16)), layers=2, num_blocks=129, block_size=4,
+        heads=8, kv_heads=2, head_dim=16, dtype="float32", reps=1,
+        interpret=True)
+    assert line["phase"] == "ragged_gqa" and line["kv_heads"] == 2
+    assert set(line["ms_per_call"]) == {"T8_MB8", "T23_MB16"}
+    assert max(line["rel_err"].values()) < 1e-4
+
+
+def test_ssd_phase_toy():
+    """The ``ssd`` phase's control flow, the kernel in interpret mode: 4
+    slots, steps of 4 decode rows, of a chunk of 5 beside 3 decode rows
+    and padding, and of 4 chunks."""
+    line = chip_smoke.ssd_phase(
+        cases=(4, 9, 20), layers=3, slots=4, heads=4, head_dim=8,
+        state=16, chunk=5, reps=3, interpret=True)
+    assert line["phase"] == "ssd" and line["pool"] == [3, 4, 16, 32]
+    assert set(line["ms_per_call"]) == {"T4", "T9", "T20"}
+    assert max(line["rel_err"].values()) <= chip_smoke.SSD_TOL
+
+
 def test_refuses_to_run_without_a_tpu():
     p = subprocess.run(
         [sys.executable, os.path.join(REPO, "chip_smoke.py")],

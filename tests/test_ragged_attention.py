@@ -812,3 +812,139 @@ def test_bucket_spec_page_aligned_length_axis():
     assert pad_to_bucket(x, 4, axis=1).shape == (2, 4)
     with pytest.raises(ValueError):
         pad_to_bucket(x, 2, axis=1)
+
+
+# ------------- grouped queries over packed K/V rows (ISSUE 32) --
+# ``gqa_flat_attention``: 32 query heads over 8 kv heads of 64, ONE pool
+# of rows [V | K] of 1024 values, through the latent kernel's walk
+# (blocks of 8 tokens at 32 heads, 256 keys a loop step). Tables of 48
+# pages of 16 are 768 keys, three steps; only such tables tell a run's
+# walk from another's.
+
+from mxnet_tpu.ops.ragged_attention import (  # noqa: E402
+    gqa_flat_attention, gqa_flat_attention_reference)
+
+GQA_H, GQA_G, GQA_D, GQA_BS = 32, 8, 64, 16
+GQA_LENS = [760, 37, 530, 290]                  # 48, 3, 34 and 19 pages
+GQA_SCALE = 1.0 / 64
+
+GQA_BATCHES = {
+    # runs of 5, 1, 12 and 1 tokens, walks of 3, 1, 3 and 2 steps; the
+    # run of 12 crosses from one block of 8 tokens into the next two
+    "mixed-runs": (_run(0, 750, 5) + _run(1, 36) + _run(2, 515, 12)
+                   + _run(3, 289)),
+    # a sequence twice in one block, apart (PR 29's fault): its valid
+    # tokens, then another's, then tokens an earlier step left
+    "twice-apart": (_run(2, 300, 3) + _run(1, 20) + _run(2, 520, 2)
+                    + _run(0, 600) + _run(2, 5, 1)),
+    # a chunk of 16 from position 0 beside decode rows, and a tail that
+    # names the last token's slot at position 0, as the engine writes it
+    "chunk-and-tail": (_run(3, 0, 16) + _run(0, 759) + _run(1, 3)
+                       + [(1, 0)] * 5),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _gqa_case(dtype):
+    """``(pages [2, N, bs, 1024] of dtype, tables [4, 48], dense k/v per
+    sequence as float64, as the pool holds them)``: layer 1 of a stacked
+    pool of two, layer 0 garbage."""
+    rng = np.random.RandomState(7)
+    MB = max(-(-t // GQA_BS) for t in GQA_LENS)
+    N = 1 + sum(-(-t // GQA_BS) for t in GQA_LENS) + 9
+    pages = rng.randn(2, N, GQA_BS, 2 * GQA_G * GQA_D).astype(np.float32)
+    free = list(1 + rng.permutation(N - 1))     # fragmented tables
+    bt = np.zeros((len(GQA_LENS), MB), np.int32)
+    dense = []
+    for i, t in enumerate(GQA_LENS):
+        nb = -(-t // GQA_BS)
+        bt[i, :nb] = [free.pop() for _ in range(nb)]
+        rows = pages[1, bt[i, :nb]].reshape(nb * GQA_BS, 2, GQA_G, GQA_D)
+        dense.append(rows)
+    pages = jnp.asarray(pages).astype(dtype)
+    dense = [np.asarray(jnp.asarray(r).astype(dtype).astype(jnp.float32),
+                        np.float64) for r in dense]
+    return pages, bt, dense
+
+
+def _gqa_oracle(q, sids, poss, dense):
+    """Single-query dense attention over each token's own causal prefix,
+    query head ``j`` against kv head ``j // 4``, in float64."""
+    outs = []
+    m = GQA_H // GQA_G
+    for qt, i, pos in zip(q.astype(np.float64), sids, poss):
+        v, k = dense[i][:pos + 1, 0], dense[i][:pos + 1, 1]
+        qg = qt.reshape(GQA_G, m, GQA_D)
+        logits = np.einsum("gmd,tgd->gmt", qg, k) * GQA_SCALE
+        p = np.exp(logits - logits.max(axis=-1, keepdims=True))
+        outs.append(np.einsum("gmt,tgd->gmd",
+                              p / p.sum(axis=-1, keepdims=True),
+                              v).reshape(GQA_H, GQA_D))
+    return np.stack(outs)
+
+
+def _gqa(path, q, pages, bt, sids, poss, layer=1):
+    return np.asarray(gqa_flat_attention(
+        q, pages, bt, sids, poss, GQA_SCALE, GQA_G, layer=layer,
+        use_pallas=(path == "pallas"), interpret=True))
+
+
+@pytest.mark.parametrize("batch", list(GQA_BATCHES))
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@both_paths
+def test_grouped_walk_vs_dense_oracle(dtype, batch, path):
+    """Every VALID token of the packed batch equals single-query dense
+    grouped attention over its own causal prefix, through the stacked
+    pool's layer 1. The tolerance is float32 round-off over 760 keys
+    (the pages' rounding to bfloat16 is the oracle's too); queries four
+    times unit scale, as the model's ``wq`` makes them."""
+    pages, bt, dense = _gqa_case(dtype)
+    sids, poss = (np.asarray(x, np.int32)
+                  for x in zip(*GQA_BATCHES[batch]))
+    q = 4 * np.random.RandomState(len(sids)).randn(
+        len(sids), GQA_H, GQA_D).astype(np.float32)
+    got = _gqa(path, q, pages, bt, sids, poss)
+    valid = len(sids) - (5 if batch == "chunk-and-tail" else 0)
+    np.testing.assert_allclose(
+        got[:valid], _gqa_oracle(q, sids, poss, dense)[:valid],
+        rtol=2e-5, atol=5e-6)
+
+
+@both_paths
+def test_grouped_heads_read_their_own_group(path):
+    """With every kv head but one zeroed in V, only that group's four
+    query heads bring anything back."""
+    pages, bt, _ = _gqa_case("float32")
+    v = np.asarray(pages).reshape(pages.shape[:3] + (2, GQA_G, GQA_D)).copy()
+    v[..., 0, :5, :] = 0
+    v[..., 0, 6:, :] = 0
+    only5 = jnp.asarray(v.reshape(pages.shape))
+    sids, poss = np.asarray([0, 2], np.int32), np.asarray([700, 40],
+                                                          np.int32)
+    q = np.random.RandomState(3).randn(2, GQA_H, GQA_D).astype(np.float32)
+    got = _gqa(path, q, only5, bt, sids, poss)
+    assert np.abs(got[:, 20:24]).min() > 0
+    assert np.abs(got[:, :20]).max() == 0 and np.abs(got[:, 24:]).max() == 0
+
+
+def test_grouped_kernel_equals_its_twin_and_one_layer_pool():
+    """The kernel in interpret mode against the gather reference on the
+    same inputs, and a pool of one layer without ``layer`` through the
+    same kernel."""
+    pages, bt, _ = _gqa_case("bfloat16")
+    sids, poss = (np.asarray(x, np.int32)
+                  for x in zip(*GQA_BATCHES["mixed-runs"]))
+    q = np.random.RandomState(1).randn(len(sids), GQA_H,
+                                       GQA_D).astype(np.float32)
+    twin = np.asarray(gqa_flat_attention_reference(
+        jnp.asarray(q), pages, jnp.asarray(bt), jnp.asarray(sids),
+        jnp.asarray(poss), GQA_SCALE, GQA_G, layer=1))
+    got = _gqa("pallas", q, pages, bt, sids, poss)
+    np.testing.assert_allclose(got, twin, rtol=2e-5, atol=5e-6)
+    np.testing.assert_array_equal(
+        got, _gqa("pallas", q, pages[1], bt, sids, poss, layer=None))
+    with pytest.raises(ValueError, match="kv_heads"):
+        gqa_flat_attention(q, pages[1], bt, sids, poss, GQA_SCALE, GQA_G,
+                           layer=0)
+    with pytest.raises(ValueError, match="kv_heads"):
+        gqa_flat_attention(q, pages, bt, sids, poss, GQA_SCALE, 5, layer=0)
