@@ -1,26 +1,24 @@
 """Driver for serving cells: a decoder behind ``LLMServer`` under the
 requests of a traffic mix.
 
-The construction follows ``chip_smoke.py``'s ``serve_phase``; the weights
-are made on the device from the seed by the configuration's reference
-(``init_params`` of the program draws them on the host). The mix fixes the
-work: ``benchmark/traffic.py`` gives every request with the moment it is
-due, and the closed law's ``clients`` caps the requests in flight (a
-closed loop is a batch that is all due at once under such a cap). The
-window opens on a warm, empty server with the first request and closes
-when the last one is answered, so every metric is over all of the work
-and all of the time; ``--seconds`` sets how much work there is. A request
-still unanswered a minute after its window should have closed is
-``unanswered``, which ``correct`` does not allow.
+The construction follows ``chip_smoke.py``'s ``serve_phase``; the weights are
+made on the device from the seed by the configuration's reference. The mix
+fixes the work: ``benchmark/traffic.py`` gives every request with the moment
+it is due, and the closed law's ``clients`` caps the requests in flight. The
+window opens on a warm, empty server with the first request and closes when
+the last one is answered, so every metric is over all of the work and all of
+the time; ``--seconds`` sets how much work there is. A request unanswered a
+minute after its window should have closed is ``unanswered``, which
+``correct`` does not allow. A traced run records ``trace_seconds`` of steps
+from the answer that makes ``trace_after_share`` of the run's requests.
 
-Once the window has closed and the server's pools are freed, the
-reference runs once over each of a sample of finished requests (drawn from
-the seed, the longest among them): prompt and served tokens together. The
-number compared is the widest gap by which a served token's logit lies
-below the reference's best at its position.
+Once the window has closed and the server's pools are freed, the reference
+runs over a sample of finished requests (drawn from the seed, the longest
+among them): the widest gap of a served token's logit below its best.
 """
 import gc
 import importlib
+import math
 import queue
 import random
 import time
@@ -29,6 +27,8 @@ from benchmark import trace as trace_mod, traffic as traffic_mod
 
 #: how long past the window's nominal close an answer is waited for
 STRAGGLER_S = 60.0
+#: the fewest steps of a recording that per-layer metrics are read from
+MIN_TRACED_STEPS = 20
 
 COUNTED = ("requests_completed", "requests_failed", "tokens_generated",
            "decode_steps", "prefill_chunks", "preemptions", "prefix_hits")
@@ -147,11 +147,12 @@ def run(ctx):
     t_give_up = t_open + ctx["seconds"] + STRAGGLER_S
     sent = 0
 
-    def pump(until):
-        """Send what is due and wait for answers, up to ``until``."""
+    def pump(until, answers=len(plan)):
+        """Send what is due and wait for answers, up to ``until`` or until
+        ``answers`` requests have been answered."""
         nonlocal sent
-        while time.monotonic() < until and (sent < len(plan)
-                                            or in_flight[0]):
+        while time.monotonic() < until and len(records) < answers and (
+                sent < len(plan) or in_flight[0]):
             now = time.monotonic()
             free = cap is None or in_flight[0] < cap
             if sent < len(plan) and free and t_open + plan[sent][0] <= now:
@@ -165,15 +166,33 @@ def run(ctx):
 
     trace_data = traced = None
     if ctx["trace"]:
-        pump(min(t_give_up, t_open + traffic["trace_after_seconds"]))
+        # the recording starts by work, not by the clock: when this share
+        # of the run's requests is answered, however fast the program is;
+        # it lasts ``trace_seconds`` at the most and ends with the last
+        # answer (pump returns when nothing is left to send or wait for)
+        start_at = math.ceil(traffic["trace_after_share"] * len(plan))
+        pump(t_give_up, start_at)
         with trace_mod.recording() as recorded:
             c0 = _counts(server)
+            t_on = time.monotonic()
             steplog.on = True
-            pump(min(t_give_up, time.monotonic() + traffic["trace_seconds"]))
+            pump(min(t_give_up, t_on + traffic["trace_seconds"]))
             steplog.on = False
+            t_off = time.monotonic()
             c1 = _counts(server)
-        traced = {"steps": steplog.steps,
+        traced = {"steps": steplog.steps, "after_answers": start_at,
+                  "started_s": t_on - t_open, "recorded_s": t_off - t_on,
                   "counts": {k: c1[k] - c0[k] for k in c0}}
+        if len(steplog.steps) < MIN_TRACED_STEPS:
+            server.shutdown(drain=False)
+            raise RuntimeError(
+                f"traffic mix {ctx['cell']['traffic']!r}: the recording, "
+                f"started after {start_at} of {len(plan)} answers "
+                f"{traced['started_s']:.2f} s into the batch, held "
+                f"{len(steplog.steps)} steps in a window_s of "
+                f"{traced['recorded_s']:.2f}; {MIN_TRACED_STEPS} are the "
+                "least a per-layer metric is read from: give the mix more "
+                "requests a second of --seconds (benchmark/README.md)")
         trace_data = recorded["trace"]
     pump(t_give_up)
     # all answered: the window closes with the last answer; else now
@@ -247,6 +266,13 @@ def run(ctx):
     counters["kv_blocks_pool"] = blocks["pool"]
     notes = {"requests": len(plan), "answered": len(records),
              "out_tokens": out_tokens, "window_s": window_s,
+             # under a half, the mix is due for more requests (README.md)
+             "window_share": window_s / ctx["seconds"],
+             # how many answers had come by each whole second of the window:
+             # a mix's trace_after_share is chosen from this line
+             "answers_by_second": [
+                 sum(r["t_done"] - t_open <= s for r in records)
+                 for s in range(1, math.ceil(window_s) + 1)],
              "setup_s": setup_s, "reference_s": reference_s,
              "tokens_compared": compared, "warmed_programs": len(warmed),
              "warmup_s": sum(warmed.values()), "setup_marks": marks,
@@ -255,7 +281,7 @@ def run(ctx):
                               if "bytes" in k},
              "bytes_in_use_at_reference": freed,
              "traced": traced and {
-                 "counts": traced["counts"], "steps": len(traced["steps"]),
+                 **traced, "steps": len(traced["steps"]),
                  "tokens": sum(n for step in traced["steps"]
                                for n, _ in step)},
              "latency_ms": {name: {"p50": 1e3 * _percentile(v, 0.5),

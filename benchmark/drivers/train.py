@@ -2,8 +2,8 @@
 window over batches that are already on the device.
 
 The construction follows ``chip_smoke.py``'s ``train_phase`` (a Gluon net
-through ``Trainer.compile_step``); the timing follows ``bench.py`` (steps
-chained, the loss fetched now and then and after the last step). What a
+through ``Trainer.compile_step``); the timing is a training loop's: steps
+chained, the loss fetched now and then and after the last step. What a
 configuration and a traffic mix may set is in ``benchmark/README.md``.
 
 Set-up builds the one step object, drives it through its first steps on

@@ -5,9 +5,9 @@ device time by operation name. Nothing else.
 that ``jax.profiler`` writes (with nothing but JAX) into plain lists;
 ``reduce`` does the arithmetic
 on those lists, so the test can feed it the recorded, cut-down trace under
-``benchmark/testdata/``. The arithmetic follows
-``mxnet_tpu/observability/rollup.py`` (sums over the device's "XLA Ops"
-lane), with the busy *union* added: events on one lane can nest (a
+``benchmark/testdata/``. The arithmetic is sums over the device's "XLA
+Ops" lane (the program's own roll-up of a trace went with PR 30; this is
+the one copy), with the busy *union* added: events on one lane can nest (a
 ``while`` holds its body's operations), so a plain sum counts time twice.
 """
 import contextlib

@@ -533,7 +533,7 @@ TOY_CHAT = {"arrivals": {"law": "closed", "clients": 16,
                               "sigma": 0.9, "min": 4, "max": 64},
             "output_tokens": {"law": "geometric", "mean": 8, "min": 2,
                               "max": 24},
-            "checked_requests": 4, "trace_after_seconds": 0.1,
+            "checked_requests": 4, "trace_after_share": 0.1,
             "trace_seconds": 0.3}
 TOY_OPEN = dict(TOY_CHAT, arrivals={"law": "open", "rate_per_s": 40,
                                     "cv": 2.0})
@@ -653,6 +653,118 @@ def test_traced_serving_rehearsal_reports_the_per_layer_metrics():
     for name, m in result["metrics"].items():
         assert m["value"] > 0, name
     assert 0 < result["metrics"]["step.mfu.serve"]["value"] <= 100
+
+
+@needs_serve_cell
+def test_recording_starts_at_the_stated_share_of_answers():
+    from benchmark.drivers import serve
+    mix = dict(TOY_CHAT, trace_after_share=0.5)
+    with recorded_trace():
+        out, _ = serve_rehearsal(traced=True, mix=mix)
+    traced, notes = out["traced"], out["notes"]
+    # 48 requests: the recording starts with the 24th answer, so at most
+    # the other 24 complete inside it, and it holds steps
+    assert traced["after_answers"] == 24 and notes["answered"] == 48
+    assert 0 < traced["counts"]["requests_completed"] <= 24
+    assert len(traced["steps"]) >= serve.MIN_TRACED_STEPS
+    assert 0 < traced["started_s"] < notes["window_s"]
+    assert 0 < traced["recorded_s"] <= mix["trace_seconds"] + 0.2
+    assert notes["traced"]["steps"] == len(traced["steps"])
+    # the notes say how the batch filled its window, second by second
+    assert notes["window_share"] == pytest.approx(notes["window_s"] / 1.0)
+    assert notes["answers_by_second"][-1] == 48
+    assert notes["answers_by_second"] == sorted(notes["answers_by_second"])
+
+
+@needs_serve_cell
+@pytest.mark.parametrize("share,trace_seconds", [(1.0, 0.3), (0.1, 0.0)],
+                         ids=["batch_over_at_the_share", "recording_too_short"])
+def test_recording_with_too_few_steps_fails_with_the_drivers_message(
+        share, trace_seconds):
+    from benchmark.drivers import serve
+    mix = dict(TOY_CHAT, trace_after_share=share, trace_seconds=trace_seconds)
+    with recorded_trace(), pytest.raises(RuntimeError) as err:
+        serve_rehearsal(traced=True, mix=mix)
+    said = str(err.value)
+    # the mix by name, the recording's length, the steps it held
+    assert repr(SERVE_CELLS[0]["traffic"]) in said
+    assert re.search(r"held \d+ steps in a window_s of \d+\.\d+", said)
+    assert f"{serve.MIN_TRACED_STEPS} are the least" in said
+    assert "README.md" in said
+
+
+@pytest.mark.parametrize("cell", SERVE_CELLS, ids=lambda c: c["name"])
+def test_request_counts_are_what_perf_md_states(cell):
+    from benchmark import traffic
+    mix = run_mod.load_json("benchmark", "traffic", cell["traffic"] + ".json")
+    plan = traffic.requests(1, mix, 64, BENCH["run_seconds"])
+    with open(os.path.join(ROOT, "PERF.md")) as fh:
+        section = fh.read().split("\n## 4. Cells", 1)[1].split("\n## 5.")[0]
+    bullet = section.split(f"- `{cell['name']}` (traffic `{cell['traffic']}`",
+                           1)[1].split("\n- `")[0].split("\n**")[0]
+    stated = int(re.search(r"([\d,]+)\s+requests", bullet).group(1)
+                 .replace(",", ""))
+    assert len(plan) == stated
+    # where the cell's line in BENCHMARK.json states a count, the same
+    in_why = re.search(r"([\d,]+) requests", cell["why"])
+    assert in_why is None or int(in_why.group(1).replace(",", "")) == stated
+    assert 0 < mix["trace_after_share"] < 1 and mix["trace_seconds"] > 0
+
+
+@pytest.mark.parametrize("cell", SERVE_CELLS, ids=lambda c: c["name"])
+def test_lengths_seed_is_the_one_the_files_own_rule_picks(cell):
+    """A mix that says its seed is "of seeds 1 to 8" the draw nearest the
+    published statistics holds the seed that rule picks at the size
+    ``run_seconds`` gives it: the mean where the law is stated by a mean,
+    the median where by a median, the two relative distances added."""
+    import numpy as np
+    from benchmark import traffic
+    mix = run_mod.load_json("benchmark", "traffic", cell["traffic"] + ".json")
+    if not mix["assumed"]["lengths_seed"].startswith("of seeds 1 to 8"):
+        pytest.skip("the mix states another rule for its seed")
+
+    def lengths(seed):
+        plan = traffic.requests(1, dict(mix, lengths_seed=seed), 64,
+                                BENCH["run_seconds"])
+        return {"prompt_tokens": [len(p) for _, p, _ in plan],
+                "output_tokens": [o for _, _, o in plan]}
+
+    def distance(seed):
+        total = 0.0
+        for part, drawn in lengths(seed).items():
+            stat = "mean" if "mean" in mix[part] else "median"
+            total += abs(getattr(np, stat)(drawn) / mix[part][stat] - 1)
+        return total
+    assert min(range(1, 9), key=distance) == mix["lengths_seed"]
+    # the file says at what size the choice was made
+    n = len(lengths(mix["lengths_seed"])["output_tokens"])
+    assert str(n) in mix["assumed"]["lengths_seed"]
+
+
+def test_readme_lists_the_keys_of_a_serving_mix_that_are_read():
+    """One trigger, not two: what README.md says a serving mix holds is
+    what ``serve.py`` and ``traffic.requests`` read, beside the keys that
+    only document the mix."""
+    import inspect
+    from benchmark import traffic
+    from benchmark.drivers import serve
+    read = set()
+    for source in (inspect.getsource(serve), inspect.getsource(
+            traffic.requests)):
+        read |= set(re.findall(r'traffic(?:\[|\.get\()"(\w+)"', source))
+        # ``traffic[part]`` for each part of the loop's tuple
+        for parts in re.findall(r"part in enumerate\(\(([^)]*)\)\)", source):
+            read |= set(re.findall(r'"(\w+)"', parts))
+    with open(os.path.join(ROOT, "benchmark", "README.md")) as fh:
+        row = next(line for line in fh if line.startswith(
+            "| `traffic/<traffic>.json`"))
+    listed = set(re.findall(r"`(\w+)`", row.split("A serving mix", 1)[1]))
+    assert listed - {"source", "assumed", "why"} == read
+    assert "trace_after_share" in read and len(read) == 8
+    for cell in SERVE_CELLS:
+        mix = run_mod.load_json("benchmark", "traffic",
+                                cell["traffic"] + ".json")
+        assert read - {"shared_prefix"} <= set(mix) <= listed | {"why"}
 
 
 @needs_serve_cell

@@ -40,7 +40,7 @@ TOY_CHAT = {"arrivals": {"law": "closed", "clients": 16,
                               "sigma": 0.9, "min": 4, "max": 64},
             "output_tokens": {"law": "geometric", "mean": 8, "min": 2,
                               "max": 24},
-            "checked_requests": 4, "trace_after_seconds": 0.1,
+            "checked_requests": 4, "trace_after_share": 0.1,
             "trace_seconds": 0.3}
 
 
