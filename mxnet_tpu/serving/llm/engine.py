@@ -15,11 +15,17 @@ The execution core of ``mxnet_tpu.serving.llm``. One engine iteration
    into the regular step — a prefill chunk is just a multi-token
    decode, so long prompts never stall running decodes behind a
    monolithic prefill launch;
-2. **plan + allocate** — each running sequence declares this step's
-   query tokens: the next ``prefill_chunk`` prompt tokens while its
-   prompt is still being written, one token in plain decode, or
+2. **plan + allocate** — a step has a TOKEN BUDGET, the longest
+   packed batch the engine compiles (``max_seqs x q_tokens``: the top
+   rung of ``_t_buckets``), and the scheduler deals it out. Decoding
+   sequences take theirs first: one token in plain decode, or
    ``K + 1`` positions (last committed token + K draft proposals) in
-   speculative decode. Blocks covering the step's KV writes are
+   speculative decode. What is left goes to the sequences whose prompt
+   is still being written, in admission order, each taking as much of
+   its prompt as the budget still holds (first come, first finished);
+   one the budget does not reach sits the step out, its slot
+   untouched. ``prefill_chunk`` is the budget's tokens a slot, not a
+   cap on a row. Blocks covering the step's KV writes are
    allocated up front (on the sequence, so every failure path frees
    them); under KV pressure the newest-admitted sequence is preempted
    (blocks freed, generation folded into its prompt, requeued — the
@@ -461,8 +467,11 @@ class LLMEngine:
     preempted near-full prompt must re-prefill whole); ``num_blocks``
     must leave room for at least one full-context sequence, which also
     guarantees a lone sequence can never deadlock on allocation.
-    ``prefill_chunk`` (``MXNET_TPU_LLM_PREFILL_CHUNK``) sets how many
-    prompt tokens one step writes; ``draft_model``/``draft_params`` +
+    ``prefill_chunk`` (``MXNET_TPU_LLM_PREFILL_CHUNK``) is the step's
+    token budget a slot: a step feeds at most ``max_seqs x
+    max(prefill_chunk, spec_k + 1)`` tokens, the decoding rows' first
+    and the rest to prefilling rows in admission order, however long a
+    row's share comes out; ``draft_model``/``draft_params`` +
     ``spec_k`` (``MXNET_TPU_LLM_SPEC_K``) enable speculative decoding
     (the draft must share the target's vocab and cover its context).
     """
@@ -546,8 +555,9 @@ class LLMEngine:
             raise ValueError(f"spec_k must be >= 0, got {spec_k}")
         self.spec_k = int(spec_k) if draft_model is not None else 0
         self.draft_model = draft_model if self.spec_k > 0 else None
-        # per-row query budget: a prefill chunk or a K+1-position
-        # speculative verify, whichever is wider
+        # the step's token budget a slot: a slot's share of prompt
+        # tokens or a K+1-position speculative verify, whichever is
+        # wider
         self.q_tokens = max(self.prefill_chunk, self.spec_k + 1)
         # the FLAT step packs every row's query tokens into one
         # [total_q_tokens] batch — no per-sequence padding, so a
@@ -567,6 +577,9 @@ class LLMEngine:
                     + (self.max_seqs - i) * (self.spec_k + 1))
                 for i in (1, 2) if i <= self.max_seqs}
         self._t_buckets = sorted({t_lo, t_hi} | mids)
+        # what one step may feed: the top rung, dealt out by _schedule
+        # (the draft program's top rung is the same length)
+        self._step_budget = t_hi
         # draft feeds are 1-2 tokens per row in steady state
         # (catch-up + proposal) and chunk-wide during prefill
         # mirroring
@@ -827,7 +840,7 @@ class LLMEngine:
         # directly with XLA for the core on small hosts
         self._bufs = {}
         self._draft_bufs = {}
-        self._arange = np.arange(self.q_tokens, dtype=np.int32)
+        self._arange = np.arange(self._step_budget, dtype=np.int32)
         self._device_get = jax.device_get
         # the last dispatch's expert load (a model with expert
         # layers), host ints
@@ -1389,11 +1402,15 @@ class LLMEngine:
             events.append(("expired", seq))
 
     # ----------------------------------------------------- planning --
-    def _plan(self, seq, events):
+    def _plan(self, seq, budget, events):
         """This step's work for one running sequence: which committed
-        tokens feed the chunk, how many draft slots it gets, and the
-        KV end position the allocator must cover. Returns the plan
-        dict or None (sequence was poison-isolated at prefill start)."""
+        tokens feed the chunk (a prefilling row takes ``budget`` of
+        them at the most, what the step's token budget still holds),
+        how many draft slots it gets, and the KV end position the
+        allocator must cover. ``cost`` is what the row takes of the
+        step's budget: its ``ntok``, or what its draft has to be fed,
+        if that is more. Returns the plan dict or None (sequence was
+        poison-isolated at prefill start)."""
         if not seq.generated:
             # prefilling (fresh prompt or preemption-folded resume —
             # folding moves the generation INTO the prompt, so an
@@ -1416,10 +1433,11 @@ class LLMEngine:
                     if self._breaker is not None:
                         self._breaker.record_failure(site="prefill")
                     return None
-            ntok = min(self.prefill_chunk, remaining)
+            ntok = min(budget, remaining)
             return {"kind": "prefill", "tokens":
                     committed[seq.seq_len:seq.seq_len + ntok],
-                    "ntok": ntok, "cl": cl, "committed": committed,
+                    "ntok": ntok, "cost": ntok, "cl": cl,
+                    "committed": committed,
                     "k": 0, "emit": ntok == remaining,
                     "draft_tokens": [], "draft_probs": []}
         # decode: one committed token outstanding. Speculate when the
@@ -1427,16 +1445,19 @@ class LLMEngine:
         # (steady state: 1-2 tokens behind; a degraded draft recovers
         # over catch-up-only feeds first)
         cl = len(seq.prompt) + len(seq.generated)
-        k = 0
+        k = behind = 0
         committed = None
         if self.draft_model is not None:
             committed = seq.prompt + seq.generated
-            if cl - seq.draft_len <= self.q_tokens:
+            behind = cl - seq.draft_len
+            if behind <= self.q_tokens:
                 rem_new = seq.max_new_tokens - seq.num_generated
                 k = max(0, min(self.spec_k, rem_new - 1,
                                self.max_context - 1 - seq.seq_len))
         return {"kind": "decode", "tokens": [seq.last_token],
-                "ntok": 1 + k, "cl": cl, "committed": committed,
+                "ntok": 1 + k,
+                "cost": max(1 + k, min(behind, self.q_tokens)),
+                "cl": cl, "committed": committed,
                 "k": k, "emit": True,
                 "draft_tokens": [], "draft_probs": []}
 
@@ -1559,20 +1580,15 @@ class LLMEngine:
         if self.draft_model is None:
             return
         feeds, counters, proposing = {}, {}, []
+        # the draft program's own top rung, as long as the step's
+        # budget: the decoding rows' feeds first (each within its
+        # plan's ``cost``), the chunks' mirrors from what is left
+        left = self._draft_t_buckets[-1]
         for seq in rows:
             plan = plans[seq]
             if plan["kind"] == "prefill":
-                # mirror the target's chunk. Normally draft_len ==
-                # seq_len and this IS the same chunk; after a
-                # degraded draft step the mirror restarts from the
-                # draft's own watermark so its KV prefix never gaps
-                committed = plan["committed"]
-                end = min(seq.seq_len + plan["ntok"],
-                          seq.draft_len + self.q_tokens)
-                feeds[seq] = (committed[seq.draft_len:end],
-                              seq.draft_len)
-                plan["draft_fed"] = end - seq.draft_len
-            elif plan["k"] > 0:
+                continue
+            if plan["k"] > 0:
                 # catch-up (bounded: <= 2 tokens in steady state) +
                 # the proposal input
                 feed = plan["committed"][seq.draft_len:plan["cl"]]
@@ -1588,6 +1604,22 @@ class LLMEngine:
                 feeds[seq] = (plan["committed"][seq.draft_len:end],
                               seq.draft_len)
                 plan["draft_fed"] = end - seq.draft_len
+            left -= plan.get("draft_fed", 0)
+        for seq in rows:
+            plan = plans[seq]
+            if plan["kind"] != "prefill":
+                continue
+            # mirror the target's chunk. Normally draft_len ==
+            # seq_len and this IS the same chunk, which the step's
+            # budget has room for; after a degraded draft step the
+            # mirror restarts from the draft's own watermark so its
+            # KV prefix never gaps, and takes what the rung has left
+            end = min(seq.seq_len + plan["ntok"], seq.draft_len + left)
+            if end > seq.draft_len:
+                feeds[seq] = (plan["committed"][seq.draft_len:end],
+                              seq.draft_len)
+                plan["draft_fed"] = end - seq.draft_len
+                left -= end - seq.draft_len
         if not feeds:
             return
         try:
@@ -1759,6 +1791,8 @@ class LLMEngine:
             sp.set("tokens", t_need)
             sp.set("t_bucket", t)
             sp.set("mb", mb)
+            if self._stats:
+                self._stats.record_step_fill(t_need, t)
         with tracer.span("mxtpu.llm.launch", "llm") as sp:
             sp.set("sampled", sampled)
             out = self._call_step(sampled, batch)
@@ -1963,21 +1997,33 @@ class LLMEngine:
     def _schedule(self, events):
         """The host's half of an iteration before any launch: expire,
         admit, plan and allocate each running sequence, propose
-        drafts. Returns ``(rows, plans)``; no rows, nothing to
-        dispatch."""
+        drafts. Returns ``(rows, plans)``, the rows in admission order;
+        no rows, nothing to dispatch."""
         self._expire(events)
         self._admit(events)
         running = sorted(self.scheduler.running(),
                          key=lambda s: s.admit_index)
         plans = {}
-        for seq in running:
+        # the step's token budget: the rows that decode or verify take
+        # theirs first (a slot's share each at the most), the rows
+        # still writing their prompt what is left, in admission order.
+        # One the budget does not reach is in no plan: it rides the
+        # step inactive on the null block, as an empty slot does. (What
+        # a row took before a later one's blocks preempted it is not
+        # dealt again.)
+        left = self._step_budget
+        for seq in ([s for s in running if s.generated]
+                    + [s for s in running if not s.generated]):
             if seq.state != RUNNING:
                 continue            # preempted by an earlier victim
-            plan = self._plan(seq, events)
+            if left <= 0:
+                break
+            plan = self._plan(seq, left, events)
             if plan is None:
                 continue            # poison-isolated at prefill start
             self._allocate(seq, plan, events)
             plans[seq] = plan
+            left -= plan["cost"]
         rows = [s for s in running
                 if s.state == RUNNING and s in plans]
         if rows:

@@ -128,6 +128,15 @@ class LLMStats:
             "mxtpu_llm_prefill_chunk_tokens_total",
             "Prompt tokens written by prefill chunks (pad excluded).",
             lbl).labels(**s)
+        self._step_tokens = r.counter(
+            "mxtpu_llm_step_tokens_total",
+            "Tokens fed by dispatched steps (decode, verify and "
+            "prefill rows together, pad excluded).", lbl).labels(**s)
+        self._step_rung_tokens = r.counter(
+            "mxtpu_llm_step_rung_tokens_total",
+            "Packed length of the step programs dispatched (the "
+            "rung each step took): what mxtpu_llm_step_tokens_total "
+            "is a share of.", lbl).labels(**s)
         self._spec_proposed = r.counter(
             "mxtpu_llm_spec_proposed_total",
             "Draft tokens proposed for speculative verification.",
@@ -374,6 +383,12 @@ class LLMStats:
         self._prefill_chunks.inc()
         self._prefill_chunk_tokens.inc(tokens)
 
+    def record_step_fill(self, tokens, rung):
+        """One dispatched step: ``tokens`` fed to a program shaped
+        for ``rung``."""
+        self._step_tokens.inc(tokens)
+        self._step_rung_tokens.inc(rung)
+
     def record_spec(self, proposed, accepted):
         self._spec_proposed.inc(proposed)
         self._spec_accepted.inc(accepted)
@@ -511,6 +526,8 @@ class LLMStats:
                 "prefill_chunks": int(self._prefill_chunks.value),
                 "prefill_chunk_tokens": int(
                     self._prefill_chunk_tokens.value),
+                "step_tokens": int(self._step_tokens.value),
+                "step_rung_tokens": int(self._step_rung_tokens.value),
                 "spec_proposed": int(self._spec_proposed.value),
                 "spec_accepted": int(self._spec_accepted.value),
                 "spec_degraded": int(self._spec_degraded.value),

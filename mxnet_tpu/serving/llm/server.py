@@ -493,6 +493,10 @@ class LLMServer:
         snap["weight_params_per_chip"] = (
             self._engine.weight_params // max(1, self._engine.tp))
         snap["draft_weight_dtype"] = self._engine.draft_weight_dtype
+        # how full the steps ran: tokens fed over the rungs taken
+        rung = snap.get("step_rung_tokens", 0)
+        snap["step_fill_share"] = (snap.get("step_tokens", 0) / rung
+                                   if rung else 0.0)
         lookups = snap.get("prefix_lookups", 0)
         snap["prefix_hit_rate"] = (snap.get("prefix_hits", 0) / lookups
                                    if lookups else 0.0)

@@ -167,10 +167,61 @@ def test_step_span_carries_the_routers_load(model):
     assert fetches and set(fetches) == {3}       # one fetch, three arrays
     assert len(spans) == len(fetches)
     first = spans[0]["attrs"]
-    # the first chunk: 8 tokens x 2 experts x 2 expert layers
-    assert first["moe_pairs"] == 32 and first["rows"] == [(8, 8)]
+    # the first step, an idle engine's whole budget (8 slots of 8): the
+    # 11 prompt tokens at once x 2 experts x 2 expert layers
+    assert first["moe_pairs"] == 44 and first["rows"] == [(11, 11)]
     assert 2 <= first["moe_experts_hit"] <= 16
-    assert 2 <= first["moe_max_load"] <= 8
+    assert 2 <= first["moe_max_load"] <= 11
+
+
+def test_long_chunks_through_both_kernels_beside_decoding_rows(monkeypatch):
+    """The budget's long runs through the latent kernel and the grouped
+    expert products themselves (interpret mode), contexts to 170: 2
+    slots of 40, so a step of 80 tokens is two query blocks of 64. A
+    140-token prompt takes 60 tokens beside the other row's prompt, then
+    79 beside its decode token (a run astride the query blocks' edge);
+    the pool is too small for both contexts, so it is preempted and
+    writes its folded prompt again in chunks of 80 and 79. Every served
+    token is the reference's first choice."""
+    import functools
+    from mxnet_tpu.serving.llm import Sequence, model as model_mod
+    for name in ("mla_flat_attention", "moe_experts"):
+        monkeypatch.setattr(model_mod, name, functools.partial(
+            getattr(model_mod, name), use_pallas=True, interpret=True))
+    toy = dict(TOY, max_context=192)
+    # a model of its own: its programs trace the kernels, not the twins
+    model = TinyDecoder(DecoderConfig(**toy))
+    params = ref.init_params(5, toy, dtype=jnp.float32)
+    engine = LLMEngine(model, params, max_seqs=2, block_size=8,
+                       prefill_chunk=40, num_blocks=26)
+    assert engine._t_buckets == [2, 41, 80]
+    rs = np.random.RandomState(7)
+    cases = [(rs.randint(0, 97, 20).tolist(), 60),
+             (rs.randint(0, 97, 140).tolist(), 30)]
+    seqs = [Sequence(p, n) for p, n in cases]
+    chunks, inner = [], engine._dispatch
+
+    def dispatch(rows, plans):
+        chunks.extend((s is seqs[1], len(plans[s]["tokens"]), len(rows))
+                      for s in rows if plans[s]["kind"] == "prefill")
+        return inner(rows, plans)
+    engine._dispatch = dispatch
+    for s in seqs:
+        engine.add(s)
+    for _ in range(500):
+        if not engine.has_work():
+            break
+        engine.step()
+    assert not engine.has_work()
+    # (the long prompt's?, tokens, rows in the step)
+    assert chunks == [(False, 20, 2), (True, 60, 2), (True, 79, 2),
+                      (True, 1, 2), (True, 80, 1), (True, 79, 1)]
+    assert [s.preemptions for s in seqs] == [0, 1]
+    for s, (prompt, n) in zip(seqs, cases):
+        served = s.output_tokens()
+        assert len(served) == n
+        gaps = ref.served_token_gaps(params, prompt, served, toy, 192)
+        assert float(gaps.max()) <= 1e-4
 
 
 def test_latent_kernel_against_its_twin_with_garbage_invisible():

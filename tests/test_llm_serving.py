@@ -210,6 +210,55 @@ def test_kv_pressure_preempts_and_resumes_exact_stream(model, params):
     eng.cache.allocator.check()
 
 
+def test_long_chunks_through_the_kernel_beside_decoding_rows(monkeypatch):
+    """The budget's long runs through the flat kernel itself (interpret
+    mode): 2 slots of 40, so a step of 80 tokens is two query blocks of
+    64. A 140-token prompt takes 60 tokens beside the other row's
+    prompt, then 79 beside its decode token (a run astride the query
+    blocks' edge); the pool is too small for both contexts, so it is
+    preempted and writes its folded prompt again in chunks of 80 and 79.
+    Every served token is the dense reference's."""
+    import functools
+    from mxnet_tpu.serving.llm import model as model_mod
+    monkeypatch.setattr(model_mod, "ragged_flat_attention", functools.partial(
+        model_mod.ragged_flat_attention, use_pallas=True, interpret=True))
+    # a model of its own: its programs trace the kernel, not the twin
+    model = TinyDecoder(DecoderConfig(
+        vocab_size=VOCAB, d_model=16, num_layers=2, num_heads=2, d_ff=32,
+        max_context=192))
+    params = model.init_params(seed=0)
+    eng = LLMEngine(model, params, max_seqs=2, block_size=BS,
+                    max_context=192, prefill_chunk=40, num_blocks=26)
+    assert eng._t_buckets == [2, 41, 80]
+    rng = np.random.RandomState(7)
+    cases = [(rng.randint(0, VOCAB, size=20).tolist(), 60),
+             (rng.randint(0, VOCAB, size=140).tolist(), 30)]
+    seqs = [Sequence(p, n) for p, n in cases]
+    chunks, inner = [], eng._dispatch
+
+    def dispatch(rows, plans):
+        chunks.extend((s is seqs[1], len(plans[s]["tokens"]), len(rows))
+                      for s in rows if plans[s]["kind"] == "prefill")
+        return inner(rows, plans)
+    eng._dispatch = dispatch
+    for s in seqs:
+        eng.add(s)
+    steps = 0
+    while eng.has_work():
+        eng.step()
+        steps += 1
+        assert steps < 500
+    # (the long prompt's?, tokens, rows in the step)
+    assert chunks == [(False, 20, 2), (True, 60, 2), (True, 79, 2),
+                      (True, 1, 2), (True, 80, 1), (True, 79, 1)]
+    assert [s.preemptions for s in seqs] == [0, 1]
+    for s, (prompt, n) in zip(seqs, cases):
+        assert s.output_tokens() == greedy_decode_reference(
+            model, params, prompt, n)
+    assert eng.cache.allocator.num_used == 0
+    eng.cache.allocator.check()
+
+
 # ------------------------------------------------------ (d) drain ----
 def test_drain_deadline_evicts_with_partial_tokens(model, params):
     """Shutdown under a deadline: sequences that cannot finish resolve
@@ -379,10 +428,16 @@ def test_llm_metrics_in_one_exposition(model, params):
     assert st["tokens_per_sec"] > 0
     assert st["ttft_ms"]["p50"] <= st["ttft_ms"]["p99"]
     text = get_registry().expose()
+    # how full the steps ran: 3 prompts of 2 tokens and 9 decode tokens
+    assert st["step_tokens"] == 15 < st["step_rung_tokens"]
+    assert st["step_fill_share"] == pytest.approx(
+        st["step_tokens"] / st["step_rung_tokens"])
     for needed in ("mxtpu_llm_tokens_per_sec", "mxtpu_llm_ttft_seconds",
                    "mxtpu_llm_kv_blocks_in_use",
                    "mxtpu_llm_requests_completed_total",
-                   "mxtpu_llm_decode_steps_total"):
+                   "mxtpu_llm_decode_steps_total",
+                   "mxtpu_llm_step_tokens_total",
+                   "mxtpu_llm_step_rung_tokens_total"):
         assert needed in text, f"{needed} missing from exposition"
     # the tools-side checker must accept the exposition wholesale
     sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(

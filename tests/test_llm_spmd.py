@@ -223,8 +223,8 @@ def test_tp2_mixed_traffic_zero_recompiles_one_dispatch(world, model,
     recompiles and exactly ONE launch of the sharded unified step per
     ``engine.step()`` — and greedy rows match the eager reference."""
     jobs = [
-        # 14-token prompt: two chunked-prefill steps through the
-        # unified program before its first token
+        # 14-token prompt: a chunk longer than ``prefill_chunk``
+        # (8), what the step's budget of 8 a slot has room for
         (list(range(1, 15)), None, None),
         ([4, 5, 6], SamplingParams(temperature=0.8, top_k=5, seed=7),
          None),

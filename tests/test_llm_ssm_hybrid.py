@@ -167,11 +167,12 @@ def test_forward_matches_the_plain_reference(model, params):
 # ------------------------------------------------- engine vs forward --
 
 def test_engine_serves_what_forward_computes(model, params):
-    """Prompts of 37, 21 and 50 tokens (chunks of 16 and a rest: the
-    state carried over chunks), one of 3 (exactly the convolution's
-    window) and one of 2 (shorter than it), five requests on three
-    slots, so that runs of several sequences lie side by side in one
-    packed batch and two slots are used again."""
+    """Prompts of 37, 21 and 50 tokens (the step's budget of 48 dealt
+    in admission order: 37 and 11, then 10, 3 and 2, ...: the state
+    carried over chunks), one of 3 (exactly the convolution's window)
+    and one of 2 (shorter than it), five requests on three slots, so
+    that runs of several sequences lie side by side in one packed batch
+    and two slots are used again."""
     rs = np.random.RandomState(1)
     prompts = [_prompt(rs, n) for n in (37, 21, 3, 2, 50)]
     n_new = [6, 9, 5, 7, 4]
@@ -188,12 +189,13 @@ def test_engine_serves_what_forward_computes(model, params):
 
 
 def test_runs_shorter_than_the_window_shift_it_through(model, params):
-    """Chunks of 2: every run is shorter than the window's 3 inputs, so
-    each step's window is part old, part new."""
+    """A budget of 2 (one slot of 2): every run is shorter than the
+    window's 3 inputs, so each step's window is part old, part new."""
     rs = np.random.RandomState(2)
     prompt = _prompt(rs, 11)
-    engine = LLMEngine(model, params, max_seqs=2, block_size=4,
+    engine = LLMEngine(model, params, max_seqs=1, block_size=4,
                        prefill_chunk=2)
+    assert engine._step_budget == 2
     seq = Sequence(prompt, 5)
     _drain(engine, [seq])
     assert len(seq.generated) == 5 \
@@ -235,6 +237,90 @@ def test_preemption_and_readmission_give_the_same_tokens(model, params):
         out = seq.output_tokens()
         assert len(out) == 40 and _is_greedy(model, params, prompt, out)
     assert engine.state_starts == 2 + sum(s.preemptions for s in seqs)
+
+
+def test_a_row_the_budget_does_not_reach_keeps_its_slot_bit_for_bit(
+        model, params):
+    """2 slots of 4, a budget of 8, prompts of 20 and 10 tokens admitted
+    together: the first takes the whole budget twice, and the second,
+    in its slot all the while, is in no step's rows; its slot's state
+    and window (garbage planted there before) come back bit for bit
+    until the budget reaches it, and it then starts from zeros."""
+    rs = np.random.RandomState(12)
+    engine = LLMEngine(model, params, max_seqs=2, block_size=4,
+                       prefill_chunk=4)
+    assert engine._step_budget == 8
+    pools = engine.cache.pools()
+    engine.cache.swap(pools[0], *(
+        jnp.asarray(rs.randn(*p.shape), jnp.float32) for p in pools[1:]))
+    prompts = [_prompt(rs, 20), _prompt(rs, 10)]
+    first, second = seqs = [Sequence(p, 6) for p in prompts]
+    rows_of, inner = [], engine._dispatch
+    engine._dispatch = lambda rows, plans: (
+        rows_of.append({s: len(plans[s]["tokens"]) for s in rows}),
+        inner(rows, plans))[1]
+    for s in seqs:
+        engine.add(s)
+    for n in (8, 16):
+        before = [np.asarray(p) for p in engine.cache.pools()[1:]]
+        engine.step()
+        assert rows_of[-1] == {first: 8} and first.seq_len == n
+        assert second.slot == 1 and second.seq_len == 0 \
+            and second.block_ids == []
+        for was, now in zip(before, engine.cache.pools()[1:]):
+            np.testing.assert_array_equal(np.asarray(now)[:, 1], was[:, 1])
+            assert not np.array_equal(np.asarray(now)[:, 0], was[:, 0])
+    engine.step()
+    assert rows_of[-1] == {first: 4, second: 4}     # the remainder
+    _drain(engine, [])
+    for seq, prompt in zip(seqs, prompts):
+        assert len(seq.generated) == 6 \
+            and _is_greedy(model, params, prompt, seq.generated)
+    assert engine.state_rows == sum(len(r) for r in rows_of)
+
+
+def test_long_chunks_through_both_kernels_beside_decoding_rows(monkeypatch):
+    """The budget's long runs through the state-space and the
+    grouped-query kernels themselves (interpret mode), contexts to 170:
+    2 slots of 40, so a step of 80 tokens is two query blocks of 64. A
+    140-token prompt takes 60 tokens beside the other row's prompt, then
+    79 beside its decode token (one run of 79 through the recurrence,
+    astride the query blocks' edge); the pool is too small for both
+    contexts, so it is preempted and writes its folded prompt again from
+    a state of zeros in chunks of 80 and 79. Every served token is the
+    reference's first choice."""
+    import functools
+    for name in ("ssd_state_step", "gqa_flat_attention"):
+        monkeypatch.setattr(model_mod, name, functools.partial(
+            getattr(model_mod, name), use_pallas=True, interpret=True))
+    toy = dict(TOY, max_context=192)
+    # a model of its own: its programs trace the kernels, not the twins
+    model = TinyDecoder(DecoderConfig(**toy))
+    params = ref.init_params(11, toy, dtype=jnp.float32)
+    engine = LLMEngine(model, params, max_seqs=2, block_size=8,
+                       prefill_chunk=40, num_blocks=26)
+    assert engine._t_buckets == [2, 41, 80]
+    rs = np.random.RandomState(7)
+    cases = [(_prompt(rs, 20), 60), (_prompt(rs, 140), 30)]
+    seqs = [Sequence(p, n) for p, n in cases]
+    chunks, inner = [], engine._dispatch
+
+    def dispatch(rows, plans):
+        chunks.extend((s is seqs[1], len(plans[s]["tokens"]), len(rows))
+                      for s in rows if plans[s]["kind"] == "prefill")
+        return inner(rows, plans)
+    engine._dispatch = dispatch
+    _drain(engine, seqs)
+    # (the long prompt's?, tokens, rows in the step)
+    assert chunks == [(False, 20, 2), (True, 60, 2), (True, 79, 2),
+                      (True, 1, 2), (True, 80, 1), (True, 79, 1)]
+    assert [s.preemptions for s in seqs] == [0, 1]
+    assert engine.state_starts == 3
+    for s, (prompt, n) in zip(seqs, cases):
+        served = s.output_tokens()
+        assert len(served) == n
+        gaps = ref.served_token_gaps(params, prompt, served, toy, 192)
+        assert float(gaps.max()) <= LOGIT_TOL
 
 
 def test_padding_rows_touch_no_state(model, params):
@@ -320,7 +406,8 @@ def test_step_span_carries_the_state_rows(model, params):
     assert spans
     first = spans[0]["attrs"]
     assert first["state_rows"] == len(first["rows"]) == 2
-    assert first["state_runs_prefill"] == 1     # 8 tokens beside 1
+    assert first["state_runs_prefill"] == 1     # 11 tokens beside 1
+    assert first["rows"] == [(11, 11), (1, 1)]
     assert all(s["attrs"]["state_rows"] == len(s["attrs"]["rows"])
                for s in spans)
     assert sum(s["attrs"]["state_rows"] for s in spans) \
