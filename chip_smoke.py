@@ -42,7 +42,8 @@ import json
 import sys
 import time
 
-PHASES = ("train", "serve", "kernels", "latent", "ragged", "ssd")
+PHASES = ("train", "serve", "kernels", "latent", "ragged", "ssd",
+          "blockdiff")
 
 #: kernel-vs-reference bounds, fixed from the dtype before any chip run.
 #: bf16 flash attention: the repo's own bf16 bound (tests/test_attention.py).
@@ -726,6 +727,131 @@ def ssd_phase(cases, layers, slots, heads, head_dim, state, chunk=16,
         interpret=interpret)
 
 
+def blockdiff_phase(cases, layers, num_blocks, block_size, heads, kv_heads,
+                    head_dim, block_length, experts, top_k, d_model, width,
+                    slots=32, median=330.0, dtype="bfloat16", reps=28,
+                    interpret=False):
+    """The two kernels of a block-diffusion expert decoder's step alone,
+    at a step as the engine lays it out: ``slots`` rows that each feed
+    one block of ``block_length`` positions (``T = slots x
+    block_length``), or, at a larger ``T``, one row's block replaced by a
+    prompt chunk of whole blocks that takes the rest; contexts lognormal
+    about ``median`` (the chat cell's prompt and half its answer), every
+    block aligned. Grouped queries over ONE pool of packed rows
+    ``[layers, num_blocks, block_size, 2 x kv_heads x head_dim]`` under
+    the BLOCK-CAUSAL mask (the kernel is given each token's block's last
+    position) against the gather reference; the grouped expert products
+    over one layer's ``experts`` stacked SwiGLUs of ``width``, ``top_k``
+    a token by the softmax router, against the plain twin. Each timed
+    over ``reps`` calls in one program."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from mxnet_tpu.ops.moe import (moe_experts, moe_experts_reference,
+                                   route_softmax_topk)
+    from mxnet_tpu.ops.ragged_attention import (
+        gqa_flat_attention, gqa_flat_attention_reference)
+
+    started, c0 = time.monotonic(), _counters()
+    B, MB = block_length, (num_blocks - 1) // slots
+    pool = jax.random.normal(
+        jax.random.PRNGKey(9), (layers, num_blocks, block_size,
+                                2 * kv_heads * head_dim)).astype(dtype)
+    ks = jax.random.split(jax.random.PRNGKey(11), 4)
+    router = 3 * d_model ** -0.5 * jax.random.normal(
+        ks[0], (d_model, experts), jnp.float32)
+    w_gate, w_up = (
+        (d_model ** -0.5 * jax.random.normal(
+            k, (experts, d_model, width), jnp.float32)).astype(dtype)
+        for k in ks[1:3])
+    w_down = (width ** -0.5 * jax.random.normal(
+        ks[3], (experts, width, d_model), jnp.float32)).astype(dtype)
+    weights = (w_gate, w_up, w_down)
+    layer, scale = layers // 2, head_dim ** -0.5
+    ms_attn, ms_experts, errs, hit, run_s = {}, {}, {}, {}, 0.0
+    for T in cases:
+        name = f"T{T}"
+        rs = np.random.RandomState(T)
+        lens = [B] * slots
+        lens[0] = T - (slots - 1) * B
+        check(lens[0] >= B and lens[0] % B == 0,
+              f"blockdiff {name}: T is {slots} blocks of {B}, or more by "
+              "whole blocks")
+        tables = jnp.asarray(1 + rs.permutation(num_blocks - 1)[
+            :slots * MB].reshape(slots, MB), jnp.int32)
+        ends = [max(n, int(np.clip(rs.lognormal(np.log(median), 0.7), n,
+                                   MB * block_size)) // B * B)
+                for n in lens]
+        sids = jnp.asarray(np.repeat(np.arange(slots), lens), jnp.int32)
+        poss = jnp.asarray(np.concatenate(
+            [np.arange(end - n, end) for n, end in zip(lens, ends)]),
+            jnp.int32)
+        sees = (poss // B + 1) * B - 1
+        q = jax.random.normal(jax.random.PRNGKey(T), (T, heads, head_dim),
+                              jnp.float32)
+
+        # every large array goes in as an argument: one that a jitted
+        # function closes over becomes a constant of its program
+        def attend(q, lyr, pool):
+            return gqa_flat_attention(q, pool, tables, sids, sees, scale,
+                                      kv_heads, layer=lyr, use_pallas=True,
+                                      interpret=interpret)
+
+        got = jax.block_until_ready(jax.jit(attend)(q, layer, pool))
+        reference = jax.jit(
+            lambda q, s, p, pool: gqa_flat_attention_reference(
+                q, pool, tables, s, p, scale, kv_heads, layer=layer))
+        with jax.default_matmul_precision("highest"):
+            want = jnp.concatenate([
+                reference(q[r:r + 16], sids[r:r + 16], sees[r:r + 16], pool)
+                for r in range(0, T, 16)])
+        errs[name + "_attn"] = _rel_err(got, want)
+        many = jax.jit(lambda q, pool: jax.lax.fori_loop(
+            0, reps, lambda i, _: attend(q, i % layers, pool),
+            jnp.zeros_like(got)))
+        jax.block_until_ready(many(q, pool))                    # compile
+        t0 = time.monotonic()
+        jax.block_until_ready(many(q, pool))
+        dt = time.monotonic() - t0
+        run_s += dt
+        ms_attn[name] = round(dt / reps * 1e3, 4)
+
+        x = jax.random.normal(jax.random.PRNGKey(T + 1), (T, d_model),
+                              jnp.float32)
+        live = jnp.ones((T,), jnp.int32)
+        sel, gates = route_softmax_topk(x, router, top_k)
+        hit[name] = int(jnp.unique(sel).size)
+
+        def products(x, wg, wu, wd):
+            return moe_experts(x.astype(dtype), sel, gates, live, wg, wu,
+                               wd, use_pallas=True, interpret=interpret)
+
+        got = jax.block_until_ready(jax.jit(products)(x, *weights))
+        want = jax.jit(lambda x, wg, wu, wd: moe_experts_reference(
+            x.astype(dtype), sel, gates, live, wg, wu, wd))(x, *weights)
+        errs[name + "_experts"] = _rel_err(got, want)
+        # each call's input leans on the last one's output, so that no
+        # call is folded into another
+        many = jax.jit(lambda x, *w: jax.lax.fori_loop(
+            0, reps, lambda i, c: x + 1e-6 * products(c, *w), x))
+        jax.block_until_ready(many(x, *weights))                # compile
+        t0 = time.monotonic()
+        jax.block_until_ready(many(x, *weights))
+        dt = time.monotonic() - t0
+        run_s += dt
+        ms_experts[name] = round(dt / reps * 1e3, 4)
+    for name, e in errs.items():
+        check(e <= LATENT_TOL,
+              f"blockdiff {name}: error {e:.3g} over bound {LATENT_TOL}")
+    return _report(
+        "blockdiff", started, c0, time.monotonic() - started - run_s, run_s,
+        pool=list(pool.shape), experts=[experts, d_model, width],
+        dtype=dtype, reps=reps, ms_per_call_attn=ms_attn,
+        ms_per_call_experts=ms_experts, experts_hit=hit,
+        rel_err={k: float(f"{e:.3g}") for k, e in errs.items()},
+        interpret=interpret)
+
+
 # ------------------------------------------------------------------- main --
 
 def main(argv=None):
@@ -735,8 +861,8 @@ def main(argv=None):
                          "four-chip mesh in this one process")
     ap.add_argument("--phases", default=",".join(PHASES),
                     help="the phases to run, comma separated, of "
-                         + ", ".join(PHASES) + " (kernels, latent, ragged and "
-                         "ssd: one chip only)")
+                         + ", ".join(PHASES) + " (kernels, latent, ragged, "
+                         "ssd and blockdiff: one chip only)")
     args = ap.parse_args(argv)
     phases = args.phases.split(",")
     if set(phases) - set(PHASES):
@@ -802,6 +928,14 @@ def main(argv=None):
         # decodes, 32 chunks
         ssd_phase(cases=(32, 47, 512), layers=36, slots=32, heads=64,
                   head_dim=64, state=128)
+    if "blockdiff" in phases and not many:
+        # the sdar_30b_a3b_chat cell's pool, heads and one layer's
+        # experts: 32 rows of a block of 4 (T 128), and 31 beside a
+        # prompt chunk of 388 (T 512)
+        blockdiff_phase(cases=(128, 512), layers=7, num_blocks=8193,
+                        block_size=16, heads=32, kv_heads=4, head_dim=128,
+                        block_length=4, experts=128, top_k=8, d_model=2048,
+                        width=768)
     dev = devices[0]
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,

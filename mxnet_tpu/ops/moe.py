@@ -1,6 +1,7 @@
-"""Routed experts for the flat serving step: the ``noaux_tc`` sigmoid
-router of DeepSeek-V3-shaped models and the grouped products over the
-experts that a step's tokens hit.
+"""Routed experts for the flat serving step: the two routers (the
+``noaux_tc`` sigmoid router of DeepSeek-V3-shaped models, and softmax
+then top-``k`` then renormalise, the Qwen3-MoE family's) and the grouped
+products over the experts that a step's tokens hit.
 
 A step's ``T`` tokens each pick ``k`` of ``E`` experts: ``T * k`` (token,
 expert) *pairs*. An expert is a SwiGLU, ``(silu(x Wg) * (x Wu)) Wd``, of
@@ -42,8 +43,8 @@ from jax.experimental.pallas import tpu as pltpu
 from .registry import register
 from .flash_attention import _on_tpu
 
-__all__ = ["route_noaux_tc", "moe_experts", "moe_experts_reference",
-           "moe_load"]
+__all__ = ["route_noaux_tc", "route_softmax_topk", "moe_experts",
+           "moe_experts_reference", "moe_load"]
 
 #: rows of one tile of the grouped products (a packed bf16 tile)
 TILE_ROWS = 16
@@ -66,6 +67,20 @@ def route_noaux_tc(x, router, bias, k, norm_topk_prob=True, scaling=1.0):
     if norm_topk_prob:
         gates = gates / (gates.sum(axis=1, keepdims=True) + 1e-20)
     return sel.astype(jnp.int32), gates * scaling
+
+
+def route_softmax_topk(x, router, k, norm_topk_prob=True):
+    """``(experts [T, k] int32, gates [T, k] float32)`` for ``x [T, d]``:
+    scores ``softmax(x router)`` over ALL the experts in float32, the
+    ``k`` largest, their scores as gates, divided by their sum if
+    ``norm_topk_prob``. No selection bias, no scaling factor."""
+    s = jax.nn.softmax(jnp.matmul(
+        x.astype(jnp.float32), router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST), axis=-1)
+    gates, sel = jax.lax.top_k(s, k)
+    if norm_topk_prob:
+        gates = gates / gates.sum(axis=1, keepdims=True)
+    return sel.astype(jnp.int32), gates
 
 
 def moe_load(sel, valid, num_experts):

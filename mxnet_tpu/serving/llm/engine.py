@@ -90,7 +90,7 @@ __all__ = ["LLMEngine"]
 
 
 def _make_step_fn(model, spec_k, sampled, quantized=False, lora=False,
-                  axis_name=None, wq=False):
+                  axis_name=None, wq=False, blockdiff=None):
     """Build the target step program body for (model, spec_k): ONE
     program covering chunked prefill + decode + speculative verify
     over the FLAT ragged layout — a packed ``[total_q_tokens]`` batch
@@ -151,7 +151,20 @@ def _make_step_fn(model, spec_k, sampled, quantized=False, lora=False,
     forwarded as ``decode_flat(..., w_scales=...)``, so positional
     pool/batch signatures (and the donation indices) are identical to
     the f32 program and a quantized hot-swap reuses every warmed
-    rung."""
+    rung.
+
+    ``blockdiff`` (the model's ``block_diffusion``) puts a REVEAL
+    epilogue where the accept rule stands: the batch grows three
+    trailing arrays, blk_idx int32 [S, B] (the flat indices of the
+    block a row feeds), blk_masked int32 [S, B] (which of its positions
+    are still masked) and n_reveal int32 [S] (how many this pass
+    reveals; 0 for a settling pass and for a row that feeds no block),
+    and the program returns, where tokens and n_accepted stand, the
+    block after the reveal int32 [S, B] and which positions the pass
+    revealed int32 [S, B]: each position's first choice, and by the
+    model's ``remasking_strategy`` the leftmost masked ones, the most
+    confident ones (by the first choice's probability), or every one
+    above ``confidence_threshold`` and at least ``n_reveal``."""
     import jax.numpy as jnp
     dkw = {} if axis_name is None else {"axis_name": axis_name}
     if wq:
@@ -176,6 +189,28 @@ def _make_step_fn(model, spec_k, sampled, quantized=False, lora=False,
             win, draft_tokens, draft_probs, n_draft, temperature,
             top_k, top_p, accept_keys, sample_keys)
 
+    def _reveal(logits, tokens, blk_idx, masked, n_reveal):
+        strategy = blockdiff["remasking_strategy"]
+        first = jnp.argmax(logits, axis=-1).astype(jnp.int32)[blk_idx]
+        m = masked.astype(bool)
+        want = n_reveal[:, None]
+        if strategy == "sequential":
+            reveal = m & (jnp.cumsum(masked, axis=1) <= want)
+        else:
+            # the first choice's probability, where a position is masked
+            top = logits.max(axis=-1, keepdims=True)
+            conf = 1.0 / jnp.exp(logits - top).sum(axis=-1)
+            conf = jnp.where(m, conf[blk_idx], -1.0)
+            order = jnp.argsort(-conf, axis=1, stable=True)
+            rank = jnp.argsort(order, axis=1, stable=True)
+            reveal = m & (rank < want)
+            if strategy == "low_confidence_dynamic":
+                high = m & (conf > blockdiff["confidence_threshold"])
+                reveal = jnp.where(
+                    high.sum(axis=1, keepdims=True) >= want, high, reveal)
+        return (jnp.where(reveal, first, tokens[blk_idx]),
+                reveal.astype(jnp.int32))
+
     layout = getattr(model, "cache_layout", None) or {"kind": "kv"}
     n_pages = 4 if quantized else 2 if layout["kind"] == "kv" else 1
     n_pools = n_pages + len(layout.get("state", ()))
@@ -194,6 +229,8 @@ def _make_step_fn(model, spec_k, sampled, quantized=False, lora=False,
             mkw = dict(mkw, k_scales=pools[2], v_scales=pools[3])
         if n_pools > n_pages:
             mkw = dict(mkw, state=pools[n_pages:])
+        if blockdiff:
+            *args, blk_idx, blk_masked, n_reveal = args
         tokens, positions, seq_ids, valid, block_tables, *accept = args
         # a pool of rows (latents, packed K/V) goes where the K pages
         # go, and has no V pool
@@ -202,7 +239,11 @@ def _make_step_fn(model, spec_k, sampled, quantized=False, lora=False,
             p, tokens, positions, seq_ids, valid, k_pages, v_pages,
             block_tables, **mkw)
         aux = pools2.pop() if len(pools2) > n_pools else None
-        toks, n_acc = _accept(logits, *accept)
+        if blockdiff:
+            toks, n_acc = _reveal(logits, tokens, blk_idx, blk_masked,
+                                  n_reveal)
+        else:
+            toks, n_acc = _accept(logits, *accept)
         return (toks, n_acc, aux, *pools2)
 
     return step
@@ -453,6 +494,29 @@ class LLMEngine:
       rejected draft: the engine keeps the prefix cache off for the
       model (``prefix_cache=None``) and its ``refused_features`` name
       ``prefix_cache`` and ``draft_model``.
+    - ``block_diffusion`` (optional): ``None``, or how a model that
+      generates by diffusion over blocks does it (``block_length`` B,
+      ``mask_token_id``, ``denoising_steps``, ``remasking_strategy``,
+      ``confidence_threshold``). Its rows are of a THIRD kind beside
+      prefill and decode. A prompt's leading whole blocks are written
+      in chunks that end on block boundaries and emit nothing; the
+      rest of it sits, revealed, in the first generated block. A
+      generating row then feeds its open block, B tokens at positions
+      ``seq_len .. seq_len + B - 1`` (a masked position as the mask
+      token), every pass: denoising passes that each reveal positions
+      (the step's reveal epilogue, :func:`_make_step_fn`) and, once
+      none is masked, one settling pass whose K/V stands; ``seq_len``
+      moves by B at that pass alone, and the block's tokens are
+      answered then (those past ``max_new_tokens`` or a stop token
+      dropped). Rows in different passes share a step with each other
+      and with prefill chunks. Which positions are masked is the row's
+      own state (``Sequence.blk_masked``), never read off the tokens.
+      The model masks block-causally and returns logits that are for
+      the position they stand at. Preemption folds the settled blocks
+      into the prompt and starts the open block again. Such a model's
+      ``refused_features`` name ``draft_model``, ``prefix_cache`` and
+      ``sampling`` (a request with a temperature is refused at
+      submission).
     - ``vocab_size``, ``max_context``, ``num_layers``, ``num_heads``,
       ``head_dim``: ints.
     - Optional: ``param_specs(axis=)`` and ``weight_scale_specs(
@@ -555,10 +619,26 @@ class LLMEngine:
             raise ValueError(f"spec_k must be >= 0, got {spec_k}")
         self.spec_k = int(spec_k) if draft_model is not None else 0
         self.draft_model = draft_model if self.spec_k > 0 else None
+        # a model that generates by diffusion over blocks (the model
+        # protocol): a generating row feeds one block a step
+        self._blockdiff = getattr(model, "block_diffusion", None)
+        self._block = 0
+        if self._blockdiff:
+            self._block = B = int(self._blockdiff["block_length"])
+            if self.max_context % B:
+                raise ValueError(
+                    f"max_context {self.max_context} must be a multiple "
+                    f"of the model's block_length {B}")
+            # what each denoising pass of a block reveals
+            steps = int(self._blockdiff["denoising_steps"])
+            base, more = divmod(B, steps)
+            self._reveal_plan = [base + (j < more) for j in range(steps)]
+        # what a generating row feeds a step
+        row_tokens = self._block or self.spec_k + 1
         # the step's token budget a slot: a slot's share of prompt
-        # tokens or a K+1-position speculative verify, whichever is
-        # wider
-        self.q_tokens = max(self.prefill_chunk, self.spec_k + 1)
+        # tokens or what a generating row feeds (a K+1-position
+        # speculative verify, a block), whichever is wider
+        self.q_tokens = max(self.prefill_chunk, row_tokens)
         # the FLAT step packs every row's query tokens into one
         # [total_q_tokens] batch — no per-sequence padding, so a
         # mixed step computes exactly the tokens that exist. The
@@ -568,13 +648,13 @@ class LLMEngine:
         # holds half the table attends over half the pages). Every
         # (t, mb, variant) rung is warmed, so selection is
         # recompile-free.
-        t_lo = self.max_seqs * (self.spec_k + 1)
+        t_lo = self.max_seqs * row_tokens
         t_hi = max(t_lo, self.max_seqs * self.q_tokens)
         # the middle rungs are the EXACT packed lengths of the
         # commonest mixed steps — one or two rows mid-prefill while
         # the rest decode/verify — so those steps dispatch pad-free
         mids = {min(t_hi, i * self.q_tokens
-                    + (self.max_seqs - i) * (self.spec_k + 1))
+                    + (self.max_seqs - i) * row_tokens)
                 for i in (1, 2) if i <= self.max_seqs}
         self._t_buckets = sorted({t_lo, t_hi} | mids)
         # what one step may feed: the top rung, dealt out by _schedule
@@ -711,7 +791,8 @@ class LLMEngine:
         def _build_step(s):
             fn = _make_step_fn(model, self.spec_k, s, self.quantized,
                                lora=lora, axis_name=self._axis_name,
-                               wq=self.weight_quantized)
+                               wq=self.weight_quantized,
+                               blockdiff=self._blockdiff)
             if self.mesh is not None:
                 pspecs = model.param_specs(axis="tp")
                 if self.weight_quantized:
@@ -721,13 +802,16 @@ class LLMEngine:
                                 self._step_extra_specs(lora), aux=True)
             return jax.jit(fn, donate_argnums=donate)
 
+        # a model that refuses sampling runs the greedy variant alone
+        self._variants = (False,) if "sampling" in self._refused \
+            else (False, True)
         self._step_jits = {
             sampled: _cached_program(
                 model, "step",
                 (self.spec_k, sampled, self.quantized, donate,
                  lora_key, self._mesh_key, self.weight_dtype),
                 lambda s=sampled: _build_step(s))
-            for sampled in (False, True)}
+            for sampled in self._variants}
         if self.draft_model is not None:
             if draft_model.vocab_size != model.vocab_size:
                 raise ValueError(
@@ -850,6 +934,9 @@ class LLMEngine:
         self._has_state = bool(self.cache.state_pools)
         self.state_rows = 0
         self.state_starts = 0
+        # a block-diffusion model: the last dispatch's rows in a
+        # denoising pass, rows in a settling pass, positions revealed
+        self._blk_step = [0, 0, 0]
         if self._has_state and self._stats is not None:
             self._stats.record_state_pool(
                 self.max_seqs, self.cache.state_stats()["bytes"])
@@ -1107,6 +1194,10 @@ class LLMEngine:
             batch += (np.full((S, self.bank.max_pages_per_adapter),
                               NULL_ADAPTER_PAGE, np.int32),
                       np.zeros(S, np.float32))
+        if self._block:
+            batch += (np.zeros((S, self._block), np.int32),
+                      np.zeros((S, self._block), np.int32),
+                      np.zeros(S, np.int32))
         return batch
 
     def step_program_text(self, sampled=False):
@@ -1161,7 +1252,7 @@ class LLMEngine:
         for T in self._t_buckets:
             for MB in self._mb_widths:
                 batch = self._null_batch(T, MB)
-                for sampled in (False, True):
+                for sampled in self._variants:
                     t0 = time.monotonic()
                     toks = self._call_step(sampled, batch)[0]
                     np.asarray(toks)
@@ -1191,6 +1282,8 @@ class LLMEngine:
             raise ValueError(
                 f"prompt of {len(seq.prompt)} tokens leaves no room to "
                 f"generate (max_context={self.max_context})")
+        self._refuse_given("sampling", seq.sampling.temperature > 0,
+                           "temperature sampling (greedy requests only)")
         vocab = self.model.vocab_size
         bad = [t for t in seq.prompt if not (0 <= t < vocab)]
         if bad:
@@ -1410,8 +1503,9 @@ class LLMEngine:
         allocator must cover. ``cost`` is what the row takes of the
         step's budget: its ``ntok``, or what its draft has to be fed,
         if that is more. Returns the plan dict or None (sequence was
-        poison-isolated at prefill start)."""
-        if not seq.generated:
+        poison-isolated at prefill start, or a block-diffusion row
+        the budget does not reach a whole block of)."""
+        if self._prefilling(seq):
             # prefilling (fresh prompt or preemption-folded resume —
             # folding moves the generation INTO the prompt, so an
             # empty generation list is exactly "prompt not complete";
@@ -1419,6 +1513,15 @@ class LLMEngine:
             committed = seq.prompt
             cl = len(committed)
             remaining = cl - seq.seq_len
+            emits = True
+            if self._block:
+                # whole blocks, and only the prompt's leading ones: the
+                # chunk ends on a block boundary and emits nothing
+                B = self._block
+                remaining = cl // B * B - seq.seq_len
+                budget, emits = budget // B * B, False
+                if not budget:
+                    return None
             if not seq.prefill_started:
                 seq.prefill_started = True
                 try:
@@ -1438,8 +1541,10 @@ class LLMEngine:
                     committed[seq.seq_len:seq.seq_len + ntok],
                     "ntok": ntok, "cost": ntok, "cl": cl,
                     "committed": committed,
-                    "k": 0, "emit": ntok == remaining,
+                    "k": 0, "emit": emits and ntok == remaining,
                     "draft_tokens": [], "draft_probs": []}
+        if self._block:
+            return self._plan_block(seq, budget)
         # decode: one committed token outstanding. Speculate when the
         # draft's committed prefix can catch up within ONE chunk feed
         # (steady state: 1-2 tokens behind; a degraded draft recovers
@@ -1459,6 +1564,47 @@ class LLMEngine:
                 "cost": max(1 + k, min(behind, self.q_tokens)),
                 "cl": cl, "committed": committed,
                 "k": k, "emit": True,
+                "draft_tokens": [], "draft_probs": []}
+
+    def _prefilling(self, seq):
+        """Whether ``seq`` is still writing its prompt. A row that
+        decodes has generated (preemption folds the generation into
+        the prompt); a block-diffusion row writes its prompt's leading
+        whole blocks and generates from there."""
+        if self._block:
+            return seq.seq_len < len(seq.prompt) // self._block \
+                * self._block
+        return not seq.generated
+
+    def _plan_block(self, seq, budget):
+        """A generating row of a block-diffusion model: one pass over
+        its open block, which is opened here if the row has none (what
+        the prompt left over, revealed, then masked positions). A
+        denoising pass while a position is masked, revealing what the
+        pass's place in the block's plan says; else the settling
+        pass."""
+        B = self._block
+        if budget < B:
+            return None
+        if seq.blk_tokens is None:
+            known = (seq.prompt + seq.generated)[seq.seq_len:]
+            fill = B - len(known)
+            seq.blk_tokens = known + [
+                int(self._blockdiff["mask_token_id"])] * fill
+            seq.blk_masked = [0] * len(known) + [1] * fill
+            seq.blk_passes = [-1] * len(known) + [None] * fill
+            seq.blk_pass = 0
+        masked = sum(seq.blk_masked)
+        n_reveal = 0
+        if masked:
+            # (the plan's passes sum to B, so a block that still has a
+            # masked position has a pass of the plan left)
+            n_reveal = min(masked, self._reveal_plan[seq.blk_pass])
+        return {"kind": "denoise" if masked else "settle",
+                "tokens": list(seq.blk_tokens), "ntok": B, "cost": B,
+                "cl": len(seq.prompt) + len(seq.generated),
+                "committed": None, "k": 0, "emit": not masked,
+                "n_reveal": n_reveal,
                 "draft_tokens": [], "draft_probs": []}
 
     def _allocate(self, seq, plan, events):
@@ -1703,6 +1849,10 @@ class LLMEngine:
                 bufs += (np.full((S, self.bank.max_pages_per_adapter),
                                  NULL_ADAPTER_PAGE, np.int32),
                          np.zeros(S, np.float32))     # a_tables/scales
+            if self._block:
+                bufs += (np.zeros((S, self._block), np.int32),  # blk_idx
+                         np.zeros((S, self._block), np.int32),  # masked
+                         np.zeros(S, np.int32))                 # n_reveal
             self._bufs[(t, mb)] = bufs
         return bufs
 
@@ -1725,6 +1875,11 @@ class LLMEngine:
          counters) = bufs[:14]
         valid.fill(0)
         n_draft.fill(0)
+        if self._block:
+            # a row that feeds no block reveals nothing
+            blk_idx, blk_masked, n_reveal = bufs[-3:]
+            blk_masked.fill(0)
+            n_reveal.fill(0)
         off = 0
         K1 = self.spec_k + 1
         for seq in rows:
@@ -1767,6 +1922,10 @@ class LLMEngine:
                 else:
                     a_tables[i] = h.pages_padded
                     a_scales[i] = h.scale
+            if self._block and plan["kind"] != "prefill":
+                blk_idx[i] = off + self._arange[:n]
+                blk_masked[i] = seq.blk_masked
+                n_reveal[i] = plan["n_reveal"]
             off += n
         self._name_tail(seq_ids, positions, off)
         return bufs
@@ -1776,7 +1935,7 @@ class LLMEngine:
         ride along inactive on the null block — the shape, and
         therefore the compiled program, never changes). Dispatch
         failures propagate to the isolation logic in :meth:`step`."""
-        if any(plans[s]["kind"] == "decode" for s in rows):
+        if any(plans[s]["kind"] != "prefill" for s in rows):
             # chaos-harness site: scripted raises / injected latency
             faults.check("llm.decode")
         tracer = get_tracer()
@@ -1832,6 +1991,21 @@ class LLMEngine:
         for seq in rows:
             plan = plans[seq]
             cl = plan["cl"]
+            if plan["kind"] == "denoise":
+                # toks: the block after the reveal; n_acc: which of its
+                # positions this pass revealed
+                for j in np.flatnonzero(n_acc[seq.slot]):
+                    seq.blk_tokens[j] = int(toks[seq.slot, j])
+                    seq.blk_masked[j] = 0
+                    seq.blk_passes[j] = seq.blk_pass
+                    self._blk_step[2] += 1
+                seq.blk_pass += 1
+                self._blk_step[0] += 1
+                continue
+            if plan["kind"] == "settle":
+                self._blk_step[1] += 1
+                decoded += self._settle_block(seq, events)
+                continue
             if plan["kind"] == "prefill":
                 seq.seq_len += plan["ntok"]
                 if "draft_fed" in plan:
@@ -1845,6 +2019,9 @@ class LLMEngine:
                              attrs={"ntok": plan["ntok"],
                                     "seq_len": seq.seq_len,
                                     "emit": plan["emit"]})
+                if self._block and self._stats \
+                        and not self._prefilling(seq):
+                    self._stats.record_prefill(seq.seq_len)
                 if not plan["emit"]:
                     continue
                 # the prompt completed: register its full immutable
@@ -1862,19 +2039,7 @@ class LLMEngine:
                     self._stats.record_prefill(
                         cl - seq.cache_hit_tokens)
                     self._stats.record_prefill_token()
-                if seq.t_first_token is None:
-                    seq.t_first_token = time.monotonic()
-                    if self._stats:
-                        # exemplar joins this TTFT observation back to
-                        # the request's flight timeline / trace span
-                        ex = None
-                        if self._flight.enabled:
-                            ex = (f"llm:{seq.seq_id}",
-                                  seq.span.span_id
-                                  if seq.span is not None else None)
-                        self._stats.record_first_token(
-                            seq.t_first_token - seq.t_submit,
-                            exemplar=ex)
+                self._record_first_token(seq)
                 if seq.done or seq.seq_len + 1 >= self.max_context:
                     self._finish(seq, events)
                 continue
@@ -1915,6 +2080,47 @@ class LLMEngine:
                     seq.done or seq.seq_len + 1 >= self.max_context):
                 self._finish(seq, events)
         return decoded
+
+    def _settle_block(self, seq, events):
+        """The host's half of a settling pass: the open block's K/V
+        stands, its tokens are answered (those past ``max_new_tokens``
+        or a stop token go to ``block_tail``), ``seq_len`` moves over
+        the block and the row has no open block. Returns the tokens
+        answered."""
+        B = self._block
+        known = len(seq.prompt) + len(seq.generated) - seq.seq_len
+        kept = 0
+        for tok, at in zip(seq.blk_tokens[known:], seq.blk_passes[known:]):
+            if seq.done:
+                seq.block_tail.append((tok, at))
+                continue
+            seq.generated.append(tok)
+            seq.last_token = tok
+            seq.reveal_pass.append(at)
+            kept += 1
+            events.append(("token", seq))
+        seq.seq_len += B
+        seq.blk_tokens = seq.blk_masked = seq.blk_passes = None
+        seq.blk_pass = 0
+        self._record_first_token(seq)
+        if seq.done or seq.seq_len + B > self.max_context:
+            self._finish(seq, events)
+        return kept
+
+    def _record_first_token(self, seq):
+        """The moment a sequence's first token is answered, once."""
+        if seq.t_first_token is not None:
+            return
+        seq.t_first_token = time.monotonic()
+        if self._stats:
+            # exemplar joins this TTFT observation back to the
+            # request's flight timeline / trace span
+            ex = None
+            if self._flight.enabled:
+                ex = (f"llm:{seq.seq_id}", seq.span.span_id
+                      if seq.span is not None else None)
+            self._stats.record_first_token(
+                seq.t_first_token - seq.t_submit, exemplar=ex)
 
     def _isolate(self, rows, plans, events):
         """Bisect-retry a failing dispatch to isolate the poison
@@ -2012,8 +2218,8 @@ class LLMEngine:
         # a row took before a later one's blocks preempted it is not
         # dealt again.)
         left = self._step_budget
-        for seq in ([s for s in running if s.generated]
-                    + [s for s in running if not s.generated]):
+        for seq in ([s for s in running if not self._prefilling(s)]
+                    + [s for s in running if self._prefilling(s)]):
             if seq.state != RUNNING:
                 continue            # preempted by an earlier victim
             if left <= 0:
@@ -2047,6 +2253,7 @@ class LLMEngine:
                              s.seq_len + len(plans[s]["tokens"]))
                             for s in rows])
         t0 = time.monotonic()
+        self._blk_step = [0, 0, 0]
         try:
             out = self._dispatch(rows, plans)
         except Exception as exc:
@@ -2072,8 +2279,16 @@ class LLMEngine:
                                        "moe_max_load"), self._moe_load):
                         sp.set(key, v)
             csp.set("decoded", decoded)
+            if self._block:
+                # what the committed passes did (isolated halves among
+                # them)
+                if self._stats:
+                    self._stats.record_blockdiff(*self._blk_step)
+                for key, v in zip(("denoise_rows", "settle_rows",
+                                   "revealed"), self._blk_step):
+                    sp.set(key, v)
             step_s = time.monotonic() - t0
-            if self._stats and any(plans[s]["kind"] == "decode"
+            if self._stats and any(plans[s]["kind"] != "prefill"
                                    for s in rows if s in plans):
                 self._stats.record_decode_step(decoded, step_s)
             if fl.enabled:
@@ -2189,7 +2404,8 @@ class LLMEngine:
                          "warmed": self._warmed,
                          "step_variants": len(self._step_jits),
                          "spec_k": self.spec_k,
-                         "prefill_chunk": self.prefill_chunk},
+                         "prefill_chunk": self.prefill_chunk,
+                         "block_diffusion": self._blockdiff},
             "state_pool": self.state_pool_stats(),
             "prefix_cache": {"enabled": self.prefix_enabled,
                              "lookups": self.prefix_lookups,

@@ -282,6 +282,23 @@ class LLMStats:
             "mxtpu_llm_state_pool_bytes",
             "Device bytes of the slot state pools, all layers and "
             "slots.", lbl).labels(**s)
+        self._blockdiff_passes = r.counter(
+            "mxtpu_llm_blockdiff_passes_total",
+            "Row passes of a model that generates by diffusion over "
+            "blocks: a row fed one block in one step, by kind "
+            "(denoise: a pass that reveals positions; settle: the pass "
+            "over the revealed block whose K/V stands).",
+            ("server", "kind"))
+        self._blockdiff_pass_children = {}
+        self._blockdiff_revealed = r.counter(
+            "mxtpu_llm_blockdiff_revealed_total",
+            "Positions revealed by denoising passes (those a last "
+            "block computes past the answer's length among them).",
+            lbl).labels(**s)
+        self._blockdiff_blocks = r.counter(
+            "mxtpu_llm_blockdiff_blocks_total",
+            "Blocks settled: their K/V written for good, their tokens "
+            "answered.", lbl).labels(**s)
         # the overload/failure series share the single-shot server's
         # mxtpu_serving_* catalog (one dashboard for both front ends)
         self._overload = OverloadStats(r, self._server)
@@ -473,6 +490,20 @@ class LLMStats:
         self._state_steps.inc()
         self._state_starts.inc(starts)
 
+    def record_blockdiff(self, denoise_rows, settle_rows, revealed):
+        """One dispatched step of a model that generates by diffusion
+        over blocks: its rows in a denoising pass, its rows in a
+        settling pass (each settles one block), the positions
+        revealed."""
+        for kind, n in (("denoise", denoise_rows),
+                        ("settle", settle_rows)):
+            if n:
+                self._labeled_child(self._blockdiff_passes,
+                                    self._blockdiff_pass_children,
+                                    kind=kind).inc(n)
+        self._blockdiff_revealed.inc(revealed)
+        self._blockdiff_blocks.inc(settle_rows)
+
     # --------------------------------------------- quantized weights --
     def record_weight_quant(self, dtype, weight_bytes,
                             params_per_chip):
@@ -578,6 +609,12 @@ class LLMStats:
                 "state_rows": int(self._state_rows.value),
                 "state_starts": int(self._state_starts.value),
                 "state_steps": int(self._state_steps.value),
+                "blockdiff_passes": {
+                    k[0][1]: int(c.value) for k, c in
+                    self._blockdiff_pass_children.items()},
+                "blockdiff_revealed": int(
+                    self._blockdiff_revealed.value),
+                "blockdiff_blocks": int(self._blockdiff_blocks.value),
                 "adapters_resident": int(
                     self._adapters_resident.value),
                 "adapter_publishes": int(

@@ -37,7 +37,8 @@ from ...ops.ragged_attention import (ragged_flat_attention,
                                      mla_flat_attention,
                                      gqa_flat_attention)
 from ...ops.ssd import ssd_state_step, slot_runs
-from ...ops.moe import route_noaux_tc, moe_experts, moe_load
+from ...ops.moe import (route_noaux_tc, route_softmax_topk, moe_experts,
+                        moe_load)
 from ...ops.flash_attention import attention_reference
 from ...ops.lora import (paged_lora_delta, gather_adapter,
                          PROJ_Q, PROJ_K, PROJ_V, PROJ_O)
@@ -45,11 +46,15 @@ from ...ops.quantization import quantized_matmul
 
 __all__ = ["DecoderConfig", "TinyDecoder", "greedy_decode_reference",
            "UnsupportedModelFeatureError", "GPT2", "DEEPSEEK_V3",
-           "GRANITE_HYBRID"]
+           "GRANITE_HYBRID", "SDAR_MOE"]
 
 #: the blocks a :class:`DecoderConfig` can describe (``model_type``)
 GPT2, DEEPSEEK_V3 = "gpt2", "deepseek_v3"
 GRANITE_HYBRID = "granitemoehybrid"
+SDAR_MOE = "sdar_moe"
+#: how a block-diffusion model picks the masked positions a pass reveals
+REMASKING = ("sequential", "low_confidence_static",
+             "low_confidence_dynamic")
 #: the kinds of layer a ``granitemoehybrid`` model's ``layer_types`` name
 MAMBA, ATTENTION = "mamba", "attention"
 
@@ -107,7 +112,24 @@ class DecoderConfig:
     ``shared_intermediate_size``); RMSNorm; the embedding times
     ``embedding_multiplier``, each residual branch times
     ``residual_multiplier``, the logits over ``logits_scaling``, the
-    head the embedding itself (``tie_word_embeddings``)."""
+    head the embedding itself (``tie_word_embeddings``).
+
+    ``model_type="sdar_moe"`` (SDAR's expert members) is a fourth block:
+    RMSNorm, ``num_heads`` query heads over ``num_key_value_heads`` K/V
+    heads of ``head_dim``, each query and key head RMS-normed over its
+    own values (``q_norm``, ``k_norm``) before rotary positions at
+    ``rope_theta`` on the whole head; then ``num_experts`` SwiGLU experts
+    of width ``moe_intermediate_size``, ``num_experts_per_tok`` a token
+    by a softmax router, renormalised if ``norm_topk_prob``; an untied
+    head. It GENERATES BY DIFFUSION OVER BLOCKS: attention is
+    block-causal (position ``p`` sees every position below ``(p //
+    block_length + 1) * block_length``), a block of ``block_length``
+    positions starts as ``mask_token_id`` and is revealed over
+    ``denoising_steps`` passes, ``remasking_strategy`` (one of
+    ``REMASKING``) choosing which positions a pass reveals
+    (``confidence_threshold`` for the dynamic one); the logits at a
+    position are for that position itself. These five are generation
+    defaults the published ``config.json`` lacks."""
 
     FIELDS = ("vocab_size", "d_model", "num_layers", "num_heads",
               "d_ff", "max_context")
@@ -128,6 +150,12 @@ class DecoderConfig:
             "embedding_multiplier", "attention_multiplier",
             "residual_multiplier", "logits_scaling",
             "tie_word_embeddings", "rms_norm_eps", "torch_dtype"),
+        SDAR_MOE: (
+            "model_type", "num_key_value_heads", "head_dim", "rope_theta",
+            "rms_norm_eps", "num_experts", "num_experts_per_tok",
+            "moe_intermediate_size", "norm_topk_prob", "torch_dtype",
+            "block_length", "mask_token_id", "denoising_steps",
+            "remasking_strategy", "confidence_threshold"),
     }
     #: every block's own fields, each once
     BLOCK_FIELDS = tuple(dict.fromkeys(
@@ -146,7 +174,10 @@ class DecoderConfig:
                  mamba_n_groups=1, mamba_expand=2,
                  embedding_multiplier=1.0, attention_multiplier=1.0,
                  residual_multiplier=1.0, logits_scaling=1.0,
-                 tie_word_embeddings=True):
+                 tie_word_embeddings=True, head_dim=0, num_experts=0,
+                 block_length=4, mask_token_id=0, denoising_steps=4,
+                 remasking_strategy="sequential",
+                 confidence_threshold=0.9):
         self.vocab_size = int(vocab_size)
         self.d_model = int(d_model)
         self.num_layers = int(num_layers)
@@ -192,6 +223,16 @@ class DecoderConfig:
         self.residual_multiplier = float(residual_multiplier)
         self.logits_scaling = float(logits_scaling)
         self.tie_word_embeddings = bool(tie_word_embeddings)
+        self.num_experts = int(num_experts)
+        self.block_length = int(block_length)
+        self.mask_token_id = int(mask_token_id)
+        self.denoising_steps = int(denoising_steps)
+        self.remasking_strategy = remasking_strategy
+        self.confidence_threshold = float(confidence_threshold)
+        if model_type == SDAR_MOE:
+            self.head_dim = int(head_dim)
+            self._check_sdar()
+            return
         if model_type == GRANITE_HYBRID:
             self._check_hybrid()
             self.head_dim = self.d_model // self.num_heads
@@ -257,6 +298,40 @@ class DecoderConfig:
         if not self.tie_word_embeddings:
             raise ValueError("the granitemoehybrid block's head is its "
                              "embedding (tie_word_embeddings)")
+
+    def _check_sdar(self):
+        """The ``sdar_moe`` keys against one another."""
+        if self.head_dim < 2 or self.head_dim % 2:
+            raise ValueError("head_dim must be even and >= 2 for "
+                             f"{SDAR_MOE}, got {self.head_dim}")
+        if self.num_key_value_heads < 1 \
+                or self.num_heads % self.num_key_value_heads:
+            raise ValueError(
+                f"num_heads {self.num_heads} over num_key_value_heads "
+                f"{self.num_key_value_heads}: the second must divide the "
+                "first")
+        if not (1 <= self.num_experts_per_tok <= self.num_experts) \
+                or self.moe_intermediate_size < 1:
+            raise ValueError(
+                f"num_experts_per_tok {self.num_experts_per_tok} of "
+                f"num_experts {self.num_experts}, each of width "
+                f"moe_intermediate_size {self.moe_intermediate_size}")
+        if not (1 <= self.denoising_steps <= self.block_length):
+            raise ValueError(
+                f"denoising_steps {self.denoising_steps} must lie in 1 to "
+                f"block_length {self.block_length}: a pass reveals at "
+                "least one position")
+        if self.max_context % self.block_length:
+            raise ValueError(
+                f"max_context {self.max_context} must be a multiple of "
+                f"block_length {self.block_length}")
+        if not (0 <= self.mask_token_id < self.vocab_size):
+            raise ValueError(f"mask_token_id {self.mask_token_id} is "
+                             f"outside the vocabulary of {self.vocab_size}")
+        if self.remasking_strategy not in REMASKING:
+            raise ValueError(
+                f"remasking_strategy {self.remasking_strategy!r} is none "
+                f"of {REMASKING}")
 
     @property
     def mamba_inner(self):
@@ -349,10 +424,11 @@ class TinyDecoder:
     #: each block's own methods end in its name here
     #: (``_forward_<name>``, ``_decode_flat_<name>``, ...), and
     #: ``_TAKES`` is what its step takes beside the packed batch
-    _BLOCK = {GPT2: "gpt2", DEEPSEEK_V3: "latent", GRANITE_HYBRID: "hybrid"}
+    _BLOCK = {GPT2: "gpt2", DEEPSEEK_V3: "latent", GRANITE_HYBRID: "hybrid",
+              SDAR_MOE: "blockdiff"}
     _TAKES = {GPT2: ("k_scales", "v_scales", "adapter", "axis_name",
                      "w_scales"),
-              DEEPSEEK_V3: (), GRANITE_HYBRID: ("state",)}
+              DEEPSEEK_V3: (), GRANITE_HYBRID: ("state",), SDAR_MOE: ()}
     #: what each of those keywords stands for, when a block refuses it
     _STANDS_FOR = {"k_scales": "quantized pages",
                    "v_scales": "quantized pages",
@@ -367,7 +443,10 @@ class TinyDecoder:
                               "weight_dtype"),
                 GRANITE_HYBRID: ("adapter_bank", "draft_model", "mesh",
                                  "weight_dtype", "kv_dtype",
-                                 "prefix_cache")}
+                                 "prefix_cache"),
+                SDAR_MOE: ("adapter_bank", "draft_model", "mesh",
+                           "weight_dtype", "kv_dtype", "prefix_cache",
+                           "sampling")}
 
     def __init__(self, config=None, **kw):
         self.config = config if config is not None else DecoderConfig(**kw)
@@ -409,8 +488,26 @@ class TinyDecoder:
         model with any returns their load from :meth:`decode_flat`,
         after the pools."""
         c = self.config
+        if c.model_type == SDAR_MOE:
+            return c.num_experts * c.num_layers
         return c.n_routed_experts * max(
             0, c.num_layers - c.first_k_dense_replace)
+
+    @property
+    def block_diffusion(self):
+        """How the model generates, where it generates by diffusion
+        over blocks (``None`` for a model that decodes a token a step):
+        ``{"block_length", "mask_token_id", "denoising_steps",
+        "remasking_strategy", "confidence_threshold"}``, read by the
+        engine (its model protocol says what it does with them)."""
+        c = self.config
+        if c.model_type != SDAR_MOE:
+            return None
+        return {"block_length": c.block_length,
+                "mask_token_id": c.mask_token_id,
+                "denoising_steps": c.denoising_steps,
+                "remasking_strategy": c.remasking_strategy,
+                "confidence_threshold": c.confidence_threshold}
 
     @property
     def cache_layout(self):
@@ -428,6 +525,10 @@ class TinyDecoder:
         slot's)}`` float32, in the order the step takes and returns the
         pools, after the pages."""
         c = self.config
+        if c.model_type == SDAR_MOE:
+            return {"kind": "kv_packed",
+                    "num_heads": c.num_key_value_heads,
+                    "head_dim": c.head_dim}
         if c.model_type == GRANITE_HYBRID:
             mamba = c.layer_types.count(MAMBA)
             layout = {"kind": "kv_packed",
@@ -1287,6 +1388,158 @@ class TinyDecoder:
             h = h + r * _swiglu(_rms_norm(h, lp["norm2"], eps),
                                 lp["w_gate"], lp["w_up"], lp["w_down"])
         return self._head_hybrid(params, h), pages, ssm, conv
+
+    # ---------------------------------------- the sdar_moe block --
+    def _init_params_blockdiff(self, w):
+        """The ``sdar_moe`` tree: the leaves of
+        ``benchmark/reference/sdar_moe_decoder.py``, and its rule of
+        scale (``WEIGHT_RULE`` there says why each): fan-in, and the
+        embedding 0.5 (a masked position starts as the mask token's
+        row, and what the layers add has to decide its first choice);
+        the matrices that write to the residual stream (``wo``,
+        ``experts_down``) by 1/sqrt(2 x num_layers) besides,
+        ``experts_down`` twice that (normalised gates average a
+        token's experts); the router three times its fan-in (a token's
+        gates then fall off as a trained router's do, the eighth a few
+        per cent of the first); the gains of ``q_norm`` and ``k_norm``
+        1.5 (scores spread by about 2 over the keys); the other gains
+        1."""
+        c = self.config
+        d, H, G, D = (c.d_model, c.num_heads, c.num_key_value_heads,
+                      c.head_dim)
+        E, m = c.num_experts, c.moe_intermediate_size
+        out = (2 * c.num_layers) ** -0.5
+        layers = []
+        for _ in range(c.num_layers):
+            layers.append({
+                "norm1": np.ones(d, np.float32),
+                "norm2": np.ones(d, np.float32),
+                "q_norm": np.full(D, 1.5, np.float32),
+                "k_norm": np.full(D, 1.5, np.float32),
+                "wq": w(d, H * D), "wk": w(d, G * D), "wv": w(d, G * D),
+                "wo": w(H * D, d, scale=out * (H * D) ** -0.5),
+                "router": w(d, E, scale=3 * d ** -0.5),
+                "experts_gate": w(E, d, m, scale=d ** -0.5),
+                "experts_up": w(E, d, m, scale=d ** -0.5),
+                "experts_down": w(E, m, d, scale=2 * out * m ** -0.5)})
+        return self._published_dtype(
+            {"embed": w(c.vocab_size, d, scale=0.5),
+             "norm_f": np.ones(d, np.float32),
+             "head": w(d, c.vocab_size), "layers": layers})
+
+    def _qkv_blockdiff(self, lp, x, positions):
+        """A layer's queries ``[..., H, D]`` and a token's page row ``[...,
+        2 x G x D]`` (its value heads, then its key heads) from normed
+        rows ``x [..., d]``: each query and key head RMS-normed over its
+        own values, then turned by its own position."""
+        import jax.numpy as jnp
+        c = self.config
+        H, G, D = c.num_heads, c.num_key_value_heads, c.head_dim
+        eps, theta = c.rms_norm_eps, c.rope_theta
+        lead = x.shape[:-1]
+        q = _rope(_rms_norm(_wmm(x, lp["wq"]).reshape(lead + (H, D)),
+                            lp["q_norm"], eps), positions, theta)
+        k = _rope(_rms_norm(_wmm(x, lp["wk"]).reshape(lead + (G, D)),
+                            lp["k_norm"], eps), positions, theta)
+        return q, k, _wmm(x, lp["wv"])
+
+    def _experts_blockdiff(self, lp, x2, valid):
+        """The expert layer for normed rows ``x2 [T, d]`` (float32):
+        ``(what is added to the stream, the routing's load int32 [3])``."""
+        c = self.config
+        sel, gates = route_softmax_topk(
+            x2, lp["router"], c.num_experts_per_tok, c.norm_topk_prob)
+        wg = lp["experts_gate"]
+        out = moe_experts(x2.astype(wg.dtype), sel, gates, valid, wg,
+                          lp["experts_up"], lp["experts_down"])
+        return out, moe_load(sel, valid, c.num_experts)
+
+    def _forward_blockdiff(self, params, tokens):
+        """:meth:`forward` for the ``sdar_moe`` block: dense grouped-query
+        attention under the BLOCK-CAUSAL mask, the expert layers through
+        the op's plain twin off the TPU. The logits at a position are
+        for that position itself. Returns ``(logits [B, T, V] float32,
+        None, None)``."""
+        import jax
+        import jax.numpy as jnp
+        c = self.config
+        B, T = tokens.shape
+        H, G, D = c.num_heads, c.num_key_value_heads, c.head_dim
+        eps = c.rms_norm_eps
+        pos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
+        live = jnp.ones((B * T,), bool)
+        t = jnp.arange(T)
+        mask = t[None, :] <= _block_last(t, c.block_length)[:, None]
+        h = params["embed"][tokens].astype(jnp.float32)
+        for lp in params["layers"]:
+            q, k, v = self._qkv_blockdiff(
+                lp, _rms_norm(h, lp["norm1"], eps), pos)
+            s = jnp.einsum("bqgmd,bkgd->bgmqk",
+                           q.reshape(B, T, G, H // G, D), k) * D ** -0.5
+            p = jax.nn.softmax(jnp.where(mask, s, -1e30), -1)
+            att = jnp.einsum("bgmqk,bkgd->bqgmd", p,
+                             v.reshape(B, T, G, D))
+            h = h + _wmm(att.reshape(B, T, H * D), lp["wo"])
+            add, _ = self._experts_blockdiff(
+                lp, _rms_norm(h, lp["norm2"], eps).reshape(B * T, -1),
+                live)
+            h = h + add.reshape(B, T, c.d_model)
+        logits = _wmm(_rms_norm(h, params["norm_f"], eps), params["head"])
+        return logits, None, None
+
+    def _decode_flat_blockdiff(self, params, tokens, positions, seq_ids,
+                               valid, pages, block_tables):
+        """:meth:`decode_flat` for the ``sdar_moe`` block, the packed
+        batch and page arithmetic the same. ``pages`` is the ONE pool of
+        packed rows ``[L, N, bs, 2 x G x D]``: each layer writes its
+        tokens' value heads and then their normed, turned key heads as
+        one row and attends through
+        :func:`~mxnet_tpu.ops.ragged_attention.gqa_flat_attention`.
+        The mask is BLOCK-CAUSAL: the kernel is given each token's
+        block's last position (``_block_last``) where a causal model
+        gives the token's own, which enters the rotary term alone; the
+        engine feeds whole blocks, so the keys a token may see beyond
+        itself were written by this very call. A row the engine feeds a
+        block of (a denoising or a settling pass) overwrites the block's
+        rows in place. Weights are read in their own dtype with float32
+        accumulation; norms, softmax, rotary positions, router scores,
+        gates and the residual stream are float32. Returns ``(logits
+        [T, V] float32, pages, load)``, ``load`` as the ``deepseek_v3``
+        block leaves it."""
+        import jax.numpy as jnp
+        c = self.config
+        T = tokens.shape[0]
+        bs = pages.shape[2]
+        H, G, D = c.num_heads, c.num_key_value_heads, c.head_dim
+        eps = c.rms_norm_eps
+        vmask = valid.astype(bool)
+        bidx = jnp.where(
+            vmask, block_tables[seq_ids, positions // bs], 0)  # null
+        slot = jnp.where(vmask, positions % bs, 0)
+        sees = _block_last(positions, c.block_length)
+        load = jnp.zeros((3,), jnp.int32)
+        h = params["embed"][tokens].astype(jnp.float32)
+        for li, lp in enumerate(params["layers"]):
+            q, k, v = self._qkv_blockdiff(
+                lp, _rms_norm(h, lp["norm1"], eps), positions)
+            row = jnp.concatenate([v, k.reshape(T, G * D)], axis=-1)
+            pages = pages.at[li, bidx, slot].set(row.astype(pages.dtype))
+            att = gqa_flat_attention(q, pages, block_tables, seq_ids, sees,
+                                     D ** -0.5, G, layer=li)
+            h = h + _wmm(att.reshape(T, H * D), lp["wo"])
+            add, hit = self._experts_blockdiff(
+                lp, _rms_norm(h, lp["norm2"], eps), vmask)
+            h = h + add
+            load = jnp.stack([load[0] + hit[0], load[1] + hit[1],
+                              jnp.maximum(load[2], hit[2])])
+        logits = _wmm(_rms_norm(h, params["norm_f"], eps), params["head"])
+        return logits, pages, load
+
+
+def _block_last(positions, block_length):
+    """The last position of each position's block: what a position of a
+    block-diffusion model sees up to."""
+    return (positions // block_length + 1) * block_length - 1
 
 
 def _gpt2_oracle_only(model):

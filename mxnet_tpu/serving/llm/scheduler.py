@@ -51,7 +51,9 @@ class Sequence:
                  "future", "span", "finish_reason", "deadline",
                  "cancelled", "tenant", "sampling", "draft_len",
                  "prefill_started", "prefix_hashes",
-                 "cache_hit_tokens", "adapter", "adapter_handle")
+                 "cache_hit_tokens", "adapter", "adapter_handle",
+                 "blk_tokens", "blk_masked", "blk_passes", "blk_pass",
+                 "reveal_pass", "block_tail")
 
     def __init__(self, prompt_tokens, max_new_tokens, stop_token=None,
                  deadline=None, tenant=None, sampling=None,
@@ -123,6 +125,22 @@ class Sequence:
         # re-prefill after a mid-flight republish stays bit-identical
         self.adapter = adapter
         self.adapter_handle = None
+        # a model that generates by diffusion over blocks: the open
+        # block at ``seq_len`` (None between blocks and while the prompt
+        # is written): its tokens, which of its positions are still
+        # masked (by position: a token that equals the mask token's id
+        # is a token), the pass that revealed each (-1: a prompt token)
+        # and the denoising passes it has had
+        self.blk_tokens = None
+        self.blk_masked = None
+        self.blk_passes = None
+        self.blk_pass = 0
+        # the pass of its block that revealed each answered token, and
+        # ``(token, pass)`` of what the last block computed past the
+        # answer's length; both survive preemption (they describe
+        # settled blocks)
+        self.reveal_pass = []
+        self.block_tail = []
 
     def expired(self, now=None):
         if self.deadline is None:
@@ -238,6 +256,10 @@ class Scheduler:
         seq.prefill_started = False
         seq.prefix_hashes = None
         seq.cache_hit_tokens = 0
+        # an open block starts again, all of it masked, after the
+        # settled blocks (now prompt) are written back
+        seq.blk_tokens = seq.blk_masked = seq.blk_passes = None
+        seq.blk_pass = 0
         seq.state = WAITING
         seq.preemptions += 1
         self.waiting.appendleft(seq)

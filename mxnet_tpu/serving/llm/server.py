@@ -118,15 +118,24 @@ def _resolve_server_mesh(mesh):
 
 class GenerationResult:
     """A completed generation: ``tokens`` (ints, prompt excluded),
-    ``seq_id``, ``ttft_s``, ``finish_reason``."""
+    ``seq_id``, ``ttft_s``, ``finish_reason``. From a model that
+    generates by diffusion over blocks also ``reveal_pass``, for each
+    token the denoising pass of its block that revealed it (0 the
+    first), and ``block_tail``, ``(token, pass)`` of the positions its
+    last block computed past the answer's length; both empty
+    otherwise."""
 
-    __slots__ = ("tokens", "seq_id", "ttft_s", "finish_reason")
+    __slots__ = ("tokens", "seq_id", "ttft_s", "finish_reason",
+                 "reveal_pass", "block_tail")
 
-    def __init__(self, tokens, seq_id, ttft_s, finish_reason):
+    def __init__(self, tokens, seq_id, ttft_s, finish_reason,
+                 reveal_pass=(), block_tail=()):
         self.tokens = tokens
         self.seq_id = seq_id
         self.ttft_s = ttft_s
         self.finish_reason = finish_reason
+        self.reveal_pass = list(reveal_pass)
+        self.block_tail = list(block_tail)
 
     def __repr__(self):
         return (f"GenerationResult(seq={self.seq_id}, "
@@ -497,6 +506,11 @@ class LLMServer:
         rung = snap.get("step_rung_tokens", 0)
         snap["step_fill_share"] = (snap.get("step_tokens", 0) / rung
                                    if rung else 0.0)
+        # a model that generates by diffusion over blocks: positions
+        # revealed over row passes, settling passes among them
+        passes = sum(snap.get("blockdiff_passes", {}).values())
+        snap["tokens_per_pass"] = (snap.get("blockdiff_revealed", 0)
+                                   / passes if passes else 0.0)
         lookups = snap.get("prefix_lookups", 0)
         snap["prefix_hit_rate"] = (snap.get("prefix_hits", 0) / lookups
                                    if lookups else 0.0)
@@ -649,7 +663,8 @@ class LLMServer:
         ttft = (seq.t_first_token - seq.t_submit
                 if seq.t_first_token else None)
         res = GenerationResult(seq.output_tokens(), seq.seq_id, ttft,
-                               seq.finish_reason)
+                               seq.finish_reason, seq.reveal_pass,
+                               seq.block_tail)
         latency = time.monotonic() - seq.t_submit
         ex = None
         fl = self._flight

@@ -126,6 +126,21 @@ def test_ssd_phase_toy():
     assert max(line["rel_err"].values()) <= chip_smoke.SSD_TOL
 
 
+def test_blockdiff_phase_toy():
+    """The ``blockdiff`` phase's control flow, both kernels in interpret
+    mode: 4 rows of a block of 4, and 3 beside a prompt chunk of 20."""
+    line = chip_smoke.blockdiff_phase(
+        cases=(16, 32), layers=2, num_blocks=65, block_size=8, heads=4,
+        kv_heads=2, head_dim=16, block_length=4, experts=8, top_k=2,
+        d_model=32, width=128, slots=4, median=40.0, dtype="float32",
+        reps=2, interpret=True)
+    assert line["phase"] == "blockdiff" and line["pool"] == [2, 65, 8, 64]
+    assert set(line["ms_per_call_attn"]) == {"T16", "T32"} \
+        == set(line["ms_per_call_experts"])
+    assert line["experts_hit"] == {"T16": 8, "T32": 8}
+    assert max(line["rel_err"].values()) < 1e-4
+
+
 def test_refuses_to_run_without_a_tpu():
     p = subprocess.run(
         [sys.executable, os.path.join(REPO, "chip_smoke.py")],
