@@ -21,7 +21,13 @@ supports, with random weights made from a seed:
               ``granite_4_0_h_micro`` cell's;
 - **ssd**     the state-space step kernel alone over that cell's state
               pool (36 layers x 32 slots x 2 MB), against the per-token
-              recurrence, timed a call.
+              recurrence, timed a call;
+- **blockdiff** grouped queries under the block-causal mask and one
+              layer's 128 experts at the ``sdar_30b_a3b_chat`` cell's
+              shapes, likewise;
+- **experts** the grouped expert products alone at one layer of the
+              ``moonlight_16b_a3b`` cell (64 experts of ``[2048, 1408]``,
+              6 a token), a decode step of 8 rows and a chunk step of 128.
 
 Every phase checks what came out and prints one JSON line; a failed check
 raises, so the exit code is non-zero. The last line of standard output is
@@ -43,7 +49,7 @@ import sys
 import time
 
 PHASES = ("train", "serve", "kernels", "latent", "ragged", "ssd",
-          "blockdiff")
+          "blockdiff", "experts")
 
 #: kernel-vs-reference bounds, fixed from the dtype before any chip run.
 #: bf16 flash attention: the repo's own bf16 bound (tests/test_attention.py).
@@ -727,6 +733,94 @@ def ssd_phase(cases, layers, slots, heads, head_dim, state, chunk=16,
         interpret=interpret)
 
 
+def _expert_weights(experts, d_model, width, dtype):
+    """``(router [d, E] float32, (Wg, Wu [E, d, m], Wd [E, m, d]))`` of one
+    expert layer, drawn on the device by fan-in (the router three times
+    that, as the block-diffusion cell's)."""
+    import jax
+    import jax.numpy as jnp
+    ks = jax.random.split(jax.random.PRNGKey(11), 4)
+    router = 3 * d_model ** -0.5 * jax.random.normal(
+        ks[0], (d_model, experts), jnp.float32)
+    w_gate, w_up = (
+        (d_model ** -0.5 * jax.random.normal(
+            k, (experts, d_model, width), jnp.float32)).astype(dtype)
+        for k in ks[1:3])
+    w_down = (width ** -0.5 * jax.random.normal(
+        ks[3], (experts, width, d_model), jnp.float32)).astype(dtype)
+    return router, (w_gate, w_up, w_down)
+
+
+def _expert_products_alone(cases, router, weights, top_k, dtype, reps,
+                           interpret):
+    """The grouped expert products of one layer alone, at each ``T`` of
+    ``cases`` tokens routed ``top_k`` ways by the softmax router, against
+    the plain twin: ``(ms a call, relative error, experts hit, row tiles
+    the pairs fill, seconds timed)``, the first four by case. The
+    tiles are counted here from the routing, so that the phase runs on a
+    tree whose ``moe_load`` does not count them."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from mxnet_tpu.ops.moe import (TILE_ROWS, moe_experts,
+                                   moe_experts_reference, route_softmax_topk)
+
+    ms, errs, hit, tiles, run_s = {}, {}, {}, {}, 0.0
+    for T in cases:
+        name = f"T{T}"
+        x = jax.random.normal(jax.random.PRNGKey(T + 1),
+                              (T, router.shape[0]), jnp.float32)
+        live = jnp.ones((T,), jnp.int32)
+        sel, gates = route_softmax_topk(x, router, top_k)
+        counts = np.bincount(np.asarray(sel).reshape(-1),
+                             minlength=router.shape[1])
+        hit[name] = int((counts > 0).sum())
+        tiles[name] = int((-(-counts // TILE_ROWS)).sum())
+
+        # every large array goes in as an argument: one that a jitted
+        # function closes over becomes a constant of its program
+        def products(x, wg, wu, wd):
+            return moe_experts(x.astype(dtype), sel, gates, live, wg, wu,
+                               wd, use_pallas=True, interpret=interpret)
+
+        got = jax.block_until_ready(jax.jit(products)(x, *weights))
+        want = jax.jit(lambda x, wg, wu, wd: moe_experts_reference(
+            x.astype(dtype), sel, gates, live, wg, wu, wd))(x, *weights)
+        errs[name] = _rel_err(got, want)
+        # each call's input leans on the last one's output, so that no
+        # call is folded into another
+        many = jax.jit(lambda x, *w: jax.lax.fori_loop(
+            0, reps, lambda i, c: x + 1e-6 * products(c, *w), x))
+        jax.block_until_ready(many(x, *weights))                # compile
+        t0 = time.monotonic()
+        jax.block_until_ready(many(x, *weights))
+        dt = time.monotonic() - t0
+        run_s += dt
+        ms[name] = round(dt / reps * 1e3, 4)
+    return ms, errs, hit, tiles, run_s
+
+
+def experts_phase(cases, experts, top_k, d_model, width, dtype="bfloat16",
+                  reps=28, interpret=False):
+    """One layer's grouped expert products alone (``experts`` stacked
+    SwiGLUs of ``[d_model, width]``, ``top_k`` a token) at each ``T`` of
+    ``cases``, against the plain twin; timed over ``reps`` calls in one
+    program."""
+    started, c0 = time.monotonic(), _counters()
+    router, weights = _expert_weights(experts, d_model, width, dtype)
+    ms, errs, hit, tiles, run_s = _expert_products_alone(
+        cases, router, weights, top_k, dtype, reps, interpret)
+    for name, e in errs.items():
+        check(e <= LATENT_TOL,
+              f"experts {name}: error {e:.3g} over bound {LATENT_TOL}")
+    return _report(
+        "experts", started, c0, time.monotonic() - started - run_s, run_s,
+        experts=[experts, d_model, width], top_k=top_k, dtype=dtype,
+        reps=reps, ms_per_call=ms, experts_hit=hit, tiles=tiles,
+        rel_err={k: float(f"{e:.3g}") for k, e in errs.items()},
+        interpret=interpret)
+
+
 def blockdiff_phase(cases, layers, num_blocks, block_size, heads, kv_heads,
                     head_dim, block_length, experts, top_k, d_model, width,
                     slots=32, median=330.0, dtype="bfloat16", reps=28,
@@ -747,8 +841,6 @@ def blockdiff_phase(cases, layers, num_blocks, block_size, heads, kv_heads,
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from mxnet_tpu.ops.moe import (moe_experts, moe_experts_reference,
-                                   route_softmax_topk)
     from mxnet_tpu.ops.ragged_attention import (
         gqa_flat_attention, gqa_flat_attention_reference)
 
@@ -757,18 +849,9 @@ def blockdiff_phase(cases, layers, num_blocks, block_size, heads, kv_heads,
     pool = jax.random.normal(
         jax.random.PRNGKey(9), (layers, num_blocks, block_size,
                                 2 * kv_heads * head_dim)).astype(dtype)
-    ks = jax.random.split(jax.random.PRNGKey(11), 4)
-    router = 3 * d_model ** -0.5 * jax.random.normal(
-        ks[0], (d_model, experts), jnp.float32)
-    w_gate, w_up = (
-        (d_model ** -0.5 * jax.random.normal(
-            k, (experts, d_model, width), jnp.float32)).astype(dtype)
-        for k in ks[1:3])
-    w_down = (width ** -0.5 * jax.random.normal(
-        ks[3], (experts, width, d_model), jnp.float32)).astype(dtype)
-    weights = (w_gate, w_up, w_down)
+    router, weights = _expert_weights(experts, d_model, width, dtype)
     layer, scale = layers // 2, head_dim ** -0.5
-    ms_attn, ms_experts, errs, hit, run_s = {}, {}, {}, {}, 0.0
+    ms_attn, errs, run_s = {}, {}, 0.0
     for T in cases:
         name = f"T{T}"
         rs = np.random.RandomState(T)
@@ -816,30 +899,10 @@ def blockdiff_phase(cases, layers, num_blocks, block_size, heads, kv_heads,
         run_s += dt
         ms_attn[name] = round(dt / reps * 1e3, 4)
 
-        x = jax.random.normal(jax.random.PRNGKey(T + 1), (T, d_model),
-                              jnp.float32)
-        live = jnp.ones((T,), jnp.int32)
-        sel, gates = route_softmax_topk(x, router, top_k)
-        hit[name] = int(jnp.unique(sel).size)
-
-        def products(x, wg, wu, wd):
-            return moe_experts(x.astype(dtype), sel, gates, live, wg, wu,
-                               wd, use_pallas=True, interpret=interpret)
-
-        got = jax.block_until_ready(jax.jit(products)(x, *weights))
-        want = jax.jit(lambda x, wg, wu, wd: moe_experts_reference(
-            x.astype(dtype), sel, gates, live, wg, wu, wd))(x, *weights)
-        errs[name + "_experts"] = _rel_err(got, want)
-        # each call's input leans on the last one's output, so that no
-        # call is folded into another
-        many = jax.jit(lambda x, *w: jax.lax.fori_loop(
-            0, reps, lambda i, c: x + 1e-6 * products(c, *w), x))
-        jax.block_until_ready(many(x, *weights))                # compile
-        t0 = time.monotonic()
-        jax.block_until_ready(many(x, *weights))
-        dt = time.monotonic() - t0
-        run_s += dt
-        ms_experts[name] = round(dt / reps * 1e3, 4)
+    ms_experts, e_experts, hit, tiles, dt = _expert_products_alone(
+        cases, router, weights, top_k, dtype, reps, interpret)
+    run_s += dt
+    errs.update({name + "_experts": e for name, e in e_experts.items()})
     for name, e in errs.items():
         check(e <= LATENT_TOL,
               f"blockdiff {name}: error {e:.3g} over bound {LATENT_TOL}")
@@ -847,7 +910,7 @@ def blockdiff_phase(cases, layers, num_blocks, block_size, heads, kv_heads,
         "blockdiff", started, c0, time.monotonic() - started - run_s, run_s,
         pool=list(pool.shape), experts=[experts, d_model, width],
         dtype=dtype, reps=reps, ms_per_call_attn=ms_attn,
-        ms_per_call_experts=ms_experts, experts_hit=hit,
+        ms_per_call_experts=ms_experts, experts_hit=hit, tiles=tiles,
         rel_err={k: float(f"{e:.3g}") for k, e in errs.items()},
         interpret=interpret)
 
@@ -862,7 +925,7 @@ def main(argv=None):
     ap.add_argument("--phases", default=",".join(PHASES),
                     help="the phases to run, comma separated, of "
                          + ", ".join(PHASES) + " (kernels, latent, ragged, "
-                         "ssd and blockdiff: one chip only)")
+                         "ssd, blockdiff and experts: one chip only)")
     args = ap.parse_args(argv)
     phases = args.phases.split(",")
     if set(phases) - set(PHASES):
@@ -928,6 +991,11 @@ def main(argv=None):
         # decodes, 32 chunks
         ssd_phase(cases=(32, 47, 512), layers=36, slots=32, heads=64,
                   head_dim=64, state=128)
+    if "experts" in phases and not many:
+        # one expert layer of the moonlight_16b_a3b cell: a decode step of
+        # 8 rows and a full chunk step of 128
+        experts_phase(cases=(8, 128), experts=64, top_k=6, d_model=2048,
+                      width=1408)
     if "blockdiff" in phases and not many:
         # the sdar_30b_a3b_chat cell's pool, heads and one layer's
         # experts: 32 rows of a block of 4 (T 128), and 31 beside a

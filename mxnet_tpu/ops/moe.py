@@ -10,15 +10,27 @@ bytes of the experts that were hit, not of the ``E`` that are held. The
 products therefore run grouped:
 
 - pairs are sorted by expert; each expert's rows are padded to whole
-  tiles of ``TILE_ROWS`` rows, so every tile belongs to one expert;
-- :func:`_grouped_swiglu_pallas` walks the tiles: the tile's expert is a
-  scalar-prefetch operand that only the weights' index maps read, so
-  each grid step DMAs one column block of ONE expert's ``Wg``/``Wu`` and
-  the matching row block of its ``Wd`` out of the stacked ``[E, ...]``
-  weights and accumulates the tile's output in place. An expert that no
-  token hit is never read. Tiles past the last one in use are skipped
-  and map to the block already in VMEM, so they cost a grid step and no
-  bytes;
+  tiles of ``TILE_ROWS`` rows, so every tile belongs to one expert and an
+  expert's tiles lie one after the other;
+- :func:`_grouped_swiglu_pallas` walks the tiles, one a grid step: the
+  tile's expert is a scalar-prefetch operand that only the weights' index
+  maps read, so a step's blocks are ONE expert's ``Wg``, ``Wu`` and ``Wd``
+  out of the stacked ``[E, ...]`` weights, at the expert's WHOLE width
+  (three contiguous transfers), and the tile's output is one SwiGLU with
+  nothing to accumulate. The next tile of the same expert maps to the
+  same blocks, which the pipeline does not fetch again: an expert's bytes
+  are read once a call however many tiles its pairs fill, and an expert
+  that no token hit is never read. Tiles past the last one in use are
+  skipped and stay on the last expert's blocks, so they cost a grid step
+  and no bytes;
+- the block width follows from the shapes: the whole width where an
+  expert's three matrices fit ``VMEM_BUDGET`` twice (one in use, the next
+  in flight) beside the row tiles, else the widest block of whole
+  128-lane tiles that divides the width and does
+  (:func:`_column_block`). The grid is then ``(column blocks, tiles)``
+  with the column block OUTSIDE the tiles, so that each (expert, column
+  block) is still fetched once, and the column blocks' partial outputs
+  are summed after the call;
 - each pair's row is gathered back, scaled by its gate and summed into
   its token.
 
@@ -44,12 +56,16 @@ from .registry import register
 from .flash_attention import _on_tpu
 
 __all__ = ["route_noaux_tc", "route_softmax_topk", "moe_experts",
-           "moe_experts_reference", "moe_load"]
+           "moe_experts_reference", "moe_load", "moe_load_sum"]
 
 #: rows of one tile of the grouped products (a packed bf16 tile)
 TILE_ROWS = 16
-#: columns of an expert's width taken per grid step
-TILE_COLS = 128
+#: bytes of VMEM that Mosaic is handed for the grouped products
+VMEM_LIMIT = 64 * 1024 * 1024
+#: of which the blocks of one grid step may take this much, each counted
+#: twice (the pipeline holds the block in use and the next one in flight);
+#: the rest is for what the body keeps between its products
+VMEM_BUDGET = VMEM_LIMIT * 3 // 4
 
 
 def route_noaux_tc(x, router, bias, k, norm_topk_prob=True, scaling=1.0):
@@ -84,13 +100,24 @@ def route_softmax_topk(x, router, k, norm_topk_prob=True):
 
 
 def moe_load(sel, valid, num_experts):
-    """``int32 [3]``: routed pairs, experts hit and the largest load of
-    one expert, over the rows with ``valid``."""
+    """``int32 [4]``: routed pairs, experts hit, the largest load of one
+    expert and the row tiles the pairs fill (the sum over the experts of
+    ``ceil(load / TILE_ROWS)``: over the experts hit, what lies above 1
+    is tiles that find their expert's weights already in VMEM), over the
+    rows with ``valid``."""
     e = jnp.where(valid.astype(bool)[:, None], sel, num_experts)
     counts = jnp.zeros((num_experts + 1,), jnp.int32).at[
         e.reshape(-1)].add(1)[:num_experts]
-    return jnp.stack([counts.sum(), (counts > 0).sum(),
-                      counts.max()]).astype(jnp.int32)
+    return jnp.stack([counts.sum(), (counts > 0).sum(), counts.max(),
+                      (-(-counts // TILE_ROWS)).sum()]).astype(jnp.int32)
+
+
+def moe_load_sum(total, layer):
+    """A step's load over its expert layers: pairs, experts hit and
+    tiles summed, the largest load of one expert the largest."""
+    return jnp.stack([total[0] + layer[0], total[1] + layer[1],
+                      jnp.maximum(total[2], layer[2]),
+                      total[3] + layer[3]])
 
 
 def _swiglu(x, wg, wu, wd):
@@ -118,64 +145,74 @@ def moe_experts_reference(x, sel, gates, valid, w_gate, w_up, w_down):
     return out
 
 
+def _column_block(d, m, itemsize, budget):
+    """Columns of an expert's width that one grid step takes: all ``m``
+    where ``Wg``, ``Wu`` and ``Wd`` of one expert, a tile of rows and its
+    float32 output fit ``budget`` twice; else the widest block of whole
+    128-lane tiles that divides ``m`` and does (one lane tile at the
+    least). A width that is no multiple of 128 cannot be split."""
+    def fits(tn):
+        return 2 * (3 * d * tn * itemsize
+                    + TILE_ROWS * d * (itemsize + 4)) <= budget
+    lanes = m // 128
+    if m % 128 or fits(m):
+        return m
+    return 128 * max([q for q in range(1, lanes) if lanes % q == 0
+                      and fits(128 * q)], default=1)
+
+
 def _grouped_kernel(te_ref, used_ref, x_ref, wg_ref, wu_ref, wd_ref,
                     o_ref):
-    """Grid (tiles, column blocks): one tile of rows, all of one expert,
-    through one column block of that expert's SwiGLU; the tile's output
-    accumulates over the column blocks."""
-    t, j = pl.program_id(0), pl.program_id(1)
-
-    @pl.when(t < used_ref[0])
+    """Grid (column blocks, tiles): one tile of rows, all of one expert,
+    through one column block (as a rule the whole width) of that
+    expert's SwiGLU."""
+    @pl.when(pl.program_id(1) < used_ref[0])
     def _body():
-        y = _swiglu(x_ref[...], wg_ref[...], wu_ref[...], wd_ref[...])
-
-        @pl.when(j == 0)
-        def _first():
-            o_ref[...] = y
-
-        @pl.when(j > 0)
-        def _rest():
-            o_ref[...] += y
+        o_ref[...] = _swiglu(x_ref[...], wg_ref[...], wu_ref[...],
+                             wd_ref[...])
 
 
-@functools.partial(jax.jit, static_argnums=(6,))
+def _grouped_specs(d, tn):
+    """The block shapes and index maps of :func:`_grouped_swiglu_pallas`
+    for column blocks of ``tn``: ``(x, Wg, Wu, Wd, out)``, each map over
+    ``(column block, tile, tile_expert, tiles_used)``. A tile past the
+    last one in use carries the last expert in ``tile_expert``, so it
+    stays on the blocks that are already there."""
+    return (
+        pl.BlockSpec((TILE_ROWS, d), lambda j, t, te, used: (t, 0),
+                     memory_space=pltpu.VMEM),
+        pl.BlockSpec((None, d, tn), lambda j, t, te, used: (te[t], 0, j),
+                     memory_space=pltpu.VMEM),
+        pl.BlockSpec((None, d, tn), lambda j, t, te, used: (te[t], 0, j),
+                     memory_space=pltpu.VMEM),
+        pl.BlockSpec((None, tn, d), lambda j, t, te, used: (te[t], j, 0),
+                     memory_space=pltpu.VMEM),
+        pl.BlockSpec((None, TILE_ROWS, d),
+                     lambda j, t, te, used: (j, t, 0),
+                     memory_space=pltpu.VMEM),
+    )
+
+
+@functools.partial(jax.jit, static_argnums=(6, 7))
 def _grouped_swiglu_pallas(x_rows, tile_expert, tiles_used, w_gate, w_up,
-                           w_down, interpret):
+                           w_down, interpret, budget=VMEM_BUDGET):
     R, d = x_rows.shape
     m = w_gate.shape[2]
-    tn = TILE_COLS if m % TILE_COLS == 0 else m
+    tn = _column_block(d, m, w_gate.dtype.itemsize, budget)
     nj = m // tn
-
-    def col(t, j, te, used):
-        # a tile past the last one in use stays on the block that is
-        # already there
-        return jnp.where(t < used[0], j, nj - 1)
-
+    *in_specs, out_spec = _grouped_specs(d, tn)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(R // TILE_ROWS, nj),
-        in_specs=[
-            pl.BlockSpec((TILE_ROWS, d), lambda t, j, te, used: (t, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((None, d, tn), lambda t, j, te, used:
-                         (te[t], 0, col(t, j, te, used)),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((None, d, tn), lambda t, j, te, used:
-                         (te[t], 0, col(t, j, te, used)),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((None, tn, d), lambda t, j, te, used:
-                         (te[t], col(t, j, te, used), 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((TILE_ROWS, d),
-                               lambda t, j, te, used: (t, 0),
-                               memory_space=pltpu.VMEM),
-    )
-    return pl.pallas_call(
+        num_scalar_prefetch=2, grid=(nj, R // TILE_ROWS),
+        in_specs=in_specs, out_specs=out_spec)
+    parts = pl.pallas_call(
         _grouped_kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((R, d), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((nj, R, d), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
     )(tile_expert, tiles_used, x_rows, w_gate, w_up, w_down)
+    return parts[0] if nj == 1 else parts.sum(axis=0)
 
 
 def moe_experts(x, sel, gates, valid, w_gate, w_up, w_down,

@@ -116,7 +116,7 @@ def _make_step_fn(model, spec_k, sampled, quantized=False, lora=False,
     ``state=``); they follow the params, are donated and come back in
     their order. ``aux`` is whatever
     :meth:`model.decode_flat` returns after its pools (a model with
-    expert layers: their load, int32 ``[3]``), ``None`` (no output at
+    expert layers: their load, int32 ``[4]``), ``None`` (no output at
     all) for a model that returns nothing there; it sits ahead of the
     pools so that it comes back in the step's one fetch.
 
@@ -2274,9 +2274,11 @@ class LLMEngine:
                 decoded = self._commit(rows, plans, *out, events)
                 if self._moe_load is not None and sp.span_id is not None:
                     # over the step's expert layers: pairs and experts
-                    # hit summed, the largest load of one expert
+                    # hit summed, the largest load of one expert, the
+                    # row tiles the pairs fill summed
                     for key, v in zip(("moe_pairs", "moe_experts_hit",
-                                       "moe_max_load"), self._moe_load):
+                                       "moe_max_load", "moe_tiles"),
+                                      self._moe_load):
                         sp.set(key, v)
             csp.set("decoded", decoded)
             if self._block:

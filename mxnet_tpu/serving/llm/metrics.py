@@ -259,6 +259,12 @@ class LLMStats:
             "layer of a step.", lbl,
             buckets=(1, 2, 4, 8, 16, 32, 64, 128, 256, 512,
                      1024)).labels(**s)
+        self._moe_tiles = r.counter(
+            "mxtpu_llm_moe_tiles_total",
+            "Row tiles of the grouped expert products that a step's "
+            "pairs fill, summed over the step's expert layers; over "
+            "the experts hit, what lies above 1 is tiles that found "
+            "their expert's weights already in VMEM.", lbl).labels(**s)
         self._state_rows = r.counter(
             "mxtpu_llm_state_rows_total",
             "Rows (sequences) whose slot state a dispatched step "
@@ -470,13 +476,14 @@ class LLMStats:
     def record_spmd_dispatch(self, n=1):
         self._spmd_dispatches.inc(n)
 
-    def record_moe(self, pairs, experts_hit, max_load):
+    def record_moe(self, pairs, experts_hit, max_load, tiles):
         """One dispatched step of a model with expert layers (the
         histogram's count is the number of such steps: times the
         experts the model holds, the denominator of the hit share)."""
         self._moe_pairs.inc(pairs)
         self._moe_hit.inc(experts_hit)
         self._moe_max_load.observe(max_load)
+        self._moe_tiles.inc(tiles)
 
     def record_state_pool(self, slots, nbytes):
         """Engine construction, a model whose slots hold state."""
@@ -606,6 +613,7 @@ class LLMStats:
                 "moe_routed_pairs": int(self._moe_pairs.value),
                 "moe_experts_hit": int(self._moe_hit.value),
                 "moe_steps": int(self._moe_max_load.count),
+                "moe_tiles": int(self._moe_tiles.value),
                 "state_rows": int(self._state_rows.value),
                 "state_starts": int(self._state_starts.value),
                 "state_steps": int(self._state_steps.value),

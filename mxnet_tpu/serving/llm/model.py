@@ -38,7 +38,7 @@ from ...ops.ragged_attention import (ragged_flat_attention,
                                      gqa_flat_attention)
 from ...ops.ssd import ssd_state_step, slot_runs
 from ...ops.moe import (route_noaux_tc, route_softmax_topk, moe_experts,
-                        moe_load)
+                        moe_load, moe_load_sum)
 from ...ops.flash_attention import attention_reference
 from ...ops.lora import (paged_lora_delta, gather_adapter,
                          PROJ_Q, PROJ_K, PROJ_V, PROJ_O)
@@ -1003,7 +1003,7 @@ class TinyDecoder:
     def _mlp_or_experts(self, lp, x2, valid):
         """The second half of a ``deepseek_v3`` layer for normed rows
         ``x2 [T, d]`` (float32): ``(what is added to the residual
-        stream [T, d] float32, the routing's load int32 [3] or None)``.
+        stream [T, d] float32, the routing's load int32 [4] or None)``.
         A dense layer is one SwiGLU; an expert layer routes each row
         with ``valid`` to ``num_experts_per_tok`` experts (no capacity
         limit, no dropped pair) and adds the shared experts."""
@@ -1080,9 +1080,10 @@ class TinyDecoder:
         rotary positions, router scores, gates and the residual stream
         are float32. Returns ``(logits [T, V] float32, pages)`` and,
         from a model with expert layers (``expert_slots``), ``load``
-        int32 ``[3]`` after them: routed pairs and experts hit summed
-        over the expert layers, and the largest load of one expert in
-        one layer, over the rows with ``valid``."""
+        int32 ``[4]`` after them (``ops.moe.moe_load``): routed pairs
+        and experts hit summed over the expert layers, the largest load
+        of one expert in one layer, and the row tiles the pairs fill,
+        summed likewise, over the rows with ``valid``."""
         import jax.numpy as jnp
         c = self.config
         T = tokens.shape[0]
@@ -1097,7 +1098,7 @@ class TinyDecoder:
             vmask, block_tables[seq_ids, positions // bs], 0)  # null
         slot = jnp.where(vmask, positions % bs, 0)
         pad = wp - rank - rope
-        load = jnp.zeros((3,), jnp.int32)
+        load = jnp.zeros((4,), jnp.int32)
         h = params["embed"][tokens].astype(jnp.float32)
         for li, lp in enumerate(params["layers"]):
             x = _rms_norm(h, lp["norm1"], eps)
@@ -1126,8 +1127,7 @@ class TinyDecoder:
                 lp, _rms_norm(h, lp["norm2"], eps), vmask)
             h = h + add
             if hit is not None:
-                load = jnp.stack([load[0] + hit[0], load[1] + hit[1],
-                                  jnp.maximum(load[2], hit[2])])
+                load = moe_load_sum(load, hit)
         logits = _wmm(_rms_norm(h, params["norm_f"], eps), params["head"])
         return (logits, pages, load) if self.expert_slots \
             else (logits, pages)
@@ -1445,7 +1445,7 @@ class TinyDecoder:
 
     def _experts_blockdiff(self, lp, x2, valid):
         """The expert layer for normed rows ``x2 [T, d]`` (float32):
-        ``(what is added to the stream, the routing's load int32 [3])``."""
+        ``(what is added to the stream, the routing's load int32 [4])``."""
         c = self.config
         sel, gates = route_softmax_topk(
             x2, lp["router"], c.num_experts_per_tok, c.norm_topk_prob)
@@ -1517,7 +1517,7 @@ class TinyDecoder:
             vmask, block_tables[seq_ids, positions // bs], 0)  # null
         slot = jnp.where(vmask, positions % bs, 0)
         sees = _block_last(positions, c.block_length)
-        load = jnp.zeros((3,), jnp.int32)
+        load = jnp.zeros((4,), jnp.int32)
         h = params["embed"][tokens].astype(jnp.float32)
         for li, lp in enumerate(params["layers"]):
             q, k, v = self._qkv_blockdiff(
@@ -1530,8 +1530,7 @@ class TinyDecoder:
             add, hit = self._experts_blockdiff(
                 lp, _rms_norm(h, lp["norm2"], eps), vmask)
             h = h + add
-            load = jnp.stack([load[0] + hit[0], load[1] + hit[1],
-                              jnp.maximum(load[2], hit[2])])
+            load = moe_load_sum(load, hit)
         logits = _wmm(_rms_norm(h, params["norm_f"], eps), params["head"])
         return logits, pages, load
 

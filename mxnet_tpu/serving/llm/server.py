@@ -506,6 +506,11 @@ class LLMServer:
         rung = snap.get("step_rung_tokens", 0)
         snap["step_fill_share"] = (snap.get("step_tokens", 0) / rung
                                    if rung else 0.0)
+        # expert layers: row tiles over experts hit; what lies above 1
+        # is tiles that found their expert's weights already in VMEM
+        hit = snap.get("moe_experts_hit", 0)
+        snap["moe_tiles_per_expert_hit"] = (snap.get("moe_tiles", 0) / hit
+                                            if hit else 0.0)
         # a model that generates by diffusion over blocks: positions
         # revealed over row passes, settling passes among them
         passes = sum(snap.get("blockdiff_passes", {}).values())

@@ -138,6 +138,20 @@ def test_blockdiff_phase_toy():
     assert set(line["ms_per_call_attn"]) == {"T16", "T32"} \
         == set(line["ms_per_call_experts"])
     assert line["experts_hit"] == {"T16": 8, "T32": 8}
+    assert line["tiles"] == {"T16": 8, "T32": 8}
+    assert max(line["rel_err"].values()) < 1e-4
+
+
+def test_experts_phase_toy():
+    """The ``experts`` phase's control flow, the kernel in interpret mode:
+    a width of three lane tiles, and at T 80 experts of two tiles."""
+    line = chip_smoke.experts_phase(
+        cases=(4, 80), experts=8, top_k=2, d_model=32, width=384,
+        dtype="float32", reps=2, interpret=True)
+    assert line["phase"] == "experts" and line["experts"] == [8, 32, 384]
+    assert set(line["ms_per_call"]) == {"T4", "T80"}
+    assert line["experts_hit"]["T80"] == 8 < line["tiles"]["T80"]
+    assert line["tiles"]["T4"] == line["experts_hit"]["T4"] <= 8
     assert max(line["rel_err"].values()) < 1e-4
 
 
@@ -197,6 +211,27 @@ def test_flat_kernel_lowers_for_tpu_at_the_cells_shapes(T, MB, pages):
         ((8, MB), jnp.int32), ((T,), jnp.int32), ((T,), jnp.int32))]
     if pages == "int8":
         args += [jax.ShapeDtypeStruct(pool[:-1], jnp.float32)] * 2
+    exported = jax.export.export(jax.jit(kernel), platforms=["tpu"])(*args)
+    assert "tpu_custom_call" in exported.mlir_module()
+
+
+@pytest.mark.parametrize("E,m,k,T", [(128, 768, 8, 128), (128, 768, 8, 512),
+                                     (64, 1408, 6, 8), (64, 1408, 6, 128)])
+def test_expert_products_lower_for_tpu_at_the_cells_shapes(E, m, k, T):
+    """One layer of the block-diffusion cell's experts and of the
+    ``deepseek_v3`` cell's, each at its whole width in one block."""
+    import jax.numpy as jnp
+    from mxnet_tpu.ops import moe
+
+    def kernel(x, sel, gates, valid, wg, wu, wd):
+        return moe.moe_experts(x, sel, gates, valid, wg, wu, wd,
+                               use_pallas=True, interpret=False)
+    d, bf16 = 2048, jnp.bfloat16
+    assert moe._column_block(d, m, 2, moe.VMEM_BUDGET) == m
+    args = [jax.ShapeDtypeStruct(s, t) for s, t in (
+        ((T, d), bf16), ((T, k), jnp.int32), ((T, k), jnp.float32),
+        ((T,), jnp.int32), ((E, d, m), bf16), ((E, d, m), bf16),
+        ((E, m, d), bf16))]
     exported = jax.export.export(jax.jit(kernel), platforms=["tpu"])(*args)
     assert "tpu_custom_call" in exported.mlir_module()
 

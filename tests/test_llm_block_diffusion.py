@@ -442,10 +442,14 @@ def test_counters_and_step_span_attributes(model, params):
     for a in generating:
         assert a["moe_pairs"] == 2 * 2 * sum(n for n, _ in a["rows"])
         assert 1 <= a["moe_experts_hit"] <= 16
+        # a few rows of a block of 4: no expert fills a second tile
+        assert a["moe_tiles"] == a["moe_experts_hit"]
         assert all(n % B == 0 for n, _ in a["rows"])
     assert stats["moe_steps"] == len(spans)
     reg = get_registry()
     label = {"server": server._stats.server_label}
+    assert stats["moe_tiles"] == sum(a["moe_tiles"] for a in spans) \
+        == reg.get("mxtpu_llm_moe_tiles_total").labels(**label).value
     assert reg.get("mxtpu_llm_blockdiff_revealed_total").labels(
         **label).value == 19
     assert reg.get("mxtpu_llm_blockdiff_blocks_total").labels(

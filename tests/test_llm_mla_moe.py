@@ -136,6 +136,17 @@ def test_served_through_llmserver_against_the_reference(model, dtype):
     # held over the two expert layers of every dispatched step
     assert stats["moe_routed_pairs"] > 0 and model.expert_slots == 16
     assert 0 < stats["moe_experts_hit"] <= stats["moe_steps"] * 16
+    # tiles of 16 rows: one an expert hit, and in the few steps that
+    # feed a long prompt at once a second one
+    assert stats["moe_experts_hit"] <= stats["moe_tiles"] <= (
+        stats["moe_experts_hit"] + stats["moe_routed_pairs"] // 16)
+    assert 1.0 <= stats["moe_tiles_per_expert_hit"] == (
+        stats["moe_tiles"] / stats["moe_experts_hit"]) < 1.2
+    from mxnet_tpu.observability import get_registry
+    tiles = [c.value for c in
+             get_registry().get("mxtpu_llm_moe_tiles_total").children()
+             if c.labels_dict["server"] == "mla_moe_" + dtype]
+    assert tiles == [stats["moe_tiles"]]
 
 
 def test_step_span_carries_the_routers_load(model):
@@ -172,6 +183,8 @@ def test_step_span_carries_the_routers_load(model):
     assert first["moe_pairs"] == 44 and first["rows"] == [(11, 11)]
     assert 2 <= first["moe_experts_hit"] <= 16
     assert 2 <= first["moe_max_load"] <= 11
+    # at most 11 pairs an expert: one tile of 16 rows each
+    assert first["moe_tiles"] == first["moe_experts_hit"]
 
 
 def test_long_chunks_through_both_kernels_beside_decoding_rows(monkeypatch):
@@ -390,47 +403,177 @@ def per_token_loop(x, sel, gates, valid, wg, wu, wd):
     return out
 
 
-@pytest.mark.parametrize("plant", ["routed", "all_to_one", "one_unused",
-                                   "all_padding"])
-def test_expert_layer_loses_no_pair_under_imbalance(plant):
+def _planted(loads, k):
+    """``sel [T, k]`` that gives expert ``e`` exactly ``loads[e]`` pairs."""
+    flat = np.repeat(np.arange(len(loads)), loads)
+    assert flat.size % k == 0
+    return jnp.asarray(flat.reshape(-1, k), jnp.int32)
+
+
+#: plant, then what differs from T 37, d 64, E 8, m 32, k 2, float32
+EXPERT_CASES = {
+    "routed": {},
+    "all_to_one": {},                  # every pair on expert 5
+    "one_unused": {},                  # expert 3 gets no token
+    "all_padding": {},
+    # an expert's whole width is one block: three lane tiles, and a
+    # width that is no multiple of 128
+    "width_384": {"plant": "routed", "m": 384},
+    "width_200": {"plant": "routed", "m": 200},
+    "bf16": {"plant": "routed", "m": 256, "dtype": jnp.bfloat16},
+    # experts of 2, 3 and 17 tiles of 16 rows, the rest unused
+    "tiles_2_3_17": {"loads": [20, 0, 40, 0, 0, 260, 0, 0]},
+    "one_tile_each": {"loads": [16, 1, 8, 16, 3, 16, 2, 16]},
+    # the budget forced small: column blocks of 256 of 512, and of 128
+    "fallback_2_blocks": {"plant": "routed", "m": 512, "budget": 500_000,
+                          "tn": 256},
+    "fallback_3_blocks": {"loads": [20, 0, 40, 0, 0, 260, 0, 0], "m": 384,
+                          "budget": 300_000, "tn": 128},
+}
+
+
+@pytest.mark.parametrize("case", list(EXPERT_CASES))
+def test_expert_layer_loses_no_pair_under_imbalance(case, monkeypatch):
     """(d): the grouped kernel (interpret mode) and the plain twin
     against a loop over each token's experts, under the router's own
-    choice and under planted imbalance."""
+    choice and under planted imbalance; at widths of several lane tiles
+    and of no whole ones, in bf16, with experts of many tiles and of one
+    each, and through the column blocks a small budget forces."""
+    spec = EXPERT_CASES[case]
+    plant = spec.get("plant", case)
     rs = np.random.RandomState(4)
-    T, d, E, m, k = 37, 64, 8, 32, 2
-    x = jnp.asarray(rs.randn(T, d), jnp.float32)
-    wg = jnp.asarray(rs.randn(E, d, m) * d ** -0.5, jnp.float32)
-    wu = jnp.asarray(rs.randn(E, d, m) * d ** -0.5, jnp.float32)
-    wd = jnp.asarray(rs.randn(E, m, d) * m ** -0.5, jnp.float32)
+    d, E, k = 64, 8, 2
+    m, dtype = spec.get("m", 32), spec.get("dtype", jnp.float32)
+    loads = spec.get("loads")
+    T = sum(loads) // k if loads else 37
+    x = jnp.asarray(rs.randn(T, d), dtype)
+    wg = jnp.asarray(rs.randn(E, d, m) * d ** -0.5, dtype)
+    wu = jnp.asarray(rs.randn(E, d, m) * d ** -0.5, dtype)
+    wd = jnp.asarray(rs.randn(E, m, d) * m ** -0.5, dtype)
     router = jnp.asarray(rs.randn(d, E) * d ** -0.5, jnp.float32)
     bias = jnp.asarray(rs.randn(E) * 0.01, jnp.float32)
     sel, gates = moe.route_noaux_tc(x, router, bias, k, True, 2.446)
     np.testing.assert_allclose(gates.sum(axis=1), 2.446, rtol=1e-5)
-    valid = np.arange(T) < 33              # four rows of bucket padding
-    if plant == "all_to_one":              # every pair on expert 5
+    valid = np.arange(T) < T - 4           # four rows of bucket padding
+    if plant == "all_to_one":
         sel = jnp.full_like(sel, 5)
-    elif plant == "one_unused":            # expert 3 gets no token
+    elif plant == "one_unused":
         sel = jnp.where(sel == 3, (sel + 1) % E, sel)
     elif plant == "all_padding":
         valid = np.zeros(T, bool)
-    want = per_token_loop(x, sel, gates, valid, wg, wu, wd)
+    elif loads:
+        sel, valid = _planted(loads, k), np.ones(T, bool)
+    if "budget" in spec:
+        assert moe._column_block(d, m, 4, spec["budget"]) == spec["tn"]
+        kernel = moe._grouped_swiglu_pallas
+        monkeypatch.setattr(moe, "_grouped_swiglu_pallas",
+                            lambda *a: kernel(*a, spec["budget"]))
+    else:
+        assert moe._column_block(d, m, x.dtype.itemsize,
+                                 moe.VMEM_BUDGET) == m
+    f32 = [np.asarray(a, np.float32) for a in (x, wg, wu, wd)]
+    want = per_token_loop(f32[0], sel, gates, valid, *f32[1:])
     args = (x, sel, gates, jnp.asarray(valid), wg, wu, wd)
     twin = moe.moe_experts_reference(*args)
     got = moe.moe_experts(*args, use_pallas=True, interpret=True)
-    np.testing.assert_allclose(twin, want, rtol=1e-4, atol=1e-4)
-    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
+    assert got.dtype == jnp.float32 and got.shape == (T, d)
+    # bf16: silu(g) * u is rounded to the operands' dtype before the
+    # down product, in the kernel and the twin alike
+    tol = 1e-4 if dtype == jnp.float32 else 3e-2
+    np.testing.assert_allclose(twin, want, rtol=tol, atol=tol)
+    np.testing.assert_allclose(got, want, rtol=tol, atol=tol)
+    np.testing.assert_allclose(got, twin, rtol=tol / 4, atol=tol / 4)
     assert not np.asarray(got)[~valid].any()    # padding comes back 0
-    pairs, hit, biggest = (int(v) for v in moe.moe_load(
+    pairs, hit, biggest, tiles = (int(v) for v in moe.moe_load(
         sel, jnp.asarray(valid), E))
     live = int(valid.sum())
-    assert pairs == live * k
+    assert pairs == live * k and hit <= tiles <= hit + pairs // 16
     if plant == "all_to_one":
         assert (hit, biggest) == (1, live * k)
+        assert tiles == -(-live * k // 16)
     elif plant == "one_unused":
         assert hit <= E - 1
     elif plant == "all_padding":
-        assert (hit, biggest) == (0, 0)
+        assert (hit, biggest, tiles) == (0, 0, 0)
+    elif case == "one_tile_each":
+        assert (hit, biggest, tiles) == (8, 16, 8)
+    elif loads:
+        assert (hit, biggest, tiles) == (3, 260, 2 + 3 + 17)
     assert registry.get("moe_experts") is not None
+
+
+def _kernel_call(monkeypatch, sel, valid, E, d=64, m=32):
+    """What ``moe_experts`` hands the kernel for a routing: ``(x_rows,
+    tile_expert, tiles_used)``, as NumPy arrays."""
+    seen = []
+    kernel = moe._grouped_swiglu_pallas
+    monkeypatch.setattr(moe, "_grouped_swiglu_pallas",
+                        lambda *a: seen.append(a) or kernel(*a))
+    T, k = sel.shape
+    w = jnp.zeros((E, d, m), jnp.float32)
+    moe.moe_experts(jnp.ones((T, d), jnp.float32), sel,
+                    jnp.ones((T, k), jnp.float32), jnp.asarray(valid), w,
+                    w, w.transpose(0, 2, 1), use_pallas=True,
+                    interpret=True)
+    (x_rows, tile_expert, used, *_), = seen
+    return np.asarray(x_rows), np.asarray(tile_expert), np.asarray(used)
+
+
+@pytest.mark.parametrize("loads,blocks", [
+    ([20, 0, 40, 0, 0, 260, 0, 0], 1), ([20, 0, 40, 0, 0, 260, 0, 0], 3),
+    ([16, 1, 8, 16, 3, 16, 2, 16], 1), ([0, 0, 0, 0, 0, 0, 0, 2], 2),
+    ([0] * 8, 1)])
+def test_an_experts_weights_are_fetched_once(loads, blocks, monkeypatch):
+    """The grid's own index maps over the tiles ``moe_experts`` lays out:
+    walking the grid in its order, the weights' block index changes
+    (a transfer) experts hit x column blocks times and no more, however
+    many tiles an expert fills and however many lie unused behind the
+    last; every row tile is read and written once a column block."""
+    d, m, E, k = 64, 128 * blocks, len(loads), 2
+    sel = _planted(loads, k) if sum(loads) else jnp.zeros((3, k), jnp.int32)
+    valid = np.ones(sel.shape[0], bool) if sum(loads) \
+        else np.zeros(sel.shape[0], bool)
+    x_rows, te, used = _kernel_call(monkeypatch, sel, valid, E)
+    n_tiles = x_rows.shape[0] // moe.TILE_ROWS
+    hit = sum(n > 0 for n in loads)
+    assert te.shape == (n_tiles,) and used[0] == sum(
+        -(-n // moe.TILE_ROWS) for n in loads)
+    assert n_tiles > used[0]            # the tail of unused tiles is there
+    assert [int(e) for e in te[:used[0]]] == [
+        e for e, n in enumerate(loads)
+        for _ in range(-(-n // moe.TILE_ROWS))]
+    x_spec, wg_spec, wu_spec, wd_spec, o_spec = moe._grouped_specs(d, 128)
+    steps = [(j, t) for j in range(blocks) for t in range(n_tiles)]
+    for spec in (wg_spec, wu_spec, wd_spec):
+        at = [tuple(int(i) for i in spec.index_map(j, t, te, used))
+              for j, t in steps]
+        fetched = [at[0]] + [b for a, b in zip(at, at[1:]) if a != b]
+        # an empty step still holds one block: the pipeline's first
+        assert len(fetched) == max(hit, 1) * blocks
+        assert len(set(fetched)) == len(fetched)    # none comes back
+        assert {i[0] for i in fetched} == (
+            {e for e, n in enumerate(loads) if n} or {0})
+    rows, outs = ([tuple(int(i) for i in spec.index_map(j, t, te, used))
+                   for j, t in steps] for spec in (x_spec, o_spec))
+    assert rows == [(t, 0) for _, t in steps]
+    assert outs == [(j, t, 0) for j, t in steps]
+    assert wg_spec.block_shape == (None, d, 128) \
+        and wd_spec.block_shape == (None, 128, d)
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_load_counts_the_tiles_the_pairs_fill(seed):
+    rs = np.random.RandomState(seed)
+    T, k, E = 200, 6, 16
+    sel = rs.randint(0, E, (T, k)) if seed else rs.zipf(1.5, (T, k)) % E
+    valid = rs.rand(T) < 0.8
+    counts = np.bincount(sel[valid].reshape(-1), minlength=E)
+    want = [counts.sum(), (counts > 0).sum(), counts.max(),
+            sum(-(-int(n) // moe.TILE_ROWS) for n in counts)]
+    got = moe.moe_load(jnp.asarray(sel, jnp.int32), jnp.asarray(valid), E)
+    assert got.dtype == jnp.int32 and got.tolist() == want
+    twice = moe.moe_load_sum(got, got).tolist()
+    assert twice == [2 * want[0], 2 * want[1], want[2], 2 * want[3]]
 
 
 def test_selection_bias_selects_and_does_not_weigh():
