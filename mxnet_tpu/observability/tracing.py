@@ -33,7 +33,11 @@ Three integration rules keep the tracer honest:
   never claims one). The ring then holds the spans of the captured
   window with their host durations and parents, and the same spans
   stand in the xplane beside the device's events on the capture's
-  clock (``benchmark/trace.py`` reads both for the per-layer metrics).
+  clock. The chip benchmark's per-layer readers take the ring
+  (``benchmark/program_spans.py``); its trace reduction
+  (``benchmark/trace.py``) keeps the device's events and, of the
+  host's annotations, only its own ``bench.*``, so the ``mxtpu.*``
+  annotations in the xplane are read by hand so far (ROADMAP B4).
 
 Cross-thread propagation is explicit: contextvars do not follow work
 onto other threads, so producers capture ``tracer.current()`` at

@@ -77,6 +77,7 @@ from .kv_cache import (PagedKVCache, KVCacheError, NULL_BLOCK,
                        prefix_block_hashes)
 from .quant import (QuantizedWeights, quantize_weights,
                     resolve_weight_dtype, fp8_supported, FP8_NAME)
+from .metrics import PhaseClock, hand_over
 from .model import UnsupportedModelFeatureError
 from .scheduler import Scheduler, Sequence, RUNNING, FINISHED, EVICTED
 from .sampling import (TAG_SAMPLE, TAG_ACCEPT, TAG_DRAFT, row_keys,
@@ -538,6 +539,9 @@ class LLMEngine:
     row's share comes out; ``draft_model``/``draft_params`` +
     ``spec_k`` (``MXNET_TPU_LLM_SPEC_K``) enable speculative decoding
     (the draft must share the target's vocab and cover its context).
+    ``clock``: the :class:`~.metrics.PhaseClock` of the thread that
+    steps this engine (``LLMServer`` hands its own to every engine it
+    drives); left out, the engine makes one and marks its five phases.
     """
 
     def __init__(self, model, params, max_seqs=None, block_size=None,
@@ -546,7 +550,7 @@ class LLMEngine:
                  stats=None, dtype="float32", breaker=None,
                  prefix_cache=None, kv_dtype=None, adapter_bank=None,
                  mesh=None, weight_dtype=None, weight_calib=None,
-                 draft_weight_dtype=None):
+                 draft_weight_dtype=None, clock=None):
         import jax
         import jax.numpy as jnp
         self.model = model
@@ -716,6 +720,13 @@ class LLMEngine:
         self.scheduler = Scheduler(self.max_seqs)
         self._stats = stats
         self._flight = get_flightrecorder()
+        # the serving thread's phase clock: the server's, shared with
+        # its loop and its other engines, or this engine's own when it
+        # is stepped with no server around it
+        self._served = clock is not None
+        self._clock = clock if clock is not None else PhaseClock()
+        self._launched = 0      # programs launched since the hand-over
+        self._step_rung = 0     # the packed length the last one took
         if adapter_bank is not None and stats is not None:
             adapter_bank.attach_stats(stats)
         if self.prefix_enabled:
@@ -1934,12 +1945,17 @@ class LLMEngine:
         """ONE fixed-shape launch for ``rows`` (slots not in ``rows``
         ride along inactive on the null block — the shape, and
         therefore the compiled program, never changes). Dispatch
-        failures propagate to the isolation logic in :meth:`step`."""
-        if any(plans[s]["kind"] != "prefill" for s in rows):
-            # chaos-harness site: scripted raises / injected latency
-            faults.check("llm.decode")
-        tracer = get_tracer()
+        failures propagate to the isolation logic in :meth:`step`.
+
+        Three phases of the thread's clock, each under the span of its
+        name: ``build``, ``launch``, ``fetch``; the last is left open
+        for the caller to close."""
+        clock, tracer = self._clock, get_tracer()
+        clock.mark("build")
         with tracer.span("mxtpu.llm.build", "llm") as sp:
+            if any(plans[s]["kind"] != "prefill" for s in rows):
+                # chaos-harness site: scripted raises / injected latency
+                faults.check("llm.decode")
             t_need = sum(len(plans[s]["tokens"]) for s in rows)
             t = next(w for w in self._t_buckets if w >= t_need)
             mb_need = max(self.cache.blocks_for(
@@ -1947,24 +1963,42 @@ class LLMEngine:
             mb = next(w for w in self._mb_widths if w >= mb_need)
             sampled = any(s.sampling.temperature > 0 for s in rows)
             batch = self._build_batch(rows, plans, t, mb)
+            self._step_rung = t
             sp.set("tokens", t_need)
             sp.set("t_bucket", t)
             sp.set("mb", mb)
             if self._stats:
                 self._stats.record_step_fill(t_need, t)
+        clock.mark("launch")
         with tracer.span("mxtpu.llm.launch", "llm") as sp:
             sp.set("sampled", sampled)
+            # the host arrays handed over beside weights and pools
+            sp.set("args", len(batch))
+            self._launched += 1
             out = self._call_step(sampled, batch)
-        with tracer.span("mxtpu.llm.fetch", "llm"):
+        clock.mark("fetch")
+        with tracer.span("mxtpu.llm.fetch", "llm") as sp:
             # ONE fetch: the expert layers' load, where the model has
             # any, rides with the tokens
             toks, n_acc, load = self._device_get(out)
-        self._moe_load = None
-        if load is not None:
-            self._moe_load = tuple(int(v) for v in load)
-            if self._stats:
-                self._stats.record_moe(*self._moe_load)
+            self._moe_load = None
+            if load is not None:
+                self._moe_load = tuple(int(v) for v in load)
+                if self._stats:
+                    self._stats.record_moe(*self._moe_load)
+            if sp.span_id is not None:
+                sp.set("bytes", sum(a.nbytes for a in (toks, n_acc, load)
+                                    if a is not None))
         return toks, n_acc
+
+    def _redispatch(self, rows, plans):
+        """A dispatch from inside the commit phase (the halves of an
+        isolation): whatever comes of it, the clock is back in
+        ``commit`` after."""
+        try:
+            return self._dispatch(rows, plans)
+        finally:
+            self._clock.mark("commit")
 
     def _sites(self, rows, plans):
         sites = set()
@@ -2130,7 +2164,7 @@ class LLMEngine:
         Returns the committed decode-token count."""
         if len(rows) == 1:
             try:
-                toks, n_acc = self._dispatch(rows, plans)
+                toks, n_acc = self._redispatch(rows, plans)
             except Exception as exc:
                 if self._pages_deleted():
                     raise       # KV pool gone mid-bisect: fatal
@@ -2145,7 +2179,7 @@ class LLMEngine:
         mid = len(rows) // 2
         for half in (rows[:mid], rows[mid:]):
             try:
-                toks, n_acc = self._dispatch(half, plans)
+                toks, n_acc = self._redispatch(half, plans)
             except Exception:
                 if self._pages_deleted():
                     raise       # KV pool gone mid-bisect: fatal
@@ -2181,12 +2215,16 @@ class LLMEngine:
         ``[("admitted"|"token"|"finished"|"preempted"|"expired"|
         "poisoned", Sequence)]``.
 
-        The span ``mxtpu.llm.step`` covers the whole iteration; its
+        The span ``mxtpu.llm.step`` covers the whole iteration, and its
         children ``schedule``, ``build``, ``launch``, ``fetch`` (the
-        last three in :meth:`_dispatch`) and ``commit`` cover it
-        between them."""
-        tracer = get_tracer()
+        last three in :meth:`_dispatch`) and ``commit`` cover it: no
+        statement of the iteration stands between two of them. Each
+        opens at a mark of the thread's phase clock
+        (:class:`~.metrics.PhaseClock`), which is the one clock a step
+        is timed by."""
+        clock, tracer = self._clock, get_tracer()
         events = []
+        clock.mark("schedule")
         with tracer.span("mxtpu.llm.step", "llm") as sp:
             with tracer.span("mxtpu.llm.schedule", "llm") as ssp:
                 rows, plans = self._schedule(events)
@@ -2194,11 +2232,31 @@ class LLMEngine:
                     ssp.set("admitted", sum(
                         1 for kind, _ in events if kind == "admitted"))
                     ssp.set("waiting", self.scheduler.num_waiting)
+                if rows:
+                    prefilling = self._describe_step(sp, rows, plans)
+                else:
+                    self._record_block_gauges()
             if rows:
-                self._run(tracer, sp, rows, plans, events)
-            else:
-                self._record_block_gauges()
+                self._run(tracer, sp, rows, plans, events, prefilling)
+        if not self._served:
+            # nobody's time until the next step
+            clock.mark(None)
         return events
+
+    def _describe_step(self, sp, rows, plans):
+        """What the step span says of the rows about to be dispatched.
+        Returns how many of them are prefilling, where someone asks."""
+        if sp.span_id is None and not self._flight.enabled:
+            return None
+        prefilling = sum(1 for s in rows if plans[s]["kind"] == "prefill")
+        if sp.span_id is not None:
+            sp.set("running", len(rows))
+            sp.set("prefilling", prefilling)
+            # (tokens fed, context after) of each row
+            sp.set("rows", [(len(plans[s]["tokens"]),
+                             s.seq_len + len(plans[s]["tokens"]))
+                            for s in rows])
+        return prefilling
 
     def _schedule(self, events):
         """The host's half of an iteration before any launch: expire,
@@ -2237,22 +2295,10 @@ class LLMEngine:
             rows = [s for s in rows if s.state == RUNNING]
         return rows, plans
 
-    def _run(self, tracer, sp, rows, plans, events):
+    def _run(self, tracer, sp, rows, plans, events, prefilling):
         """Dispatch ``rows`` and commit what comes back, under the
         step span ``sp``."""
         fl = self._flight
-        prefilling = None
-        if sp.span_id is not None or fl.enabled:
-            prefilling = sum(1 for s in rows
-                             if plans[s]["kind"] == "prefill")
-        if sp.span_id is not None:
-            sp.set("running", len(rows))
-            sp.set("prefilling", prefilling)
-            # (tokens fed, context after) of each row
-            sp.set("rows", [(len(plans[s]["tokens"]),
-                             s.seq_len + len(plans[s]["tokens"]))
-                            for s in rows])
-        t0 = time.monotonic()
         self._blk_step = [0, 0, 0]
         try:
             out = self._dispatch(rows, plans)
@@ -2262,6 +2308,7 @@ class LLMEngine:
             sp.set("error", repr(exc))
             out = None
         # commit, and the step's bookkeeping once its results are in
+        self._clock.mark("commit")
         with tracer.span("mxtpu.llm.commit", "llm") as csp:
             self._record_breaker(rows, plans, out is not None)
             if out is None:
@@ -2289,7 +2336,16 @@ class LLMEngine:
                 for key, v in zip(("denoise_rows", "settle_rows",
                                    "revealed"), self._blk_step):
                     sp.set(key, v)
-            step_s = time.monotonic() - t0
+            # the step's one hand-over of the thread's clock: what it
+            # holds is this step's phases so far, with the loop's
+            # resolve and wait before it
+            self._clock.mark("commit")
+            ns = hand_over(self._clock, self._stats, fl, self._launched,
+                           running=len(rows), rung=self._step_rung)
+            self._launched = 0
+            # dispatch and commit together, as ever
+            step_s = (ns["build"] + ns["launch"] + ns["fetch"]
+                      + ns["commit"]) / 1e9
             if self._stats and any(plans[s]["kind"] != "prefill"
                                    for s in rows if s in plans):
                 self._stats.record_decode_step(decoded, step_s)
@@ -2298,7 +2354,10 @@ class LLMEngine:
                          attrs={"running": len(rows),
                                 "prefilling": prefilling,
                                 "decoded": decoded,
-                                "step_ms": round(step_s * 1e3, 3)})
+                                "step_ms": round(step_s * 1e3, 3),
+                                "phases_ms": {
+                                    p: round(v / 1e6, 3)
+                                    for p, v in ns.items() if v}})
             self._record_block_gauges()
 
     def state_pool_stats(self):

@@ -19,16 +19,99 @@ The serving-economics headline numbers ("Fine-Tuning and Serving Gemma
 - ``mxtpu_llm_queue_wait_seconds`` — the queue wait alone (submit →
   first placement into a decode slot);
 - ``mxtpu_llm_kv_blocks_in_use`` / ``_total`` — paged-cache occupancy.
+
+The serving thread's own time is :class:`PhaseClock`'s: every moment of
+it under one of :data:`PHASES`, handed to :class:`LLMStats` once a step
+(``mxtpu_llm_step_phase_seconds_total`` and the stall families).
 """
 from __future__ import annotations
 
 import threading
+import time
 
 from ...observability import get_registry
 from ..telemetry import (OverloadStats, TenantStats,
                          _claim_server_label, _LATENCY_BUCKETS)
 
-__all__ = ["LLMStats"]
+__all__ = ["LLMStats", "PhaseClock", "PHASES", "STALL_S", "hand_over"]
+
+#: the serving thread's phases, in the order a loop iteration takes
+#: them: the loop's head (the condition variable, routing, the scans for
+#: work), the engine's five, the loop's flush of Futures
+PHASES = ("wait", "schedule", "build", "launch", "fetch", "commit",
+          "resolve")
+#: one stay in a phase longer than this is a stall (PERF.md section 7)
+STALL_S = 0.1
+_STALL_NS = int(STALL_S * 1e9)
+
+
+class PhaseClock:
+    """The serving thread's time as a chain of phases with no gap
+    between them. :meth:`mark` is the one operation: ONE read of the
+    monotonic clock, which charges the time since the last mark to the
+    phase that was open and opens the next, so the phases sum to the
+    thread's wall time by construction. A stay is the time between two
+    marks; a stay of more than :data:`STALL_S` in any phase but
+    ``wait`` (a server without a request waits by right) is a stall.
+
+    One thread marks, so there is no lock here; the sums are plain
+    Python numbers until :func:`hand_over` gives them to
+    :class:`LLMStats`, once a step. ``LLMServer`` holds one clock for
+    its worker thread and all its engines; an engine stepped with no
+    server around it makes its own and marks its five phases alone."""
+
+    __slots__ = ("_open", "_t_ns", "ns", "longest_ns", "stalls")
+
+    def __init__(self):
+        self._open = None       # the phase time is charged to, if any
+        self._t_ns = 0
+        self._fresh()
+
+    def _fresh(self):
+        self.ns = dict.fromkeys(PHASES, 0)
+        self.longest_ns = dict.fromkeys(PHASES, 0)
+        self.stalls = []        # (phase, ns) of each stalled stay
+
+    def mark(self, phase):
+        """Close the open phase and open ``phase`` (``None``: none, the
+        thread's time is nobody's until the next mark). Marking the
+        open phase again charges it so far and ends that stay. Returns
+        the clock's reading, nanoseconds."""
+        now = time.monotonic_ns()
+        was = self._open
+        if was is not None:
+            stay = now - self._t_ns
+            self.ns[was] += stay
+            if stay > self.longest_ns[was]:
+                self.longest_ns[was] = stay
+            if stay > _STALL_NS and was != "wait":
+                self.stalls.append((was, stay))
+        self._open, self._t_ns = phase, now
+        return now
+
+    def take(self):
+        """``(ns by phase, longest stay by phase, stalls)`` since the
+        last take, and start them anew; the open phase stays open."""
+        out = self.ns, self.longest_ns, self.stalls
+        self._fresh()
+        return out
+
+
+def hand_over(clock, stats, flight, dispatched=0, **step):
+    """Give ``stats`` what ``clock`` gathered since its last hand-over
+    (``dispatched``: the programs launched meanwhile). With the flight
+    recorder on, each stalled stay leaves an event ``llm.stall`` with
+    its phase, its milliseconds and ``step``, what the caller knows of
+    the step at hand (for a stall in ``resolve``, the step after it).
+    Returns the nanoseconds by phase."""
+    ns, longest, stalls = clock.take()
+    if stats is not None:
+        stats.record_step_phases(ns, longest, stalls, dispatched)
+    if stalls and flight.enabled:
+        for phase, stay in stalls:
+            flight.event("llm.stall", attrs={
+                "phase": phase, "ms": round(stay / 1e6, 3), **step})
+    return ns
 
 
 class LLMStats:
@@ -305,6 +388,38 @@ class LLMStats:
             "mxtpu_llm_blockdiff_blocks_total",
             "Blocks settled: their K/V written for good, their tokens "
             "answered.", lbl).labels(**s)
+        # the serving thread's time by phase (PhaseClock), one child a
+        # phase made here: a hand-over looks nothing up
+        by_phase = ("server", "phase")
+
+        def phase_children(metric, phases=PHASES):
+            return {p: metric.labels(server=self._server, phase=p)
+                    for p in phases}
+        self._phase_seconds = phase_children(r.counter(
+            "mxtpu_llm_step_phase_seconds_total",
+            "Seconds the serving thread spent in each phase of its loop "
+            "(wait, schedule, build, launch, fetch, commit, resolve); "
+            "the phases sum to the thread's wall time.", by_phase))
+        self._phase_longest = phase_children(r.gauge(
+            "mxtpu_llm_step_phase_longest_seconds",
+            "The longest single stay in each phase over the server's "
+            "life.", by_phase))
+        self._longest_ns = dict.fromkeys(PHASES, 0)     # guarded-by: _lock
+        stalled = tuple(p for p in PHASES if p != "wait")
+        self._stalls = phase_children(r.counter(
+            "mxtpu_llm_step_stalls_total",
+            "Stays in a phase longer than 0.1 s (wait left out: a "
+            "server without a request waits by right).", by_phase),
+            stalled)
+        self._stall_seconds = phase_children(r.counter(
+            "mxtpu_llm_step_stall_seconds_total",
+            "Seconds of the stays counted by "
+            "mxtpu_llm_step_stalls_total.", by_phase), stalled)
+        self._steps_dispatched = r.counter(
+            "mxtpu_llm_steps_dispatched_total",
+            "Step programs launched, of any kind of row (prefill "
+            "chunks, decode, verify, block passes; the halves of an "
+            "isolation among them).", lbl).labels(**s)
         # the overload/failure series share the single-shot server's
         # mxtpu_serving_* catalog (one dashboard for both front ends)
         self._overload = OverloadStats(r, self._server)
@@ -411,6 +526,24 @@ class LLMStats:
         for ``rung``."""
         self._step_tokens.inc(tokens)
         self._step_rung_tokens.inc(rung)
+
+    def record_step_phases(self, ns, longest_ns, stalls, dispatched):
+        """One hand-over of the serving thread's clock
+        (:func:`hand_over`): nanoseconds and longest stay by phase,
+        the stalled stays ``[(phase, ns)]``, the programs launched."""
+        with self._lock:
+            for phase, v in ns.items():
+                if v:
+                    self._phase_seconds[phase].inc(v / 1e9)
+            for phase, v in longest_ns.items():
+                if v > self._longest_ns[phase]:
+                    self._longest_ns[phase] = v
+                    self._phase_longest[phase].set(v / 1e9)
+            for phase, v in stalls:
+                self._stalls[phase].inc()
+                self._stall_seconds[phase].inc(v / 1e9)
+            if dispatched:
+                self._steps_dispatched.inc(dispatched)
 
     def record_spec(self, proposed, accepted):
         self._spec_proposed.inc(proposed)
@@ -566,6 +699,14 @@ class LLMStats:
                     self._prefill_chunk_tokens.value),
                 "step_tokens": int(self._step_tokens.value),
                 "step_rung_tokens": int(self._step_rung_tokens.value),
+                "steps_dispatched": int(self._steps_dispatched.value),
+                "step_phases_s": {p: c.value for p, c in
+                                  self._phase_seconds.items()},
+                "step_phase_longest_s": {
+                    p: c.value for p, c in self._phase_longest.items()},
+                "stalls": {p: {"count": int(c.value),
+                               "seconds": self._stall_seconds[p].value}
+                           for p, c in self._stalls.items() if c.value},
                 "spec_proposed": int(self._spec_proposed.value),
                 "spec_accepted": int(self._spec_accepted.value),
                 "spec_degraded": int(self._spec_degraded.value),

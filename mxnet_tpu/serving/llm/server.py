@@ -44,7 +44,11 @@ queue-wait/ttft/token counts); on the worker thread one
 ``mxtpu.llm.step`` an engine iteration with the children
 ``mxtpu.llm.schedule``, ``.build``, ``.launch``, ``.fetch`` and
 ``.commit`` (``mxtpu.llm.isolate`` under it on the failure path) and
-its sibling ``mxtpu.llm.resolve`` around this loop's flush; the
+its siblings ``mxtpu.llm.wait`` around this loop's head and
+``mxtpu.llm.resolve`` around its flush, so that the leaves cover the
+worker's time between them; the same boundaries mark the worker's
+:class:`~.metrics.PhaseClock`, always on, which feeds
+``mxtpu_llm_step_phase_seconds_total`` and the stall series; the
 ``mxtpu_llm_*`` registry series (:class:`~.metrics.LLMStats`) and the
 shared ``mxtpu_serving_{shed,deadline_expired,poison_isolated,
 breaker_state}`` overload series. Spans record while the tracer is on
@@ -65,7 +69,7 @@ from ..envutil import env_float as _env_float
 from ..overload import (CircuitBreaker, resolve_deadline,
                         resolve_overload_knobs, shed_if_breaker_open)
 from .engine import LLMEngine
-from .metrics import LLMStats
+from .metrics import LLMStats, PhaseClock, hand_over
 from .sampling import SamplingParams
 from .scheduler import Sequence
 from ..telemetry import compile_count
@@ -176,6 +180,9 @@ class LLMServer:
         self.name = name
         self._stats = LLMStats(server=name)
         self._flight = get_flightrecorder()
+        # the worker thread's phase clock: one thread, so one clock,
+        # however many engines it steps
+        self._clock = PhaseClock()
         self._breaker = CircuitBreaker(
             threshold=breaker_threshold,
             cooldown_ms=breaker_cooldown_ms,
@@ -189,11 +196,13 @@ class LLMServer:
             self._engines = [LLMEngine(model, params,
                                        stats=self._stats,
                                        breaker=self._breaker,
+                                       clock=self._clock,
                                        **engine_kw)]
         else:
             self._engines = [LLMEngine(model, params,
                                        stats=self._stats,
                                        breaker=self._breaker,
+                                       clock=self._clock,
                                        mesh=sub, **engine_kw)
                              for sub in submeshes]
             # engines published their tp-submesh shape; overwrite
@@ -516,6 +525,13 @@ class LLMServer:
         passes = sum(snap.get("blockdiff_passes", {}).values())
         snap["tokens_per_pass"] = (snap.get("blockdiff_revealed", 0)
                                    / passes if passes else 0.0)
+        # of the worker's time outside the loop's wait, the share that
+        # is not the wait for the device (the fetch): what the chip
+        # stands idle for, seen from inside
+        phases = snap["step_phases_s"]
+        busy = sum(phases.values()) - phases["wait"]
+        snap["host_share"] = ((busy - phases["fetch"]) / busy
+                              if busy else 0.0)
         lookups = snap.get("prefix_lookups", 0)
         snap["prefix_hit_rate"] = (snap.get("prefix_hits", 0) / lookups
                                    if lookups else 0.0)
@@ -813,6 +829,10 @@ class LLMServer:
             # resolve every live Future
             self._fail_everything(exc)
             raise
+        finally:
+            # what the clock holds since the last step's hand-over
+            self._clock.mark(None)
+            hand_over(self._clock, self._stats, self._flight)
 
     def _route(self, seq):
         """Pick the replica for one admitted sequence: least loaded
@@ -826,43 +846,50 @@ class LLMServer:
     def _run_loop_inner(self):
         engines = self._engines
         tracer = get_tracer()
+        clock = self._clock
         while True:
-            with self._cv:
-                while (not self._pending
-                       and not any(e.has_work() for e in engines)
-                       and not self._closed):
-                    self._cv.wait(timeout=0.05)
-                pending, self._pending = self._pending, []
-                closed, drain = self._closed, self._drain
-                deadline = self._deadline
-            for seq in pending:
-                self._route(seq).add(seq)
-            # chaos-harness point: crash_at_point("llm.worker")
-            # simulates the engine thread dying mid-loop
-            faults.point("llm.worker")
-            if closed:
-                expired = (deadline is not None
-                           and time.monotonic() >= deadline)
-                if not drain or expired:
-                    reason = ("shutdown" if not drain
-                              else "drain_deadline")
-                    self._flush_engine()
-                    for eng in engines:
-                        for seq in eng.evict_all(reason):
-                            self._resolve_evicted(seq, reason)
-                    return
-                if not any(e.has_work() for e in engines):
-                    self._flush_engine()
-                    return
-            stepped = False
-            for eng in engines:
-                if eng.has_work():
-                    eng.step()
-                    stepped = True
-            # the sibling of mxtpu.llm.step on this thread: Futures
-            # resolve here, with their callbacks
+            # the loop's head, the sibling of mxtpu.llm.step before it:
+            # the condition variable, routing, the scans for work
+            clock.mark("wait")
+            with tracer.span("mxtpu.llm.wait", "llm") as wsp:
+                blocked_ns = 0
+                with self._cv:
+                    while (not self._pending
+                           and not any(e.has_work() for e in engines)
+                           and not self._closed):
+                        t0 = time.monotonic_ns()
+                        self._cv.wait(timeout=0.05)
+                        blocked_ns += time.monotonic_ns() - t0
+                    pending, self._pending = self._pending, []
+                    closed, drain = self._closed, self._drain
+                    deadline = self._deadline
+                for seq in pending:
+                    self._route(seq).add(seq)
+                # chaos-harness point: crash_at_point("llm.worker")
+                # simulates the engine thread dying mid-loop
+                faults.point("llm.worker")
+                ready = [e for e in engines if e.has_work()]
+                wsp.set("blocked_us", blocked_ns // 1000)
+                wsp.set("routed", len(pending))
+                if closed:
+                    expired = (deadline is not None
+                               and time.monotonic() >= deadline)
+                    if not drain or expired:
+                        reason = ("shutdown" if not drain
+                                  else "drain_deadline")
+                        self._flush_engine()
+                        for eng in engines:
+                            for seq in eng.evict_all(reason):
+                                self._resolve_evicted(seq, reason)
+                        return
+                    if not ready:
+                        self._flush_engine()
+                        return
+            for eng in ready:
+                eng.step()
+            # the sibling of mxtpu.llm.step after it: Futures resolve
+            # here, with their callbacks
+            clock.mark("resolve")
             with tracer.span("mxtpu.llm.resolve", "llm") as sp:
                 resolved = self._flush_engine()
                 sp.set("resolved", resolved)
-            if not stepped:
-                continue
