@@ -99,46 +99,79 @@ def route_softmax_topk(x, router, k, norm_topk_prob=True):
     return sel.astype(jnp.int32), gates
 
 
-def moe_load(sel, valid, num_experts):
+def _held_pairs(sel, live, num_experts, first):
+    """``(experts as this layer numbers the ones it holds, which pairs it
+    holds)``: every pair of a row with ``live`` where ``first`` is
+    ``None`` (the layer holds every expert), else the pairs whose expert
+    lies in ``[first, first + num_experts)``."""
+    held = live[:, None]
+    if first is None:
+        return sel, held
+    sel = sel - first
+    return sel, held & (sel >= 0) & (sel < num_experts)
+
+
+def moe_load(sel, valid, num_experts, first=None):
     """``int32 [4]``: routed pairs, experts hit, the largest load of one
     expert and the row tiles the pairs fill (the sum over the experts of
     ``ceil(load / TILE_ROWS)``: over the experts hit, what lies above 1
     is tiles that find their expert's weights already in VMEM), over the
-    rows with ``valid``."""
-    e = jnp.where(valid.astype(bool)[:, None], sel, num_experts)
+    rows with ``valid``. With ``first``, of a layer that holds only the
+    ``num_experts`` experts from ``first`` on (:func:`moe_experts`): the
+    four over the pairs it holds, and ``int32 [5]``, the fifth the pairs
+    routed to experts held elsewhere."""
+    live = valid.astype(bool)
+    local, held = _held_pairs(sel, live, num_experts, first)
+    e = jnp.where(held, local, num_experts)
     counts = jnp.zeros((num_experts + 1,), jnp.int32).at[
         e.reshape(-1)].add(1)[:num_experts]
-    return jnp.stack([counts.sum(), (counts > 0).sum(), counts.max(),
-                      (-(-counts // TILE_ROWS)).sum()]).astype(jnp.int32)
+    load = [counts.sum(), (counts > 0).sum(), counts.max(),
+            (-(-counts // TILE_ROWS)).sum()]
+    if first is not None:
+        load.append(live.sum() * sel.shape[1] - counts.sum())
+    return jnp.stack(load).astype(jnp.int32)
 
 
 def moe_load_sum(total, layer):
     """A step's load over its expert layers: pairs, experts hit and
-    tiles summed, the largest load of one expert the largest."""
-    return jnp.stack([total[0] + layer[0], total[1] + layer[1],
-                      jnp.maximum(total[2], layer[2]),
-                      total[3] + layer[3]])
+    tiles summed, the largest load of one expert the largest (and the
+    pairs held elsewhere summed, where the load has them)."""
+    load = [total[0] + layer[0], total[1] + layer[1],
+            jnp.maximum(total[2], layer[2]), total[3] + layer[3]]
+    load += [total[4] + layer[4]] if total.shape[0] == 5 else []
+    return jnp.stack(load)
 
 
-def _swiglu(x, wg, wu, wd):
+def _swiglu(x, wg, wu, wd, limit=None):
     g = jnp.dot(x, wg, preferred_element_type=jnp.float32)
     u = jnp.dot(x, wu, preferred_element_type=jnp.float32)
+    if limit is not None:
+        g, u = jnp.minimum(g, limit), jnp.clip(u, -limit, limit)
     return jnp.dot((jax.nn.silu(g) * u).astype(x.dtype), wd,
                    preferred_element_type=jnp.float32)
 
 
-def moe_experts_reference(x, sel, gates, valid, w_gate, w_up, w_down):
-    """The expert layer's routed part, plainly: every expert over every
-    token, summed with each token's gate on it (0 where the token did
-    not select it, and on rows without ``valid``). ``[T, d]`` float32."""
+def moe_experts_reference(x, sel, gates, valid, w_gate, w_up, w_down,
+                          first=None, limit=None):
+    """The expert layer's routed part, plainly: every expert held over
+    every token, summed with each token's gate on it (0 where the token
+    did not select it, and on rows without ``valid``). ``[T, d]``
+    float32. ``first`` and ``limit`` as :func:`moe_experts` takes them."""
     T, E = x.shape[0], w_gate.shape[0]
-    weight = jnp.zeros((T, E), jnp.float32).at[
-        jnp.arange(T)[:, None], sel].add(gates)
+    if first is None:
+        weight = jnp.zeros((T, E), jnp.float32).at[
+            jnp.arange(T)[:, None], sel].add(gates)
+    else:
+        # a pair held elsewhere adds its gate to a column that is dropped
+        local, held = _held_pairs(sel, valid.astype(bool), E, first)
+        weight = jnp.zeros((T, E + 1), jnp.float32).at[
+            jnp.arange(T)[:, None], jnp.where(held, local, E)].add(
+                gates)[:, :E]
     weight = jnp.where(valid.astype(bool)[:, None], weight, 0.0)
 
     def one(acc, e):
         wg, wu, wd, w = e
-        return acc + w[:, None] * _swiglu(x, wg, wu, wd), None
+        return acc + w[:, None] * _swiglu(x, wg, wu, wd, limit), None
     out, _ = jax.lax.scan(one, jnp.zeros((T, w_down.shape[2]),
                                          jnp.float32),
                           (w_gate, w_up, w_down, weight.T))
@@ -162,14 +195,14 @@ def _column_block(d, m, itemsize, budget):
 
 
 def _grouped_kernel(te_ref, used_ref, x_ref, wg_ref, wu_ref, wd_ref,
-                    o_ref):
+                    o_ref, limit=None):
     """Grid (column blocks, tiles): one tile of rows, all of one expert,
     through one column block (as a rule the whole width) of that
     expert's SwiGLU."""
     @pl.when(pl.program_id(1) < used_ref[0])
     def _body():
         o_ref[...] = _swiglu(x_ref[...], wg_ref[...], wu_ref[...],
-                             wd_ref[...])
+                             wd_ref[...], limit)
 
 
 def _grouped_specs(d, tn):
@@ -193,9 +226,11 @@ def _grouped_specs(d, tn):
     )
 
 
-@functools.partial(jax.jit, static_argnums=(6, 7))
+@functools.partial(jax.jit, static_argnums=(6, 7),
+                   static_argnames=("limit",))
 def _grouped_swiglu_pallas(x_rows, tile_expert, tiles_used, w_gate, w_up,
-                           w_down, interpret, budget=VMEM_BUDGET):
+                           w_down, interpret, budget=VMEM_BUDGET,
+                           limit=None):
     R, d = x_rows.shape
     m = w_gate.shape[2]
     tn = _column_block(d, m, w_gate.dtype.itemsize, budget)
@@ -204,8 +239,10 @@ def _grouped_swiglu_pallas(x_rows, tile_expert, tiles_used, w_gate, w_up,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2, grid=(nj, R // TILE_ROWS),
         in_specs=in_specs, out_specs=out_spec)
+    kernel = _grouped_kernel if limit is None else functools.partial(
+        _grouped_kernel, limit=limit)
     parts = pl.pallas_call(
-        _grouped_kernel, grid_spec=grid_spec,
+        kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((nj, R, d), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
@@ -216,19 +253,28 @@ def _grouped_swiglu_pallas(x_rows, tile_expert, tiles_used, w_gate, w_up,
 
 
 def moe_experts(x, sel, gates, valid, w_gate, w_up, w_down,
-                use_pallas=None, interpret=None):
+                use_pallas=None, interpret=None, first=None, limit=None):
     """The routed part of an expert layer for a packed batch: ``x [T,
     d]`` tokens, ``sel``/``gates`` ``[T, k]`` from the router, ``valid
     [T]`` (rows of padding are routed nowhere), the experts' stacked
     ``w_gate``/``w_up`` ``[E, d, m]`` and ``w_down [E, m, d]``. Returns
     ``sum_i gates[t, i] * E_sel[t, i](x[t])`` as ``[T, d]`` float32.
     Grouped Mosaic kernel on a TPU (only the experts hit are read), the
-    plain twin elsewhere; see the module docstring."""
+    plain twin elsewhere; see the module docstring.
+
+    ``first``: the layer holds only the ``E`` experts from ``first`` on
+    of the router's (one chip's share under expert parallelism); ``sel``
+    names the router's experts, and a pair routed to one held elsewhere
+    is given to no expert here, as a row of padding is, so it is neither
+    computed nor read. ``None``: the ``E`` experts are all the router's.
+    ``limit``: each expert's gate product is capped at ``limit`` and its
+    up product clipped to ``[-limit, limit]`` before the SwiGLU's
+    product (``swiglu_limit``); ``None``, neither."""
     if use_pallas is None:
         use_pallas = _on_tpu()
     if not use_pallas:
         return moe_experts_reference(x, sel, gates, valid, w_gate, w_up,
-                                     w_down)
+                                     w_down, first, limit)
     if interpret is None:
         interpret = not _on_tpu()
     T, k = sel.shape
@@ -237,19 +283,24 @@ def moe_experts(x, sel, gates, valid, w_gate, w_up, w_down,
     n_tiles = min(P, E) + P // tm
     R = n_tiles * tm
     live = valid.astype(bool)
-    # pairs by expert; a pair of padding goes to the sentinel E, last
-    e = jnp.where(live[:, None], sel, E).reshape(P)
+    # pairs by expert; a pair of padding (or held elsewhere) goes to the
+    # sentinel E, last
+    if first is None:
+        e = jnp.where(live[:, None], sel, E).reshape(P)
+    else:
+        sel, held = _held_pairs(sel, live, E, first)
+        e = jnp.where(held, sel, E).reshape(P)
     counts = jnp.zeros((E + 1,), jnp.int32).at[e].add(1)
     tiles = (counts[:E] + tm - 1) // tm
     tile_end = jnp.cumsum(tiles)
     # first row of each expert's tiles; the sentinel's lies past the end
     row0 = jnp.concatenate([(tile_end - tiles) * tm,
                             jnp.full((1,), R, jnp.int32)])
-    first = jnp.cumsum(counts) - counts         # in the sorted order
+    lead = jnp.cumsum(counts) - counts          # in the sorted order
     order = jnp.argsort(e, stable=True)
     e_sorted = e[order]
     row_sorted = row0[e_sorted] + jnp.arange(P, dtype=jnp.int32) \
-        - first[e_sorted]
+        - lead[e_sorted]
     row_sorted = jnp.minimum(row_sorted, R)     # sentinel pairs: row R
     # row -> the token it holds (T: the zero row appended below)
     row_token = jnp.full((R + 1,), T, jnp.int32).at[row_sorted].set(
@@ -260,12 +311,14 @@ def moe_experts(x, sel, gates, valid, w_gate, w_up, w_down,
         tile_end, jnp.minimum(jnp.arange(n_tiles), used[0] - 1),
         side="right").astype(jnp.int32)
     tile_expert = jnp.minimum(tile_expert, E - 1)
-    y_rows = _grouped_swiglu_pallas(x_rows, tile_expert, used, w_gate,
-                                    w_up, w_down, bool(interpret))
+    y_rows = _grouped_swiglu_pallas(
+        x_rows, tile_expert, used, w_gate, w_up, w_down, bool(interpret),
+        **({} if limit is None else {"limit": limit}))
     # each pair's row, in the pairs' own order
     row = jnp.zeros((P,), jnp.int32).at[order].set(row_sorted)
     y = jnp.take(y_rows, row, axis=0, mode="clip").reshape(T, k, d)
-    y = jnp.where(live[:, None, None], y, 0.0)
+    y = jnp.where(live[:, None, None] if first is None else held[..., None],
+                  y, 0.0)
     return (y * gates[..., None]).sum(axis=1)
 
 

@@ -130,7 +130,8 @@ def _make_step_fn(model, spec_k, sampled, quantized=False, lora=False,
     ``state=``); they follow the params, are donated and come back in
     their order. ``aux`` is whatever
     :meth:`model.decode_flat` returns after its pools (a model with
-    expert layers: their load, int32 ``[4]``), ``None`` (no output at
+    expert layers: their load, int32 ``[4]``, or ``[5]`` from a model
+    that holds a share of its experts), ``None`` (no output at
     all) for a model that returns nothing there; it sits ahead of the
     pools so that it comes back in the step's one fetch.
 
@@ -2617,6 +2618,10 @@ class LLMEngine:
                                        "moe_max_load", "moe_tiles"),
                                       self._moe_load):
                         sp.set(key, v)
+                    if len(self._moe_load) == 5:
+                        # a model that holds a share of its experts: the
+                        # pairs routed to the others, summed
+                        sp.set("moe_pairs_elsewhere", self._moe_load[4])
             csp.set("decoded", decoded)
             if self._block:
                 # what the committed passes did (isolated halves among

@@ -348,6 +348,12 @@ class LLMStats:
             "pairs fill, summed over the step's expert layers; over "
             "the experts hit, what lies above 1 is tiles that found "
             "their expert's weights already in VMEM.", lbl).labels(**s)
+        self._moe_elsewhere = r.counter(
+            "mxtpu_llm_moe_pairs_elsewhere_total",
+            "(token, expert) pairs that the step's expert layers routed "
+            "to experts this chip does not hold (a model that holds a "
+            "share of its router's experts); neither computed nor read "
+            "here.", lbl).labels(**s)
         self._state_rows = r.counter(
             "mxtpu_llm_state_rows_total",
             "Rows (sequences) whose slot state a dispatched step "
@@ -626,14 +632,19 @@ class LLMStats:
     def record_spmd_dispatch(self, n=1):
         self._spmd_dispatches.inc(n)
 
-    def record_moe(self, pairs, experts_hit, max_load, tiles):
+    def record_moe(self, pairs, experts_hit, max_load, tiles,
+                   elsewhere=None):
         """One dispatched step of a model with expert layers (the
         histogram's count is the number of such steps: times the
-        experts the model holds, the denominator of the hit share)."""
+        experts the model holds, the denominator of the hit share).
+        ``elsewhere``: pairs routed to experts held on other chips,
+        from a model that holds a share of them."""
         self._moe_pairs.inc(pairs)
         self._moe_hit.inc(experts_hit)
         self._moe_max_load.observe(max_load)
         self._moe_tiles.inc(tiles)
+        if elsewhere is not None:
+            self._moe_elsewhere.inc(elsewhere)
 
     def record_state_pool(self, slots, nbytes):
         """Engine construction, a model whose slots hold state."""
@@ -775,6 +786,7 @@ class LLMStats:
                 "moe_experts_hit": int(self._moe_hit.value),
                 "moe_steps": int(self._moe_max_load.count),
                 "moe_tiles": int(self._moe_tiles.value),
+                "moe_pairs_elsewhere": int(self._moe_elsewhere.value),
                 "state_rows": int(self._state_rows.value),
                 "state_starts": int(self._state_starts.value),
                 "state_steps": int(self._state_steps.value),

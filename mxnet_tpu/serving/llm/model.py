@@ -2,7 +2,7 @@
 
 What the serving engine calls and reads of a model is stated once, in
 :class:`~.engine.LLMEngine`'s docstring (the model protocol);
-:class:`TinyDecoder` is its reference implementation, for three blocks
+:class:`TinyDecoder` is its reference implementation, for five blocks
 (``DecoderConfig.model_type``; each entry point below goes to the
 block's own method through ``TinyDecoder._of_block``). Its entry points:
 
@@ -14,7 +14,8 @@ block's own method through ``TinyDecoder._of_block``). Its entry points:
   :func:`~mxnet_tpu.ops.ragged_attention.mla_flat_attention` or
   :func:`~mxnet_tpu.ops.ragged_attention.gqa_flat_attention`; each
   state-space layer advances its sequences' slot states through
-  :func:`~mxnet_tpu.ops.ssd.ssd_state_step`. A pure function of
+  :func:`~mxnet_tpu.ops.ssd.ssd_state_step`, each GatedDeltaNet layer
+  through :func:`~mxnet_tpu.ops.gdn.gdn_state_step`. A pure function of
   ``(params, inputs)``; the engine jits it with donated pools, and
   prefill, decode and speculative verify are all this one call.
 - :meth:`TinyDecoder.forward`: the eager oracle. A dense causal forward
@@ -37,6 +38,7 @@ from ...ops.ragged_attention import (ragged_flat_attention,
                                      mla_flat_attention,
                                      gqa_flat_attention)
 from ...ops.ssd import ssd_state_step, slot_runs
+from ...ops.gdn import gdn_state_step
 from ...ops.moe import (route_noaux_tc, route_softmax_topk, moe_experts,
                         moe_load, moe_load_sum)
 from ...ops.flash_attention import attention_reference
@@ -46,12 +48,14 @@ from ...ops.quantization import quantized_matmul
 
 __all__ = ["DecoderConfig", "TinyDecoder", "greedy_decode_reference",
            "UnsupportedModelFeatureError", "GPT2", "DEEPSEEK_V3",
-           "GRANITE_HYBRID", "SDAR_MOE"]
+           "GRANITE_HYBRID", "SDAR_MOE", "GIGACHAT3_5", "yarn_inv_freq",
+           "yarn_mscale"]
 
 #: the blocks a :class:`DecoderConfig` can describe (``model_type``)
 GPT2, DEEPSEEK_V3 = "gpt2", "deepseek_v3"
 GRANITE_HYBRID = "granitemoehybrid"
 SDAR_MOE = "sdar_moe"
+GIGACHAT3_5 = "gigachat3_5"
 #: how a block-diffusion model picks the masked positions a pass reveals
 REMASKING = ("sequential", "low_confidence_static",
              "low_confidence_dynamic")
@@ -129,7 +133,34 @@ class DecoderConfig:
     ``REMASKING``) choosing which positions a pass reveals
     (``confidence_threshold`` for the dynamic one); the logits at a
     position are for that position itself. These five are generation
-    defaults the published ``config.json`` lacks."""
+    defaults the published ``config.json`` lacks.
+
+    ``model_type="gigachat3_5"`` (GigaChat 3.5) is a fifth block. Layers
+    whose index is in ``full_attention_layers`` are latent attention as
+    the ``deepseek_v3`` block's, with a query down-projection to
+    ``q_lora_rank`` values (normed) before the heads, a sigmoid gate
+    from the layer's input on the heads' outputs (``gated_attention``),
+    interleaved rotary pairs under YaRN (``rope_scaling``, the softmax
+    scale times the square of its ``mscale_all_dim`` factor); every other
+    layer is GatedDeltaNet linear attention (``linear_num_key_heads``
+    key and query heads of ``linear_key_head_dim``,
+    ``linear_num_value_heads`` value heads of ``linear_value_head_dim``,
+    a causal convolution ``linear_conv_kernel_dim`` wide, the delta rule
+    over a slot state of ``[value heads, key size, value size]``, the
+    output normed per head and gated by ``linear_sigmoid_gate_scale x
+    sigmoid(z)``). Each sublayer is normed before and after (``pre_post``),
+    every norm zero-centred (gains stored as their distance from 1) and
+    the four sublayer norms and the final one gated per channel by
+    ``layernorm_gating_weight x sigmoid(gate)``. The first
+    ``first_k_dense_replace`` layers take a SwiGLU of width ``d_ff``, the
+    rest ``router_experts`` routed experts by the ``deepseek_v3``
+    block's sigmoid router (``num_experts_per_tok`` a token,
+    ``routed_scaling_factor``) and ``n_shared_experts`` shared ones, each
+    of width ``moe_intermediate_size``, every SwiGLU's products held to
+    ``swiglu_limit``. Of the routed experts the model HOLDS
+    ``n_routed_experts``, those from ``first_routed_expert`` on (one
+    chip's share under expert parallelism): a pair routed elsewhere adds
+    nothing here. The head is untied."""
 
     FIELDS = ("vocab_size", "d_model", "num_layers", "num_heads",
               "d_ff", "max_context")
@@ -156,6 +187,18 @@ class DecoderConfig:
             "moe_intermediate_size", "norm_topk_prob", "torch_dtype",
             "block_length", "mask_token_id", "denoising_steps",
             "remasking_strategy", "confidence_threshold"),
+        GIGACHAT3_5: (
+            "model_type", "full_attention_layers", "q_lora_rank",
+            "kv_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim",
+            "v_head_dim", "rope_theta", "rope_scaling", "rms_norm_eps",
+            "linear_num_key_heads", "linear_num_value_heads",
+            "linear_key_head_dim", "linear_value_head_dim",
+            "linear_conv_kernel_dim", "linear_sigmoid_gate_scale",
+            "layernorm_gating_weight", "first_k_dense_replace",
+            "router_experts", "first_routed_expert", "n_routed_experts",
+            "num_experts_per_tok", "n_shared_experts",
+            "moe_intermediate_size", "routed_scaling_factor",
+            "norm_topk_prob", "swiglu_limit", "torch_dtype"),
     }
     #: every block's own fields, each once
     BLOCK_FIELDS = tuple(dict.fromkeys(
@@ -177,7 +220,13 @@ class DecoderConfig:
                  tie_word_embeddings=True, head_dim=0, num_experts=0,
                  block_length=4, mask_token_id=0, denoising_steps=4,
                  remasking_strategy="sequential",
-                 confidence_threshold=0.9):
+                 confidence_threshold=0.9, full_attention_layers=(),
+                 q_lora_rank=0, rope_scaling=None, linear_num_key_heads=0,
+                 linear_num_value_heads=0, linear_key_head_dim=0,
+                 linear_value_head_dim=0, linear_conv_kernel_dim=4,
+                 linear_sigmoid_gate_scale=2.0, layernorm_gating_weight=2.0,
+                 router_experts=0, first_routed_expert=0,
+                 swiglu_limit=None):
         self.vocab_size = int(vocab_size)
         self.d_model = int(d_model)
         self.num_layers = int(num_layers)
@@ -229,6 +278,27 @@ class DecoderConfig:
         self.denoising_steps = int(denoising_steps)
         self.remasking_strategy = remasking_strategy
         self.confidence_threshold = float(confidence_threshold)
+        self.full_attention_layers = tuple(int(i)
+                                           for i in full_attention_layers)
+        self.q_lora_rank = int(q_lora_rank)
+        self.rope_scaling = None if rope_scaling is None \
+            else dict(rope_scaling)
+        self.linear_num_key_heads = int(linear_num_key_heads)
+        self.linear_num_value_heads = int(linear_num_value_heads)
+        self.linear_key_head_dim = int(linear_key_head_dim)
+        self.linear_value_head_dim = int(linear_value_head_dim)
+        self.linear_conv_kernel_dim = int(linear_conv_kernel_dim)
+        self.linear_sigmoid_gate_scale = float(linear_sigmoid_gate_scale)
+        self.layernorm_gating_weight = float(layernorm_gating_weight)
+        # the router's experts, of which n_routed_experts are held
+        self.router_experts = int(router_experts) or self.n_routed_experts
+        self.first_routed_expert = int(first_routed_expert)
+        self.swiglu_limit = None if swiglu_limit is None \
+            else float(swiglu_limit)
+        if model_type == GIGACHAT3_5:
+            self._check_gigachat()
+            self.head_dim = self.qk_nope_head_dim + self.qk_rope_head_dim
+            return
         if model_type == SDAR_MOE:
             self.head_dim = int(head_dim)
             self._check_sdar()
@@ -333,6 +403,56 @@ class DecoderConfig:
                 f"remasking_strategy {self.remasking_strategy!r} is none "
                 f"of {REMASKING}")
 
+    def _check_gigachat(self):
+        """The ``gigachat3_5`` keys against one another."""
+        for f in ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+                  "qk_rope_head_dim", "v_head_dim", "linear_num_key_heads",
+                  "linear_num_value_heads", "linear_key_head_dim",
+                  "linear_value_head_dim", "moe_intermediate_size"):
+            if getattr(self, f) < 1:
+                raise ValueError(f"{f} must be >= 1 for {GIGACHAT3_5}, "
+                                 f"got {getattr(self, f)}")
+        full = self.full_attention_layers
+        if not full or len(set(full)) != len(full) or not all(
+                0 <= i < self.num_layers for i in full):
+            raise ValueError(
+                f"full_attention_layers {list(full)} must name one or more "
+                f"distinct layers of {self.num_layers}")
+        if self.qk_rope_head_dim % 2:
+            raise ValueError("qk_rope_head_dim must be even, got "
+                             f"{self.qk_rope_head_dim}")
+        if self.linear_num_value_heads % self.linear_num_key_heads:
+            raise ValueError(
+                f"linear_num_value_heads {self.linear_num_value_heads} "
+                f"over linear_num_key_heads {self.linear_num_key_heads}: "
+                "the second must divide the first")
+        if self.linear_conv_kernel_dim < 2:
+            raise ValueError("linear_conv_kernel_dim must be >= 2, got "
+                             f"{self.linear_conv_kernel_dim}")
+        if not (1 <= self.num_experts_per_tok <= self.router_experts) \
+                or self.n_routed_experts < 1 or not (
+                    0 <= self.first_routed_expert <= self.router_experts
+                    - self.n_routed_experts):
+            raise ValueError(
+                f"n_routed_experts {self.n_routed_experts} held from "
+                f"first_routed_expert {self.first_routed_expert} of "
+                f"router_experts {self.router_experts}, "
+                f"num_experts_per_tok {self.num_experts_per_tok}")
+
+    @property
+    def linear_layers(self):
+        """The GatedDeltaNet layers of a ``gigachat3_5`` model, by
+        index."""
+        return tuple(i for i in range(self.num_layers)
+                     if i not in self.full_attention_layers)
+
+    @property
+    def linear_conv_dim(self):
+        """Channels a GatedDeltaNet layer's convolution runs over: its
+        queries, keys and values."""
+        return (2 * self.linear_num_key_heads * self.linear_key_head_dim
+                + self.linear_num_value_heads * self.linear_value_head_dim)
+
     @property
     def mamba_inner(self):
         """Channels of a state-space layer's inner stream (heads x head
@@ -354,8 +474,9 @@ class DecoderConfig:
     def to_dict(self):
         out = {f: getattr(self, f)
                for f in self.FIELDS + self.FIELDS_OF[self.model_type]}
-        if "layer_types" in out:
-            out["layer_types"] = list(self.layer_types)     # as JSON has it
+        for f in ("layer_types", "full_attention_layers"):
+            if f in out:
+                out[f] = list(out[f])                       # as JSON has it
         return out
 
     @classmethod
@@ -385,16 +506,19 @@ def _rms_norm(x, g, eps):
         * g.astype(jnp.float32)
 
 
-def _rope(x, positions, theta):
+def _rope(x, positions, theta, freqs=None):
     """Rotary positions on the last axis of ``x [..., T, (heads,) r]``
     (float32), ``positions`` shaped like ``x`` up to and including its
     token axis. Half-split pairing: element ``i`` turns with element
     ``i + r/2`` (the published checkpoints store the rotary part
     interleaved and the published code permutes it into this order;
-    with seeded weights the two are one fixed relabelling)."""
+    with seeded weights the two are one fixed relabelling). ``freqs``:
+    the ``r/2`` inverse frequencies, where they are not ``theta``'s
+    plain ones (YaRN's)."""
     import jax.numpy as jnp
     half = x.shape[-1] // 2
-    freqs = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    if freqs is None:
+        freqs = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
     ang = positions.astype(jnp.float32)[..., None] * freqs
     ang = ang.reshape(positions.shape
                       + (1,) * (x.ndim - positions.ndim - 1) + (half,))
@@ -402,6 +526,67 @@ def _rope(x, positions, theta):
     x1, x2 = x[..., :half], x[..., half:]
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
                            axis=-1)
+
+
+def _rope_interleaved(x, positions, freqs):
+    """Rotary positions on interleaved pairs (``rope_interleave``):
+    element ``2i`` turns with element ``2i + 1`` at ``freqs[i]``. The
+    pairs are gathered to the half-split order (evens, then odds) and
+    turned there, as the published code does; queries and keys are
+    gathered alike, so their products are those of the pairs."""
+    r = x.shape[-1]
+    split = x.reshape(x.shape[:-1] + (r // 2, 2)).swapaxes(-1, -2)
+    return _rope(split.reshape(x.shape), positions, None, freqs)
+
+
+def yarn_mscale(scale, mscale=1.0):
+    """YaRN's attention factor for a context stretched ``scale`` times:
+    ``0.1 mscale ln(scale) + 1`` (1 where nothing is stretched)."""
+    import math
+    return 0.1 * mscale * math.log(scale) + 1.0 if scale > 1 else 1.0
+
+
+def yarn_inv_freq(dim, base, scaling):
+    """YaRN's inverse frequencies for a rotary part of ``dim`` values
+    (DeepSeek-V3's reading, as numpy float32 ``[dim / 2]``): the plain
+    ones of ``base`` for the pairs that turn more than ``beta_fast``
+    times over ``original_max_position_embeddings``, those over
+    ``factor`` for the pairs that turn fewer than ``beta_slow`` times,
+    and a linear ramp between."""
+    import math
+    half = dim // 2
+    plain = 1.0 / base ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    orig = scaling["original_max_position_embeddings"]
+
+    def at(turns):
+        return dim * math.log(orig / (turns * 2 * math.pi)) \
+            / (2 * math.log(base))
+    low = max(math.floor(at(scaling["beta_fast"])), 0)
+    high = min(math.ceil(at(scaling["beta_slow"])), dim - 1)
+    ramp = np.clip((np.arange(half) - low) / max(high - low, 0.001), 0, 1)
+    keep = 1.0 - ramp
+    return (plain / scaling["factor"] * (1 - keep) + plain * keep).astype(
+        np.float32)
+
+
+def _zc_norm(x, g, eps, gate=None, gating_weight=2.0):
+    """Zero-centred RMSNorm in float32: the gain is ``1 + g``; with
+    ``gate``, each channel also times ``gating_weight x sigmoid(gate)``
+    (``ZeroCenteredGatedNorm``)."""
+    import jax
+    import jax.numpy as jnp
+    x = x.astype(jnp.float32)
+    out = x * jax.lax.rsqrt((x * x).mean(axis=-1, keepdims=True) + eps) \
+        * (1.0 + g.astype(jnp.float32))
+    if gate is None:
+        return out
+    return out * (gating_weight * jax.nn.sigmoid(gate.astype(jnp.float32)))
+
+
+def _l2_norm(x, eps=1e-6):
+    """Each row over its last axis to length 1 (float32)."""
+    import jax
+    return x * jax.lax.rsqrt((x * x).sum(axis=-1, keepdims=True) + eps)
 
 
 def _wmm(x, w):
@@ -413,9 +598,64 @@ def _wmm(x, w):
                       preferred_element_type=jnp.float32)
 
 
-def _swiglu(x, w_gate, w_up, w_down):
+def _swiglu(x, w_gate, w_up, w_down, limit=None):
+    """``(silu(x Wg) * (x Wu)) Wd``; with ``limit``, ``x Wg`` capped at
+    it and ``x Wu`` clipped to ``[-limit, limit]`` first."""
     import jax
-    return _wmm(jax.nn.silu(_wmm(x, w_gate)) * _wmm(x, w_up), w_down)
+    import jax.numpy as jnp
+    if limit is None:
+        return _wmm(jax.nn.silu(_wmm(x, w_gate)) * _wmm(x, w_up), w_down)
+    g = jnp.minimum(_wmm(x, w_gate), limit)
+    return _wmm(jax.nn.silu(g) * jnp.clip(_wmm(x, w_up), -limit, limit),
+                w_down)
+
+
+def _conv_windows(seq_ids, valid, positions, slots, K):
+    """Where a causal convolution ``K`` wide over the packed step finds
+    each token's ``K - 1`` predecessors, for every layer alike: ``(taps,
+    keeps)``, row indices into ``[the step's inputs [T] | every slot's
+    window [S x (K - 1)] | one row of zeros]``. ``taps`` (oldest first)
+    gives each token's predecessors from its run or, for the run's first
+    tokens, from its slot's window (zeros for a run that starts at
+    position 0); ``keeps [S, K - 1]`` is what each slot's window holds
+    after the step: the last ``K - 1`` inputs of window and run together
+    (a run shorter than the window shifts it through)."""
+    import jax.numpy as jnp
+    T, S = seq_ids.shape[0], slots
+    start, length = slot_runs(seq_ids, valid, S)
+    fresh = jnp.logical_and(length > 0, positions[start] == 0)
+    t = jnp.arange(T, dtype=jnp.int32)
+    place = t - start[seq_ids]                  # in its slot's run
+    zero_row = T + S * (K - 1)
+    taps = []                                   # oldest first
+    for back in range(K - 1, 0, -1):
+        held = T + seq_ids * (K - 1) + (K - 1 - back) + place
+        taps.append(jnp.clip(jnp.where(
+            place >= back, t - back,
+            jnp.where(fresh[seq_ids], zero_row, held)), 0, zero_row))
+    j = length[:, None] + jnp.arange(K - 1, dtype=jnp.int32)[None]
+    s_row = jnp.arange(S, dtype=jnp.int32)[:, None]
+    keeps = jnp.where(
+        j >= K - 1, start[:, None] + j - (K - 1),
+        jnp.where(fresh[:, None], zero_row,
+                  T + s_row * (K - 1) + j))
+    return taps, keeps
+
+
+def _causal_conv(x, window, conv_w, taps, keeps):
+    """A depthwise causal convolution of one layer over the packed step:
+    ``x [T, C]`` the step's inputs, ``window [S, K - 1, C]`` every slot's
+    last inputs, ``conv_w [C, K]``; ``(the sums [T, C] float32, every
+    slot's window after the step)`` by :func:`_conv_windows`'s rows."""
+    import jax.numpy as jnp
+    K = conv_w.shape[1]
+    rows = jnp.concatenate(
+        [x, window.reshape(-1, x.shape[1]),
+         jnp.zeros((1, x.shape[1]), jnp.float32)])
+    w = conv_w.astype(jnp.float32)
+    conved = w[:, K - 1] * x + sum(
+        w[:, i] * rows[tap] for i, tap in enumerate(taps))
+    return conved, rows[keeps]
 
 
 class TinyDecoder:
@@ -425,10 +665,11 @@ class TinyDecoder:
     #: (``_forward_<name>``, ``_decode_flat_<name>``, ...), and
     #: ``_TAKES`` is what its step takes beside the packed batch
     _BLOCK = {GPT2: "gpt2", DEEPSEEK_V3: "latent", GRANITE_HYBRID: "hybrid",
-              SDAR_MOE: "blockdiff"}
+              SDAR_MOE: "blockdiff", GIGACHAT3_5: "gdn"}
     _TAKES = {GPT2: ("k_scales", "v_scales", "adapter", "axis_name",
                      "w_scales"),
-              DEEPSEEK_V3: (), GRANITE_HYBRID: ("state",), SDAR_MOE: ()}
+              DEEPSEEK_V3: (), GRANITE_HYBRID: ("state",), SDAR_MOE: (),
+              GIGACHAT3_5: ("state",)}
     #: what each of those keywords stands for, when a block refuses it
     _STANDS_FOR = {"k_scales": "quantized pages",
                    "v_scales": "quantized pages",
@@ -446,7 +687,9 @@ class TinyDecoder:
                                  "prefix_cache"),
                 SDAR_MOE: ("adapter_bank", "draft_model", "mesh",
                            "weight_dtype", "kv_dtype", "prefix_cache",
-                           "sampling")}
+                           "sampling"),
+                GIGACHAT3_5: ("adapter_bank", "draft_model", "mesh",
+                              "weight_dtype", "kv_dtype", "prefix_cache")}
 
     def __init__(self, config=None, **kw):
         self.config = config if config is not None else DecoderConfig(**kw)
@@ -490,6 +733,7 @@ class TinyDecoder:
         c = self.config
         if c.model_type == SDAR_MOE:
             return c.num_experts * c.num_layers
+        # (held experts: a gigachat3_5 model holds a share of its router's)
         return c.n_routed_experts * max(
             0, c.num_layers - c.first_k_dense_replace)
 
@@ -529,6 +773,17 @@ class TinyDecoder:
             return {"kind": "kv_packed",
                     "num_heads": c.num_key_value_heads,
                     "head_dim": c.head_dim}
+        if c.model_type == GIGACHAT3_5:
+            linear = len(c.linear_layers)
+            layout = {"kind": "latent", "latent_dim": c.latent_dim,
+                      "layers": len(c.full_attention_layers)}
+            if linear:
+                layout["state"] = {
+                    "gdn": (linear, c.linear_num_value_heads,
+                            c.linear_key_head_dim, c.linear_value_head_dim),
+                    "conv": (linear, c.linear_conv_kernel_dim - 1,
+                             c.linear_conv_dim)}
+            return layout
         if c.model_type == GRANITE_HYBRID:
             mamba = c.layer_types.count(MAMBA)
             layout = {"kind": "kv_packed",
@@ -567,6 +822,72 @@ class TinyDecoder:
             return (rs.randn(*shape) * scale).astype(np.float32)
 
         return self._of_block("init_params")(w)
+
+    def _init_params_gdn(self, w):
+        """The ``gigachat3_5`` tree: the leaves of
+        ``benchmark/reference/gigachat35_decoder.py``, and its rule of
+        scale (``WEIGHT_RULE`` there says why each): every matrix by its
+        fan-in (the router and the experts too: at larger scales the
+        routing swaps experts at near ties under bfloat16 so often that
+        what a sound run serves moves as far as a fault does), the
+        embedding 1; the norms' gains (their distance from 1) normal at
+        0.1 and their gates at 0.5; the router's bias normal at 0.01; of
+        a GatedDeltaNet layer, ``A_log`` the logarithms of 1/16 to 1 and
+        ``dt_bias`` the inverse softplus of 0.001 to 0.1 (geometric)."""
+        c = self.config
+        d, H, qr, rank = c.d_model, c.num_heads, c.q_lora_rank, \
+            c.kv_lora_rank
+        nope, rope, vd = (c.qk_nope_head_dim, c.qk_rope_head_dim,
+                          c.v_head_dim)
+        hk, hv = c.linear_num_key_heads, c.linear_num_value_heads
+        dk, dvl = c.linear_key_head_dim, c.linear_value_head_dim
+        K, C = c.linear_conv_kernel_dim, c.linear_conv_dim
+        E, m = c.n_routed_experts, c.moe_intermediate_size
+        sh = c.n_shared_experts * m
+        steps = np.exp(np.linspace(np.log(1e-3), np.log(1e-1), hv))
+
+        def gains(*names, n=d):
+            out = {}
+            for name in names:
+                out[name] = w(n, scale=0.1)
+                out[name + "_gate"] = w(n, scale=0.5)
+            return out
+        layers = []
+        for li in range(c.num_layers):
+            lp = gains("norm1", "norm2", "norm3", "norm4")
+            if li in c.full_attention_layers:
+                lp.update(wq_a=w(d, qr), norm_q=w(qr, scale=0.1),
+                          wq_b=w(qr, H * (nope + rope)),
+                          wkv_a=w(d, rank + rope),
+                          norm_kv=w(rank, scale=0.1),
+                          wkv_b=w(rank, H * (nope + vd)),
+                          w_gate_attn=w(d, H * vd), wo=w(H * vd, d))
+            else:
+                lp.update(
+                    wq=w(d, hk * dk), wk=w(d, hk * dk), wv=w(d, hv * dvl),
+                    wz=w(d, hv * dvl), wa=w(d, hv), wb=w(d, hv),
+                    conv_w=w(C, K, scale=K ** -0.5),
+                    A_log=np.log(np.linspace(1.0 / 16, 1.0, hv)).astype(
+                        np.float32),
+                    dt_bias=np.log(np.expm1(steps)).astype(np.float32),
+                    norm_o=w(dvl, scale=0.1), wo=w(hv * dvl, d))
+            if li < c.first_k_dense_replace:
+                lp.update(w_gate=w(d, c.d_ff), w_up=w(d, c.d_ff),
+                          w_down=w(c.d_ff, d))
+            else:
+                lp.update(
+                    router=w(d, c.router_experts, scale=d ** -0.5),
+                    router_bias=w(c.router_experts, scale=0.01),
+                    experts_gate=w(E, d, m, scale=d ** -0.5),
+                    experts_up=w(E, d, m, scale=d ** -0.5),
+                    experts_down=w(E, m, d, scale=m ** -0.5),
+                    shared_gate=w(d, sh), shared_up=w(d, sh),
+                    shared_down=w(sh, d))
+            layers.append(lp)
+        return self._published_dtype(
+            {"embed": w(c.vocab_size, d, scale=1.0),
+             **gains("norm_f"), "head": w(d, c.vocab_size),
+             "layers": layers})
 
     def _published_dtype(self, params):
         """``params`` (host float32) in the configuration's
@@ -841,9 +1162,10 @@ class TinyDecoder:
         All of the above is the GPT-2 block's. The other blocks take
         none of those keywords (each is refused by what it stands for)
         and ONE pool of pages, ``k_pages``, with ``v_pages`` ``None``:
-        :meth:`_decode_flat_latent`, and :meth:`_decode_flat_hybrid`,
-        which takes ``state``, the pools of its sequences' slot states,
-        and returns them after the pages.
+        :meth:`_decode_flat_latent`, :meth:`_decode_flat_blockdiff`, and
+        :meth:`_decode_flat_hybrid` and :meth:`_decode_flat_gdn`, which
+        take ``state``, the pools of their sequences' slot states, and
+        return them after the pages.
         """
         given = {k: v for k, v in (
             ("k_scales", k_scales), ("v_scales", v_scales),
@@ -1333,26 +1655,8 @@ class TinyDecoder:
             vmask, block_tables[seq_ids, positions // bs], 0)  # null
         slot = jnp.where(vmask, positions % bs, 0)
         # where the convolution finds each token's predecessors, once
-        # for all layers: rows of [the step's inputs | every slot's
-        # window | one row of zeros]
-        start, length = slot_runs(seq_ids, valid, S)
-        fresh = jnp.logical_and(length > 0, positions[start] == 0)
-        t = jnp.arange(T, dtype=jnp.int32)
-        place = t - start[seq_ids]                  # in its slot's run
-        zero_row = T + S * (K - 1)
-        taps = []                                   # oldest first
-        for back in range(K - 1, 0, -1):
-            held = T + seq_ids * (K - 1) + (K - 1 - back) + place
-            taps.append(jnp.clip(jnp.where(
-                place >= back, t - back,
-                jnp.where(fresh[seq_ids], zero_row, held)), 0, zero_row))
-        # and what each slot's window holds after the step
-        j = length[:, None] + jnp.arange(K - 1, dtype=jnp.int32)[None]
-        s_row = jnp.arange(S, dtype=jnp.int32)[:, None]
-        keeps = jnp.where(
-            j >= K - 1, start[:, None] + j - (K - 1),
-            jnp.where(fresh[:, None], zero_row,
-                      T + s_row * (K - 1) + j))
+        # for all layers
+        taps, keeps = _conv_windows(seq_ids, valid, positions, S, K)
         h = params["embed"][tokens].astype(jnp.float32) \
             * c.embedding_multiplier
         att_layer = ssm_layer = 0
@@ -1371,13 +1675,9 @@ class TinyDecoder:
                 att_layer += 1
             else:
                 z, xbc, dt_raw = self._mamba_inputs(lp, x)
-                rows = jnp.concatenate(
-                    [xbc, conv[ssm_layer].reshape(S * (K - 1), -1),
-                     jnp.zeros((1, xbc.shape[1]), jnp.float32)])
-                w = lp["conv_w"].astype(jnp.float32)
-                conved = w[:, K - 1] * xbc + sum(
-                    w[:, i] * rows[tap] for i, tap in enumerate(taps))
-                conv = conv.at[ssm_layer].set(rows[keeps])
+                conved, window = _causal_conv(xbc, conv[ssm_layer],
+                                              lp["conv_w"], taps, keeps)
+                conv = conv.at[ssm_layer].set(window)
                 xs, dt, A, Bm, Cm = self._mamba_scan_inputs(
                     lp, conved, dt_raw)
                 y, ssm = ssd_state_step(xs, dt, A, Bm, Cm, ssm, seq_ids,
@@ -1533,6 +1833,206 @@ class TinyDecoder:
             load = moe_load_sum(load, hit)
         logits = _wmm(_rms_norm(h, params["norm_f"], eps), params["head"])
         return logits, pages, load
+
+    # ------------------------------------- the gigachat3_5 block --
+    def _norm_gdn(self, lp, name, x):
+        """``x`` through the layer's zero-centred gated norm ``name``."""
+        c = self.config
+        return _zc_norm(x, lp[name], c.rms_norm_eps, lp[name + "_gate"],
+                        c.layernorm_gating_weight)
+
+    def _rope_gdn(self):
+        """``(inverse frequencies [rope / 2] float32, softmax scale)`` of
+        the latent layers: YaRN's where ``rope_scaling`` names it (its
+        ``mscale_all_dim`` factor squared on the scale; the published
+        ``mscale`` equal to it, cos and sin are not rescaled), else the
+        plain ones of ``rope_theta``."""
+        c = self.config
+        r = c.qk_rope_head_dim
+        scale = (c.qk_nope_head_dim + r) ** -0.5
+        sc = c.rope_scaling
+        if not sc:
+            return (c.rope_theta ** (-np.arange(r // 2, dtype=np.float64)
+                                     / (r // 2))).astype(np.float32), scale
+        m = yarn_mscale(sc["factor"], sc.get("mscale_all_dim", 1.0))
+        return yarn_inv_freq(r, c.rope_theta, sc), scale * m * m
+
+    def _mlp_gdn(self, lp, x, valid):
+        """The MLP sublayer for normed rows ``x [T, d]``: ``(its output
+        [T, d] float32, the routing's load int32 [5] or None)``. A dense
+        layer is one SwiGLU; an expert layer routes each row over all
+        ``router_experts`` and computes the held experts' pairs alone
+        (``ops.moe.moe_experts`` with ``first=``), then the shared
+        experts; every SwiGLU held to ``swiglu_limit``."""
+        c = self.config
+        lim = c.swiglu_limit
+        if "w_gate" in lp:
+            return _swiglu(x, lp["w_gate"], lp["w_up"], lp["w_down"],
+                           lim), None
+        sel, gates = route_noaux_tc(
+            x, lp["router"], lp["router_bias"], c.num_experts_per_tok,
+            c.norm_topk_prob, c.routed_scaling_factor)
+        wg = lp["experts_gate"]
+        out = moe_experts(x.astype(wg.dtype), sel, gates, valid, wg,
+                          lp["experts_up"], lp["experts_down"],
+                          first=c.first_routed_expert, limit=lim)
+        out = out + _swiglu(x, lp["shared_gate"], lp["shared_up"],
+                            lp["shared_down"], lim)
+        return out, moe_load(sel, valid, c.n_routed_experts,
+                             first=c.first_routed_expert)
+
+    def _gdn_inputs(self, lp, x):
+        """A GatedDeltaNet layer's projections of normed rows ``x [T,
+        d]``: ``(q, k and v before the convolution [T, conv_dim], z [T,
+        Hv x Dv], the decay's log g [T, Hv], beta [T, Hv])``."""
+        import jax
+        import jax.numpy as jnp
+        f32 = jnp.float32
+        qkv = jnp.concatenate([_wmm(x, lp[n]) for n in ("wq", "wk", "wv")],
+                              axis=-1)
+        g = -jnp.exp(lp["A_log"].astype(f32)) * jax.nn.softplus(
+            _wmm(x, lp["wa"]) + lp["dt_bias"].astype(f32))
+        return qkv, _wmm(x, lp["wz"]), g, jax.nn.sigmoid(_wmm(x, lp["wb"]))
+
+    def _gdn_heads(self, conved):
+        """``(q, k [T, Hk, Dk], v [T, Hv, Dv])`` of the recurrence from
+        the convolution's sums: SiLU, then ``q`` and ``k`` L2-normed per
+        head and ``q`` times ``Dk ** -0.5``."""
+        import jax
+        c = self.config
+        T = conved.shape[0]
+        hk, dk = c.linear_num_key_heads, c.linear_key_head_dim
+        act = jax.nn.silu(conved)
+        q = act[:, :hk * dk].reshape(T, hk, dk)
+        k = act[:, hk * dk:2 * hk * dk].reshape(T, hk, dk)
+        v = act[:, 2 * hk * dk:].reshape(T, c.linear_num_value_heads,
+                                          c.linear_value_head_dim)
+        return _l2_norm(q) * dk ** -0.5, _l2_norm(k), v
+
+    def _gdn_output(self, lp, o, z):
+        """A GatedDeltaNet layer's way out: each head's output normed
+        over its values, times ``linear_sigmoid_gate_scale x
+        sigmoid(z)``, then the output projection."""
+        import jax
+        c = self.config
+        T = o.shape[0]
+        gate = c.linear_sigmoid_gate_scale * jax.nn.sigmoid(
+            z.reshape(o.shape))
+        y = _zc_norm(o, lp["norm_o"], c.rms_norm_eps) * gate
+        return _wmm(y.reshape(T, -1), lp["wo"])
+
+    def _forward_gdn(self, params, tokens):
+        """:meth:`forward` is not written for the ``gigachat3_5`` block:
+        its oracle is ``benchmark/reference/gigachat35_decoder.py``."""
+        self._refuse("the dense oracle: compare with the benchmark's "
+                     "reference decoder")
+
+    def _decode_flat_gdn(self, params, tokens, positions, seq_ids, valid,
+                         pages, block_tables, state):
+        """:meth:`decode_flat` for the ``gigachat3_5`` block, the packed
+        batch and page arithmetic the same.
+
+        ``pages`` is the ONE pool of latents of the full-attention layers
+        alone, ``[La, N, bs, Wp]``: attention layer ``a`` (in the order
+        the layers come) writes each token's ``(norm(c_kv), rope(k_rope))``
+        and attends in the absorbed form through
+        :func:`~mxnet_tpu.ops.ragged_attention.mla_flat_attention` with
+        ``layer=a``, as the ``deepseek_v3`` block does, its queries from
+        the normed query latent, its rotary pairs interleaved under YaRN,
+        the heads' outputs gated by ``sigmoid(x W_gate_attn)`` before
+        ``wo``.
+
+        ``state`` is ``(gdn [Ll, S, Hv, Dk, Dv], conv [Ll, S, K - 1,
+        conv_dim])`` float32, indexed by GatedDeltaNet layer and by
+        SLOT, as the ``granitemoehybrid`` block's: the convolution
+        over ``[q | k | v]`` takes each token's predecessors from its run
+        or its slot's window (:func:`_conv_windows`), the delta rule is
+        :func:`~mxnet_tpu.ops.gdn.gdn_state_step` on the WHOLE pool with
+        ``layer=``, and a run that starts at position 0 reads a window
+        and a state of zeros.
+
+        Every sublayer is normed before and after; the MLP sublayer is
+        dense or routed (:meth:`_mlp_gdn`). Weights are read in their own
+        dtype with float32 accumulation; norms, softmax, rotary
+        positions, the recurrence, router scores, gates and the residual
+        stream are float32. Returns ``(logits [T, V] float32, pages,
+        gdn, conv)`` and, from a model with expert layers, ``load`` int32
+        ``[5]`` after them: the ``deepseek_v3`` block's four over the held
+        experts, then the pairs routed to experts held elsewhere, summed
+        over the expert layers."""
+        import jax
+        import jax.numpy as jnp
+        c = self.config
+        gdn, conv = state
+        T, S = tokens.shape[0], block_tables.shape[0]
+        bs, wp = pages.shape[2], pages.shape[3]
+        H, rank = c.num_heads, c.kv_lora_rank
+        nope, rope, vd = (c.qk_nope_head_dim, c.qk_rope_head_dim,
+                          c.v_head_dim)
+        eps = c.rms_norm_eps
+        freqs, scale = self._rope_gdn()
+        freqs = jnp.asarray(freqs)
+        vmask = valid.astype(bool)
+        bidx = jnp.where(
+            vmask, block_tables[seq_ids, positions // bs], 0)  # null
+        slot = jnp.where(vmask, positions % bs, 0)
+        pad = wp - rank - rope
+        taps, keeps = _conv_windows(seq_ids, valid, positions, S,
+                                    c.linear_conv_kernel_dim)
+        load = jnp.zeros((5,), jnp.int32)
+        h = params["embed"][tokens].astype(jnp.float32)
+        att_layer = lin_layer = 0
+        for lp in params["layers"]:
+            x = self._norm_gdn(lp, "norm1", h)
+            if "wq_a" in lp:
+                q = _wmm(_zc_norm(_wmm(x, lp["wq_a"]), lp["norm_q"], eps),
+                         lp["wq_b"]).reshape(T, H, nope + rope)
+                a = _wmm(x, lp["wkv_a"])
+                latent = jnp.concatenate(
+                    [_zc_norm(a[:, :rank], lp["norm_kv"], eps),
+                     _rope_interleaved(a[:, rank:], positions, freqs),
+                     jnp.zeros((T, pad), jnp.float32)], axis=-1)
+                pages = pages.at[att_layer, bidx, slot].set(
+                    latent.astype(pages.dtype))
+                wkv_b = lp["wkv_b"].reshape(rank, H, nope + vd)
+                q_lat = jnp.einsum(
+                    "thn,rhn->thr", q[..., :nope].astype(wkv_b.dtype),
+                    wkv_b[..., :nope], preferred_element_type=jnp.float32)
+                qq = jnp.concatenate(
+                    [q_lat, _rope_interleaved(q[..., nope:], positions,
+                                              freqs),
+                     jnp.zeros((T, H, pad), jnp.float32)], axis=-1)
+                o_lat = mla_flat_attention(qq, pages, block_tables,
+                                           seq_ids, positions, scale, rank,
+                                           layer=att_layer)
+                att = jnp.einsum(
+                    "thr,rhv->thv", o_lat.astype(wkv_b.dtype),
+                    wkv_b[..., nope:], preferred_element_type=jnp.float32)
+                att = att.reshape(T, H * vd) * jax.nn.sigmoid(
+                    _wmm(x, lp["w_gate_attn"]))
+                mix = _wmm(att, lp["wo"])
+                att_layer += 1
+            else:
+                qkv, z, g, beta = self._gdn_inputs(lp, x)
+                conved, window = _causal_conv(qkv, conv[lin_layer],
+                                              lp["conv_w"], taps, keeps)
+                conv = conv.at[lin_layer].set(window)
+                q, k, v = self._gdn_heads(conved)
+                o, gdn = gdn_state_step(q, k, v, g, beta, gdn, seq_ids,
+                                        positions, valid, lin_layer)
+                mix = self._gdn_output(lp, o, z)
+                lin_layer += 1
+            h = h + self._norm_gdn(lp, "norm2", mix)
+            add, hit = self._mlp_gdn(lp, self._norm_gdn(lp, "norm3", h),
+                                     vmask)
+            h = h + self._norm_gdn(lp, "norm4", add)
+            if hit is not None:
+                load = moe_load_sum(load, hit)
+        logits = _wmm(_zc_norm(h, params["norm_f"], eps,
+                               params["norm_f_gate"],
+                               c.layernorm_gating_weight), params["head"])
+        return (logits, pages, gdn, conv, load) if self.expert_slots \
+            else (logits, pages, gdn, conv)
 
 
 def _block_last(positions, block_length):
