@@ -42,11 +42,11 @@ The execution core of ``mxnet_tpu.serving.llm``. One engine iteration
    IN-PROGRAM on host-indexed per-row logit windows; committed
    tokens come out, KV pages are donated through. The packed length
    and the block-table width are bucketed on small warmed ladders
-   (pure-decode, common mixed, full) — so after :meth:`warmup`
-   (every (t, mb, greedy|sampled) rung, plus the draft program's
-   when speculation is on) steady state compiles NOTHING, no matter
-   how the arrival/length/stop/sampling mix shifts (asserted via the
-   ``backend_compile`` counter in tier-1).
+   (pure-decode, halvings of the budget, full) — so after
+   :meth:`warmup` (every (t, mb, greedy|sampled) rung, plus the draft
+   program's when speculation is on) steady state compiles NOTHING,
+   no matter how the arrival/length/stop/sampling mix shifts
+   (asserted via the ``backend_compile`` counter in tier-1).
 
 Speculative decoding: a small DRAFT model proposes up to ``spec_k``
 tokens per sequence (one fixed-shape draft dispatch each, its KV pages
@@ -670,20 +670,24 @@ class LLMEngine:
         # the FLAT step packs every row's query tokens into one
         # [total_q_tokens] batch — no per-sequence padding, so a
         # mixed step computes exactly the tokens that exist. The
-        # packed length is bucketed on a three-rung ladder (all-rows
-        # decode/verify, half batch, full batch), and the BLOCK-TABLE
-        # width on a two-rung ladder (a dispatch whose longest row
-        # holds half the table attends over half the pages). Every
+        # packed length is bucketed on a ladder of at most four rungs
+        # (all rows decode/verify, a half and a quarter of the
+        # budget, the full budget), and the BLOCK-TABLE width on a
+        # two-rung ladder (a dispatch whose longest row holds half
+        # the table attends over half the pages). Every
         # (t, mb, variant) rung is warmed, so selection is
         # recompile-free.
         t_lo = self.max_seqs * row_tokens
         t_hi = max(t_lo, self.max_seqs * self.q_tokens)
-        # the middle rungs are the EXACT packed lengths of the
-        # commonest mixed steps — one or two rows mid-prefill while
-        # the rest decode/verify — so those steps dispatch pad-free
-        mids = {min(t_hi, i * self.q_tokens
-                    + (self.max_seqs - i) * row_tokens)
-                for i in (1, 2) if i <= self.max_seqs}
+        # the middle rungs are the budget's half and quarter (rounded
+        # up), each where it lies above the all-decode rung: a prompt
+        # goes into a step whole, so a step that admits one beside
+        # the decoding rows needs any length up to the budget, and
+        # one longer than a quarter of it then computes under twice
+        # its tokens. At most S - 1 of them, so an engine of S slots
+        # warms at most S + 1 rungs: one slot never mixes rows
+        halvings = (-(-t_hi // 2), -(-t_hi // 4))[:self.max_seqs - 1]
+        mids = {m for m in halvings if m > t_lo}
         self._t_buckets = sorted({t_lo, t_hi} | mids)
         # what one step may feed: the top rung, dealt out by _schedule
         # (the draft program's top rung is the same length)

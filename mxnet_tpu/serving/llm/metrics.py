@@ -220,6 +220,11 @@ class LLMStats:
             "Packed length of the step programs dispatched (the "
             "rung each step took): what mxtpu_llm_step_tokens_total "
             "is a share of.", lbl).labels(**s)
+        self._steps_by_rung = r.counter(
+            "mxtpu_llm_steps_by_rung_total",
+            "Step programs dispatched at each packed length (the rung "
+            "each step took).", ("server", "rung"))
+        self._rung_children = {}    # rung -> child, engine thread
         self._spec_proposed = r.counter(
             "mxtpu_llm_spec_proposed_total",
             "Draft tokens proposed for speculative verification.",
@@ -542,6 +547,11 @@ class LLMStats:
         for ``rung``."""
         self._step_tokens.inc(tokens)
         self._step_rung_tokens.inc(rung)
+        child = self._rung_children.get(rung)
+        if child is None:
+            child = self._rung_children[rung] = self._steps_by_rung.labels(
+                server=self._server, rung=rung)
+        child.inc()
 
     def record_step_phases(self, ns, longest_ns, stalls, dispatched):
         """One hand-over of the serving thread's clock
@@ -727,6 +737,8 @@ class LLMStats:
                     self._prefill_chunk_tokens.value),
                 "step_tokens": int(self._step_tokens.value),
                 "step_rung_tokens": int(self._step_rung_tokens.value),
+                "steps_by_rung": {t: int(c.value) for t, c in sorted(
+                    self._rung_children.items())},
                 "steps_dispatched": int(self._steps_dispatched.value),
                 "steps_overlapped": int(self._steps_overlapped.value),
                 "overlap_discarded_rows": int(

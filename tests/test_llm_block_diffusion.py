@@ -469,9 +469,9 @@ def test_counters_and_step_span_attributes(model, params):
 def test_ladder_and_warmup_follow_the_block(model, params):
     engine = LLMEngine(model, params, max_seqs=4, block_size=8,
                        prefill_chunk=16)
-    # four rows of a block; one or two prompts' shares beside the others'
-    # blocks; the whole budget
-    assert engine._t_buckets == [16, 28, 40, 64]
+    # four rows of a block; half the budget (its quarter is the four
+    # rows' blocks); the whole budget
+    assert engine._t_buckets == [16, 32, 64]
     assert engine.q_tokens == 16 and engine.spec_k == 0
     timings = engine.warmup()
     # the greedy variant alone: sampling is refused

@@ -207,7 +207,7 @@ def test_long_chunks_through_both_kernels_beside_decoding_rows(monkeypatch):
     params = ref.init_params(5, toy, dtype=jnp.float32)
     engine = LLMEngine(model, params, max_seqs=2, block_size=8,
                        prefill_chunk=40, num_blocks=26)
-    assert engine._t_buckets == [2, 41, 80]
+    assert engine._t_buckets == [2, 40, 80]
     rs = np.random.RandomState(7)
     cases = [(rs.randint(0, 97, 20).tolist(), 60),
              (rs.randint(0, 97, 140).tolist(), 30)]

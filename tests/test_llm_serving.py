@@ -230,7 +230,7 @@ def test_long_chunks_through_the_kernel_beside_decoding_rows(monkeypatch):
     params = model.init_params(seed=0)
     eng = LLMEngine(model, params, max_seqs=2, block_size=BS,
                     max_context=192, prefill_chunk=40, num_blocks=26)
-    assert eng._t_buckets == [2, 41, 80]
+    assert eng._t_buckets == [2, 40, 80]
     rng = np.random.RandomState(7)
     cases = [(rng.randint(0, VOCAB, size=20).tolist(), 60),
              (rng.randint(0, VOCAB, size=140).tolist(), 30)]

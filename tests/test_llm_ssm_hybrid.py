@@ -299,7 +299,7 @@ def test_long_chunks_through_both_kernels_beside_decoding_rows(monkeypatch):
     params = ref.init_params(11, toy, dtype=jnp.float32)
     engine = LLMEngine(model, params, max_seqs=2, block_size=8,
                        prefill_chunk=40, num_blocks=26)
-    assert engine._t_buckets == [2, 41, 80]
+    assert engine._t_buckets == [2, 40, 80]
     rs = np.random.RandomState(7)
     cases = [(_prompt(rs, 20), 60), (_prompt(rs, 140), 30)]
     seqs = [Sequence(p, n) for p, n in cases]

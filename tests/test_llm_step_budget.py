@@ -13,7 +13,11 @@ one warmed engine for the module.
 - with speculation on, the draft is fed the chunk the target takes;
 - the counters ``mxtpu_llm_step_tokens_total`` and
   ``mxtpu_llm_step_rung_tokens_total``, and the benchmark's reader of the
-  same share from the ``mxtpu.llm.build`` spans.
+  same share from the ``mxtpu.llm.build`` spans;
+- the packed-length ladder: all rows decoding, the budget's half and
+  quarter where they lie above that, the budget; a step that admits a
+  prompt beside the decoding rows takes the least of them that holds it
+  (``mxtpu_llm_steps_by_rung_total``).
 """
 import os
 import sys
@@ -99,10 +103,99 @@ def _drain(engine, limit=2000):
 
 def test_the_budget_is_the_top_rung_and_no_program_is_new(warmed):
     engine = warmed[0]
-    assert engine._t_buckets == [8, 23, 38, 128]
+    assert engine._t_buckets == [8, 32, 64, 128]
     assert engine._step_budget == max(engine._t_buckets) == 128
     assert engine._draft_t_buckets[-1] == engine._step_budget
     assert engine.debug_status()["programs"]["prefill_chunk"] == 16
+
+
+def _block_model():
+    """A block-diffusion model: a generating row feeds a block of 4."""
+    return TinyDecoder(DecoderConfig(
+        vocab_size=61, d_model=16, num_layers=1, num_heads=2, d_ff=32,
+        max_context=128, model_type="sdar_moe", num_key_value_heads=1,
+        head_dim=8, rope_theta=1e6, rms_norm_eps=1e-6, num_experts=4,
+        num_experts_per_tok=2, moe_intermediate_size=16,
+        norm_topk_prob=True, torch_dtype="float32", block_length=4,
+        mask_token_id=60, denoising_steps=4,
+        remasking_strategy="sequential", confidence_threshold=0.9))
+
+
+def _exact_mix_ladder(slots, row, q):
+    """A ladder whose middle rungs are the exact lengths of a step with
+    one or two rows taking ``q`` tokens beside rows feeding ``row``:
+    the most programs a ladder of this engine may warm."""
+    lo, hi = slots * row, slots * q
+    return {lo, hi} | {min(hi, i * q + (slots - i) * row)
+                       for i in (1, 2) if i <= slots}
+
+
+@pytest.mark.parametrize("slots,chunk,block,ladder", [
+    (8, 16, False, [8, 32, 64, 128]),
+    (32, 16, False, [32, 128, 256, 512]),
+    (32, 16, True, [128, 256, 512]),
+    (128, 16, False, [128, 512, 1024, 2048]),
+    (2, 40, False, [2, 40, 80]),
+], ids=["8x16", "32x16", "32-rows-of-block-4", "128x16", "2x40"])
+def test_the_ladder_is_decode_halvings_of_the_budget_and_the_budget(
+        model, params, slots, chunk, block, ladder):
+    if block:
+        model = _block_model()
+        params = model.init_params(seed=0)
+    engine = LLMEngine(model, params, max_seqs=slots, block_size=BS,
+                       max_context=128, prefill_chunk=chunk,
+                       prefix_cache=False)
+    row = 4 if block else 1
+    lo, hi = slots * row, slots * chunk
+    assert engine._t_buckets == ladder
+    # the all-decode rung, then the halvings above it, then the budget
+    assert ladder[0] == lo and ladder[-1] == hi
+    assert set(ladder[1:-1]) <= {-(-hi // 2), -(-hi // 4)}
+    assert all(m > lo for m in ladder[1:-1])
+    # the top rung is the step's budget, a slot's share of it the chunk
+    assert engine._step_budget == hi == slots * engine.q_tokens
+    assert len(ladder) <= len(_exact_mix_ladder(slots, row, chunk))
+
+
+def test_an_admitted_prompt_beside_decoding_rows_takes_a_quarter_budget(
+        model, params):
+    """16 slots (a budget of 256): with 15 rows decoding, a prompt of 20
+    tokens goes in whole and the step of 35 tokens takes the 64 rung,
+    the budget's quarter, not the budget; nothing compiles after
+    ``warmup()``, and the counter of steps by rung counts that step."""
+    stats = LLMStats("step_budget_rungs_t")
+    engine = LLMEngine(model, params, max_seqs=16, block_size=BS,
+                       max_context=CTX, prefix_cache=False, stats=stats)
+    assert engine._t_buckets == [16, 64, 128, 256]
+    engine.warmup()
+    log = DispatchLog(engine)
+    rng = np.random.RandomState(5)
+    with serving.CompileCounter() as cc:
+        decoding = [Sequence(_prompt(rng, 4), 12) for _ in range(15)]
+        for s in decoding:
+            engine.add(s)
+        engine.step()           # the 15 prompts, 60 tokens
+        engine.step()           # 15 rows decoding
+        assert all(s.generated for s in decoding)
+        before = stats.snapshot()["steps_by_rung"]
+        prompt = _prompt(rng, 20)
+        admitted = Sequence(prompt, 4)
+        engine.add(admitted)
+        engine.step()
+        _, rows = log.steps[-1]
+        assert rows[admitted] == 20 and sum(rows.values()) == 35
+        assert engine._step_rung == 64 == engine._step_budget // 4
+        after = stats.snapshot()["steps_by_rung"]
+        assert after[64] == before.get(64, 0) + 1
+        assert sum(after.values()) == sum(before.values()) + 1
+        _drain(engine)
+    assert cc.count == 0
+    assert admitted.output_tokens() == greedy_decode_reference(
+        model, params, prompt, 4)
+    snap = stats.snapshot()
+    assert sum(snap["steps_by_rung"].values()) == len(log.steps)
+    assert snap["step_rung_tokens"] == sum(
+        t * n for t, n in snap["steps_by_rung"].items())
 
 
 def test_a_100_token_prompt_on_the_idle_engine_is_one_step(warmed, model,
