@@ -71,14 +71,15 @@ VMEM_BUDGET = VMEM_LIMIT * 3 // 4
 def route_noaux_tc(x, router, bias, k, norm_topk_prob=True, scaling=1.0):
     """``(experts [T, k] int32, gates [T, k] float32)`` for ``x [T, d]``:
     scores ``sigmoid(x router)`` in float32, the ``k`` experts with the
-    largest ``score + bias`` (the bias selects only), gates the selected
-    scores, renormalised to sum to one if ``norm_topk_prob``, times
-    ``scaling``. One group, so the published group limiting is the
-    identity."""
+    largest ``score + bias`` (the bias selects only; ``None``, a router
+    without one: the largest scores), gates the selected scores,
+    renormalised to sum to one if ``norm_topk_prob``, times ``scaling``.
+    One group, so the published group limiting is the identity."""
     s = jax.nn.sigmoid(jnp.matmul(
         x.astype(jnp.float32), router.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
-    _, sel = jax.lax.top_k(s + bias.astype(jnp.float32), k)
+    _, sel = jax.lax.top_k(s if bias is None
+                           else s + bias.astype(jnp.float32), k)
     gates = jnp.take_along_axis(s, sel, axis=1)
     if norm_topk_prob:
         gates = gates / (gates.sum(axis=1, keepdims=True) + 1e-20)

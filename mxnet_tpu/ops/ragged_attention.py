@@ -953,6 +953,296 @@ def gqa_flat_attention(q, pages, block_tables, seq_ids, positions, scale,
                             int(kv_heads), bool(interpret))
 
 
+# ------------------- grouped queries per group, under a sliding window --
+def _window_bounds(positions, valid, window):
+    """The first key position each token sees: ``p - window + 1`` (not
+    below 0) under a window, 0 without one; past every key for a token
+    without ``valid``, so that it neither sees a key nor moves the start
+    of its run's walk."""
+    pos = jax.lax.max(positions.astype(jnp.int32), 0)
+    first = jnp.zeros_like(pos) if window is None \
+        else jax.lax.max(pos - (int(window) - 1), 0)
+    if valid is None:
+        return first
+    return jnp.where(valid.astype(bool), first, jnp.iinfo(jnp.int32).max)
+
+
+def gqa_window_attention_reference(q, pages, block_tables, seq_ids,
+                                   positions, scale, kv_heads, window=None,
+                                   valid=None, layer=None):
+    """Gather-based oracle of :func:`gqa_window_attention`: every query
+    token's pages gathered through its sequence's table row and split
+    into the ``kv_heads`` value and key heads, query head ``j`` against
+    kv head ``j // (H // kv_heads)``, one softmax over the keys ``j`` with
+    ``p - window < j <= p`` (``j <= p`` without a window). Everything in
+    float32; a row that sees no key (``valid`` false) is zeros."""
+    T, H, D = q.shape
+    G = kv_heads
+    bs = pages.shape[-2]
+    MB = block_tables.shape[1]
+    tbl = block_tables[seq_ids]                       # (T, MB)
+    kv = pages[(tbl,) if layer is None else (layer, tbl)]
+    kv = kv.reshape(T, MB * bs, 2, G, D).astype(jnp.float32)
+    qg = q.astype(jnp.float32).reshape(T, G, H // G, D)
+    logits = jnp.einsum("tgmd,tkgd->gmtk", qg, kv[:, :, 1],
+                        precision=jax.lax.Precision.HIGHEST) * scale
+    pos = jnp.arange(MB * bs, dtype=jnp.int32)
+    first = _window_bounds(positions, valid, window)
+    mask = (pos[None, :] <= positions[:, None]) \
+        & (pos[None, :] >= first[:, None])
+    mask = mask[None, None]
+    s = jnp.where(mask, logits, _NEG_INF)
+    p = jnp.where(mask, jnp.exp(s - s.max(axis=-1, keepdims=True)), 0.0)
+    probs = p / jnp.maximum(p.sum(axis=-1, keepdims=True), 1e-30)
+    out = jnp.einsum("gmtk,tkgd->tgmd", probs, kv[:, :, 0],
+                     precision=jax.lax.Precision.HIGHEST)
+    return out.reshape(T, H, D).astype(q.dtype)
+
+
+def _window_tiles(T, group, bs, MB):
+    """``(query tokens a block, pages a loop step)`` of the per-group
+    kernel: as many tokens as put 256 rows (tokens x the group's query
+    heads) before the MXU in each group's products, and as many pages as
+    make 256 keys a loop step, never more than the table is wide."""
+    qb = max(1, min(T, 256 // group))
+    if qb < T:
+        per = next(n for n in (1, 2, 4, 8) if n * group % 8 == 0)
+        qb = qb // per * per or T
+    return qb, max(1, min(MB, 256 // bs))
+
+
+def _gqa_window_kernel(layer_ref, sid_ref, pos_ref, first_ref, bt_ref,
+                       q_ref, pool_ref, o_ref, kv_buf, sems, acc_ref, m_ref,
+                       l_ref, qpos_ref, qfirst_ref, *, scale, group, kv_heads,
+                       block_size, head_dim):
+    """Grid ``(query blocks,)``: one grid step holds ``QB`` packed query
+    tokens, as ``G`` matrices of ``R = QB x group`` rows (each kv group's
+    query heads, token-major). The walk is ``_mla_flat_kernel``'s, a RUN
+    of consecutive tokens of one sequence at a time, ``PP`` pages a loop
+    step by DMA from the pool in HBM through the scalar-prefetched table,
+    double buffered, with one difference: a run's walk STARTS at the page
+    of the smallest first visible key of its tokens (``first_ref``; under
+    a window ``p - W + 1``), not at page 0. Each loop step then computes
+    every group apart, its query rows against its own kv head's ``D``
+    columns of the page rows (keys in ``[G x D, 2 x G x D)``, values in
+    ``[0, G x D)``), so no product is wider than the group needs. A row
+    sees a key if the row is one of the run's own and ``first <= key <=
+    position`` for its token. Online softmax in float32 scratch per
+    group."""
+    G, D = kv_heads, head_dim
+    R = q_ref.shape[1]
+    QB = R // group
+    KB = kv_buf.shape[1]
+    PP = KB // block_size
+    MB = bt_ref.shape[1]
+    t0 = pl.program_id(0) * QB
+    lyr = layer_ref[0]
+    last_key = MB * block_size - 1      # the reference's table ends here
+
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+    def bounds_to_rows(i, carry):       # each token's bounds, per row
+        rows = pl.ds(pl.multiple_of(i * group, group), group)
+        qpos_ref[rows, :] = jnp.full(
+            (group, 1), jnp.minimum(pos_ref[t0 + i], last_key), jnp.int32)
+        qfirst_ref[rows, :] = jnp.full((group, 1), first_ref[t0 + i],
+                                       jnp.int32)
+        return carry
+
+    jax.lax.fori_loop(0, QB, bounds_to_rows, 0)
+    row = jax.lax.broadcasted_iota(jnp.int32, (R, 1), 0)
+
+    def pages(seq, start, k, slot, go):
+        """Loop step ``k`` of a walk from page ``start``: start (``go``)
+        or await the DMA of its ``PP`` pages into buffer ``slot``. Past
+        the table's width the last entry again: masked."""
+        def page(p, carry):
+            copy = pltpu.make_async_copy(
+                pool_ref.at[lyr, bt_ref[seq, jnp.minimum(
+                    start + k * PP + p, MB - 1)]],
+                kv_buf.at[slot, pl.ds(pl.multiple_of(p * block_size,
+                                                     block_size),
+                                      block_size)],
+                sems.at[slot])
+            if go:
+                copy.start()
+            else:
+                copy.wait()
+            return carry
+
+        jax.lax.fori_loop(0, PP, page, 0)
+
+    def run(i):
+        seq = sid_ref[t0 + i]
+
+        def same_seq(c):
+            j, _, _ = c
+            return jnp.logical_and(
+                j < QB, sid_ref[t0 + jnp.minimum(j, QB - 1)] == seq)
+
+        def take(c):
+            j, last, low = c
+            return (j + 1, jnp.maximum(last, pos_ref[t0 + j]),
+                    jnp.minimum(low, first_ref[t0 + j]))
+
+        end, last, low = jax.lax.while_loop(
+            same_seq, take, (i + 1, pos_ref[t0 + i], first_ref[t0 + i]))
+        last_page = jnp.minimum(last, last_key) // block_size
+        start = jnp.minimum(low // block_size, last_page)
+        steps = (last_page - start) // PP + 1
+        mine = jnp.logical_and(row >= i * group, row < end * group)
+        limit = jnp.where(mine, qpos_ref[...], -1)
+        first = qfirst_ref[...]
+        pages(seq, start, 0, 0, True)
+
+        def step(k, carry):
+            slot = jax.lax.rem(k, 2)
+
+            @pl.when(k + 1 < steps)
+            def _next():
+                pages(seq, start, k + 1, 1 - slot, True)
+
+            pages(seq, start, k, slot, False)
+            kpos = (start + k * PP) * block_size \
+                + jax.lax.broadcasted_iota(jnp.int32, (R, KB), 1)
+            mask = jnp.logical_and(kpos <= limit, kpos >= first)
+            for g in range(G):
+                q = q_ref[g].astype(jnp.float32)                  # (R, D)
+                kk = kv_buf[slot, :, pl.ds((G + g) * D, D)].astype(
+                    jnp.float32)                                  # (KB, D)
+                vv = kv_buf[slot, :, pl.ds(g * D, D)].astype(jnp.float32)
+                s = jax.lax.dot_general(
+                    q, kk, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale   # (R, KB)
+                s = jnp.where(mask, s, _NEG_INF)
+                m_prev = m_ref[g]                                 # (R, 1)
+                m_new = jnp.maximum(m_prev,
+                                    jnp.max(s, axis=1, keepdims=True))
+                p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+                alpha = jnp.exp(m_prev - m_new)
+                l_ref[g] = l_ref[g] * alpha + jnp.sum(p, axis=1,
+                                                      keepdims=True)
+                acc_ref[g] = acc_ref[g] * alpha + jnp.dot(
+                    p, vv, preferred_element_type=jnp.float32)
+                m_ref[g] = m_new
+            return carry
+
+        jax.lax.fori_loop(0, steps, step, 0)
+        return end
+
+    jax.lax.while_loop(lambda i: i < QB, run, 0)
+    l_safe = jnp.maximum(l_ref[...], 1e-30)
+    o_ref[...] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnums=(7, 8, 9))
+def _gqa_window_pallas(q, pages, block_tables, seq_ids, positions, first,
+                       layer, scale, kv_heads, interpret):
+    """The call of ``_gqa_window_kernel``: the queries regrouped to ``[G,
+    T x group, D]`` (each kv group's heads token-major) and back."""
+    T, H, D = q.shape
+    G = kv_heads
+    group = H // G
+    bs = pages.shape[2]
+    S, MB = block_tables.shape
+    qb, pp = _window_tiles(T, group, bs, MB)
+    blocks = -(-T // qb)
+    total = blocks * qb
+    sid, pos = _block_scalars(seq_ids, positions, S, total)
+    first = first.astype(jnp.int32)
+    if total > T:
+        first = jnp.concatenate(
+            [first, jnp.full((total - T,), jnp.iinfo(jnp.int32).max,
+                             jnp.int32)])
+    R = qb * group
+    qg = q.reshape(T, G, group, D).transpose(1, 0, 2, 3).reshape(
+        G, T * group, D)
+
+    def rows(b, lyr, sid, pos, first, bt):
+        return (0, b, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=5,
+        grid=(blocks,),
+        in_specs=[
+            pl.BlockSpec((G, R, D), rows, memory_space=pltpu.VMEM),
+            pl.BlockSpec(memory_space=pl.ANY),        # the pool, in HBM
+        ],
+        out_specs=pl.BlockSpec((G, R, D), rows, memory_space=pltpu.VMEM),
+        scratch_shapes=[
+            pltpu.VMEM((2, pp * bs, 2 * G * D), pages.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.VMEM((G, R, D), jnp.float32),
+            pltpu.VMEM((G, R, 1), jnp.float32),
+            pltpu.VMEM((G, R, 1), jnp.float32),
+            pltpu.VMEM((R, 1), jnp.int32),
+            pltpu.VMEM((R, 1), jnp.int32),
+        ],
+    )
+    kernel = functools.partial(_gqa_window_kernel, scale=scale,
+                               group=group, kv_heads=G, block_size=bs,
+                               head_dim=D)
+    out = pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((G, T * group, D), q.dtype),
+        interpret=interpret,
+    )(layer.astype(jnp.int32).reshape(1), sid, pos, first,
+      block_tables.astype(jnp.int32), qg, pages)
+    return out.reshape(G, T, group, D).transpose(1, 0, 2, 3).reshape(
+        T, H, D)
+
+
+def gqa_window_attention(q, pages, block_tables, seq_ids, positions, scale,
+                         kv_heads, window=None, valid=None, layer=None,
+                         use_pallas=None, interpret=None):
+    """Flat-ragged grouped-query attention over ONE pool of packed K/V
+    rows (:func:`gqa_flat_attention`'s pool and result), computed PER
+    KV GROUP and, with ``window``, under a sliding window: token ``t`` at
+    position ``p`` sees the keys ``j`` of its sequence with ``p - window <
+    j <= p`` (``j <= p`` with ``window=None``, a full layer's causal
+    walk). ``valid`` (optional ``[T]``): a token without it sees no key,
+    its row is zeros, and it does not widen its run's walk (a padded tail
+    at position 0 would otherwise start the walk at page 0).
+
+    What the kernel makes of the packed batch (``_gqa_window_kernel``):
+    blocks of query tokens (:func:`_window_tiles`), and in a block every
+    RUN of one sequence walks its pages once, from the page of its
+    tokens' smallest first visible key to the page of its largest
+    position: a window layer reads about ``window`` keys whatever the
+    context, and its table may name the null block behind that (the
+    pages a window has left are given back by the engine). Each kv
+    group's query heads are one matrix against that group's keys and
+    values alone (:func:`gqa_flat_attention` widens every head over all
+    the groups' columns). Gated alike: Mosaic on a TPU, the gather
+    reference elsewhere, interpret mode on request."""
+    if use_pallas is None:
+        use_pallas = _on_tpu()
+    if pages.ndim != (3 if layer is None else 4) \
+            or pages.shape[-1] != 2 * kv_heads * q.shape[-1] \
+            or q.shape[1] % kv_heads or (window is not None and window < 1):
+        raise ValueError(
+            "the pool is [L, N, bs, 2 * kv_heads * D] with `layer`, [N, "
+            f"bs, ...] without, kv_heads divides H and a window is >= 1; "
+            f"got q {q.shape}, pages {pages.shape}, kv_heads={kv_heads}, "
+            f"window={window!r}, layer={layer!r}")
+    block_tables = jnp.asarray(block_tables)
+    seq_ids, positions = jnp.asarray(seq_ids), jnp.asarray(positions)
+    if not use_pallas:
+        return gqa_window_attention_reference(
+            q, pages, block_tables, seq_ids, positions, float(scale),
+            int(kv_heads), window, valid, layer=layer)
+    if interpret is None:
+        interpret = not _on_tpu()
+    if layer is None:
+        layer, pages = 0, pages[None]
+    return _gqa_window_pallas(
+        q, pages, block_tables, seq_ids, positions,
+        _window_bounds(positions, valid, window), jnp.asarray(layer),
+        float(scale), int(kv_heads), bool(interpret))
+
+
 @register("mla_flat_attention", differentiable=False)
 def _mla_flat_op(q, pages, block_tables, seq_ids, positions, *, scale,
                  v_dim, layer=None, use_pallas=None):

@@ -506,8 +506,13 @@ class LLMEngine:
       "latent", "latent_dim": W}`` or ``{"kind": "kv_packed",
       "num_heads": G, "head_dim": D}`` (one pool of rows of ``2 x G x
       D``); absent, K and V pools of ``[num_heads, head_dim]``. With
-      ``"layers": n`` only ``n`` layers leave pages. With ``"state":
-      {name: (layers, *shape)}`` every SLOT (a row of
+      ``"layers": n`` only ``n`` layers leave pages. With
+      ``"window_layers"`` and ``"window": W`` those layers see the ``W``
+      keys up to a token's own: a page is then one layer's, every layer
+      draws on one pool, a window layer's pages behind the window go
+      back to it before each step (:meth:`_allocate`), and the step is
+      handed one table a layer, ``block_tables [L, S, MB]``. With
+      ``"state": {name: (layers, *shape)}`` every SLOT (a row of
       ``block_tables``, what ``seq_ids`` names) also holds a fixed
       float32 state a layer that its tokens overwrite: the engine keeps
       the pools ``[layers, max_seqs, *shape]``, donates them through
@@ -623,15 +628,6 @@ class LLMEngine:
             raise ValueError(
                 f"max_context {max_context} must be a multiple of "
                 f"block_size {block_size}")
-        blocks_per_seq = max_context // block_size
-        if num_blocks is None:
-            num_blocks = _env_int(
-                "MXNET_TPU_LLM_NUM_BLOCKS",
-                max_seqs * blocks_per_seq + 1)
-        if num_blocks - 1 < blocks_per_seq:
-            raise ValueError(
-                f"num_blocks {num_blocks} cannot hold one full-context "
-                f"sequence ({blocks_per_seq} blocks + the null block)")
         self.max_seqs = int(max_seqs)
         self.max_context = int(max_context)
         if prefill_chunk is None:
@@ -700,6 +696,24 @@ class LLMEngine:
             {d_lo, t_hi} | {max(d_lo, m) for m in mids})
         mb = max_context // block_size
         self._mb_widths = sorted({max(1, -(-mb // 2)), mb})
+        # the pages a full-context sequence holds: a block, or under
+        # window layers (a page is then one layer's) every block of each
+        # full layer and, of each window layer, what its window holds
+        # over one step and the step in flight
+        layout = getattr(model, "cache_layout", None) or {"kind": "kv"}
+        seq_pages = mb
+        if layout.get("window_layers"):
+            seq_pages = PagedKVCache.seq_pages_for(
+                layout.get("layers", model.num_layers),
+                layout["window_layers"], layout["window"], block_size,
+                t_hi, max_context)
+        if num_blocks is None:
+            num_blocks = _env_int("MXNET_TPU_LLM_NUM_BLOCKS",
+                                  self.max_seqs * seq_pages + 1)
+        if num_blocks - 1 < seq_pages:
+            raise ValueError(
+                f"num_blocks {num_blocks} cannot hold one full-context "
+                f"sequence ({seq_pages} pages + the null block)")
         # cross-request prefix caching (ISSUE 13): constructor arg >
         # MXNET_TPU_LLM_PREFIX_CACHE env > on. Hits only rewrite host
         # state (block tables, start offsets) — cache hit vs miss can
@@ -734,7 +748,6 @@ class LLMEngine:
         # the pool's shape is the model's to say (cache_layout): K and
         # V pools of [H, D] a slot, or one pool of latents, which is
         # never quantized
-        layout = getattr(model, "cache_layout", None) or {"kind": "kv"}
         if (layout["kind"] != "kv"
                 and kv_dtype in PagedKVCache.QUANTIZED_DTYPES):
             raise UnsupportedModelFeatureError(
@@ -742,8 +755,14 @@ class LLMEngine:
                 f"{kv_dtype} pages")
         self.cache = PagedKVCache.for_model(
             model, block_size, num_blocks, max_context,
-            max_seqs=self.max_seqs, dtype=kv_dtype,
-            prefix_cache=self.prefix_enabled, mesh=self.mesh)
+            max_seqs=self.max_seqs, step_tokens=self._step_budget,
+            dtype=kv_dtype, prefix_cache=self.prefix_enabled,
+            mesh=self.mesh)
+        # each layer's own table in a step, where layers hold different
+        # pages (window and full layers); the cache's count of window
+        # pages given back when the gauges were last recorded
+        self._windowed = self.cache.windowed
+        self._window_released = 0
         self.quantized = self.cache.quantized
         self.scheduler = Scheduler(self.max_seqs)
         self._stats = stats
@@ -1231,6 +1250,13 @@ class LLMEngine:
                      tenant=seq.tenant,
                      attrs={"old": old, "new": new})
 
+    def _table_shape(self, mb):
+        """A step's block tables at width ``mb``: ``[S, mb]``, or ``[L, S,
+        mb]``, a table a layer, under a cache with window layers."""
+        if self._windowed:
+            return (self.cache.num_layers, self.max_seqs, mb)
+        return (self.max_seqs, mb)
+
     # ------------------------------------------------------- warmup --
     def _null_batch(self, T, MB):
         """An all-padding step batch of packed length ``T`` and table
@@ -1240,7 +1266,7 @@ class LLMEngine:
         V = self.model.vocab_size
         batch = (np.zeros(T, np.int32), np.zeros(T, np.int32),
                  np.zeros(T, np.int32), np.zeros(T, np.int32),
-                 np.full((S, MB), NULL_BLOCK, np.int32),
+                 np.full(self._table_shape(MB), NULL_BLOCK, np.int32),
                  np.zeros((S, K + 1), np.int32),
                  np.zeros((S, K), np.int32),
                  np.zeros((S, K, V), np.float32),
@@ -1363,6 +1389,12 @@ class LLMEngine:
                 a.num_used, a.num_usable, cached=a.num_cached,
                 shared=a.num_shared,
                 free=a.num_free - a.num_cached)
+            if self._windowed:
+                w = self.cache.window_stats()
+                self._stats.record_window_pages(
+                    w["pages_held"], w["pages_full_layout"],
+                    w["window_pages_released"] - self._window_released)
+                self._window_released = w["window_pages_released"]
             self._stats.record_admission_state(
                 self.scheduler.num_waiting, self.scheduler.num_running)
 
@@ -1415,6 +1447,10 @@ class LLMEngine:
             need = self.cache.blocks_for(T) - len(hit)
             if T % self.cache.block_size == 0:
                 need += 1           # first decode opens a new page
+            if self._windowed:
+                # by kind: every block of the full layers, of the window
+                # layers what a window holds (no prefix cache here)
+                need = self.cache.pages_for(T + 1)
             if hit_tokens and hit_tokens < len(hit) * \
                     self.cache.block_size:
                 # truncated (block-aligned full) hit: the recompute
@@ -1444,6 +1480,8 @@ class LLMEngine:
                     self._stats.record_admitted(
                         seq.t_admit - seq.t_submit)
             seq.block_ids = list(hit)
+            if self._windowed:
+                self.cache.start(seq)
             seq.seq_len = hit_tokens
             seq.draft_len = 0
             seq.prefill_started = False
@@ -1477,8 +1515,7 @@ class LLMEngine:
     def _finish(self, seq, events, length):
         """``length``: the tokens whose K/V the committed steps wrote."""
         self._register_blocks(seq, length)
-        self.cache.allocator.free(seq.block_ids)
-        seq.block_ids = []
+        self.cache.free_seq(seq)
         self._release_adapter(seq)
         reason = ("stop_token" if (seq.stop_token is not None
                                    and seq.generated
@@ -1491,8 +1528,7 @@ class LLMEngine:
         events.append(("finished", seq))
 
     def _preempt(self, seq):
-        self.cache.allocator.free(seq.block_ids)
-        seq.block_ids = []
+        self.cache.free_seq(seq)
         self.scheduler.preempt(seq)
         if self._stats:
             self._stats.record_preemption()
@@ -1506,9 +1542,7 @@ class LLMEngine:
     def _poison(self, seq, exc, events):
         """Release ``seq`` as poison-isolated: blocks freed, slot
         freed, the ORIGINAL exception queued for the server."""
-        if seq.block_ids:
-            self.cache.allocator.free(seq.block_ids)
-            seq.block_ids = []
+        self.cache.free_seq(seq)
         self._release_adapter(seq)
         self.scheduler.release(seq, EVICTED, "poison")
         self._poison_pending.append((seq, exc))
@@ -1533,9 +1567,7 @@ class LLMEngine:
                 if reason is None:
                     keep.append(seq)
                     continue
-                if seq.block_ids:       # defensive: waiting seqs
-                    self.cache.allocator.free(seq.block_ids)
-                    seq.block_ids = []  # normally hold no blocks
+                self.cache.free_seq(seq)  # waiting: normally no blocks
                 self._release_adapter(seq)
                 self.scheduler.release(seq, EVICTED, reason)
                 self._dead_pending.append((seq, reason))
@@ -1546,8 +1578,7 @@ class LLMEngine:
                       else "deadline" if seq.expired(now) else None)
             if reason is None:
                 continue
-            self.cache.allocator.free(seq.block_ids)
-            seq.block_ids = []
+            self.cache.free_seq(seq)
             self._release_adapter(seq)
             self.scheduler.release(seq, EVICTED, reason)
             self._dead_pending.append((seq, reason))
@@ -1754,7 +1785,23 @@ class LLMEngine:
         sequence is about to extend/rewrite) is copied to a private
         block first, so shared prefix KV is immutable for every other
         owner. COW capacity is reserved through the same
-        preempt-under-pressure loop."""
+        preempt-under-pressure loop.
+
+        Under a cache with window layers the pages are counted by kind
+        (:meth:`~.kv_cache.PagedKVCache.grow`), and the window layers'
+        pages wholly behind the window of the step's first position go
+        back to the pool first (of the step in flight's, where it holds
+        the sequence: a failed step is dispatched again from there)."""
+        if self._windowed:
+            ahead = self._ahead().get(seq)
+            lowest = seq.seq_len if ahead is None \
+                else min(seq.seq_len, ahead["pos"])
+            self.cache.release_behind(seq, lowest)
+            end = seq.seq_len + plan["ntok"]
+            self._make_room(seq, self.cache.pages_to_grow(seq, end, lowest),
+                            events)
+            self.cache.grow(seq, end, lowest)
+            return
         need = self.cache.blocks_for(seq.seq_len + plan["ntok"]) \
             - len(seq.block_ids)
         cow = []
@@ -1766,7 +1813,17 @@ class LLMEngine:
             cow = [bi for bi in range(first, last + 1)
                    if self.cache.allocator.refcount(
                        seq.block_ids[bi]) > 1]
-        total = max(need, 0) + len(cow)
+        self._make_room(seq, max(need, 0) + len(cow), events)
+        for bi in cow:
+            # a victim preemption above may have dropped the share
+            if self.cache.allocator.refcount(seq.block_ids[bi]) > 1:
+                self._cow_block(seq, bi)
+        if need > 0:
+            seq.block_ids.extend(self.cache.allocator.alloc(need))
+
+    def _make_room(self, seq, total, events):
+        """``total`` free blocks for ``seq``, preempting newest-arrived
+        sequences but ``seq`` until there are."""
         if (self._inflight is not None and total > 0
                 and not self.cache.allocator.can_alloc(total)):
             # preemption folds committed tokens into the prompt: it
@@ -1780,12 +1837,6 @@ class LLMEngine:
                     "small for max_context")
             self._preempt(victim)
             events.append(("preempted", victim))
-        for bi in cow:
-            # a victim preemption above may have dropped the share
-            if self.cache.allocator.refcount(seq.block_ids[bi]) > 1:
-                self._cow_block(seq, bi)
-        if need > 0:
-            seq.block_ids.extend(self.cache.allocator.alloc(need))
 
     # -------------------------------------------------- draft phase --
     def _draft_dispatch(self, rows, feeds, counters_v):
@@ -1982,7 +2033,7 @@ class LLMEngine:
                     np.zeros(t, np.int32),            # positions
                     np.zeros(t, np.int32),            # seq_ids
                     np.zeros(t, np.int32),            # valid
-                    np.full((S, mb), NULL_BLOCK, np.int32),
+                    np.full(self._table_shape(mb), NULL_BLOCK, np.int32),
                     np.zeros((S, K + 1), np.int32),   # win_idx
                     np.zeros((S, K), np.int32),       # draft tokens
                     np.zeros((S, K, V), np.float32),  # draft probs
@@ -2046,9 +2097,12 @@ class LLMEngine:
             win_idx[i] = np.clip(start + self._arange[:K1], 0, t - 1)
             # blocks past the sliced width only cover positions the
             # causal mask can never reach — truncation is invisible
-            nb = min(len(seq.block_ids), mb)
-            tables[i, :nb] = seq.block_ids[:nb]
-            tables[i, nb:] = NULL_BLOCK
+            if self._windowed:
+                self.cache.fill_tables(tables, i, seq)
+            else:
+                nb = min(len(seq.block_ids), mb)
+                tables[i, :nb] = seq.block_ids[:nb]
+                tables[i, nb:] = NULL_BLOCK
             k = plan["k"]
             n_draft[i] = k
             if k:
@@ -2670,6 +2724,12 @@ class LLMEngine:
         if sp.span_id is not None:
             sp.set("running", len(rows))
             sp.set("prefilling", prefilling)
+            if self._windowed:
+                # the pages the step's sequences hold, and what an
+                # all-full layout would hold for them
+                w = self.cache.window_stats()
+                sp.set("kv_pages_held", sum(w["pages_held"].values()))
+                sp.set("kv_pages_full_layout", w["pages_full_layout"])
             # (tokens fed, context after) of each row
             sp.set("rows", [(len(plans[s]["tokens"]),
                              s.seq_len + len(plans[s]["tokens"]))
@@ -2777,16 +2837,13 @@ class LLMEngine:
                 rec["span"].discard()
         out = []
         for seq in self.scheduler.running():
-            self.cache.allocator.free(seq.block_ids)
-            seq.block_ids = []
+            self.cache.free_seq(seq)
             self._release_adapter(seq)
             self.scheduler.release(seq, EVICTED, reason)
             out.append(seq)
         while self.scheduler.waiting:
             seq = self.scheduler.waiting.popleft()
-            if seq.block_ids:       # defensive: waiting seqs normally
-                self.cache.allocator.free(seq.block_ids)
-                seq.block_ids = []  # hold no blocks
+            self.cache.free_seq(seq)  # waiting: normally no blocks
             self._release_adapter(seq)
             self.scheduler.release(seq, EVICTED, reason)
             out.append(seq)

@@ -74,7 +74,7 @@ import hashlib
 import numpy as np
 
 __all__ = ["KVCacheError", "NoFreeBlocksError", "BlockAccountingError",
-           "BlockAllocator", "PagedKVCache", "NULL_BLOCK",
+           "BlockAllocator", "PagedKVCache", "LayerPages", "NULL_BLOCK",
            "prefix_block_hashes"]
 
 # block 0 is reserved: the write/read sink for padding and inactive rows
@@ -300,6 +300,21 @@ class BlockAllocator:
         return True
 
 
+class LayerPages:
+    """One sequence's pages in a cache with window layers: ``table[l, b]``
+    is the page of layer ``l`` that holds the sequence's block ``b`` (the
+    null block where a window gave it back, and from ``blocks`` on), and
+    ``window_from`` the first block whose window pages it still holds. The
+    step's per-layer tables are copied from ``table`` a row at a time."""
+
+    __slots__ = ("table", "blocks", "window_from")
+
+    def __init__(self, num_layers, max_blocks):
+        self.table = np.full((num_layers, max_blocks), NULL_BLOCK, np.int32)
+        self.blocks = 0
+        self.window_from = 0
+
+
 class PagedKVCache:
     """The block pool's storage + allocator + block-table helpers +
     the cross-request prefix index.
@@ -328,6 +343,18 @@ class PagedKVCache:
     :meth:`pools` lists them after the pages and :meth:`swap` takes them
     back; no block, table or hash knows of them.
 
+    ``window_layers`` (with ``window``, ``W``, and ``step_tokens``, the
+    most tokens one step writes for one sequence) makes a cache of TWO
+    KINDS of layer in one pool. A page is then ONE layer's ``block_size``
+    rows, the pool is ``[1, num_blocks, block_size, W]`` and every layer
+    draws its pages from the one allocator: a sequence holds a full
+    layer's pages for all its positions and a window layer's only from
+    its window's first position on (:meth:`grow`; a page that falls
+    wholly behind it goes back to the free list at once, for either kind
+    to take), and the step is handed one table a layer
+    (:meth:`fill_tables`). Nothing splits the pool between the kinds.
+    Neither quantized, sharded nor prefix-cached.
+
     ``prefix_cache=True`` enables the content-addressed prefix index:
     :meth:`register` maps a chained block hash to a live block,
     :meth:`prefix_get` answers hit lookups, and LRU reclaims (the
@@ -341,18 +368,22 @@ class PagedKVCache:
 
     @classmethod
     def for_model(cls, model, block_size, num_blocks, max_context,
-                  max_seqs=0, **kw):
+                  max_seqs=0, step_tokens=0, **kw):
         """The pools a model's layers write: shaped by its
         ``cache_layout`` (``TinyDecoder.cache_layout``), or K and V
         pools of ``[num_heads, head_dim]`` for a model without one.
         ``max_seqs`` is how many slots a layout's ``state`` is held
-        for."""
+        for; ``step_tokens`` the most tokens a step writes for one
+        sequence, where the layout has window layers."""
         layout = getattr(model, "cache_layout", None) or {
             "kind": "kv", "num_heads": model.num_heads,
             "head_dim": model.head_dim}
         layers = layout.get("layers", model.num_layers)
         if "state" in layout:
             kw.update(state=layout["state"], state_slots=max_seqs)
+        if layout.get("window_layers"):
+            kw.update(window_layers=layout["window_layers"],
+                      window=layout["window"], step_tokens=step_tokens)
         if layout["kind"] == "latent":
             return cls(layers, 0, 0, block_size, num_blocks, max_context,
                        latent_dim=layout["latent_dim"], **kw)
@@ -368,7 +399,8 @@ class PagedKVCache:
     def __init__(self, num_layers, num_heads, head_dim, block_size,
                  num_blocks, max_context, dtype="float32",
                  prefix_cache=False, mesh=None, shard_axis="tp",
-                 latent_dim=0, kind=None, state=None, state_slots=0):
+                 latent_dim=0, kind=None, state=None, state_slots=0,
+                 window_layers=(), window=0, step_tokens=0):
         import jax.numpy as jnp
         if max_context < 1:
             raise ValueError(f"max_context must be >= 1, {max_context}")
@@ -387,6 +419,32 @@ class PagedKVCache:
         self.quantized = self.dtype.name in self.QUANTIZED_DTYPES
         # every sequence's table has room for a full-context sequence
         self.max_blocks_per_seq = -(-self.max_context // self.block_size)
+        # two kinds of layer in one pool of one-layer pages
+        self.window_layers = tuple(int(i) for i in window_layers)
+        self.windowed = bool(self.window_layers)
+        self.window = int(window)
+        self.step_tokens = 0
+        # pages held now, by kind; the sequences' blocks (what an
+        # all-full layout holds a layer); window pages given back
+        self.pages_held = {"window": 0, "full": 0}
+        self.blocks_covered = 0
+        self.window_released = 0
+        if self.windowed:
+            if not (self.window >= 1 and step_tokens >= 1
+                    and all(0 <= i < self.num_layers
+                            for i in self.window_layers)):
+                raise KVCacheError(
+                    f"window layers {list(self.window_layers)} of "
+                    f"{self.num_layers} need window >= 1 and step_tokens "
+                    f">= 1, got {window} and {step_tokens}")
+            if self.dtype.name in self.QUANTIZED_DTYPES or mesh is not None \
+                    or prefix_cache or state or not latent_dim:
+                raise KVCacheError(
+                    "a cache with window layers is one pool of rows, "
+                    "neither quantized, sharded, prefix-cached nor "
+                    "holding slot state")
+            self.step_tokens = int(step_tokens)
+            self._window_rows = np.asarray(self.window_layers)[:, None]
         self.prefix_enabled = bool(prefix_cache)
         self.allocator = BlockAllocator(
             self.num_blocks,
@@ -444,8 +502,9 @@ class PagedKVCache:
                 if self.kind == "kv_packed" \
                 else -(-self.latent_dim // self.LANES) * self.LANES
             self.latent_pages = jnp.zeros(
-                (self.num_layers, self.num_blocks, self.block_size,
-                 self.pool_width), dtype=jnp.dtype(self.dtype))
+                (1 if self.windowed else self.num_layers, self.num_blocks,
+                 self.block_size, self.pool_width),
+                dtype=jnp.dtype(self.dtype))
             return
         shape = (self.num_layers, self.num_blocks, self.block_size,
                  self.num_heads, self.head_dim)
@@ -529,6 +588,146 @@ class PagedKVCache:
         row[:len(block_ids)] = block_ids
         return row
 
+    # ----------------------------------------- window and full layers --
+    @staticmethod
+    def window_blocks_for(window, block_size, step_tokens):
+        """The most blocks of positions a window layer holds for one
+        sequence: a step's tokens (at most ``step_tokens``) and the
+        ``window - 1`` positions before the first of them, and, behind
+        those, the tokens of the step in flight (which is dispatched
+        again from its own position if it fails), over one more block
+        where they do not start on a block's edge."""
+        return -(-(window - 1 + 2 * step_tokens) // block_size) + 1
+
+    @classmethod
+    def seq_pages_for(cls, num_layers, window_layers, window, block_size,
+                      step_tokens, num_tokens):
+        """The most pages a sequence of ``num_tokens`` holds at once under
+        a layout with ``window_layers`` of ``num_layers``: every block of
+        each full layer, and of each window layer what its window holds."""
+        blocks = -(-int(num_tokens) // block_size)
+        held = min(blocks, cls.window_blocks_for(window, block_size,
+                                                 step_tokens))
+        n_win = len(window_layers)
+        return (num_layers - n_win) * blocks + n_win * held
+
+    def pages_for(self, num_tokens):
+        """One-layer pages a sequence of ``num_tokens`` holds at the most
+        in a cache with window layers, by kind (:meth:`seq_pages_for`)."""
+        return self.seq_pages_for(self.num_layers, self.window_layers,
+                                  self.window, self.block_size,
+                                  self.step_tokens, num_tokens)
+
+    def start(self, seq):
+        """An admitted sequence holds no page yet: its per-layer
+        record (:class:`LayerPages`)."""
+        seq.layer_blocks = LayerPages(self.num_layers,
+                                      self.max_blocks_per_seq)
+
+    def _first_block(self, lowest):
+        """The first block the window of position ``lowest`` reaches."""
+        return max(0, lowest - self.window + 1) // self.block_size
+
+    def release_behind(self, seq, lowest):
+        """Give back the window layers' pages of ``seq`` that lie wholly
+        behind the window of position ``lowest`` (the first position any
+        query of it may still be computed at): every key they hold is
+        before ``lowest - window + 1``. Returns how many went back."""
+        lp = seq.layer_blocks
+        below = min(self._first_block(lowest), lp.blocks)
+        if below <= lp.window_from:
+            return 0
+        held = lp.table[self._window_rows, lp.window_from:below]
+        gone = held[held != NULL_BLOCK].tolist()
+        lp.table[self._window_rows, lp.window_from:below] = NULL_BLOCK
+        lp.window_from = below
+        if gone:
+            self.allocator.free(gone)
+            self.pages_held["window"] -= len(gone)
+            self.window_released += len(gone)
+        return len(gone)
+
+    def pages_to_grow(self, seq, end, lowest):
+        """Pages :meth:`grow` takes to cover positions below ``end``: a
+        block a full layer lacks, and of those the window of ``lowest``
+        reaches, a block a window layer lacks."""
+        blocks = self.blocks_for(end)
+        have = seq.layer_blocks.blocks
+        n_win = len(self.window_layers)
+        return (self.num_layers - n_win) * max(0, blocks - have) \
+            + n_win * max(0, blocks - max(have, self._first_block(lowest)))
+
+    def grow(self, seq, end, lowest):
+        """Pages for positions below ``end`` onto ``seq``, after
+        :meth:`release_behind`: each layer's next blocks, a window
+        layer's only from the window of ``lowest`` on (the null block
+        behind it, which :meth:`release_behind` then need not look at
+        again). All-or-nothing (``NoFreeBlocksError``)."""
+        need = self.pages_to_grow(seq, end, lowest)
+        if not need:
+            return
+        lp = seq.layer_blocks
+        blocks, have = self.blocks_for(end), lp.blocks
+        first = min(max(have, self._first_block(lowest)), blocks)
+        n_full = blocks - have
+        pages = []
+        try:
+            pages = self.allocator.alloc(need)
+            for layer in range(self.num_layers):
+                start = first if layer in self.window_layers else have
+                lp.table[layer, start:blocks] = pages[:blocks - start]
+                pages = pages[blocks - start:]
+                self.pages_held[self._kind(layer)] += blocks - start
+        except BaseException:
+            # the table is what free_seq gives back: what did not reach
+            # it goes back here
+            self.allocator.free(pages)
+            raise
+        if lp.window_from >= have:
+            # every block before the new ones was given back already, and
+            # the new ones behind the window hold none
+            lp.window_from = first
+        lp.blocks = blocks
+        self.blocks_covered += n_full
+
+    def _kind(self, layer):
+        return "window" if layer in self.window_layers else "full"
+
+    def seq_pages(self, seq):
+        """The pages ``seq`` owns: its ``block_ids``, or under window
+        layers the pages of its per-layer table."""
+        lp = seq.layer_blocks
+        if lp is None:
+            return list(seq.block_ids)
+        table = lp.table[:, :lp.blocks]
+        return table[table != NULL_BLOCK].tolist()
+
+    def free_seq(self, seq):
+        """Every page ``seq`` owns back to the pool; it holds none after."""
+        self.allocator.free(self.seq_pages(seq))
+        lp = seq.layer_blocks
+        if lp is not None:
+            held = lp.table[:, :lp.blocks] != NULL_BLOCK
+            for layer in range(self.num_layers):
+                self.pages_held[self._kind(layer)] -= int(held[layer].sum())
+            self.blocks_covered -= lp.blocks
+        seq.block_ids = []
+        seq.layer_blocks = None
+
+    def fill_tables(self, tables, slot, seq):
+        """Row ``slot`` of the step's per-layer tables ``[L, S, MB]``:
+        each layer's pages by block (the null block where a window gave
+        one back, and past the sequence's blocks)."""
+        tables[:, slot] = seq.layer_blocks.table[:, :tables.shape[2]]
+
+    def window_stats(self):
+        """Pages held by kind, what an all-full layout would hold for the
+        same sequences, and the window pages given back so far."""
+        return {"window": self.window,
+                "pages_held": dict(self.pages_held),
+                "pages_full_layout": self.num_layers * self.blocks_covered,
+                "window_pages_released": self.window_released}
+
     # ------------------------------------------------------ storage --
     def pools(self):
         """The device arrays a step program takes, donates and returns,
@@ -549,6 +748,14 @@ class PagedKVCache:
         ``stats()``: bytes over all layers, as allocated (a latent's
         lane padding and a quantized pool's scales included)."""
         itemsize = self.dtype.itemsize
+        if self.windowed:
+            return {"kind": self.kind,
+                    "values_per_token": self.latent_dim,
+                    "pool_shape": list(self.latent_pages.shape),
+                    "window_layers": list(self.window_layers),
+                    "window": self.window,
+                    "bytes_per_page": self.block_size * self.pool_width
+                    * itemsize}
         if self.latent_dim:
             return {"kind": self.kind,
                     "values_per_token": self.latent_dim,
@@ -647,7 +854,7 @@ class PagedKVCache:
     # -------------------------------------------------------- stats --
     def stats(self):
         a = self.allocator
-        return {
+        out = {
             "num_blocks": self.num_blocks,
             "block_size": self.block_size,
             "blocks_used": a.num_used,
@@ -667,6 +874,10 @@ class PagedKVCache:
             "shards": self.shards,
             "heads_per_shard": self.heads_per_shard,
         }
+        if self.windowed:
+            # (blocks_used counts one-layer pages here)
+            out.update(self.window_stats())
+        return out
 
     def state_stats(self):
         """The slot state pools, for ``stats()``: how many slots, each

@@ -182,6 +182,25 @@ class LLMStats:
             "mxtpu_llm_kv_blocks_free",
             "Strictly free blocks (not allocated, not cached).",
             lbl).labels(**s)
+        self._pages_held = r.gauge(
+            "mxtpu_llm_kv_pages_held",
+            "One-layer KV pages live sequences hold, by the kind of layer "
+            "(a cache with window layers: sliding-window layers hold a "
+            "window's pages, full layers all of theirs).",
+            ("server", "kind"))
+        self._pages_held_children = {
+            kind: self._pages_held.labels(server=s["server"], kind=kind)
+            for kind in ("window", "full")}
+        self._pages_full_layout = r.gauge(
+            "mxtpu_llm_kv_pages_full_layout",
+            "One-layer pages the same sequences would hold if every "
+            "layer kept all of its pages (a cache with window layers): "
+            "what mxtpu_llm_kv_pages_held is a share of.",
+            lbl).labels(**s)
+        self._window_released = r.counter(
+            "mxtpu_llm_kv_window_pages_released_total",
+            "Window layers' pages given back to the pool as they fell "
+            "wholly behind their sequence's window.", lbl).labels(**s)
         self._prefix_lookups = r.counter(
             "mxtpu_llm_prefix_lookup_total",
             "Prefix-cache lookups (one per admission while the cache "
@@ -482,6 +501,16 @@ class LLMStats:
         self._blocks_shared.set(shared)
         self._blocks_free.set(total - in_use - cached
                               if free is None else free)
+
+    def record_window_pages(self, held, full_layout, released=0):
+        """A cache with window layers: the pages held by kind (``{"window":
+        n, "full": n}``), what an all-full layout would hold for the same
+        sequences, and window pages given back since the last record."""
+        for kind, n in held.items():
+            self._pages_held_children[kind].set(n)
+        self._pages_full_layout.set(full_layout)
+        if released:
+            self._window_released.inc(released)
 
     def record_prefix_lookup(self, hit_tokens, tenant=None):
         """One admission-time prefix-cache lookup: counts the lookup,
@@ -799,6 +828,13 @@ class LLMStats:
                 "moe_steps": int(self._moe_max_load.count),
                 "moe_tiles": int(self._moe_tiles.value),
                 "moe_pairs_elsewhere": int(self._moe_elsewhere.value),
+                "kv_pages_held": {
+                    k: int(c.value)
+                    for k, c in self._pages_held_children.items()},
+                "kv_pages_full_layout": int(
+                    self._pages_full_layout.value),
+                "kv_window_pages_released": int(
+                    self._window_released.value),
                 "state_rows": int(self._state_rows.value),
                 "state_starts": int(self._state_starts.value),
                 "state_steps": int(self._state_steps.value),

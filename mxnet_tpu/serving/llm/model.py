@@ -2,7 +2,7 @@
 
 What the serving engine calls and reads of a model is stated once, in
 :class:`~.engine.LLMEngine`'s docstring (the model protocol);
-:class:`TinyDecoder` is its reference implementation, for five blocks
+:class:`TinyDecoder` is its reference implementation, for six blocks
 (``DecoderConfig.model_type``; each entry point below goes to the
 block's own method through ``TinyDecoder._of_block``). Its entry points:
 
@@ -11,8 +11,9 @@ block's own method through ``TinyDecoder._of_block``). Its entry points:
   attention layer writes its tokens' K/V (or latents) into their page
   slots, then attends over the block-table-indirected history through
   :func:`~mxnet_tpu.ops.ragged_attention.ragged_flat_attention`,
-  :func:`~mxnet_tpu.ops.ragged_attention.mla_flat_attention` or
-  :func:`~mxnet_tpu.ops.ragged_attention.gqa_flat_attention`; each
+  :func:`~mxnet_tpu.ops.ragged_attention.mla_flat_attention`,
+  :func:`~mxnet_tpu.ops.ragged_attention.gqa_flat_attention` or
+  :func:`~mxnet_tpu.ops.ragged_attention.gqa_window_attention`; each
   state-space layer advances its sequences' slot states through
   :func:`~mxnet_tpu.ops.ssd.ssd_state_step`, each GatedDeltaNet layer
   through :func:`~mxnet_tpu.ops.gdn.gdn_state_step`. A pure function of
@@ -36,7 +37,8 @@ import numpy as np
 from ...ops.ragged_attention import (ragged_flat_attention,
                                      ragged_flat_attention_sharded,
                                      mla_flat_attention,
-                                     gqa_flat_attention)
+                                     gqa_flat_attention,
+                                     gqa_window_attention)
 from ...ops.ssd import ssd_state_step, slot_runs
 from ...ops.gdn import gdn_state_step
 from ...ops.moe import (route_noaux_tc, route_softmax_topk, moe_experts,
@@ -48,19 +50,22 @@ from ...ops.quantization import quantized_matmul
 
 __all__ = ["DecoderConfig", "TinyDecoder", "greedy_decode_reference",
            "UnsupportedModelFeatureError", "GPT2", "DEEPSEEK_V3",
-           "GRANITE_HYBRID", "SDAR_MOE", "GIGACHAT3_5", "yarn_inv_freq",
-           "yarn_mscale"]
+           "GRANITE_HYBRID", "SDAR_MOE", "GIGACHAT3_5", "COHERE2_MOE",
+           "yarn_inv_freq", "yarn_mscale"]
 
 #: the blocks a :class:`DecoderConfig` can describe (``model_type``)
 GPT2, DEEPSEEK_V3 = "gpt2", "deepseek_v3"
 GRANITE_HYBRID = "granitemoehybrid"
 SDAR_MOE = "sdar_moe"
 GIGACHAT3_5 = "gigachat3_5"
+COHERE2_MOE = "cohere2_moe"
 #: how a block-diffusion model picks the masked positions a pass reveals
 REMASKING = ("sequential", "low_confidence_static",
              "low_confidence_dynamic")
 #: the kinds of layer a ``granitemoehybrid`` model's ``layer_types`` name
 MAMBA, ATTENTION = "mamba", "attention"
+#: the kinds of layer a ``cohere2_moe`` model's ``layer_types`` name
+SLIDING, FULL = "sliding_attention", "full_attention"
 
 
 class UnsupportedModelFeatureError(ValueError):
@@ -160,7 +165,24 @@ class DecoderConfig:
     ``swiglu_limit``. Of the routed experts the model HOLDS
     ``n_routed_experts``, those from ``first_routed_expert`` on (one
     chip's share under expert parallelism): a pair routed elsewhere adds
-    nothing here. The head is untied."""
+    nothing here. The head is untied.
+
+    ``model_type="cohere2_moe"`` (Command A+) is a sixth block, a PARALLEL
+    one: attention and the expert layer both read ``u = LN(x)`` (a
+    LayerNorm with a gain and no bias, ``layer_norm_eps``) and both are
+    added to the residual, ``x + attn(u) + experts(u)``. ``layer_types``
+    names each layer ``"sliding_attention"`` (GPT-J rotary positions on
+    interleaved pairs at ``rope_theta`` on queries and keys, and a window:
+    position ``p`` sees the keys ``j`` with ``p - sliding_window < j <=
+    p``) or ``"full_attention"`` (no positions of any kind, causal);
+    ``num_heads`` query heads over ``num_key_value_heads`` K/V heads of
+    ``head_dim``. The expert layer is the ``gigachat3_5`` block's held
+    share (``router_experts``, ``first_routed_expert``,
+    ``n_routed_experts``) under a sigmoid router WITHOUT a selection bias
+    (``num_experts_per_tok`` a token, renormalised if ``norm_topk_prob``),
+    plus the MEAN of ``num_shared_experts`` shared experts, every expert a
+    SwiGLU of width ``moe_intermediate_size``. The head is the embedding
+    (tied), the logits times ``logit_scale``."""
 
     FIELDS = ("vocab_size", "d_model", "num_layers", "num_heads",
               "d_ff", "max_context")
@@ -199,6 +221,13 @@ class DecoderConfig:
             "num_experts_per_tok", "n_shared_experts",
             "moe_intermediate_size", "routed_scaling_factor",
             "norm_topk_prob", "swiglu_limit", "torch_dtype"),
+        COHERE2_MOE: (
+            "model_type", "layer_types", "sliding_window",
+            "num_key_value_heads", "head_dim", "rope_theta",
+            "layer_norm_eps", "router_experts", "first_routed_expert",
+            "n_routed_experts", "num_experts_per_tok", "num_shared_experts",
+            "moe_intermediate_size", "norm_topk_prob", "logit_scale",
+            "torch_dtype"),
     }
     #: every block's own fields, each once
     BLOCK_FIELDS = tuple(dict.fromkeys(
@@ -226,7 +255,8 @@ class DecoderConfig:
                  linear_value_head_dim=0, linear_conv_kernel_dim=4,
                  linear_sigmoid_gate_scale=2.0, layernorm_gating_weight=2.0,
                  router_experts=0, first_routed_expert=0,
-                 swiglu_limit=None):
+                 swiglu_limit=None, sliding_window=0, layer_norm_eps=1e-5,
+                 num_shared_experts=0, logit_scale=1.0):
         self.vocab_size = int(vocab_size)
         self.d_model = int(d_model)
         self.num_layers = int(num_layers)
@@ -295,6 +325,14 @@ class DecoderConfig:
         self.first_routed_expert = int(first_routed_expert)
         self.swiglu_limit = None if swiglu_limit is None \
             else float(swiglu_limit)
+        self.sliding_window = int(sliding_window)
+        self.layer_norm_eps = float(layer_norm_eps)
+        self.num_shared_experts = int(num_shared_experts)
+        self.logit_scale = float(logit_scale)
+        if model_type == COHERE2_MOE:
+            self.head_dim = int(head_dim)
+            self._check_cohere2()
+            return
         if model_type == GIGACHAT3_5:
             self._check_gigachat()
             self.head_dim = self.qk_nope_head_dim + self.qk_rope_head_dim
@@ -438,6 +476,47 @@ class DecoderConfig:
                 f"first_routed_expert {self.first_routed_expert} of "
                 f"router_experts {self.router_experts}, "
                 f"num_experts_per_tok {self.num_experts_per_tok}")
+
+    def _check_cohere2(self):
+        """The ``cohere2_moe`` keys against one another."""
+        if self.head_dim < 2 or self.head_dim % 2:
+            raise ValueError("head_dim must be even and >= 2 for "
+                             f"{COHERE2_MOE}, got {self.head_dim}")
+        if self.num_key_value_heads < 1 \
+                or self.num_heads % self.num_key_value_heads:
+            raise ValueError(
+                f"num_heads {self.num_heads} over num_key_value_heads "
+                f"{self.num_key_value_heads}: the second must divide the "
+                "first")
+        if len(self.layer_types) != self.num_layers or any(
+                k not in (SLIDING, FULL) for k in self.layer_types):
+            raise ValueError(
+                f"layer_types names {len(self.layer_types)} layers of "
+                f"{sorted(set(self.layer_types))}; it takes "
+                f"{self.num_layers} of {SLIDING!r} or {FULL!r}")
+        if SLIDING in self.layer_types and self.sliding_window < 1:
+            raise ValueError("a sliding_attention layer needs "
+                             f"sliding_window >= 1, got {self.sliding_window}")
+        if not (1 <= self.num_experts_per_tok <= self.router_experts) \
+                or self.n_routed_experts < 1 \
+                or self.moe_intermediate_size < 1 \
+                or self.num_shared_experts < 0 or not (
+                    0 <= self.first_routed_expert <= self.router_experts
+                    - self.n_routed_experts):
+            raise ValueError(
+                f"n_routed_experts {self.n_routed_experts} held from "
+                f"first_routed_expert {self.first_routed_expert} of "
+                f"router_experts {self.router_experts}, "
+                f"num_experts_per_tok {self.num_experts_per_tok}, "
+                f"moe_intermediate_size {self.moe_intermediate_size}, "
+                f"num_shared_experts {self.num_shared_experts}")
+
+    @property
+    def window_layers(self):
+        """The sliding-window layers of a ``cohere2_moe`` model, by
+        index."""
+        return tuple(i for i, k in enumerate(self.layer_types)
+                     if k == SLIDING)
 
     @property
     def linear_layers(self):
@@ -665,11 +744,12 @@ class TinyDecoder:
     #: (``_forward_<name>``, ``_decode_flat_<name>``, ...), and
     #: ``_TAKES`` is what its step takes beside the packed batch
     _BLOCK = {GPT2: "gpt2", DEEPSEEK_V3: "latent", GRANITE_HYBRID: "hybrid",
-              SDAR_MOE: "blockdiff", GIGACHAT3_5: "gdn"}
+              SDAR_MOE: "blockdiff", GIGACHAT3_5: "gdn",
+              COHERE2_MOE: "window"}
     _TAKES = {GPT2: ("k_scales", "v_scales", "adapter", "axis_name",
                      "w_scales"),
               DEEPSEEK_V3: (), GRANITE_HYBRID: ("state",), SDAR_MOE: (),
-              GIGACHAT3_5: ("state",)}
+              GIGACHAT3_5: ("state",), COHERE2_MOE: ()}
     #: what each of those keywords stands for, when a block refuses it
     _STANDS_FOR = {"k_scales": "quantized pages",
                    "v_scales": "quantized pages",
@@ -689,6 +769,8 @@ class TinyDecoder:
                            "weight_dtype", "kv_dtype", "prefix_cache",
                            "sampling"),
                 GIGACHAT3_5: ("adapter_bank", "draft_model", "mesh",
+                              "weight_dtype", "kv_dtype", "prefix_cache"),
+                COHERE2_MOE: ("adapter_bank", "draft_model", "mesh",
                               "weight_dtype", "kv_dtype", "prefix_cache")}
 
     def __init__(self, config=None, **kw):
@@ -764,15 +846,27 @@ class TinyDecoder:
         "head_dim": D}`` (one pool of rows, a token's ``G`` value heads
         and then its ``G`` key heads: whole lane tiles at any head
         size). ``"layers"``, where fewer layers than the model has leave
-        pages. ``"state"``, where a sequence also holds a fixed state
-        that its tokens overwrite: ``{name: (layers, *shape of one
-        slot's)}`` float32, in the order the step takes and returns the
-        pools, after the pages."""
+        pages. ``"window_layers"`` (with ``"window"``, ``W``), where some
+        layers attend through a sliding window: a position of those
+        layers sees the ``W`` keys up to itself, so a sequence need hold
+        their pages from its window's first position on, while every
+        other layer keeps all of its pages. ``"state"``, where a sequence
+        also holds a fixed state that its tokens overwrite: ``{name:
+        (layers, *shape of one slot's)}`` float32, in the order the step
+        takes and returns the pools, after the pages."""
         c = self.config
         if c.model_type == SDAR_MOE:
             return {"kind": "kv_packed",
                     "num_heads": c.num_key_value_heads,
                     "head_dim": c.head_dim}
+        if c.model_type == COHERE2_MOE:
+            layout = {"kind": "kv_packed",
+                      "num_heads": c.num_key_value_heads,
+                      "head_dim": c.head_dim}
+            if c.window_layers:
+                layout.update(window_layers=list(c.window_layers),
+                              window=c.sliding_window)
+            return layout
         if c.model_type == GIGACHAT3_5:
             linear = len(c.linear_layers)
             layout = {"kind": "latent", "latent_dim": c.latent_dim,
@@ -2033,6 +2127,177 @@ class TinyDecoder:
                                c.layernorm_gating_weight), params["head"])
         return (logits, pages, gdn, conv, load) if self.expert_slots \
             else (logits, pages, gdn, conv)
+
+    # ------------------------------------- the cohere2_moe block --
+    def _init_params_window(self, w):
+        """The ``cohere2_moe`` tree: the leaves of
+        ``benchmark/reference/command_a_plus_decoder.py``, and its rule of
+        scale (``WEIGHT_RULE`` there says why each): every matrix by its
+        fan-in (the router and the experts too), ``wq`` and ``wk`` twice
+        that (scores spread by about 4 over the keys, so that which keys a
+        window leaves out shows), the embedding ``d ** -0.5`` (the head
+        is the embedding: logits of about unit spread, decided by the
+        layers, not by a token's own row); the LayerNorm gains 1 plus a
+        normal at 0.1."""
+        c = self.config
+        d, H, G, D = (c.d_model, c.num_heads, c.num_key_value_heads,
+                      c.head_dim)
+        E, m = c.n_routed_experts, c.moe_intermediate_size
+        sh = c.num_shared_experts * m
+        layers = []
+        for _ in range(c.num_layers):
+            lp = {"norm": 1.0 + w(d, scale=0.1),
+                  "wq": w(d, H * D, scale=2 * d ** -0.5),
+                  "wk": w(d, G * D, scale=2 * d ** -0.5),
+                  "wv": w(d, G * D), "wo": w(H * D, d),
+                  "router": w(d, c.router_experts),
+                  "experts_gate": w(E, d, m, scale=d ** -0.5),
+                  "experts_up": w(E, d, m, scale=d ** -0.5),
+                  "experts_down": w(E, m, d, scale=m ** -0.5)}
+            if sh:
+                lp.update(shared_gate=w(d, sh), shared_up=w(d, sh),
+                          shared_down=w(sh, d, scale=m ** -0.5))
+            layers.append(lp)
+        return self._published_dtype(
+            {"embed": w(c.vocab_size, d, scale=d ** -0.5),
+             "norm_f": 1.0 + w(d, scale=0.1), "layers": layers})
+
+    def _norm_window(self, x, g):
+        """LayerNorm with a gain and no bias, in float32."""
+        import jax
+        import jax.numpy as jnp
+        x = x.astype(jnp.float32)
+        x = x - x.mean(axis=-1, keepdims=True)
+        return x * jax.lax.rsqrt((x * x).mean(axis=-1, keepdims=True)
+                                 + self.config.layer_norm_eps) \
+            * g.astype(jnp.float32)
+
+    def _qkv_window(self, lp, u, positions, sliding):
+        """A layer's queries ``[..., H, D]``, keys ``[..., G, D]`` and
+        values ``[..., G x D]`` from normed rows ``u [..., d]``; on a
+        sliding layer queries and keys turned by their positions on
+        interleaved pairs (GPT-J), on a full layer not at all."""
+        c = self.config
+        H, G, D = c.num_heads, c.num_key_value_heads, c.head_dim
+        lead = u.shape[:-1]
+        q = _wmm(u, lp["wq"]).reshape(lead + (H, D))
+        k = _wmm(u, lp["wk"]).reshape(lead + (G, D))
+        if sliding:
+            freqs = c.rope_theta ** (-np.arange(D // 2, dtype=np.float32)
+                                     / (D // 2))
+            q = _rope_interleaved(q, positions, freqs)
+            k = _rope_interleaved(k, positions, freqs)
+        return q, k, _wmm(u, lp["wv"])
+
+    def _experts_window(self, lp, u, valid):
+        """The expert layer for normed rows ``u [T, d]`` (float32): ``(the
+        routed pairs held here plus the mean of the shared experts [T, d]
+        float32, the routing's load int32 [5])``: the sigmoid router over
+        all ``router_experts`` with no selection bias, the held experts'
+        pairs through ``ops.moe.moe_experts`` told their range (a pair
+        routed elsewhere adds nothing here)."""
+        c = self.config
+        sel, gates = route_noaux_tc(u, lp["router"], None,
+                                    c.num_experts_per_tok, c.norm_topk_prob)
+        wg = lp["experts_gate"]
+        out = moe_experts(u.astype(wg.dtype), sel, gates, valid, wg,
+                          lp["experts_up"], lp["experts_down"],
+                          first=c.first_routed_expert)
+        if c.num_shared_experts:
+            out = out + _swiglu(u, lp["shared_gate"], lp["shared_up"],
+                                lp["shared_down"]) / c.num_shared_experts
+        return out, moe_load(sel, valid, c.n_routed_experts,
+                             first=c.first_routed_expert)
+
+    def _head_window(self, params, h):
+        """Logits of the stream ``h [..., d]``: the final LayerNorm
+        against the embedding (tied), times ``logit_scale``."""
+        import jax.numpy as jnp
+        e = params["embed"]
+        x = self._norm_window(h, params["norm_f"]).astype(e.dtype)
+        return jnp.einsum("...d,vd->...v", x, e,
+                          preferred_element_type=jnp.float32) \
+            * self.config.logit_scale
+
+    def _forward_window(self, params, tokens):
+        """:meth:`forward` for the ``cohere2_moe`` block: dense grouped-query
+        attention under each layer's mask (the window's on a sliding
+        layer, causal on a full one), the expert layers through the op's
+        plain twin off the TPU. Returns ``(logits [B, T, V] float32, None,
+        None)``."""
+        import jax
+        import jax.numpy as jnp
+        c = self.config
+        B, T = tokens.shape
+        H, G, D = c.num_heads, c.num_key_value_heads, c.head_dim
+        pos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
+        live = jnp.ones((B * T,), bool)
+        t = jnp.arange(T)
+        causal = t[None, :] <= t[:, None]
+        h = params["embed"][tokens].astype(jnp.float32)
+        for kind, lp in zip(c.layer_types, params["layers"]):
+            sliding = kind == SLIDING
+            mask = jnp.logical_and(
+                causal, t[None, :] > t[:, None] - c.sliding_window) \
+                if sliding else causal
+            u = self._norm_window(h, lp["norm"])
+            q, k, v = self._qkv_window(lp, u, pos, sliding)
+            s = jnp.einsum("bqgmd,bkgd->bgmqk",
+                           q.reshape(B, T, G, H // G, D), k) * D ** -0.5
+            p = jax.nn.softmax(jnp.where(mask, s, -1e30), -1)
+            att = jnp.einsum("bgmqk,bkgd->bqgmd", p,
+                             v.reshape(B, T, G, D))
+            add, _ = self._experts_window(lp, u.reshape(B * T, -1), live)
+            h = h + _wmm(att.reshape(B, T, H * D), lp["wo"]) \
+                + add.reshape(B, T, c.d_model)
+        return self._head_window(params, h), None, None
+
+    def _decode_flat_window(self, params, tokens, positions, seq_ids,
+                            valid, pages, block_tables):
+        """:meth:`decode_flat` for the ``cohere2_moe`` block, the packed
+        batch the same. ``pages`` is ONE pool of packed rows ``[1, N, bs,
+        2 x G x D]`` (a token's value heads, then its key heads) whose
+        pages every layer draws on, and ``block_tables [L, S, MB]`` holds
+        each LAYER's table: layer ``l`` writes its tokens' rows through
+        ``block_tables[l]`` and attends through
+        :func:`~mxnet_tpu.ops.ragged_attention.gqa_window_attention`, a
+        sliding layer under its window (the engine gives the pages behind
+        a window back: its table names the null block there), a full
+        layer causally. Attention and the expert layer both read the
+        layer's one LayerNorm and are added to the stream together.
+        Weights are read in their own dtype with float32 accumulation;
+        norms, softmax, rotary positions, router scores, gates and the
+        residual stream are float32. Returns ``(logits [T, V] float32,
+        pages, load)``, ``load`` as the ``gigachat3_5`` block leaves it
+        (the held experts' four numbers, then the pairs routed
+        elsewhere)."""
+        import jax.numpy as jnp
+        c = self.config
+        T = tokens.shape[0]
+        bs = pages.shape[2]
+        H, G, D = c.num_heads, c.num_key_value_heads, c.head_dim
+        vmask = valid.astype(bool)
+        slot = jnp.where(vmask, positions % bs, 0)
+        load = jnp.zeros((5,), jnp.int32)
+        h = params["embed"][tokens].astype(jnp.float32)
+        for li, (kind, lp) in enumerate(zip(c.layer_types,
+                                            params["layers"])):
+            sliding = kind == SLIDING
+            tables = block_tables[li]
+            bidx = jnp.where(vmask, tables[seq_ids, positions // bs],
+                             0)                                 # null
+            u = self._norm_window(h, lp["norm"])
+            q, k, v = self._qkv_window(lp, u, positions, sliding)
+            row = jnp.concatenate([v, k.reshape(T, G * D)], axis=-1)
+            pages = pages.at[0, bidx, slot].set(row.astype(pages.dtype))
+            att = gqa_window_attention(
+                q, pages, tables, seq_ids, positions, D ** -0.5, G,
+                window=c.sliding_window if sliding else None, valid=vmask,
+                layer=0)
+            add, hit = self._experts_window(lp, u, vmask)
+            h = h + _wmm(att.reshape(T, H * D), lp["wo"]) + add
+            load = moe_load_sum(load, hit)
+        return self._head_window(params, h), pages, load
 
 
 def _block_last(positions, block_length):

@@ -53,7 +53,7 @@ class Sequence:
                  "prefill_started", "prefix_hashes",
                  "cache_hit_tokens", "adapter", "adapter_handle",
                  "blk_tokens", "blk_masked", "blk_passes", "blk_pass",
-                 "reveal_pass", "block_tail")
+                 "reveal_pass", "block_tail", "layer_blocks")
 
     def __init__(self, prompt_tokens, max_new_tokens, stop_token=None,
                  deadline=None, tenant=None, sampling=None,
@@ -72,6 +72,10 @@ class Sequence:
         self.state = WAITING
         self.slot = None
         self.block_ids = []       # KV blocks currently owned
+        # under a cache with window layers (PagedKVCache.windowed): each
+        # layer's pages by the sequence's block (kv_cache.LayerPages),
+        # which then hold what it owns (block_ids stays empty)
+        self.layer_blocks = None
         self.seq_len = 0          # tokens whose KV sits in the cache
         self.last_token = None    # next decode input
         self.t_submit = time.monotonic()
